@@ -19,10 +19,13 @@ def _state(strategy, seed=0):
     return state
 
 
-def test_save_and_restore_roundtrip(tmp_path):
+def test_save_and_restore_roundtrip(tmp_path, monkeypatch):
     strat = MultiWorkerMirroredStrategy()
     state = _state(strat)
-    mngr = CheckpointManager(str(tmp_path / "ckpt"), async_save=False)
+    # a RELATIVE directory, as `--working-dir out` passes it: orbax itself
+    # refuses one at the first save
+    monkeypatch.chdir(tmp_path)
+    mngr = CheckpointManager("ckpt", async_save=False)
     assert mngr.latest_step is None
     assert mngr.restore_latest(state) is None
 

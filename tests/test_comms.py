@@ -30,7 +30,6 @@ from tfde_tpu.training.step import (
     make_custom_train_step,
     make_train_step,
 )
-from tfde_tpu.utils import compat
 
 
 def _dp_mesh(n=4):
@@ -135,8 +134,8 @@ def _run_exchange(vecs, residuals, cfg, mesh):
         # keep per-device outputs visible: fake a leading device dim
         return out[None], new_r[None], ov[None]
 
-    f = compat.shard_map(
-        body, mesh,
+    f = jax.shard_map(
+        body, mesh=mesh,
         in_specs=(P("data"), P("data")),
         out_specs=(P("data"), P("data"), P("data")),
         check_vma=False,

@@ -315,7 +315,7 @@ def test_cifar_tarball_conversion(tmp_path):
 
 
 def test_device_prefetch_background_matches_inline():
-    """background=True (worker-thread device_put, the tunnel-overlap mode)
+    """background=True (worker-thread device_put, the link-overlap mode)
     must yield the same stream in the same order, and surface source
     errors in the consumer."""
     import jax

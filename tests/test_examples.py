@@ -12,7 +12,6 @@ import pytest
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 from examples import mnist_estimator, mnist_multiworker, mnist_tf2  # noqa: E402
-from tfde_tpu.utils import compat  # noqa: E402
 
 
 def test_multiworker_example_runs(tmp_path):
@@ -69,10 +68,6 @@ def test_cifar_resnet_example_smoke():
     assert int(jax.device_get(state.step)) == 2
 
 
-@pytest.mark.skipif(
-    not compat.supports_partial_manual(),
-    reason="3D pp x tp needs partial-auto shard_map, unsupported on this jax",
-)
 def test_gpt_lm_example_3d_smoke():
     """gpt_lm's 3D surface (--pipeline x --tensor) runs a couple of steps
     end-to-end on the fake mesh."""

@@ -12,7 +12,6 @@ import optax
 import pytest
 
 from tfde_tpu.data.pipeline import Dataset
-from tfde_tpu.utils import compat
 from tfde_tpu.models.gpt import gpt_tiny_test, next_token_loss
 from tfde_tpu.ops.losses import masked_lm_loss
 from tfde_tpu.training.lifecycle import Estimator, EvalSpec, RunConfig, TrainSpec
@@ -241,10 +240,6 @@ def test_partial_eval_batch_fails_with_named_cause(tmp_path):
         est.evaluate(ragged_input_fn, name="ragged")
 
 
-@pytest.mark.skipif(
-    not compat.supports_partial_manual(),
-    reason="partial-auto shard_map unsupported on this jax",
-)
 def test_pipelined_1f1b_estimator_lifecycle_and_resume(tmp_path):
     """The full Estimator machinery — checkpointing the pipe-sharded
     [S, L, ...] stage params via orbax, resume-by-default, throttled eval

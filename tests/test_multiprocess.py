@@ -746,9 +746,6 @@ _ELASTIC_CHILD = textwrap.dedent(
     from tfde_tpu.training.lifecycle import Estimator, RunConfig
 
     mode, model_dir, hb_path, opt_sharding = sys.argv[1:5]
-    # kill two full steps after the step-5 save: the async commit barrier
-    # needs both processes alive to finalize, and steps 6-7's collectives
-    # guarantee they were
     MAX_STEPS, SAVE_EVERY, KILL_AT = 12, 5, 10
     rng = np.random.default_rng(0)  # same arrays on every host
     X = rng.random((16, 784), np.float32)
@@ -783,6 +780,16 @@ _ELASTIC_CHILD = textwrap.dedent(
             while True:
                 n += 1
                 if world == 2 and rank == 1 and n == KILL_AT:
+                    # die only once the step-5 save is COMMITTED: its async
+                    # commit barrier needs both processes, and on a fast
+                    # host steps 6-9 finish before it does -- the survivor
+                    # would then wait forever on a barrier with the dead
+                    committed = os.path.join(model_dir, "checkpoints",
+                                             str(SAVE_EVERY))
+                    deadline = time.time() + 120
+                    while (not os.path.isdir(committed)
+                           and time.time() < deadline):
+                        time.sleep(0.05)
                     os.kill(os.getpid(), signal.SIGKILL)  # no teardown
                 if world == 2 and rank == 0 and n == KILL_AT:
                     # production detection channel, deterministic in-suite:

@@ -8,7 +8,6 @@ import numpy as np
 import pytest
 
 from tfde_tpu.parallel.pipeline import pipeline_apply, stack_stage_params
-from tfde_tpu.utils import compat
 from tfde_tpu.runtime.mesh import make_mesh
 
 
@@ -107,13 +106,6 @@ def test_pipeline_rejects_stage_count_mismatch(rng):
         )
 
 
-_partial_auto = pytest.mark.skipif(
-    not compat.supports_partial_manual(),
-    reason="partial-auto shard_map unsupported on this jax",
-)
-
-
-@_partial_auto
 @pytest.mark.parametrize("s,m", [(2, 4), (4, 8)])
 def test_pipeline_auto_mode_matches_sequential(rng, s, m):
     """mode='auto' (manual over 'pipe' only; data under the automatic
@@ -130,7 +122,6 @@ def test_pipeline_auto_mode_matches_sequential(rng, s, m):
                                rtol=2e-5, atol=2e-6)
 
 
-@_partial_auto
 def test_pipeline_auto_mode_gradients_match_manual(rng):
     mesh = _mesh({"data": 2, "pipe": 2})
     stages = stack_stage_params(_stages(rng, 2, 8))

@@ -18,12 +18,6 @@ from tfde_tpu.parallel.strategies import (
 )
 from tfde_tpu.runtime.mesh import make_mesh
 from tfde_tpu.training.step import init_state, make_custom_train_step
-from tfde_tpu.utils import compat
-
-_partial_auto = pytest.mark.skipif(
-    not compat.supports_partial_manual(),
-    reason="partial-auto shard_map unsupported on this jax",
-)
 
 
 @pytest.fixture(scope="module")
@@ -233,7 +227,6 @@ def test_pipelined_dropout_in_pipe(tokens):
     assert np.isfinite(float(m["loss"]))
 
 
-@_partial_auto
 def test_3d_dp_pp_tp_matches_dp(model, tokens):
     """3D parallelism (dp=2 x pipe=2 x tensor=2, 8 devices): stage weights
     shard over BOTH 'pipe' (stage dim) and 'tensor' (Megatron column/row
@@ -283,7 +276,6 @@ def test_tensor_without_pipe_rejected():
         strat.params_spec({"stages": {"w": jnp.zeros((1, 2, 4, 4))}})
 
 
-@_partial_auto
 def test_3d_with_dropout_trains(tokens):
     """3D mesh + dropout: auto-mode global masks, one finite training step
     through the last-stage-reduction loss."""
@@ -298,7 +290,6 @@ def test_3d_with_dropout_trains(tokens):
     assert np.isfinite(float(m["loss"]))
 
 
-@_partial_auto
 def test_3d_with_remat_dots_trains(tokens):
     """jax.checkpoint('dots' policy) inside the partial-manual pipe: one
     finite training step on the 3D mesh."""
@@ -313,7 +304,6 @@ def test_3d_with_remat_dots_trains(tokens):
     assert np.isfinite(float(m["loss"]))
 
 
-@_partial_auto
 def test_flash_refused_inside_partial_manual_pipe(tokens):
     """Explicit flash inside the partial-manual 3D pipe must error with
     guidance (the kernel's custom-VJP variance doesn't compose with a
@@ -344,7 +334,7 @@ def test_auto_dispatch_skips_flash_under_abstract_mesh(monkeypatch):
         (chosen.append("reference"), q)[1],
     )
     q = jnp.zeros((1, 4096, 1, 4), jnp.bfloat16)
-    abstract = compat.abstract_mesh((2,), ("data",))
+    abstract = jax.sharding.AbstractMesh((2,), ("data",))
     with axes_lib.use_axes(abstract):
         att.attention(q, q, q)
     assert chosen == ["reference"]

@@ -23,16 +23,31 @@ citations throughout the docstrings.
 
 __version__ = "0.1.0"
 
+import os as _os
+
 import jax as _jax
 
 # Sharding-invariant RNG is a framework invariant: params initialized under
 # an FSDP/TP sharding must equal the unsharded init, or "numerics identical
-# across strategies" dies at step 0. Newer jax defaults (or hardwires) this
-# on; older releases default it off — pin it. No-op where the flag is gone.
-try:
-    _jax.config.update("jax_threefry_partitionable", True)
-except AttributeError:
-    pass
+# across strategies" dies at step 0.
+_jax.config.update("jax_threefry_partitionable", True)
+
+
+def _place_compile_cache() -> None:
+    """Persistent XLA compile cache for every entry point (package import
+    is the one place they all pass). JAX_COMPILATION_CACHE_DIR, when set,
+    is read by jax itself and the package sets nothing; otherwise the cache
+    lives at <checkout>/.jax_cache. The directory is part of the cache key,
+    so it is derived from this file's location alone — the same checkout
+    always finds its own entries."""
+    if _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    root = _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__)))
+    _jax.config.update("jax_compilation_cache_dir",
+                       _os.path.join(root, ".jax_cache"))
+
+
+_place_compile_cache()
 
 # Surface TFDE_* typos (unregistered names in the environment) at import,
 # before any knob read silently runs a default the operator didn't ask for.

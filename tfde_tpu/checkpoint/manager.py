@@ -13,6 +13,7 @@ SaveV2/RestoreV2 + MonitoredTrainingSession analog).
 from __future__ import annotations
 
 import logging
+import os
 from typing import TYPE_CHECKING, Any, Optional
 
 import jax
@@ -21,6 +22,7 @@ import orbax.checkpoint as ocp
 from tfde_tpu.observability import metrics
 from tfde_tpu.observability.spans import span
 from tfde_tpu.resilience.policy import RetryPolicy, policy_from_env, retry_call
+from tfde_tpu.utils.fs import is_remote
 
 if TYPE_CHECKING:  # avoid the training<->checkpoint import cycle at runtime
     from tfde_tpu.training.train_state import TrainState
@@ -50,6 +52,9 @@ class CheckpointManager:
         async_save: bool = True,
         retry_policy: Optional[RetryPolicy] = None,
     ):
+        if not is_remote(directory):
+            # orbax refuses a relative path at the first save, not here
+            directory = os.path.abspath(directory)
         self._dir = directory
         self._retry = retry_policy or policy_from_env()
         options = ocp.CheckpointManagerOptions(

@@ -348,38 +348,16 @@ _register(
          "gated metric regressed past twice its slack so the gate must "
          "fail.",
          "tools/trendgate.py"),
-    # --- bench driver ------------------------------------------------------
+    # --- bench -------------------------------------------------------------
     Knob("TFDE_BENCH_", "spec", None, (),
-         "Bench driver family prefix (see members below).",
+         "Bench family prefix (see members below).",
          "bench.py", prefix=True),
-    Knob("TFDE_BENCH_BUDGET_S", "float", 1200.0, (),
-         "Total driver retry budget, seconds, across probes and attempts.",
-         "bench.py"),
-    Knob("TFDE_BENCH_ATTEMPT_TIMEOUT_S", "float", 900.0, (),
-         "Per-attempt wall-clock timeout, seconds, for one full bench run.",
-         "bench.py"),
-    Knob("TFDE_BENCH_PROBE_TIMEOUT_S", "float", 120.0, (),
-         "Hard timeout, seconds, on one backend-liveness probe subprocess "
-         "(a hung TPU runtime init must not eat the budget).",
-         "bench.py"),
-    Knob("TFDE_BENCH_MAX_PROBE_FAILS", "int", 3, (),
-         "Consecutive failed backend probes before the driver gives up "
-         "with a skip reason instead of burning the remaining budget.",
-         "bench.py"),
     Knob("TFDE_BENCH_ALLOW_CPU", "flag", False, (),
-         "Let the measurement run on CPU and say so in the artifact "
-         "(otherwise a CPU-only backend is an honest-zero skip).",
-         "bench.py"),
-    Knob("TFDE_BENCH_FORCE_CPU", "flag", False, (),
-         "Force JAX_PLATFORMS=cpu for the bench (implies ALLOW_CPU): the "
-         "smoke path of the driver and tier-1.",
+         "Let the measurement run on CPU and say so in the output "
+         "(otherwise a CPU backend is refused with a non-zero exit).",
          "bench.py"),
     Knob("TFDE_BENCH_SMOKE", "flag", False, (),
          "Tiny shapes, path validation only — numbers are not reportable.",
-         "bench.py"),
-    Knob("TFDE_BENCH_WATCH_OUT", "str", None, (),
-         "Artifact path for --watch mode's first-open-window capture "
-         "(default BENCH_builder_rNN.json next to bench.py).",
          "bench.py"),
 )
 

@@ -224,9 +224,7 @@ class PipelinedLM:
                 # partial-manual (auto) mode: non-pipe axes stay under the
                 # automatic partitioner — bind constraints to the abstract
                 # mesh so 'tensor'/'data' annotations apply inside the ring
-                from tfde_tpu.utils import compat as _compat
-
-                with axes_lib.use_axes(_compat.get_abstract_mesh()):
+                with axes_lib.use_axes(jax.sharding.get_abstract_mesh()):
                     h = block.apply({"params": lp}, h, None, train, **kwargs)
             else:
                 h = block.apply({"params": lp}, h, None, train, **kwargs)

@@ -158,13 +158,10 @@ def device_bytes(tree) -> int:
 
 
 def _cost_flops(compiled) -> float:
-    """`cost_analysis()` returns a dict on new JAX, a [dict] on 0.4.x."""
     try:
         cost = compiled.cost_analysis()
     except Exception:  # noqa: BLE001
         return 0.0
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
     try:
         return float(cost.get("flops", 0.0))
     except Exception:  # noqa: BLE001

@@ -35,8 +35,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from tfde_tpu.utils.compat import shard_map as _compat_shard_map
-
 from tfde_tpu.parallel import axes as axes_lib
 
 
@@ -184,16 +182,7 @@ def _seq_parallel_active() -> bool:
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
-
-
-def _have(module: str) -> bool:
-    import importlib.util
-
-    return importlib.util.find_spec(f"tfde_tpu.ops.{module}") is not None
+    return jax.default_backend() == "tpu"
 
 
 # impls whose kernels take scale/logit_cap natively. All three current
@@ -307,7 +296,7 @@ def attention(
         )
     if impl == "auto":
         flash_min_seq = _flash_min_seq(causal)
-        if _seq_parallel_active() and _have("ring_attention"):
+        if _seq_parallel_active():
             impl = "ring"
         elif (
             _on_tpu()
@@ -319,7 +308,6 @@ def attention(
             and q.shape[2] % k.shape[2] == 0
             and q.shape[1] % 128 == 0
             and mask is None
-            and _have("flash_attention")
             # inside a partial-manual pipeline region (AbstractMesh) the
             # kernel's custom-VJP variance doesn't compose with a nested
             # shard_map; the reference einsum partitions fine there
@@ -419,7 +407,7 @@ def _flash_sharded(q: jax.Array, k: jax.Array, v: jax.Array,
                                   interpret=interpret, scale=scale,
                                   logit_cap=logit_cap)
     spec = P(batch_axes if batch_axes else None, None, heads, None)
-    fn = _compat_shard_map(
+    fn = jax.shard_map(
         lambda q, k, v: fa.flash_attention(
             q, k, v, causal=causal, window=window, interpret=interpret,
             scale=scale, logit_cap=logit_cap

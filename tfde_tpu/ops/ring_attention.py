@@ -39,7 +39,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from tfde_tpu.utils.compat import shard_map as _compat_shard_map
 
 _NEG = -1e30  # finite -inf stand-in: keeps exp() NaN-free on fully-masked blocks
 
@@ -324,14 +323,14 @@ def ring_attention(
         def local2(q, k, v):
             return local(q, k, v, None)
 
-        fn = _compat_shard_map(
+        fn = jax.shard_map(
             local2, mesh=mesh,
             in_specs=(qkv_spec, qkv_spec, qkv_spec),
             out_specs=qkv_spec,
         )
         return fn(q, k, v)
 
-    fn = _compat_shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(qkv_spec, qkv_spec, qkv_spec, valid_spec),
         out_specs=qkv_spec,

@@ -22,8 +22,6 @@ from typing import Optional, Sequence, Union
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from tfde_tpu.utils import compat as _compat
-
 Axis = Union[str, Sequence[str], None]
 
 _state = threading.local()
@@ -84,9 +82,9 @@ def vary_over(x: jax.Array, axes: Sequence[str]) -> jax.Array:
     fori_loop/scan must match the loop body's variance, and psums demand
     their operands vary over the reduced axes. Shared by the pipeline's
     reductions and ring attention's accumulators."""
-    have = _compat.vma_of(x)
+    have = jax.typeof(x).vma
     missing = tuple(a for a in axes if a not in have)
-    return _compat.pcast(x, missing, to="varying") if missing else x
+    return jax.lax.pcast(x, missing, to="varying") if missing else x
 
 
 def batch_axes() -> tuple:

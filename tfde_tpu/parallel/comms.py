@@ -44,11 +44,9 @@ together with the step's scalars (loss/metrics/weights), so the whole
 exchange is a fixed five collectives regardless of model structure —
 tests/test_comms.py pins the count from the lowered HLO.
 
-Implemented with `utils/compat.shard_map` so the same code runs on old
-(check_rep/auto) and new (check_vma/axis_names) jax. The fp32 default is a
-true no-op: training/step.py does not even import this module's exchange
-into the traced program, and the jaxpr is bit-identical to the
-pre-compression step.
+The fp32 default is a true no-op: training/step.py does not even import
+this module's exchange into the traced program, and the jaxpr is
+bit-identical to the pre-compression step.
 """
 
 from __future__ import annotations

@@ -66,9 +66,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from tfde_tpu.utils import compat as _compat
-from tfde_tpu.utils.compat import shard_map as _compat_shard_map
-
 
 def stack_stage_params(stage_params: list) -> Any:
     """[per-stage pytree, ...] -> one pytree with leading stage dim."""
@@ -215,7 +212,7 @@ def pipeline_apply(
         # device-varying over the manual axes up front so the fori_loop
         # carry type stays stable once rank-dependent values mix in ('pipe'
         # always; plus the data axes in fully-manual mode)
-        state, outputs = _compat.pcast(
+        state, outputs = jax.lax.pcast(
             (
                 jnp.zeros(micro_shape, xs.dtype),
                 jnp.zeros((m,) + micro_shape, xs.dtype),
@@ -299,7 +296,7 @@ def pipeline_apply(
         )
 
     kwargs = {"axis_names": {axis}} if mode == "auto" else {}
-    fn = _compat_shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(p_spec, x_spec, aux_spec, extra_spec),
         out_specs=out_specs,
@@ -488,11 +485,11 @@ def pipeline_train_1f1b(
             # cotangent seed: 1 on grad_key, 0 elsewhere — with each leaf's
             # device-variance matched to the primal output it seeds
             seed = {
-                k: _compat.pcast(
+                k: jax.lax.pcast(
                     (jnp.ones if k == grad_key else jnp.zeros)(
                         v.shape, v.dtype
                     ),
-                    tuple(_compat.vma_of(v)),
+                    tuple(jax.typeof(v).vma),
                     to="varying",
                 )
                 for k, v in sums_i.items()
@@ -568,7 +565,7 @@ def pipeline_train_1f1b(
     sums_spec = jax.tree_util.tree_map(
         lambda v: P(*(None,) * v.ndim), sums_abstract
     )
-    fn = _compat_shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(p_spec, x_spec, aux_spec, extra_spec),
         out_specs=(sums_spec,

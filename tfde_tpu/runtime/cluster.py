@@ -243,11 +243,6 @@ def initialized() -> bool:
     return _INITIALIZED
 
 
-#: True when _initialize_resilient built the runtime client itself (with
-#: shutdown_on_destruction=False) — only then can an abandon-teardown
-#: safely drop the client object without its destructor entering the
-#: shutdown barrier
-_RESILIENT_CLIENT = False
 #: runtime clients/services abandoned by an elastic teardown — once a peer
 #: died, neither can be shut down or destroyed without terminating the
 #: survivor, so they are made immortal (permanent incref) and listed here
@@ -261,72 +256,7 @@ _ZOMBIE_CLIENTS: list = []
 #: which can actually survive it — the stock runtime's reaction to a dead
 #: peer is LOG(FATAL) in every process, the exact opposite of elastic
 #: training. ~12 days: effectively never, without integer-overflow risk.
-_HEARTBEAT_INTERVAL_S = 1_000
-_MAX_MISSING_HEARTBEATS = 1_000
-
-
-def _initialize_resilient(coord: str, info: "ClusterInfo",
-                          policy) -> bool:
-    """Build the jax.distributed runtime with survivor-safe options the
-    public `initialize()` does not expose: heartbeat windows long enough
-    that the coordination service never declares a peer dead (the default
-    reaction is process termination), and no graceful shutdown from the
-    client destructor — so an abandon-teardown after a peer death cannot
-    enter the doomed cluster-wide shutdown barrier. Returns False when
-    this jax version's internals don't match — the caller falls back to
-    the vanilla path."""
-    global _RESILIENT_CLIENT
-    try:
-        from jax._src import distributed as jdist
-        from jax._src.lib import xla_extension as xe
-
-        state = jdist.global_state
-        if state.client is not None:
-            return True  # already up (re-entrant bootstrap)
-
-        def build_and_connect():
-            if info.process_id == 0 and state.service is None:
-                bind = "[::]:" + coord.rsplit(":", 1)[1]
-                state.service = xe.get_distributed_runtime_service(
-                    bind, info.num_processes,
-                    heartbeat_interval=_HEARTBEAT_INTERVAL_S,
-                    max_missing_heartbeats=_MAX_MISSING_HEARTBEATS)
-            client = xe.get_distributed_runtime_client(
-                coord, info.process_id,
-                heartbeat_interval=_HEARTBEAT_INTERVAL_S,
-                max_missing_heartbeats=_MAX_MISSING_HEARTBEATS,
-                shutdown_on_destruction=False,
-                use_compression=True,
-            )
-            try:
-                client.connect()
-            except Exception:
-                del client  # partial state must not leak into the retry
-                raise
-            state.client = client
-            state.coordinator_address = coord
-            state.process_id = info.process_id
-            state.num_processes = info.num_processes
-            if state.preemption_sync_manager is None:
-                state.initialize_preemption_sync_manager()
-
-        from tfde_tpu.resilience.policy import retry_call
-
-        retry_call(
-            build_and_connect,
-            policy=policy,
-            what="distributed runtime connect",
-            counter="resilience/bootstrap_retries",
-        )
-        _RESILIENT_CLIENT = True
-        return True
-    except (ImportError, AttributeError, TypeError):
-        # jax moved the internals: vanilla initialize still works, minus
-        # the survive-a-dead-peer teardown
-        log.warning("resilient distributed-runtime construction unavailable "
-                    "on this jax; falling back to jax.distributed.initialize",
-                    exc_info=True)
-        return False
+_HEARTBEAT_TIMEOUT_S = 1_000_000
 
 
 def shutdown(abandon: bool = False) -> None:
@@ -349,15 +279,17 @@ def shutdown(abandon: bool = False) -> None:
     disowned, their threads quiescent under the long heartbeat window —
     and the re-bootstrap moves to a fresh coordination port (see
     elastic.shrink_env) instead of re-binding the abandoned one."""
-    global _INITIALIZED, _RESILIENT_CLIENT
+    global _INITIALIZED
     if not _INITIALIZED:
         return
     import jax
 
     if abandon:
-        try:
-            from jax._src import distributed as jdist
+        # jax has no public handle on the runtime's client and service; a
+        # jax that moves them fails this import loudly, not into a fallback
+        from jax._src import distributed as jdist
 
+        try:
             state = jdist.global_state
             client, service = state.client, state.service
             state.client = None
@@ -398,7 +330,6 @@ def shutdown(abandon: bool = False) -> None:
             log.warning("jax.distributed.shutdown failed (continuing "
                         "teardown)", exc_info=True)
     _INITIALIZED = False
-    _RESILIENT_CLIENT = False
     from tfde_tpu.observability import flightrec
 
     flightrec.record("distributed_shutdown", abandoned=bool(abandon))
@@ -419,30 +350,12 @@ def bootstrap(coordinator_port: int = 8476, force: bool = False) -> ClusterInfo:
     if force:
         shutdown()
     info = resolve_cluster()
-    if not info.is_distributed:
-        # a world that shrank to one process must build its next CPU
-        # backend WITHOUT cross-process collectives (the gloo impl set on
-        # the way up would demand the distributed client we abandoned)
-        import jax
-
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "none")
-        except (AttributeError, ValueError):
-            pass
     if info.is_distributed and not _INITIALIZED:
         import jax
 
         coord = info.coordinator_address
         if coord:
             coord = coordinator_endpoint(coord, coordinator_port)
-        # Multi-process over the CPU backend (tests, local rehearsal of a
-        # pod topology) needs a cross-process collectives impl; older jax
-        # ships gloo behind a config knob that newer jax dropped. Harmless
-        # for TPU — the option only touches the CPU client.
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except (AttributeError, ValueError):
-            pass
         log.info(
             "jax.distributed.initialize(coordinator=%s, num_processes=%d, process_id=%d)",
             coord, info.num_processes, info.process_id,
@@ -460,19 +373,16 @@ def bootstrap(coordinator_port: int = 8476, force: bool = False) -> ClusterInfo:
         policy = _dc.replace(
             base, retryable=tuple(base.retryable) + (RuntimeError,)
         )
-        # survivor-safe construction first (long heartbeat window + an
-        # abandonable client — the elastic teardown depends on both);
-        # vanilla initialize only when jax's internals moved
-        if not (coord and _initialize_resilient(coord, info, policy)):
-            retry_call(
-                jax.distributed.initialize,
-                coordinator_address=coord,
-                num_processes=info.num_processes,
-                process_id=info.process_id,
-                policy=policy,
-                what="jax.distributed.initialize",
-                counter="resilience/bootstrap_retries",
-            )
+        retry_call(
+            jax.distributed.initialize,
+            coordinator_address=coord,
+            num_processes=info.num_processes,
+            process_id=info.process_id,
+            heartbeat_timeout_seconds=_HEARTBEAT_TIMEOUT_S,
+            policy=policy,
+            what="jax.distributed.initialize",
+            counter="resilience/bootstrap_retries",
+        )
         _INITIALIZED = True
         from tfde_tpu.observability import flightrec
 

@@ -29,7 +29,6 @@ from tfde_tpu.parallel import comms as comms_lib
 from tfde_tpu.parallel import zero as zero_lib
 from tfde_tpu.parallel.strategies import Strategy
 from tfde_tpu.training.train_state import TrainState
-from tfde_tpu.utils import compat
 
 log = logging.getLogger(__name__)
 
@@ -609,9 +608,9 @@ def _make_comms_step(strategy: Strategy, state: TrainState, loss_fn,
             lambda l: P(axis, *(None,) * (l.ndim - 1)), batch
         )
         if zlay is None:
-            exchanged = compat.shard_map(
+            exchanged = jax.shard_map(
                 lambda s, p, bs, r, b, k: body(s, p, bs, (), r, b, k),
-                mesh,
+                mesh=mesh,
                 in_specs=(P(), P(), P(), P(), batch_specs, P()),
                 out_specs=P(),
                 check_vma=False,  # the residual is deliberately device-varying
@@ -633,8 +632,8 @@ def _make_comms_step(strategy: Strategy, state: TrainState, loss_fn,
         # sharded update: params/opt emerge from the shard_map already
         # final — no apply_gradients outside (the update ran on-chunk)
         opt_specs = zero_lib.opt_state_spec(state.opt_state, axis, nshards)
-        outs = compat.shard_map(
-            body, mesh,
+        outs = jax.shard_map(
+            body, mesh=mesh,
             in_specs=(P(), P(), P(), opt_specs, P(), batch_specs, P()),
             out_specs=(P(), opt_specs, P(), P(), P(), P(), P(), P(), P()),
             check_vma=False,  # the residual is deliberately device-varying

@@ -1,36 +1,15 @@
-"""Virtual CPU device setup, portable across JAX versions.
-
-Newer JAX exposes `jax_num_cpu_devices` as a config option; older releases
-only honor the `--xla_force_host_platform_device_count` XLA flag, and pass
-unknown *config* names straight to AttributeError. Call
-`request_cpu_devices(n)` before the first computation (before the CPU
-backend is instantiated) and it picks whichever mechanism this JAX has.
-"""
+"""Virtual CPU device setup (the CPU test method: N devices on one host)."""
 
 from __future__ import annotations
-
-import os
-
-_FLAG = "--xla_force_host_platform_device_count"
 
 
 def request_cpu_devices(n: int) -> None:
     """Ask for `n` virtual CPU devices on the host platform.
 
     Must run before the JAX backend initializes (i.e. before the first
-    device/computation touch; importing jax is fine). No-op if the backend
-    is already up — JAX itself raises in that case for the config path,
-    and the env var is simply never re-read.
+    device/computation touch; importing jax is fine) — jax raises if the
+    backend is already up.
     """
     import jax
 
-    try:
-        jax.config.update("jax_num_cpu_devices", int(n))
-        return
-    except AttributeError:
-        pass  # older jax: config option absent -> use the XLA flag
-    # The caller asked for exactly n: override any inherited flag value (a
-    # parent test process's XLA_FLAGS leaks into subprocesses).
-    cur = os.environ.get("XLA_FLAGS", "")
-    kept = [t for t in cur.split() if not t.startswith(f"{_FLAG}=")]
-    os.environ["XLA_FLAGS"] = " ".join(kept + [f"{_FLAG}={int(n)}"])
+    jax.config.update("jax_num_cpu_devices", int(n))

@@ -41,7 +41,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import _Clock, chip_peak_flops
+from bench import (
+    _Clock, device_peak_flops, exit_if_errors, peak_tflops_field,
+)
 from tfde_tpu.ops import roofline as rl
 from tfde_tpu.ops.flash_attention import flash_attention, bwd_tile_plan
 
@@ -125,12 +127,12 @@ def main():
 
     dev = jax.devices()[0]
     interpret = dev.platform == "cpu"
-    peak, peak_known = chip_peak_flops(getattr(dev, "device_kind", ""))
+    peak = device_peak_flops(dev)
     clock = _Clock()
     out = {
         "platform": dev.platform,
-        "chip_peak_tflops": round(peak / 1e12, 1),
-        "chip_peak_known": peak_known,
+        "device_kind": dev.device_kind,
+        "chip_peak_tflops": peak_tflops_field(peak),
     }
     for name, seq, causal, window, cap in OPS:
         b, s, h, d = (1, 512, 2, 64) if args.smoke else (1, seq, 12, 64)
@@ -143,6 +145,7 @@ def main():
             out[f"{name}_error"] = f"{type(e).__name__}: {e}"[:200]
         print(json.dumps(out), flush=True)
     print(json.dumps(out))
+    exit_if_errors(out, "roofline")
 
 
 if __name__ == "__main__":

@@ -130,6 +130,9 @@
 # without diffing logs by hand.
 set -o pipefail
 cd "$(dirname "$0")/.." || exit 1
+# no persistent compile cache under the gates: memgate and the suite pin
+# compile counts and seconds, which a cache hit would change
+export JAX_ENABLE_COMPILATION_CACHE=false
 
 rm -f /tmp/_t1.log
 # 30 min: the suite has grown a subsystem per PR — PR 10's memwatch

@@ -12,7 +12,7 @@ looking numbers but no calibration anchor. This gate reads them ALL, in
 round order, and sorts each into:
 
 - **comparable**: parses, has no error, ``platform == "tpu"`` and a
-  calibration anchor at >= 0.8 of chip peak (the BASELINE.md trust rule
+  calibration anchor at >= 0.8 of chip peak (the trust rule
   — a capture that cannot vouch for its own clock cannot vouch for a
   trend either);
 - **skipped-with-reason**: everything else, listed in TREND.md so a
