@@ -56,3 +56,23 @@ def test_chip_smoke_refuses_cpu():
     assert proc.returncode != 0
     assert proc.stdout.strip() == ""
     assert "platform='cpu'" in proc.stderr
+
+
+def test_chip_smoke_result_line_has_exactly_the_agreed_keys():
+    """The last line of stdout is parsed by whoever runs the check: `ok`
+    and `device` {platform, kind, count} and nothing else (the run's other
+    facts go on the `[summary]` line before it)."""
+    import jax
+
+    sys.path.insert(0, ROOT)
+    try:
+        import chip_smoke
+    finally:
+        sys.path.remove(ROOT)
+    devices = jax.devices()
+    for ok in (True, False):
+        line = chip_smoke.result_line(ok, devices)
+        assert "\n" not in line
+        assert json.loads(line) == {"ok": ok, "device": {
+            "platform": devices[0].platform,
+            "kind": devices[0].device_kind, "count": len(devices)}}
