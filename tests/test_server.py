@@ -766,3 +766,226 @@ def test_kv_headroom_default_off_admits_identically(lm, rng, monkeypatch):
     for rid in rids:
         np.testing.assert_array_equal(done[rid],
                                       _solo(model, params, p, 4))
+
+
+# -- the step's own account (stats()'s ledger, spans.span) --------------------
+def _ledger_run(srv, rng, waves=2):
+    """Drive `srv` through staggered waves; stats() after every step."""
+    shots = [srv.stats()]
+    for _ in range(waves):
+        for plen, n in [(3, 9), (5, 4), (2, 12), (7, 7), (4, 1), (6, 10)]:
+            srv.submit(rng.integers(0, 97, plen).astype(np.int64), n)
+        while not srv.idle:
+            srv.step()
+            shots.append(srv.stats())
+    return shots
+
+
+@pytest.fixture(scope="module")
+def ledger_run(lm):
+    from tfde_tpu.observability import metrics
+
+    model, params = lm
+    srv = ContinuousBatcher(model, params, kv_quant="fp", batch_size=3,
+                            max_len=48, scan_depth=4)
+    hist = metrics.default_registry().histogram("serving/queue_wait_ms")
+    before = (hist.sum, hist.count)
+    shots = _ledger_run(srv, np.random.default_rng(5))
+    return {"shots": shots, "last": shots[-1],
+            "queue_wait_ms": (hist.sum - before[0], hist.count - before[1])}
+
+
+def _ledger_case_ints_never_fall(run):
+    from tfde_tpu.inference.server import _PHASE_KEYS
+
+    for prev, cur in zip(run["shots"], run["shots"][1:]):
+        for k in _PHASE_KEYS:
+            assert type(cur[k]) is int, (k, type(cur[k]))
+            assert cur[k] >= prev[k], k
+    assert all(run["last"][k] > 0 for k in _PHASE_KEYS)
+
+
+def _ledger_case_a_step_is_admit_decode_emit(run):
+    s = run["last"]
+    parts = s["admit_ns"] + s["decode_ns"] + s["emit_ns"]
+    assert parts <= s["step_ns"]
+    assert parts >= 0.98 * s["step_ns"], parts / s["step_ns"]
+    assert s["steps"] == len(run["shots"]) - 1
+
+
+def _ledger_case_children_fit_their_parents(run):
+    s = run["last"]
+    assert (s["prefill_pack_ns"] + s["prefill_template_ns"]
+            + s["prefill_run_ns"] + s["prefill_scatter_ns"]
+            ) <= s["prefill_ns"] <= s["admit_ns"]
+    assert s["decode_upload_ns"] + s["decode_dispatch_ns"] <= s["decode_ns"]
+    assert s["device_wait_ns"] <= s["prefill_ns"] + s["decode_ns"]
+    assert s["uploads"] <= s["scans"] <= s["steps"]
+
+
+def _ledger_case_rows_and_cells(run):
+    s = run["last"]
+    # the real rows of the waves are the requests admitted
+    assert s["admitted"] == 12 <= s["prefill_rows_padded"]
+    # every prompt of the run, and each padded to the 8 bucket
+    assert s["prefill_tokens"] == 2 * (3 + 5 + 2 + 7 + 4 + 6)
+    assert s["prefill_cells"] == 8 * s["prefill_rows_padded"]
+    assert s["prefill_tokens"] <= s["prefill_cells"]
+
+
+def _ledger_case_queue_wait_agrees_with_the_histogram(run):
+    total_ms, count = run["queue_wait_ms"]
+    s = run["last"]
+    assert count == s["admitted"]
+    assert s["queue_wait_ns"] / s["admitted"] / 1e6 == pytest.approx(
+        total_ms / count, rel=1e-6)
+
+
+def _ledger_case_first_tokens_are_held_for_the_round(run):
+    s = run["last"]
+    # a first token waits out the rest of its step: less than the steps
+    # themselves, more than nothing
+    assert 0 < s["first_token_hold_ns"] <= s["admitted"] * s["step_ns"]
+    assert s["first_token_hold_ns"] / s["admitted"] <= (
+        s["step_ns"] - s["admit_ns"] + s["prefill_ns"])
+
+
+@pytest.mark.parametrize("case", [
+    _ledger_case_ints_never_fall,
+    _ledger_case_a_step_is_admit_decode_emit,
+    _ledger_case_children_fit_their_parents,
+    _ledger_case_rows_and_cells,
+    _ledger_case_queue_wait_agrees_with_the_histogram,
+    _ledger_case_first_tokens_are_held_for_the_round,
+], ids=lambda f: f.__name__[len("_ledger_case_"):])
+def test_step_ledger(ledger_run, case):
+    case(ledger_run)
+
+
+def test_decode_least_bytes_is_the_hand_count(lm):
+    """Two rows, one scan: depth x (the parameters + the committed cells
+    of both rows), every size from the toy model's shapes."""
+    model, params = lm
+    srv = ContinuousBatcher(model, params, kv_quant="fp", batch_size=2,
+                            max_len=32, scan_depth=4)
+    srv.submit(np.arange(1, 4), 3)
+    srv.submit(np.arange(1, 6), 3)
+    srv.run()
+    s = srv.stats()
+    # fp32 GPT: 97x32 + 64x32 embeddings; per block 2 LN (2x32 each), qkv
+    # and out projections (32x32+32 each), MLP 32x64+64 and 64x32+32; a
+    # final LN; the head is tied
+    block = 2 * 64 + 4 * (32 * 32 + 32) + 32 * 64 + 64 + 64 * 32 + 32
+    param_bytes = 4 * (97 * 32 + 64 * 32 + 2 * block + 64)
+    assert param_bytes == sum(
+        a.size * 4 for a in jax.tree_util.tree_leaves(params))
+    # a cell: K and V of 4 heads x 8 over 2 layers, fp32
+    cell_bytes = 2 * 2 * 4 * 8 * 4
+    # both first tokens come with the prefill; the two that remain take one
+    # scan of depth 2, started with 3 and 5 cells committed
+    assert (s["scans"], s["rounds"]) == (1, 2)
+    assert s["decode_least_bytes"] == 2 * (param_bytes
+                                           + (3 + 5) * cell_bytes)
+
+
+class _Annotations:
+    """Stands where spans.py holds jax: counts TraceAnnotations and keeps
+    the order they open and close in."""
+
+    def __init__(self):
+        self.log = []
+        self.profiler = self
+
+    def TraceAnnotation(self, name):
+        outer = self
+
+        class _One:
+            def __enter__(self):
+                outer.log.append(("open", name))
+
+            def __exit__(self, *exc):
+                outer.log.append(("close", name))
+
+        return _One()
+
+
+@pytest.mark.parametrize("active", [False, True])
+def test_leaf_spans_open_inside_their_parents(lm, monkeypatch, active):
+    from tfde_tpu.observability import spans
+
+    model, params = lm
+    srv = ContinuousBatcher(model, params, kv_quant="fp", batch_size=2,
+                            max_len=32, scan_depth=2)
+    stand_in = _Annotations()
+    monkeypatch.setattr(spans, "_jax", stand_in)
+    monkeypatch.setattr(spans, "_trace_active", active)
+    srv.submit(np.arange(1, 4), 4)
+    srv.step()
+    if not active:
+        assert stand_in.log == []
+        return
+    opened = [name for what, name in stand_in.log if what == "open"]
+    assert opened == [
+        "serving/step", "serving/admit", "serving/prefill",
+        "serving/prefill/pack", "serving/prefill/template",
+        "serving/prefill/run", "serving/prefill/scatter",
+        "serving/prefill/fetch", "serving/decode", "serving/decode/upload",
+        "serving/decode/scan", "serving/decode/fetch", "serving/emit"]
+    parent_of = {"serving/prefill": "serving/admit"}
+    stack = []
+    for what, name in stand_in.log:
+        if what == "close":
+            assert stack.pop() == name
+            continue
+        want = parent_of.get(name) or (
+            name.rsplit("/", 1)[0] if name.count("/") == 2
+            else "serving/step" if name != "serving/step" else None)
+        assert (stack[-1] if stack else None) == want, (name, stack)
+        stack.append(name)
+    assert stack == []
+
+
+def test_ring_events_name_the_span_that_caused_them(lm):
+    from tfde_tpu.observability import trace
+
+    model, params = lm
+    srv = ContinuousBatcher(model, params, kv_quant="fp", batch_size=2,
+                            max_len=32, scan_depth=2)
+    was = trace.active()
+    trace.enable()
+    try:
+        trace.clear()
+        srv.submit(np.arange(1, 4), 4)
+        srv.step()
+        parents = {e["name"]: e.get("parent") for e in trace.events()
+                   if e["name"].startswith("serving/")}
+    finally:
+        if not was:
+            trace.disable()
+    assert parents["serving/step"] is None
+    assert parents["serving/prefill"] == "serving/admit"
+    assert parents["serving/prefill/template"] == "serving/prefill"
+    assert parents["serving/decode/upload"] == "serving/decode"
+    assert parents["serving/emit"] == "serving/step"
+
+
+def test_speculative_batcher_keeps_the_same_ledger(lm, draft, rng):
+    from tfde_tpu.inference.server import (
+        _PHASE_KEYS, SpeculativeContinuousBatcher,
+    )
+
+    model, params = lm
+    dmodel, dparams = draft
+    srv = SpeculativeContinuousBatcher(model, dmodel, params, dparams,
+                                       batch_size=2, max_len=40, num_draft=2)
+    shots = _ledger_run(srv, rng, waves=1)
+    s = shots[-1]
+    plain = ContinuousBatcher(model, params, batch_size=2, max_len=40)
+    assert set(_PHASE_KEYS) <= set(s)
+    assert {k for k in s if type(s[k]) is int} >= {
+        k for k, v in plain.stats().items() if type(v) is int}
+    assert all(type(s[k]) is int for k in _PHASE_KEYS)
+    parts = s["admit_ns"] + s["decode_ns"] + s["emit_ns"]
+    assert 0.98 * s["step_ns"] <= parts <= s["step_ns"]
+    assert s["scans"] == s["uploads"] == s["rounds"] > 0
+    assert s["decode_least_bytes"] > 0 and s["device_wait_ns"] > 0

@@ -63,7 +63,6 @@ import collections
 import dataclasses
 import functools
 import itertools
-import time
 from typing import Optional
 
 import jax
@@ -89,11 +88,13 @@ from tfde_tpu.inference.speculative import _set_index_counters
 from tfde_tpu.analysis import hlolint as _hlolint
 from tfde_tpu.observability import boot as _boot
 from tfde_tpu.observability import capacity as _capacity
+from tfde_tpu.observability import flightrec
 from tfde_tpu.observability import memwatch as _memwatch
 from tfde_tpu.observability import metrics
 from tfde_tpu.observability import recompile as _recompile
 from tfde_tpu.observability import trace as _trace
-from tfde_tpu.observability.spans import span
+from tfde_tpu.observability.spans import now_ns, span
+from tfde_tpu.utils.summary import _count as _count_params
 
 #: per-batcher fingerprint tag: distinct batcher instances hold distinct
 #: static model objects, so the SAME (kind, key, wave) signature compiles
@@ -101,6 +102,31 @@ from tfde_tpu.observability.spans import span
 #: this tag so a second batcher's first wave reads as a novel compile,
 #: not as an unexpected recompile of the first batcher's site
 _BATCHER_TAGS = itertools.count()
+
+
+#: The batcher's own account of its step, all integers and cumulative:
+#: every `*_ns` key is nanoseconds on `spans.now_ns`, added by the span at
+#: that boundary; the others count what the boundary handled. `stats()`
+#: returns them beside the counts it always had.
+_PHASE_KEYS = (
+    # spans (key of the time, key of the count)
+    "step_ns", "steps",                  # serving/step: all of step()
+    "admit_ns",                          # serving/admit: _admit()
+    "prefill_ns", "prefill_waves",       # serving/prefill: one group wave
+    "prefill_pack_ns",                   # .../pack: prompts packed on host
+    "prefill_template_ns",               # .../template: fresh zero rows
+    "prefill_run_ns",                    # .../run: operands + dispatch
+    "prefill_scatter_ns",                # .../scatter: rows into the slab
+    "device_wait_ns",                    # .../fetch of both: blocked on device
+    "decode_ns", "scans",                # serving/decode: repair..fetch
+    "decode_upload_ns", "uploads",       # .../upload: loop state to device
+    "decode_dispatch_ns",                # .../scan: dispatch of the scan
+    "emit_ns",                           # serving/emit: fetch's return..step's
+    # counters at the same boundaries
+    "admitted", "queue_wait_ns", "first_token_hold_ns",
+    "prefill_rows_padded", "prefill_tokens", "prefill_cells",
+    "decode_least_bytes",
+)
 
 
 def _fetch(tree):
@@ -144,6 +170,7 @@ def _decode_scan(model, cache, params, tok, idx, budget, done, seen, rng,
     used to pay on every step.
     """
 
+    @jax.named_scope("decode_tick")
     def body(carry, _):
         cache, tok, idx, budget, done, seen, rng = carry
         # index surgery each tick instead of trusting the model's own
@@ -218,10 +245,11 @@ def _prefill_rows(model, row_cache, params, prompts, last, valid, rng,
     None). Pad correctness rides the per-row index machinery: pad K/V
     lands beyond each row's committed count once the admission rewind
     sets it to the TRUE prompt length."""
-    logits, mutated = model.apply(
-        {"params": params, "cache": row_cache}, prompts, train=False,
-        mutable=["cache"],
-    )
+    with jax.named_scope("prefill_rows"):
+        logits, mutated = model.apply(
+            {"params": params, "cache": row_cache}, prompts, train=False,
+            mutable=["cache"],
+        )
     r = prompts.shape[0]
     ar = jnp.arange(r)
     logits = logits[ar, last].astype(jnp.float32)
@@ -580,9 +608,10 @@ class _BatcherBase:
         self._admission = (admission_ctl if admission_ctl is not None
                            else _admission.AdmissionController())
         self._priority: dict = {}       # rid -> priority class
-        self._deadline_at: dict = {}    # rid -> absolute TTFT deadline
         self._shed: set = set()         # rids deadline-shed at dequeue
-        self._submitted_at: dict = {}   # rid -> submit wall time (TTFT)
+        # times below are integer nanoseconds on spans.now_ns
+        self._deadline_at: dict = {}    # rid -> absolute TTFT deadline
+        self._submitted_at: dict = {}   # rid -> submit time (TTFT)
         self._first_at: dict = {}       # rid -> first-token time (TPOT)
         # rid -> request trace id; populated ONLY while the trace ring is
         # active AND the submitter handed one over, so the off path pays
@@ -593,6 +622,15 @@ class _BatcherBase:
         self._generated = 0      # every delivered token (incl. prefill 1st)
         self._dispatches = 0     # jitted-program / eager-op invocations
         self._syncs = 0          # blocking device->host fetches
+        # the step's own account (_PHASE_KEYS): spans add the times, the
+        # boundaries add the counts; stats() returns it
+        self._phase = dict.fromkeys(_PHASE_KEYS, 0)
+        # what one decode tick cannot avoid reading of the parameters
+        self._param_bytes = _count_params(params)[1]
+        # first tokens fetched in this step and not yet handed back: how
+        # many, and the sum of the times they reached the host
+        self._held_n = 0
+        self._held_at_ns = 0
         # per-request incremental delivery (router/SSE): off by default —
         # run()/step() consumers read completions, not partials, and an
         # unread stream entry would leak
@@ -852,14 +890,14 @@ class _BatcherBase:
         self._next_id += 1
         self._queue.append((rid, prompt, budget, primed), priority=priority)
         _boot.note_first_admit()
-        now = time.perf_counter()
+        now = now_ns()
         self._submitted_at[rid] = now
         self._priority[rid] = priority
         self._usage.begin(rid, int(prompt.size), priority)
         dl = (float(ttft_deadline_ms) if ttft_deadline_ms is not None
               else self._admission.ttft_deadline_ms)
         if dl and dl > 0:
-            self._deadline_at[rid] = now + dl / 1e3
+            self._deadline_at[rid] = now + int(dl * 1e6)
         if self._track_progress:
             self._stream[rid] = {"tokens": [], "taken": 0, "done": False}
         if trace is not None and _trace.active():
@@ -899,6 +937,71 @@ class _BatcherBase:
         # occupancy + headroom ride every stats publication (including
         # idle steps), so the kv/* gauges track the slab per round
         self.kv_stats()
+
+    # -- the step and its account -------------------------------------------
+    def _span(self, name: str, ns: str, n: Optional[str] = None,
+              histogram: bool = False) -> span:
+        """A span that adds its time to this batcher's ledger under `ns`
+        (and counts itself under `n`): the one timer the serving loop
+        has. Leaf spans keep out of the registry's histograms."""
+        return span(name, ledger=self._phase, ns=ns, n=n,
+                    histogram=histogram)
+
+    def step(self) -> list:
+        """Admit into free rows, then run one decode round for the whole
+        batch (`_round`: a fused scan of up to `scan_depth` ticks, or one
+        speculative round); returns [(request_id, tokens 1-D np.int32),
+        ...] that finished now."""
+        with self._span("serving/step", "step_ns", "steps") as whole:
+            with self._span("serving/admit", "admit_ns", histogram=True):
+                finished = self._admit()
+            active = [r for r in range(self._b) if self._req[r] is not None]
+            if active:
+                self._round(active, finished)
+            else:
+                with self._span("serving/emit", "emit_ns"):
+                    self._publish_stats()
+        if self._held_n:
+            # a streaming client sees a first token when the step that
+            # fetched it returns: the decode round it waits out
+            self._phase["first_token_hold_ns"] += (
+                self._held_n * whole.t1_ns - self._held_at_ns)
+            self._held_n = self._held_at_ns = 0
+        return finished
+
+    def _round(self, active: list, finished: list) -> None:
+        """One decode round over the `active` rows inside a
+        `serving/decode` span, then their tokens taken inside a
+        `serving/emit` span that ends with `_publish_stats`; completions
+        are appended to `finished`."""
+        raise NotImplementedError
+
+    def _close_round(self, decode: span, traced: list, depth: int,
+                     rows: int, n_emitted: int) -> None:
+        """What a round reports once its tokens are taken: the time from
+        the decode span's start to now, before `_publish_stats`."""
+        dt = (now_ns() - decode.t0_ns) * 1e-9
+        if traced:
+            _trace.event("serve/decode_round", traces=traced, dur=dt,
+                         depth=depth, rows=rows, emitted=n_emitted)
+        if n_emitted:
+            metrics.default_registry().histogram(
+                "serving/ms_per_token"
+            ).observe(dt * 1e3 / n_emitted)
+            self._admission.note_drain(n_emitted, dt)
+
+    def _fetch_first(self, tok) -> np.ndarray:
+        """A wave's one blocking fetch: its rows' first tokens."""
+        with self._span("serving/prefill/fetch", "device_wait_ns"):
+            tok_np = _fetch(tok)
+        self._syncs += 1
+        return tok_np
+
+    def _kv_read_bytes(self, active: list) -> int:
+        """Bytes of the cells the `active` rows have committed: what one
+        decode tick reads of the KV cache at the least, from shapes."""
+        cells = int(self._committed[active].sum())
+        return int(round(cells * self._ledger.cell_bytes))
 
     # -- hooks --------------------------------------------------------------
     def _validate_submit(self, prompt: np.ndarray,
@@ -967,7 +1070,7 @@ class _BatcherBase:
             if t1 is not None and n > 1:
                 # decode-side TPOT: first token -> last token, per decode
                 # step (the SLO layer's second latency axis)
-                tpot_ms = (time.perf_counter() - t1) * 1e3 / (n - 1)
+                tpot_ms = (now_ns() - t1) / 1e6 / (n - 1)
                 metrics.default_registry().histogram(
                     "serving/tpot_ms").observe(tpot_ms)
                 tid = self._trace_ids.get(rid)
@@ -1054,19 +1157,20 @@ class _BatcherBase:
     def _cold_wave(self, bucket: int, group, rows) -> np.ndarray:
         n = len(group)
         rp = _pad_wave(n, self._b)
-        prompts = np.full((rp, bucket), self._pad, np.int32)
-        last = np.zeros(rp, np.int32)
-        plens = np.zeros(rp, np.int32)
-        rows_pad = np.asarray(rows + [rows[0]] * (rp - n), np.int32)
-        for i in range(rp):
-            # wave padding repeats row 0's request verbatim: the
-            # duplicate prefill K/V is bit-identical (prefill is
-            # row-independent and deterministic), so the duplicate
-            # cache-scatter writes never race on ordering
-            _rid, prompt, _budget, _pr, _x = group[i if i < n else 0]
-            prompts[i, :prompt.size] = prompt
-            last[i] = prompt.size - 1
-            plens[i] = prompt.size
+        with self._span("serving/prefill/pack", "prefill_pack_ns"):
+            prompts = np.full((rp, bucket), self._pad, np.int32)
+            last = np.zeros(rp, np.int32)
+            plens = np.zeros(rp, np.int32)
+            rows_pad = np.asarray(rows + [rows[0]] * (rp - n), np.int32)
+            for i in range(rp):
+                # wave padding repeats row 0's request verbatim: the
+                # duplicate prefill K/V is bit-identical (prefill is
+                # row-independent and deterministic), so the duplicate
+                # cache-scatter writes never race on ordering
+                _rid, prompt, _budget, _pr, _x = group[i if i < n else 0]
+                prompts[i, :prompt.size] = prompt
+                last[i] = prompt.size - 1
+                plens[i] = prompt.size
         return self._prefill_wave(prompts, last, rows_pad, plens, n)
 
     def _primed_wave(self, bucket: int, group, rows) -> np.ndarray:
@@ -1118,21 +1222,19 @@ class _BatcherBase:
                 n = len(group)
                 rows = free[taken:taken + n]
                 taken += n
-                t_wave = time.perf_counter()
-                wall_wave = time.time()
-                with span("serving/prefill"):
+                with self._span("serving/prefill", "prefill_ns",
+                                "prefill_waves", histogram=True) as wave:
                     toks = self._admit_group(kind, key, group, rows)
                 # admission waves in the flight ring: one event per wave
                 # (not per request), enough to reconstruct the admit/queue
                 # rhythm in a serving post-mortem
-                from tfde_tpu.observability import flightrec
-
                 flightrec.record(
                     "admit", rows=n, group=kind,
                     key=list(key) if isinstance(key, tuple) else int(key),
                     queue_depth=len(self._queue),
                 )
-                now = time.perf_counter()
+                # the wave's two clock reads stamp everything it admitted
+                t_wave, now = wave.t0_ns, wave.t1_ns
                 if self._trace_ids:
                     tids = [self._trace_ids.get(it[0]) for it in group]
                     if any(tids):
@@ -1140,7 +1242,7 @@ class _BatcherBase:
                         # the waterfall shows who shared the prefill
                         _trace.event(
                             f"serve/prefill_{kind}", traces=tids,
-                            ts=wall_wave, dur=now - t_wave, rows=n,
+                            ts=wave.wall, dur=wave.dur_ns * 1e-9, rows=n,
                             key=list(key) if isinstance(key, tuple)
                             else int(key),
                         )
@@ -1151,16 +1253,26 @@ class _BatcherBase:
                 # granted, so the histogram reads intra-block slack), of
                 # which each request's true token count is real — the
                 # rest is the waste the ledger sizes paged-KV's win by
+                phase, rp = self._phase, _pad_wave(n, self._b)
+                phase["admitted"] += n
+                phase["prefill_rows_padded"] += rp
+                # the first tokens are on the host; the step's return
+                # hands them back (first_token_hold_ns)
+                self._held_n += n
+                self._held_at_ns += n * now
                 for i, (rid, prompt, budget, _pr, _x) in enumerate(group):
                     r = rows[i]
                     self._req[r] = rid
                     self._out[r] = []
                     self._budget[r] = budget
                     self._committed[r] = prompt.size
+                    alloc, used = self._admission_cells(kind, key, group[i])
                     if self._ledger is not None:
-                        alloc, used = self._admission_cells(
-                            kind, key, group[i])
                         self._ledger.note_admission(kind, alloc, int(used))
+                    phase["prefill_tokens"] += int(used)
+                    # ladder padding repeats row 0, cells and all
+                    copies = 1 + rp - n if i == 0 else 1
+                    phase["prefill_cells"] += copies * int(alloc)
                     self._usage.admitted(rid)
                     t0 = self._submitted_at.pop(rid, None)
                     self._first_at[rid] = now
@@ -1171,8 +1283,9 @@ class _BatcherBase:
                         # the TTFT decomposition the bench reports:
                         # queue_wait (submit -> wave start) + prefill
                         # (the serving/prefill span) = first token
-                        queue_ms = (t_wave - t0) * 1e3
-                        ttft_ms = (now - t0) * 1e3
+                        phase["queue_wait_ns"] += t_wave - t0
+                        queue_ms = (t_wave - t0) / 1e6
+                        ttft_ms = (now - t0) / 1e6
                         reg.histogram("serving/queue_wait_ms").observe(
                             queue_ms
                         )
@@ -1206,14 +1319,16 @@ class _BatcherBase:
         so the HTTP layer can report it explicitly."""
         rid, _prompt, budget, _pr = item
         dl = self._deadline_at.get(rid)
-        if dl is None or time.perf_counter() <= dl:
+        if dl is None:
+            return False
+        now = now_ns()
+        if now <= dl:
             return False
         pr = self._priority.pop(rid, _admission.DEFAULT_PRIORITY)
         self._deadline_at.pop(rid, None)
         t0 = self._submitted_at.pop(rid, None)
         self._first_at.pop(rid, None)
-        waited_ms = ((time.perf_counter() - t0) * 1e3
-                     if t0 is not None else None)
+        waited_ms = (now - t0) / 1e6 if t0 is not None else None
         self._shed.add(rid)
         ent = self._stream.get(rid)
         if ent is not None:
@@ -1229,8 +1344,6 @@ class _BatcherBase:
             _trace.event("serve/shed", trace=tid, rid=rid, priority=pr,
                          waited_ms=round(waited_ms, 3)
                          if waited_ms is not None else None)
-        from tfde_tpu.observability import flightrec
-
         flightrec.record("shed", rid=rid, priority=pr,
                          waited_ms=waited_ms, budget=int(budget))
         return True
@@ -1444,7 +1557,27 @@ class ContinuousBatcher(_BatcherBase):
         run, tokens delivered, tokens/round (mean occupied rows per
         tick), and the per-token host cost — jitted dispatches and
         blocking syncs per generated token (the O(1/K) bound the fused
-        scan exists for; tests/test_server.py guards it)."""
+        scan exists for; tests/test_server.py guards it).
+
+        Beside them the step's own account (`_PHASE_KEYS`), every value
+        an int that never falls. Nanoseconds, added by the span at that
+        boundary: `step_ns` (all of step(); `steps` of them), `admit_ns`,
+        `prefill_ns` (`prefill_waves` group waves) with its leaves
+        `prefill_pack_ns`, `prefill_template_ns`, `prefill_run_ns`,
+        `prefill_scatter_ns`; `decode_ns` (`scans`) with its leaves
+        `decode_upload_ns` (`uploads`) and `decode_dispatch_ns`;
+        `device_wait_ns` (both blocking fetches: the only time the host
+        waits for the device); `emit_ns` (from the scan's fetch to
+        step()'s return). admit + decode + emit make up a step. Counts:
+        `admitted` requests (the real rows of the waves) and their
+        `queue_wait_ns` (submit to wave start) and `first_token_hold_ns`
+        (first token on the host to the return of the step that fetched
+        it); `prefill_rows_padded` ladder rows; `prefill_tokens` real prompt
+        or suffix tokens of `prefill_cells` computed or written (rows x
+        bucket; granted blocks under paging); `decode_least_bytes`, bytes:
+        per scan, depth x (the parameters handed to the scan + the
+        committed KV cells of its active rows), what the ticks cannot
+        avoid reading, computed from shapes and not measured."""
         g = max(self._generated, 1)
         return {
             "rounds": self._rounds,
@@ -1454,27 +1587,19 @@ class ContinuousBatcher(_BatcherBase):
             "syncs": self._syncs,
             "dispatches_per_token": self._dispatches / g,
             "syncs_per_token": self._syncs / g,
+            **self._phase,
         }
 
-    def step(self) -> list:
-        """Admit into free rows, run one fused decode scan (up to
-        `scan_depth` ticks); returns [(request_id, tokens 1-D np.int32),
-        ...] that finished now."""
-        with span("serving/admit"):
-            finished = self._admit()
-        active = [r for r in range(self._b) if self._req[r] is not None]
-        if not active:
-            self._publish_stats()
-            return finished
-
+    def _round(self, active: list, finished: list) -> None:
+        """One fused decode scan (up to `scan_depth` ticks)."""
         depth = self._pick_depth(active)
         traced = (
             [self._trace_ids[rid] for r in active
              if (rid := self._req[r]) in self._trace_ids]
             if self._trace_ids else []
         )
-        t0 = time.perf_counter()
-        with span("serving/decode"):
+        with self._span("serving/decode", "decode_ns", "scans",
+                        histogram=True) as decode:
             if self._paged and self._tables_dirty:
                 # a released row's DEVICE table still points at its old
                 # blocks, and the frozen row keeps writing pad K/V at
@@ -1486,62 +1611,63 @@ class ContinuousBatcher(_BatcherBase):
                 self._tables_dirty = False
                 self._dispatches += 1
             if self._dev is None:
-                self._upload_state()
+                with self._span("serving/decode/upload", "decode_upload_ns",
+                                "uploads"):
+                    self._upload_state()
             tok, idx, budget, done = self._dev
             rng = self._rng if self._sampling["temperature"] != 0.0 else None
-            self._mem_register(
-                f"serve/decode/k{depth}",
-                functools.partial(
-                    _decode_scan, self._scan_model, depth=depth,
-                    eos_id=self._eos, pad_id=self._pad, **self._sampling,
-                ),
-                (self._cache, self._params, tok, idx, budget, done,
-                 self._seen, rng),
-                donated=(self._cache, tok, idx, budget, done, self._seen),
-            )
-            # steady-state decode is the shape-stable site: the depth
-            # ladder gives O(log scan_depth) expected signatures, and any
-            # repeat-fingerprint miss is an unexpected recompile (the
-            # per-token-recompile pathology memgate pins)
-            rc = _recompile.site("serve/decode", stable=True)
-            with rc.watch(self._rc_tag, depth, traces=traced or None):
-                out = _decode_scan(
-                    self._scan_model, self._cache, self._params, tok, idx,
-                    budget, done, self._seen, rng, depth=depth,
-                    eos_id=self._eos, pad_id=self._pad, **self._sampling,
+            self._phase["decode_least_bytes"] += depth * (
+                self._param_bytes + self._kv_read_bytes(active))
+            with self._span("serving/decode/scan", "decode_dispatch_ns"):
+                self._mem_register(
+                    f"serve/decode/k{depth}",
+                    functools.partial(
+                        _decode_scan, self._scan_model, depth=depth,
+                        eos_id=self._eos, pad_id=self._pad,
+                        **self._sampling,
+                    ),
+                    (self._cache, self._params, tok, idx, budget, done,
+                     self._seen, rng),
+                    donated=(self._cache, tok, idx, budget, done,
+                             self._seen),
                 )
+                # steady-state decode is the shape-stable site: the depth
+                # ladder gives O(log scan_depth) expected signatures, and
+                # any repeat-fingerprint miss is an unexpected recompile
+                # (the per-token-recompile pathology memgate pins)
+                rc = _recompile.site("serve/decode", stable=True)
+                with rc.watch(self._rc_tag, depth, traces=traced or None):
+                    out = _decode_scan(
+                        self._scan_model, self._cache, self._params, tok,
+                        idx, budget, done, self._seen, rng, depth=depth,
+                        eos_id=self._eos, pad_id=self._pad,
+                        **self._sampling,
+                    )
             self._dispatches += 1
             (self._cache, tok, idx, budget, done, self._seen, rng,
              toks, emitted) = out
             self._dev = (tok, idx, budget, done)
             if rng is not None:
                 self._rng = rng
-            toks_np, emitted_np = _fetch((toks, emitted))
+            with self._span("serving/decode/fetch", "device_wait_ns"):
+                toks_np, emitted_np = _fetch((toks, emitted))
             self._syncs += 1
-        self._rounds += depth
-        self._profiler_round(traced)
-        n_emitted = 0
-        for r in active:
-            row = toks_np[r][emitted_np[r]]
-            if row.size == 0:
-                continue
-            n_emitted += int(row.size)
-            # feeding each pending token committed it; the row's last
-            # sample stays pending
-            self._committed[r] += int(row.size)
-            for t in row:
-                finished.extend(self._take_token(r, int(t)))
-        dt = time.perf_counter() - t0
-        if traced:
-            _trace.event("serve/decode_round", traces=traced, dur=dt,
-                         depth=depth, rows=len(active), emitted=n_emitted)
-        if n_emitted:
-            metrics.default_registry().histogram(
-                "serving/ms_per_token"
-            ).observe(dt * 1e3 / n_emitted)
-            self._admission.note_drain(n_emitted, dt)
-        self._publish_stats()
-        return finished
+        with self._span("serving/emit", "emit_ns"):
+            self._rounds += depth
+            self._profiler_round(traced)
+            n_emitted = 0
+            for r in active:
+                row = toks_np[r][emitted_np[r]]
+                if row.size == 0:
+                    continue
+                n_emitted += int(row.size)
+                # feeding each pending token committed it; the row's last
+                # sample stays pending
+                self._committed[r] += int(row.size)
+                for t in row:
+                    finished.extend(self._take_token(r, int(t)))
+            self._close_round(decode, traced, depth, len(active), n_emitted)
+            self._publish_stats()
 
     # -- internals ----------------------------------------------------------
     def _validate_submit(self, prompt, max_new_tokens) -> None:
@@ -1602,15 +1728,16 @@ class ContinuousBatcher(_BatcherBase):
         """FRESH zero row cache for a donated prefill call, materialized
         from shapes cached per wave size (the donation consumed the last
         one — reusing it would hand jit a deleted buffer)."""
-        if rp not in self._row_shapes:
-            self._row_shapes[rp] = jax.tree.map(
-                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
-                init_cache(self._model, rp, self._max_len,
-                           kv_quant=self._kv_quant),
-            )
-        self._dispatches += 1
-        return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
-                            self._row_shapes[rp])
+        with self._span("serving/prefill/template", "prefill_template_ns"):
+            if rp not in self._row_shapes:
+                self._row_shapes[rp] = jax.tree.map(
+                    lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+                    init_cache(self._model, rp, self._max_len,
+                               kv_quant=self._kv_quant),
+                )
+            self._dispatches += 1
+            return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
+                                self._row_shapes[rp])
 
     def _prefill_wave(self, prompts, last, rows, plens, n) -> np.ndarray:
         rp, bucket = prompts.shape
@@ -1623,32 +1750,41 @@ class ContinuousBatcher(_BatcherBase):
         if self._sampling["temperature"] != 0.0:
             self._rng, rng = jax.random.split(self._rng)
         tmpl = self._row_template(rp)
-        prompts_dev = jnp.asarray(prompts)
-        last_dev = jnp.asarray(last)
-        self._mem_register(
-            f"serve/prefill/b{bucket}r{rp}",
-            functools.partial(_prefill_rows, self._decode_model,
-                              **self._sampling),
-            (tmpl, self._params, prompts_dev, last_dev, valid, rng),
-            donated=tmpl,
-        )
-        row_cache, tok, row_seen = _prefill_rows(
-            self._decode_model, tmpl, self._params,
-            prompts_dev, last_dev, valid, rng,
-            **self._sampling,
-        )
-        self._dispatches += 1
-        if self._prefix is not None:
-            # cold admissions SEED the prefix cache: store each real
-            # row's complete prompt blocks before the scatter consumes
-            # our interest in row_cache (slices are fresh buffers, so
-            # the donated-output aliasing never bites)
-            for i in range(n):
-                self._prefix.insert(prompts[i, :plens[i]], row_cache, i)
+        with self._span("serving/prefill/run", "prefill_run_ns"):
+            prompts_dev = jnp.asarray(prompts)
+            last_dev = jnp.asarray(last)
+            self._mem_register(
+                f"serve/prefill/b{bucket}r{rp}",
+                functools.partial(_prefill_rows, self._decode_model,
+                                  **self._sampling),
+                (tmpl, self._params, prompts_dev, last_dev, valid, rng),
+                donated=tmpl,
+            )
+            row_cache, tok, row_seen = _prefill_rows(
+                self._decode_model, tmpl, self._params,
+                prompts_dev, last_dev, valid, rng,
+                **self._sampling,
+            )
+            self._dispatches += 1
+        with self._span("serving/prefill/scatter", "prefill_scatter_ns"):
+            if self._prefix is not None:
+                # cold admissions SEED the prefix cache: store each real
+                # row's complete prompt blocks before the scatter consumes
+                # our interest in row_cache (slices are fresh buffers, so
+                # the donated-output aliasing never bites)
+                for i in range(n):
+                    self._prefix.insert(prompts[i, :plens[i]], row_cache, i)
+            self._scatter_wave(row_cache, row_seen, rows, n)
+        return self._fetch_first(tok)
+
+    def _scatter_wave(self, row_cache, row_seen, rows, n: int) -> None:
+        """Land a prefilled wave (`rows` padded to the ladder, `n` of them
+        real) in the batch cache, and its seen rows in the seen mask."""
         rows_dev = jnp.asarray(rows)
         self._cache = _scatter_rows(self._cache, row_cache, rows_dev)
         self._dispatches += 1
         if row_seen is not None:
+            rp = len(rows)
             if rp > n:
                 # a ladder-padding row's K/V duplicates row 0 bit-exactly,
                 # but its sampled-first-token seen bit can differ under
@@ -1660,9 +1796,6 @@ class ContinuousBatcher(_BatcherBase):
                 row_seen = row_seen[jnp.asarray(sel)]
             self._seen = self._seen.at[rows_dev].set(row_seen)
             self._dispatches += 1
-        tok_np = _fetch(tok)
-        self._syncs += 1
-        return tok_np
 
     # -- prefix cache (warm admission) ---------------------------------------
     _accepts_primed = True
@@ -1826,7 +1959,6 @@ class ContinuousBatcher(_BatcherBase):
             self._prefix.remap(plan)
         self._tables_dirty = True
         metrics.default_registry().counter("kv/pool_defrags").incr()
-        from tfde_tpu.observability import flightrec
         flightrec.record("kv_defrag", moved=len(plan),
                          frag=round(float(frag), 3),
                          free=self._pool.free_blocks)
@@ -1966,8 +2098,7 @@ class ContinuousBatcher(_BatcherBase):
                 seen_out = seen_out[jnp.asarray(sel)]
             self._seen = self._seen.at[jnp.asarray(rows_pad)].set(seen_out)
             self._dispatches += 1
-        tok_np = _fetch(tok)
-        self._syncs += 1
+        tok_np = self._fetch_first(tok)
         if self._prefix is not None:
             for i in range(n):
                 _rid, prompt, _budget, _pr, extra = group[i]
@@ -2060,64 +2191,58 @@ class ContinuousBatcher(_BatcherBase):
         assert pre_len + sbucket <= self._max_len, (pre_len, sbucket)
         n = len(group)
         rp = _pad_wave(n, self._b)
-        suffixes = np.full((rp, sbucket), self._pad, np.int32)
-        last = np.zeros(rp, np.int32)
-        fullp = plens = None
-        if self._seen is not None:
-            fullp = np.full((rp, fbucket), self._pad, np.int32)
-            plens = np.zeros(rp, np.int32)
-        kv_rows = []
-        for i in range(rp):
-            _rid, prompt, _budget, _pr, kv = group[i if i < n else 0]
-            suffix = prompt[pre_len:]
-            suffixes[i, :suffix.size] = suffix
-            last[i] = suffix.size - 1
-            if fullp is not None:
-                fullp[i, :prompt.size] = prompt
-                plens[i] = prompt.size
-            kv_rows.append(kv)
-        kv_stack = {
-            name: jnp.stack([k[name] for k in kv_rows])
-            for name in kv_rows[0]
-        }
-        valid = None
-        if fullp is not None:
-            valid = jnp.asarray(np.arange(fbucket)[None, :] < plens[:, None])
-            fullp = jnp.asarray(fullp)
+        with self._span("serving/prefill/pack", "prefill_pack_ns"):
+            suffixes = np.full((rp, sbucket), self._pad, np.int32)
+            last = np.zeros(rp, np.int32)
+            fullp = plens = None
+            if self._seen is not None:
+                fullp = np.full((rp, fbucket), self._pad, np.int32)
+                plens = np.zeros(rp, np.int32)
+            kv_rows = []
+            for i in range(rp):
+                _rid, prompt, _budget, _pr, kv = group[i if i < n else 0]
+                suffix = prompt[pre_len:]
+                suffixes[i, :suffix.size] = suffix
+                last[i] = suffix.size - 1
+                if fullp is not None:
+                    fullp[i, :prompt.size] = prompt
+                    plens[i] = prompt.size
+                kv_rows.append(kv)
+            rows_pad = np.asarray(rows + [rows[0]] * (rp - n), np.int32)
         rng = None
         if self._sampling["temperature"] != 0.0:
             self._rng, rng = jax.random.split(self._rng)
         tmpl = self._row_template(rp)
-        suffixes_dev = jnp.asarray(suffixes)
-        last_dev = jnp.asarray(last)
-        self._mem_register(
-            f"serve/prefill_warm/p{pre_len}s{sbucket}r{rp}",
-            functools.partial(_prefill_suffix, self._decode_model,
-                              **self._sampling),
-            (tmpl, self._params, kv_stack, suffixes_dev, last_dev, fullp,
-             valid, rng),
-            donated=tmpl,
-        )
-        row_cache, tok, row_seen = _prefill_suffix(
-            self._decode_model, tmpl, self._params,
-            kv_stack, suffixes_dev, last_dev, fullp,
-            valid, rng, **self._sampling,
-        )
-        self._dispatches += 2  # the per-wave kv stack + the fused prefill
-        rows_pad = np.asarray(rows + [rows[0]] * (rp - n), np.int32)
-        rows_dev = jnp.asarray(rows_pad)
-        self._cache = _scatter_rows(self._cache, row_cache, rows_dev)
-        self._dispatches += 1
-        if row_seen is not None:
-            if rp > n:
-                sel = np.arange(rp)
-                sel[n:] = 0
-                row_seen = row_seen[jnp.asarray(sel)]
-            self._seen = self._seen.at[rows_dev].set(row_seen)
-            self._dispatches += 1
-        tok_np = _fetch(tok)
-        self._syncs += 1
-        return tok_np
+        with self._span("serving/prefill/run", "prefill_run_ns"):
+            kv_stack = {
+                name: jnp.stack([k[name] for k in kv_rows])
+                for name in kv_rows[0]
+            }
+            valid = None
+            if fullp is not None:
+                valid = jnp.asarray(
+                    np.arange(fbucket)[None, :] < plens[:, None])
+                fullp = jnp.asarray(fullp)
+            suffixes_dev = jnp.asarray(suffixes)
+            last_dev = jnp.asarray(last)
+            self._mem_register(
+                f"serve/prefill_warm/p{pre_len}s{sbucket}r{rp}",
+                functools.partial(_prefill_suffix, self._decode_model,
+                                  **self._sampling),
+                (tmpl, self._params, kv_stack, suffixes_dev, last_dev,
+                 fullp, valid, rng),
+                donated=tmpl,
+            )
+            row_cache, tok, row_seen = _prefill_suffix(
+                self._decode_model, tmpl, self._params,
+                kv_stack, suffixes_dev, last_dev, fullp,
+                valid, rng, **self._sampling,
+            )
+            # the per-wave kv stack + the fused prefill
+            self._dispatches += 2
+        with self._span("serving/prefill/scatter", "prefill_scatter_ns"):
+            self._scatter_wave(row_cache, row_seen, rows_pad, n)
+        return self._fetch_first(tok)
 
     # -- prefill/decode role split -------------------------------------------
     def prime(self, prompt, max_new_tokens: int,
@@ -2129,7 +2254,7 @@ class ContinuousBatcher(_BatcherBase):
         long-prompt admissions without ever stalling a decode scan."""
         if self._role == "decode":
             raise RuntimeError("decode-only replica cannot prime")
-        t_prime = time.perf_counter()
+        t_prime = now_ns()
         prompt = self._check_request(prompt, max_new_tokens)
         bucket = next(b for b in self._buckets if b >= prompt.size)
         prompts = np.full((1, bucket), self._pad, np.int32)
@@ -2164,7 +2289,7 @@ class ContinuousBatcher(_BatcherBase):
             # the prefill half of the primed hand-off: the decode
             # replica's serve/queued(primed=True) is the other half
             _trace.event("serve/prime", trace=trace,
-                         dur=time.perf_counter() - t_prime,
+                         dur=(now_ns() - t_prime) * 1e-9,
                          prompt_tokens=int(prompt.size))
         return PrimedRequest(
             prompt=prompt.astype(np.int32),
@@ -2265,13 +2390,14 @@ class SpeculativeContinuousBatcher(_BatcherBase):
             _spec_round_sampled,
         )
 
-        self._round = _spec_round
-        self._round_sampled = _spec_round_sampled
+        self._spec_round = _spec_round
+        self._spec_round_sampled = _spec_round_sampled
         self._temperature = float(temperature)
         self._draft = draft_model
         self._tgt = _decode_clone(model)
         self._drf = _decode_clone(draft_model)
         self._dparams = draft_params
+        self._dparam_bytes = _count_params(draft_params)[1]
         self._nd = int(num_draft)
         # the speculative cache invariant: each round feeds at most
         # num_draft+1 tokens past a row's committed count before the
@@ -2295,7 +2421,11 @@ class SpeculativeContinuousBatcher(_BatcherBase):
         (1.0 = no draft ever accepted, num_draft+1 = perfect draft);
         acceptance_rate is the fraction of proposed draft tokens the
         target committed. dispatches/syncs mirror ContinuousBatcher's
-        host-overhead accounting."""
+        host-overhead accounting, and the step's own account has the keys
+        `ContinuousBatcher.stats` lists: a scan here is one speculative
+        round, an upload its rewind of both caches' index counters, and
+        `decode_least_bytes` counts the target's parameters once and the
+        draft's `num_draft` times a round."""
         return {
             "rounds": self._rounds,
             "generated": self._generated,
@@ -2307,6 +2437,7 @@ class SpeculativeContinuousBatcher(_BatcherBase):
             ),
             "dispatches": self._dispatches,
             "syncs": self._syncs,
+            **self._phase,
         }
 
     def _validate_submit(self, prompt, max_new_tokens) -> None:
@@ -2316,120 +2447,119 @@ class SpeculativeContinuousBatcher(_BatcherBase):
     def _template(self, shapes: dict, model, rp: int):
         """Fresh zero rows for the donated prefill, from shapes cached
         per wave size (see ContinuousBatcher._row_template)."""
-        if rp not in shapes:
-            shapes[rp] = jax.tree.map(
-                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
-                init_cache(model, rp, self._cache_len),
-            )
-        self._dispatches += 1
-        return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
-                            shapes[rp])
+        with self._span("serving/prefill/template", "prefill_template_ns"):
+            if rp not in shapes:
+                shapes[rp] = jax.tree.map(
+                    lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+                    init_cache(model, rp, self._cache_len),
+                )
+            self._dispatches += 1
+            return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
+                                shapes[rp])
 
     def _prefill_wave(self, prompts, last, rows, plens, n) -> np.ndarray:
         rp = prompts.shape[0]
-        prompts_dev = jnp.asarray(prompts)
-        last_dev = jnp.asarray(last)
         rng = None
         if self._temperature > 0.0:
             self._rng, rng = jax.random.split(self._rng)
-        tgt_rows, tok, _ = _prefill_rows(
-            self._tgt, self._template(self._tgt_templates, self._model, rp),
-            self._params, prompts_dev, last_dev, None, rng,
-            temperature=self._temperature, top_k=None, top_p=None,
-            min_p=None, repetition_penalty=1.0,
-        )
-        # the draft prefill only needs its cache filled; its sampled token
-        # is discarded (greedy argmax — no rng consumed)
-        drf_rows, _, _ = _prefill_rows(
-            self._drf, self._template(self._drf_templates, self._draft, rp),
-            self._dparams, prompts_dev, last_dev, None, None,
-            temperature=0.0, top_k=None, top_p=None, min_p=None,
-            repetition_penalty=1.0,
-        )
-        self._dispatches += 2
-        rows_dev = jnp.asarray(rows)
-        self._tgt_cache = _scatter_rows(self._tgt_cache, tgt_rows, rows_dev)
-        self._drf_cache = _scatter_rows(self._drf_cache, drf_rows, rows_dev)
-        self._dispatches += 2
-        tok_np = _fetch(tok)
-        self._syncs += 1
-        return tok_np
+        tgt_tmpl = self._template(self._tgt_templates, self._model, rp)
+        drf_tmpl = self._template(self._drf_templates, self._draft, rp)
+        with self._span("serving/prefill/run", "prefill_run_ns"):
+            prompts_dev = jnp.asarray(prompts)
+            last_dev = jnp.asarray(last)
+            tgt_rows, tok, _ = _prefill_rows(
+                self._tgt, tgt_tmpl, self._params, prompts_dev, last_dev,
+                None, rng, temperature=self._temperature, top_k=None,
+                top_p=None, min_p=None, repetition_penalty=1.0,
+            )
+            # the draft prefill only needs its cache filled; its sampled
+            # token is discarded (greedy argmax — no rng consumed)
+            drf_rows, _, _ = _prefill_rows(
+                self._drf, drf_tmpl, self._dparams, prompts_dev, last_dev,
+                None, None, temperature=0.0, top_k=None, top_p=None,
+                min_p=None, repetition_penalty=1.0,
+            )
+            self._dispatches += 2
+        with self._span("serving/prefill/scatter", "prefill_scatter_ns"):
+            rows_dev = jnp.asarray(rows)
+            self._tgt_cache = _scatter_rows(self._tgt_cache, tgt_rows,
+                                            rows_dev)
+            self._drf_cache = _scatter_rows(self._drf_cache, drf_rows,
+                                            rows_dev)
+            self._dispatches += 2
+        return self._fetch_first(tok)
 
-    def step(self) -> list:
-        """Admit, then run ONE speculative round for the whole batch;
-        returns the requests that finished on it."""
-        with span("serving/admit"):
-            finished = self._admit()
-        active = [r for r in range(self._b) if self._req[r] is not None]
-        if not active:
-            self._publish_stats()
-            return finished
+    def _round(self, active: list, finished: list) -> None:
+        """ONE speculative round for the whole batch."""
         self._rounds += 1
-        t0 = time.perf_counter()
-        with span("serving/decode"):
+        with self._span("serving/decode", "decode_ns", "scans",
+                        histogram=True) as decode:
             # per-round rewind is unconditional: acceptance lengths diverge
             # every round (host ints/np arrays — own buffer per index leaf,
             # across BOTH donated caches)
-            committed = self._committed.astype(np.int32)
-            self._tgt_cache = _set_index_counters(self._tgt_cache, committed)
-            self._drf_cache = _set_index_counters(self._drf_cache, committed)
-            self._dispatches += 2
-            if self._temperature > 0.0:
-                self._rng, sub = jax.random.split(self._rng)
-                (self._tgt_cache, self._drf_cache, round_toks, n_new,
-                 _pending, _rng_out) = self._round_sampled(
-                    self._tgt, self._drf, self._tgt_cache, self._drf_cache,
-                    self._params, self._dparams,
-                    jnp.asarray(self._tok, jnp.int32), sub, self._nd,
-                    self._pad, self._temperature,
-                )
-            else:
-                (self._tgt_cache, self._drf_cache, round_toks, n_new,
-                 _pending) = self._round(
-                    self._tgt, self._drf, self._tgt_cache, self._drf_cache,
-                    self._params, self._dparams,
-                    jnp.asarray(self._tok, jnp.int32), self._nd, self._pad,
-                )
-            self._dispatches += 1
-            round_np, n_np = _fetch((round_toks, n_new))
+            with self._span("serving/decode/upload", "decode_upload_ns",
+                            "uploads"):
+                committed = self._committed.astype(np.int32)
+                self._tgt_cache = _set_index_counters(self._tgt_cache,
+                                                      committed)
+                self._drf_cache = _set_index_counters(self._drf_cache,
+                                                      committed)
+                self._dispatches += 2
+            self._phase["decode_least_bytes"] += (
+                self._param_bytes + self._nd * self._dparam_bytes
+                + self._kv_read_bytes(active))
+            with self._span("serving/decode/scan", "decode_dispatch_ns"):
+                if self._temperature > 0.0:
+                    self._rng, sub = jax.random.split(self._rng)
+                    (self._tgt_cache, self._drf_cache, round_toks, n_new,
+                     _pending, _rng_out) = self._spec_round_sampled(
+                        self._tgt, self._drf, self._tgt_cache,
+                        self._drf_cache, self._params, self._dparams,
+                        jnp.asarray(self._tok, jnp.int32), sub, self._nd,
+                        self._pad, self._temperature,
+                    )
+                else:
+                    (self._tgt_cache, self._drf_cache, round_toks, n_new,
+                     _pending) = self._spec_round(
+                        self._tgt, self._drf, self._tgt_cache,
+                        self._drf_cache, self._params, self._dparams,
+                        jnp.asarray(self._tok, jnp.int32), self._nd,
+                        self._pad,
+                    )
+                self._dispatches += 1
+            with self._span("serving/decode/fetch", "device_wait_ns"):
+                round_np, n_np = _fetch((round_toks, n_new))
             self._syncs += 1
         traced = (
             [self._trace_ids[rid] for r in active
              if (rid := self._req[r]) in self._trace_ids]
             if self._trace_ids else []
         )
-        self._profiler_round(traced)
-        n_emitted = 0
-        for r in active:
-            toks = round_np[r, : int(n_np[r])].tolist()
-            taken = 0
-            for t in toks:
-                if self._req[r] is None:
-                    break  # row finished mid-round; overshoot discarded
-                self._round_tokens += 1
-                finished.extend(self._take_token(r, int(t)))
-                taken += 1
-            n_emitted += taken
-            # acceptance bookkeeping: each round proposes num_draft per
-            # active row; a row's commits beyond the guaranteed target
-            # token are accepted draft proposals (capped by num_draft —
-            # the +1'th commit is the bonus token, not a draft)
-            self._draft_proposed += self._nd
-            self._draft_accepted += min(max(taken - 1, 0), self._nd)
-            if self._req[r] is not None:
-                # row still active: tok_last + accepted tokens are now in
-                # both caches (the pending one stays unfed) — the
-                # generate_speculative commit bookkeeping
-                self._committed[r] += taken
-        dt = time.perf_counter() - t0
-        if traced:
-            _trace.event("serve/decode_round", traces=traced, dur=dt,
-                         depth=self._nd, rows=len(active),
-                         emitted=n_emitted)
-        if n_emitted:
-            metrics.default_registry().histogram(
-                "serving/ms_per_token"
-            ).observe(dt * 1e3 / n_emitted)
-            self._admission.note_drain(n_emitted, dt)
-        self._publish_stats()
-        return finished
+        with self._span("serving/emit", "emit_ns"):
+            self._profiler_round(traced)
+            n_emitted = 0
+            for r in active:
+                toks = round_np[r, : int(n_np[r])].tolist()
+                taken = 0
+                for t in toks:
+                    if self._req[r] is None:
+                        break  # row finished mid-round; overshoot discarded
+                    self._round_tokens += 1
+                    finished.extend(self._take_token(r, int(t)))
+                    taken += 1
+                n_emitted += taken
+                # acceptance bookkeeping: each round proposes num_draft
+                # per active row; a row's commits beyond the guaranteed
+                # target token are accepted draft proposals (capped by
+                # num_draft — the +1'th commit is the bonus token, not a
+                # draft)
+                self._draft_proposed += self._nd
+                self._draft_accepted += min(max(taken - 1, 0), self._nd)
+                if self._req[r] is not None:
+                    # row still active: tok_last + accepted tokens are now
+                    # in both caches (the pending one stays unfed) — the
+                    # generate_speculative commit bookkeeping
+                    self._committed[r] += taken
+            self._close_round(decode, traced, self._nd, len(active),
+                              n_emitted)
+            self._publish_stats()

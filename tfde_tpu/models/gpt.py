@@ -335,7 +335,8 @@ def next_token_loss(state, params, batch, rng):
         mutated = {}
     # align: logits[:, :-1] predict tokens[:, 1:]
     labels = tokens[:, 1:].astype(jnp.int32)
-    loss, acc = masked_lm_loss(logits[:, :-1], labels)
+    with jax.named_scope("head_loss"):
+        loss, acc = masked_lm_loss(logits[:, :-1], labels)
     metrics = {"next_token_accuracy": acc}
     from tfde_tpu.training.step import sown_losses_by_name
 

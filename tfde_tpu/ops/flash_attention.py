@@ -374,6 +374,7 @@ def _flash_forward(
             pltpu.VMEM((block_q, 128), jnp.float32),  # l (col 0 used)
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(qt, kt, vt)
     return jnp.swapaxes(out, 1, 2), lse[..., 0]
 
@@ -499,10 +500,11 @@ def _bwd_blockwise(res, g, *, causal: bool, block_q=None, block_k=None,
         scale = 1.0 / (d ** 0.5)
     block_k = _resolve_block(block_k, s)
     if causal:
-        return _bwd_pair_scan(
-            res, g, block_q=_resolve_block(block_q, s), block_k=block_k,
-            window=window, scale=scale, logit_cap=logit_cap,
-        )
+        with jax.named_scope("flash_bwd_pair_scan"):
+            return _bwd_pair_scan(
+                res, g, block_q=_resolve_block(block_q, s), block_k=block_k,
+                window=window, scale=scale, logit_cap=logit_cap,
+            )
     if k.shape[2] != h:
         return _bwd_blockwise_grouped(res, g, block_k=block_k, scale=scale,
                                       logit_cap=logit_cap)
@@ -828,6 +830,7 @@ def _bwd_pallas(res, g, *, causal: bool, block_q: int, block_k: int,
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qt, kt, vt, gt, lse4, delta4)
 
     qk_q = lambda bi, hi, qi, kb: (bi, hi, qi, 0)
@@ -870,6 +873,7 @@ def _bwd_pallas(res, g, *, causal: bool, block_q: int, block_k: int,
         out_shape=[jax.ShapeDtypeStruct((b, h, s, d), q.dtype)],
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qt, kt, vt, gt, lse4, delta4)
 
     return (

@@ -10,6 +10,7 @@ from __future__ import annotations
 from typing import Any, Callable
 
 import flax.struct
+import jax
 import optax
 
 
@@ -52,15 +53,18 @@ class TrainState(flax.struct.PyTreeNode):
         shard_map body). Returns (new_param_chunks, new_opt_state). For
         elementwise transforms this is bit-identical to the replicated
         per-leaf update — see parallel/zero.py's correctness contract."""
-        updates, new_opt_state = self.tx.update(
-            grad_chunks, self.opt_state, param_chunks
-        )
-        return optax.apply_updates(param_chunks, updates), new_opt_state
+        with jax.named_scope("optimizer_update"):
+            updates, new_opt_state = self.tx.update(
+                grad_chunks, self.opt_state, param_chunks
+            )
+            return optax.apply_updates(param_chunks, updates), new_opt_state
 
     def apply_gradients(self, grads, new_batch_stats=None,
                         new_comm_residual=None):
-        updates, new_opt_state = self.tx.update(grads, self.opt_state, self.params)
-        new_params = optax.apply_updates(self.params, updates)
+        with jax.named_scope("optimizer_update"):
+            updates, new_opt_state = self.tx.update(
+                grads, self.opt_state, self.params)
+            new_params = optax.apply_updates(self.params, updates)
         return self.replace(
             step=self.step + 1,
             params=new_params,
