@@ -445,6 +445,103 @@ def test_prefill_buffers_are_donated(lm):
     assert low.as_text().count("tf.aliasing_output") >= 2
 
 
+def _all_zero(tree) -> bool:
+    return all(not np.asarray(leaf).any() for leaf in jax.tree.leaves(tree))
+
+
+@pytest.mark.parametrize("kv_quant", ["fp", "int8"])
+@pytest.mark.parametrize("rp", [1, 2, 4])
+def test_row_template_is_one_fresh_zero_program(lm, rp, kv_quant, monkeypatch):
+    """A wave's zero row cache comes from ONE compiled program per width:
+    (a) the tree `init_cache` builds, all zero; (b) new buffers every
+    call, so the one a prefill donated takes nothing from the next; (c)
+    after a width's first call nothing compiles and no per-leaf
+    `jnp.zeros` runs — a call is one launch (counts, valid on the CPU)."""
+    import tfde_tpu.inference.server as server_mod
+    from tfde_tpu.inference.decode import init_cache
+    from tfde_tpu.observability import recompile
+
+    model, params = lm
+    srv = ContinuousBatcher(model, params, batch_size=4, max_len=64,
+                            kv_quant=kv_quant)
+    quant = None if kv_quant == "fp" else kv_quant
+    want = init_cache(model, rp, 64, kv_quant=quant)
+    first = srv._row_template(rp)
+    assert jax.tree.structure(first) == jax.tree.structure(want)
+    for got, ref in zip(jax.tree.leaves(first), jax.tree.leaves(want)):
+        assert (got.shape, got.dtype) == (ref.shape, ref.dtype)
+    assert _all_zero(first)
+    # no two leaves of one tree, and no two trees, share a buffer
+    second = srv._row_template(rp)
+    ptrs = [leaf.unsafe_buffer_pointer()
+            for leaf in jax.tree.leaves((first, second))]
+    assert len(set(ptrs)) == len(ptrs)
+
+    filled, _tok, _seen = server_mod._prefill_rows(
+        srv._decode_model, first, params,
+        jnp.ones((rp, 8), jnp.int32), jnp.full((rp,), 7, jnp.int32),
+        None, None, **srv._sampling,
+    )
+    assert all(leaf.is_deleted() for leaf in jax.tree.leaves(first))
+    assert not _all_zero(filled)
+    assert _all_zero(second)  # untouched by the donated wave
+    assert _all_zero(srv._row_template(rp))  # and so is the next one
+
+    assert recompile.install()
+    calls = {"zeros": 0}
+    real_zeros = jnp.zeros
+
+    def counting_zeros(*a, **k):
+        calls["zeros"] += 1
+        return real_zeros(*a, **k)
+
+    monkeypatch.setattr(jnp, "zeros", counting_zeros)
+    compiles = recompile.process_compiles()
+    before = srv.stats()
+    for _ in range(3):
+        srv._row_template(rp)
+    after = srv.stats()
+    assert recompile.process_compiles() == compiles
+    assert calls["zeros"] == 0
+    assert after["dispatches"] - before["dispatches"] == 3
+    key = (model, rp, 64, quant)
+    assert srv._zero_programs[key]._cache_size() == 1
+
+
+def test_speculative_templates_come_from_the_shared_helper(lm, draft, rng,
+                                                           monkeypatch):
+    """Both caches of a speculative wave, target and draft, are zeroed by
+    `_BatcherBase._zero_rows`, the helper `_row_template` calls too: one
+    program per (model, width, cache length), one launch each a wave."""
+    import tfde_tpu.inference.server as server_mod
+    from tfde_tpu.inference.server import SpeculativeContinuousBatcher
+
+    model, params = lm
+    dmodel, dparams = draft
+    srv = SpeculativeContinuousBatcher(
+        model, dmodel, params, dparams, batch_size=2, max_len=40,
+        num_draft=3,
+    )
+    assert not hasattr(srv, "_template")
+    asked = []
+    real = server_mod._BatcherBase._zero_rows
+
+    def recording(self, m, rp, length, kv_quant=None):
+        asked.append((m, rp, length, kv_quant))
+        return real(self, m, rp, length, kv_quant)
+
+    monkeypatch.setattr(server_mod._BatcherBase, "_zero_rows", recording)
+    prompt = rng.integers(0, 97, 4).astype(np.int64)
+    rid = srv.submit(prompt, max_new_tokens=5)
+    done = dict(srv.run())
+    np.testing.assert_array_equal(done[rid], _solo(model, params, prompt, 5))
+    cache_len = 40 + 3 + 1
+    assert asked == [(model, 1, cache_len, None), (dmodel, 1, cache_len, None)]
+    assert set(srv._zero_programs) == set(asked)
+    assert all(p._cache_size() == 1 for p in srv._zero_programs.values())
+    assert srv.stats()["prefill_template_ns"] > 0
+
+
 def test_role_split_primed_handoff_parity(lm, rng):
     """Disaggregated prefill: a prefill-role batcher primes prompts, a
     decode-role batcher scatters the shipped K/V and streams — primed

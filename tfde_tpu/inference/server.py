@@ -239,7 +239,8 @@ def _prefill_rows(model, row_cache, params, prompts, last, valid, rng,
     instead of paying a device-side copy of every K/V leaf per admission
     wave (tests/test_server.py pins the aliasing in the lowered HLO), so
     callers must hand in a FRESH zero tree each wave — `_row_template`
-    materializes one from cached shapes.
+    runs the width's one compiled zero-fill program (`_zero_rows`), whose
+    outputs are new buffers every call.
 
     Returns (filled row cache, first tokens [R], seen rows [R, V] or
     None). Pad correctness rides the per-row index machinery: pad K/V
@@ -454,6 +455,18 @@ class PrimedRequest:
     kv: dict                    # leaf-name -> np.ndarray [P, ...]
 
 
+def _zeros_program(shapes):
+    """ONE compiled, argument-free program that returns a zero tree of
+    `shapes` (a pytree of ShapeDtypeStruct): a single launch whose
+    outputs the runtime allocates inside that execution, where mapping
+    `jnp.zeros` over the leaves issues a program (and an allocation) per
+    leaf — some 330 for a 36-layer row cache, 61 ms of host time a wave
+    with the chip idle. Every call returns NEW buffers (XLA keeps the
+    entry computation's outputs distinct), so the result can be donated."""
+    return jax.jit(lambda: jax.tree.map(
+        lambda s: jnp.zeros(s.shape, s.dtype), shapes))
+
+
 def _normalize_buckets(buckets, max_len: int) -> tuple:
     """Sorted prefill bucket lengths; default powers of two up to
     max_len. Every prompt pads up to the smallest bucket that fits."""
@@ -645,6 +658,9 @@ class _BatcherBase:
         # pad-ladder bucket, not per wave)
         self._rc_tag = next(_BATCHER_TAGS)
         self._mem_programs: set = set()
+        # (model, wave width, cache length, kv_quant) -> the compiled
+        # zero-fill program of that row cache (`_zero_rows`)
+        self._zero_programs: dict = {}
         # KV-capacity observability (observability/capacity.py): the
         # ledger/headroom pair is built by the subclass once its slab
         # exists (`_init_capacity`); the usage meter is per-batcher and
@@ -996,6 +1012,22 @@ class _BatcherBase:
             tok_np = _fetch(tok)
         self._syncs += 1
         return tok_np
+
+    def _zero_rows(self, model, rp: int, length: int, kv_quant=None):
+        """FRESH zero row cache of `model` ([rp, length] budget) for a
+        donated prefill call (the donation consumed the last one —
+        reusing it would hand jit a deleted buffer): one launch of the
+        program compiled for this (model, width, length, kv_quant) at
+        its first wave."""
+        with self._span("serving/prefill/template", "prefill_template_ns"):
+            key = (model, rp, length, kv_quant)
+            program = self._zero_programs.get(key)
+            if program is None:
+                program = self._zero_programs[key] = _zeros_program(
+                    jax.eval_shape(functools.partial(
+                        init_cache, model, rp, length, kv_quant=kv_quant)))
+            self._dispatches += 1
+            return program()
 
     def _kv_read_bytes(self, active: list) -> int:
         """Bytes of the cells the `active` rows have committed: what one
@@ -1516,21 +1548,22 @@ class ContinuousBatcher(_BatcherBase):
         # widths are pure shape substitution. _prefill_rows /
         # _prefill_suffix DONATE their cache argument (no device-side K/V
         # copy per wave), so each wave materializes fresh zeros into the
-        # donated slot instead of reusing a live template.
-        self._row_shapes: dict = {}
-        one = jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
-            init_cache(model, 1, self._max_len, kv_quant=self._kv_quant),
-        )
+        # donated slot instead of reusing a live template: one compiled
+        # zero-fill program per width (`_zero_rows`), built here from the
+        # shapes and compiled at the width's first wave.
+        one = jax.eval_shape(functools.partial(
+            init_cache, model, 1, self._max_len, kv_quant=self._kv_quant))
         rp = 1
         while True:
-            self._row_shapes[rp] = jax.tree.map(
+            self._zero_programs[
+                (model, rp, self._max_len, self._kv_quant)
+            ] = _zeros_program(jax.tree.map(
                 lambda s1, ab, _rp=rp: s1 if s1.shape == ab.shape
                 else jax.ShapeDtypeStruct(
                     (_rp,) + s1.shape[1:], s1.dtype
                 ),
                 one, raw_shapes,
-            )
+            ))
             if rp >= batch_size:
                 break
             rp = min(rp * 2, batch_size)
@@ -1725,19 +1758,10 @@ class ContinuousBatcher(_BatcherBase):
         self._dispatches += 1  # the four small host->device transfers
 
     def _row_template(self, rp: int):
-        """FRESH zero row cache for a donated prefill call, materialized
-        from shapes cached per wave size (the donation consumed the last
-        one — reusing it would hand jit a deleted buffer)."""
-        with self._span("serving/prefill/template", "prefill_template_ns"):
-            if rp not in self._row_shapes:
-                self._row_shapes[rp] = jax.tree.map(
-                    lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
-                    init_cache(self._model, rp, self._max_len,
-                               kv_quant=self._kv_quant),
-                )
-            self._dispatches += 1
-            return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
-                                self._row_shapes[rp])
+        """FRESH zero row cache (dense layout, this batcher's kv_quant)
+        for a donated prefill wave of `rp` rows."""
+        return self._zero_rows(self._model, rp, self._max_len,
+                               self._kv_quant)
 
     def _prefill_wave(self, prompts, last, rows, plens, n) -> np.ndarray:
         rp, bucket = prompts.shape
@@ -2410,8 +2434,6 @@ class SpeculativeContinuousBatcher(_BatcherBase):
         # of speculation, not serving capacity)
         self._init_capacity(self._tgt_cache,
                             cells_per_row=self._cache_len)
-        self._tgt_templates: dict = {}
-        self._drf_templates: dict = {}
         self._round_tokens = 0   # tokens produced by speculative rounds
         self._draft_proposed = 0  # num_draft per active row per round
         self._draft_accepted = 0  # committed beyond the guaranteed token
@@ -2444,26 +2466,13 @@ class SpeculativeContinuousBatcher(_BatcherBase):
         super()._validate_submit(prompt, max_new_tokens)
         validate_budget(self._draft, int(prompt.size), max_new_tokens)
 
-    def _template(self, shapes: dict, model, rp: int):
-        """Fresh zero rows for the donated prefill, from shapes cached
-        per wave size (see ContinuousBatcher._row_template)."""
-        with self._span("serving/prefill/template", "prefill_template_ns"):
-            if rp not in shapes:
-                shapes[rp] = jax.tree.map(
-                    lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
-                    init_cache(model, rp, self._cache_len),
-                )
-            self._dispatches += 1
-            return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
-                                shapes[rp])
-
     def _prefill_wave(self, prompts, last, rows, plens, n) -> np.ndarray:
         rp = prompts.shape[0]
         rng = None
         if self._temperature > 0.0:
             self._rng, rng = jax.random.split(self._rng)
-        tgt_tmpl = self._template(self._tgt_templates, self._model, rp)
-        drf_tmpl = self._template(self._drf_templates, self._draft, rp)
+        tgt_tmpl = self._zero_rows(self._model, rp, self._cache_len)
+        drf_tmpl = self._zero_rows(self._draft, rp, self._cache_len)
         with self._span("serving/prefill/run", "prefill_run_ns"):
             prompts_dev = jnp.asarray(prompts)
             last_dev = jnp.asarray(last)
