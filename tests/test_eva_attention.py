@@ -1,0 +1,468 @@
+"""attention='eva' (ops/eva_attention.py) against the plain reference
+(benchmarks/reference/evabyte.py, which imports nothing of the program), at
+a small size on the CPU with seeded weights, comparing LOGITS.
+
+Size: hidden 64, 4 heads of 16, window 32, chunk 4, 3 layers, vocabulary
+320. Everything runs in float32 at the highest matmul precision, so the
+two computations differ by the order of float32 sums alone: measured
+5e-6 on logits of magnitude 5. The tolerance is 1e-4, twenty times that;
+the same model with bfloat16 activations stands 1e-2 off and must fail it
+(`test_bfloat16_for_float32_fails_the_tolerance`).
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks.reference import evabyte as ref
+from tfde_tpu.inference import server
+from tfde_tpu.inference.decode import _decode_clone, generate, init_cache
+from tfde_tpu.inference.server import (ContinuousBatcher,
+                                       SpeculativeContinuousBatcher)
+from tfde_tpu.inference.speculative import _set_index_counters
+from tfde_tpu.models.gpt import GPT, gpt_tiny_test
+from tfde_tpu.models.transformer import UnitOffsetRMSNorm
+from tfde_tpu.observability.capacity import (CapacityLedger,
+                                             EvaCapacityLedger)
+from tfde_tpu.ops import eva_attention as eva_lib
+
+W, C, HEADS, LAYERS, VOCAB = 32, 4, 4, 3, 320
+DIMS = dict(hidden_size=64, intermediate_size=160, num_attention_heads=HEADS,
+            num_hidden_layers=LAYERS, vocab_size=VOCAB, rms_norm_eps=1e-5,
+            rope_theta=100000, window_size=W, chunk_size=C, init_std=0.15)
+TOL = 1e-4
+
+
+def eva_model(dtype=jnp.float32, **kw):
+    return GPT(vocab_size=VOCAB, hidden_size=64, depth=LAYERS,
+               num_heads=HEADS, mlp_dim=160, max_position=4096, dtype=dtype,
+               position="rope", rope_theta=1e5, norm="rms",
+               norm_unit_offset=True, ln_eps=1e-5, mlp_act="swiglu",
+               use_bias=False, tie_embeddings=False, attention="eva",
+               eva_window=W, eva_chunk=C, fp32_residual=True, **kw)
+
+
+@pytest.fixture(scope="module")
+def weights():
+    return ref.make_weights(7, DIMS)
+
+
+@pytest.fixture(scope="module")
+def params(weights):
+    return jax.tree.map(lambda x: x.astype(jnp.float32),
+                        ref.to_program_params(weights, HEADS))
+
+
+@pytest.fixture(autouse=True)
+def highest_precision():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+def rows_of(seed: int, lengths) -> list:
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, VOCAB, n).astype(np.int32) for n in lengths]
+
+
+def reference_logits(weights, row) -> np.ndarray:
+    return np.asarray(ref.forward(weights, jnp.asarray(row), DIMS))
+
+
+# ---------------------------------------------------------------------------
+# the full forward
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("length", [3, 31, 32, 33, 100, 128])
+def test_full_forward_matches_the_reference(weights, params, length):
+    (row,) = rows_of(length, [length])
+    got = eva_model().apply({"params": params}, row[None])[0]
+    want = reference_logits(weights, row)
+    assert np.abs(np.asarray(got) - want).max() < TOL
+
+
+def test_bfloat16_for_float32_fails_the_tolerance(weights, params):
+    (row,) = rows_of(1, [100])
+    got = eva_model(jnp.bfloat16).apply({"params": params}, row[None])[0]
+    assert np.abs(np.asarray(got) - reference_logits(weights, row)).max() \
+        > 10 * TOL
+
+
+def test_init_creates_the_two_pooling_vectors_per_layer():
+    model = eva_model()
+    tree = jax.eval_shape(lambda: model.init(
+        jax.random.key(0), np.zeros((1, 8), np.int32))["params"])
+    attn = tree["decoder"]["block_1"]["attn"]
+    assert attn["eva_phi"].shape == attn["eva_mu"].shape == (HEADS, 16)
+    assert "wpe" not in tree and "lm_head" in tree
+
+
+# ---------------------------------------------------------------------------
+# prefill of a padded bucket, then decode through the cache, one logit
+# vector a step: rows of different true lengths, a row frozen half way
+# ---------------------------------------------------------------------------
+
+def served_logits(model, params, rows, lengths, bucket, max_len,
+                  freeze=None):
+    """Teacher-forced serving of `rows` (each a full sequence): prefill
+    the first lengths[r] tokens right-padded to `bucket`, rewind the index
+    to the true lengths as admission does, then feed the rest one token a
+    step as `_decode_scan` does. `freeze` = (row, step): from that step on
+    the row is fed padding at a frozen index. Returns per row the logits
+    at positions lengths[r]-1 .. (one vector a fed position)."""
+    decode_model = _decode_clone(model)
+    n = len(rows)
+    lengths = np.asarray(lengths, np.int32)
+    prompts = np.zeros((n, bucket), np.int32)
+    for r, row in enumerate(rows):
+        prompts[r, :lengths[r]] = row[:lengths[r]]
+
+    @jax.jit
+    def prefill(cache, prompts, last):
+        cache = server._set_feed_pad(cache, bucket - 1 - last)
+        logits, mutated = decode_model.apply(
+            {"params": params, "cache": cache}, prompts, mutable=["cache"])
+        return mutated["cache"], logits[jnp.arange(n), last]
+
+    @jax.jit
+    def step(cache, feed, idx, done):
+        cache = _set_index_counters(cache, idx)
+        cache = server._set_feed_pad(cache, done)
+        logits, mutated = decode_model.apply(
+            {"params": params, "cache": cache}, feed[:, None],
+            mutable=["cache"])
+        return mutated["cache"], logits[:, 0]
+
+    cache, first = prefill(init_cache(model, n, max_len),
+                           jnp.asarray(prompts), jnp.asarray(lengths - 1))
+    out = [[np.asarray(first[r])] for r in range(n)]
+    idx = lengths.copy()
+    steps = max(len(row) for row in rows) - int(lengths.min())
+    for t in range(steps):
+        done = np.asarray([idx[r] >= len(rows[r]) or (
+            freeze is not None and r == freeze[0] and t >= freeze[1])
+            for r in range(n)])
+        feed = np.asarray([0 if done[r] else rows[r][idx[r]]
+                           for r in range(n)], np.int32)
+        cache, logits = step(cache, jnp.asarray(feed), jnp.asarray(idx),
+                             jnp.asarray(done))
+        for r in range(n):
+            if not done[r]:
+                out[r].append(np.asarray(logits[r]))
+                idx[r] += 1
+    return [np.stack(o) for o in out], cache
+
+
+# true lengths that end inside a chunk, on a chunk edge, on a window edge,
+# and in the bucket's last window; every row decodes across several chunk
+# edges, rows 0-2 across a window edge
+SERVED = dict(lengths=[37, 60, 64, 101], totals=[80, 75, 100, 130],
+              bucket=128, max_len=160)
+
+
+def worst_gap(weights, rows, lengths, got) -> float:
+    worst = 0.0
+    for row, n, logits in zip(rows, lengths, got):
+        want = reference_logits(weights, row)[n - 1:n - 1 + len(logits)]
+        worst = max(worst, float(np.abs(logits - want).max()))
+    return worst
+
+
+def test_prefill_and_decode_match_the_reference(weights, params):
+    rows = rows_of(3, SERVED["totals"])
+    got, _ = served_logits(eva_model(), params, rows, SERVED["lengths"],
+                           SERVED["bucket"], SERVED["max_len"])
+    assert [len(g) for g in got] == [
+        t - n + 1 for t, n in zip(SERVED["totals"], SERVED["lengths"])]
+    assert worst_gap(weights, rows, SERVED["lengths"], got) < TOL
+
+
+def test_a_frozen_row_leaves_the_others_and_its_summaries_alone(
+        weights, params):
+    """Row 1 stops after 3 steps with its index at 63, where one more key
+    would complete chunk 15; it is then fed padding 50 more times."""
+    rows = rows_of(3, SERVED["totals"])
+    got, cache = served_logits(eva_model(), params, rows, SERVED["lengths"],
+                               SERVED["bucket"], SERVED["max_len"],
+                               freeze=(1, 3))
+    assert len(got[1]) == 4
+    assert worst_gap(weights, rows, SERVED["lengths"], got) < TOL
+    table = cache["decoder"]["block_0"]["attn"]["eva_summary_key"]
+    assert np.abs(np.asarray(table[1, 14])).max() > 0    # keys 56-59
+    assert np.abs(np.asarray(table[1, 15:])).max() == 0  # never completed
+    assert np.abs(np.asarray(table[0, 80 // C:])).max() == 0
+
+
+def test_a_padded_tail_lands_in_no_summary(params):
+    """Bucket 128 behind true lengths 37 and 101: chunks 9.. and 25.. hold
+    padding (or are cut by the true length) and stay zero; the window kept
+    is the one the true length ends in."""
+    rows = rows_of(5, [37, 101])
+    _, cache = served_logits(eva_model(), params, rows, [37, 101], 128, 160)
+    attn = cache["decoder"]["block_2"]["attn"]
+    table = np.asarray(attn["eva_summary_key"])
+    assert np.abs(table[0, :9]).min(axis=(1, 2)).max() > 0
+    assert np.abs(table[0, 9:]).max() == 0
+    assert np.abs(table[1, :25]).min(axis=(1, 2)).max() > 0
+    assert np.abs(table[1, 25:]).max() == 0
+    assert attn["eva_window_key"].shape == (2, W, HEADS, 16)
+    assert attn["eva_summary_key"].shape == (2, 160 // C, HEADS, 16)
+    assert np.asarray(attn["feed_pad"]).tolist() == [0, 0]
+
+
+# three ways to get the layer wrong, each of which must show
+def _drop_summaries(monkeypatch):
+    real = eva_lib._merged_softmax
+    monkeypatch.setattr(
+        eva_lib, "_merged_softmax",
+        lambda s_loc, s_rem, ok_loc, ok_rem, v_loc, v_rem: real(
+            s_loc, s_rem, ok_loc, jnp.zeros_like(ok_rem), v_loc, v_rem))
+
+
+def _drop_mu(monkeypatch):
+    real = eva_lib.chunk_summaries
+    monkeypatch.setattr(
+        eva_lib, "chunk_summaries",
+        lambda k, v, phi, mu, scale, chunk: real(
+            k, v, phi, jnp.zeros_like(mu), scale, chunk))
+
+
+def _keep_the_old_window(monkeypatch):
+    real = eva_lib._merged_softmax
+
+    def stale(s_loc, s_rem, ok_loc, ok_rem, v_loc, v_rem):
+        if s_loc.shape[2] == 1:    # a decode step: every slot stays visible
+            ok_loc = jnp.ones_like(ok_loc)
+        return real(s_loc, s_rem, ok_loc, ok_rem, v_loc, v_rem)
+
+    monkeypatch.setattr(eva_lib, "_merged_softmax", stale)
+
+
+@pytest.mark.parametrize("break_it", [_drop_summaries, _drop_mu,
+                                      _keep_the_old_window])
+def test_a_broken_layer_fails_the_tolerance(weights, params, monkeypatch,
+                                            break_it):
+    break_it(monkeypatch)
+    rows = rows_of(3, SERVED["totals"])
+    got, _ = served_logits(eva_model(), params, rows, SERVED["lengths"],
+                           SERVED["bucket"], SERVED["max_len"])
+    assert worst_gap(weights, rows, SERVED["lengths"], got) > 100 * TOL
+
+
+# ---------------------------------------------------------------------------
+# through ContinuousBatcher
+# ---------------------------------------------------------------------------
+
+REQUESTS = ((37, 40), (50, 9), (64, 70), (101, 30), (33, 5), (70, 60))
+
+
+@pytest.fixture(scope="module")
+def served(params):
+    """Six requests through four rows: two lengths share each bucket,
+    budgets of 5 and 9 finish (and freeze) while the others run, three
+    requests cross a window edge, later ones reuse freed rows."""
+    with jax.default_matmul_precision("highest"):
+        srv = ContinuousBatcher(eva_model(), params, batch_size=4,
+                                max_len=192, scan_depth=4,
+                                prompt_buckets=(64, 128, 192))
+        prompts = rows_of(11, [n for n, _ in REQUESTS])
+        rids = [srv.submit(p, b) for p, (_, b) in zip(prompts, REQUESTS)]
+        out = dict(srv.run())
+    return srv, prompts, [out[r] for r in rids]
+
+
+@pytest.mark.parametrize("i", range(len(REQUESTS)))
+def test_batcher_serves_the_references_first_choice(weights, served, i):
+    _, prompts, outs = served
+    assert outs[i].size == REQUESTS[i][1]
+    gaps = ref.served_token_gaps(weights, prompts[i], outs[i], DIMS, 192)
+    # greedy in float32: the served byte is the reference's argmax, or a
+    # tie within the tolerance on the logits
+    assert float(gaps["gap"].max()) < TOL
+
+
+def test_batcher_counts_summaries_turns_and_cells(served):
+    srv, _, _ = served
+    stats = srv.stats()
+    # a request of prompt P and budget T commits P + T - 1 tokens
+    ends = [p + t - 1 for p, t in REQUESTS]
+    assert stats["eva_summaries_written"] == sum(e // C for e in ends)
+    assert stats["eva_window_turns"] == sum(
+        e // W - p // W for e, (p, _) in zip(ends, REQUESTS))
+    assert stats["eva_window_cells_read"] > 0
+    assert stats["eva_summary_cells_read"] > 0
+    assert set(EvaCapacityLedger.EVA_KEYS) <= set(stats)
+
+
+def test_a_dense_batcher_keeps_no_eva_counters():
+    model = gpt_tiny_test()
+    params = model.init(jax.random.key(0), np.zeros((1, 8), np.int32))[
+        "params"]
+    srv = ContinuousBatcher(model, params, batch_size=2, max_len=32)
+    assert not set(EvaCapacityLedger.EVA_KEYS) & set(srv.stats())
+    cache = init_cache(model, 2, 32)
+    same = server._set_feed_pad(cache, jnp.zeros(2, jnp.int32))
+    assert all(a is b for a, b in zip(jax.tree.leaves(cache),
+                                      jax.tree.leaves(same)))
+
+
+def test_generate_goes_through_the_same_layer(params, served):
+    """One prefill from position 0 and single steps at a shared index."""
+    _, prompts, outs = served
+    tokens = generate(eva_model(), params, jnp.asarray(prompts[0][None]),
+                      max_new_tokens=12)
+    tokens = tokens[0] if isinstance(tokens, tuple) else tokens
+    assert np.asarray(tokens)[0, 37:49].tolist() == outs[0][:12].tolist()
+
+
+# ---------------------------------------------------------------------------
+# what the layout refuses
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("kw,word", [
+    (dict(paged=True), "paged"),
+    (dict(kv_quant="int8"), "kv_quant"),
+    (dict(prefix_cache=True), "prefix cache"),
+    (dict(role="prefill"), "role"),
+    (dict(role="decode"), "role"),
+])
+def test_batcher_refuses_what_works_by_position(params, kw, word):
+    with pytest.raises(NotImplementedError, match=word):
+        ContinuousBatcher(eva_model(), params, batch_size=2, max_len=64,
+                          **kw)
+
+
+def test_speculative_batcher_refuses_the_layout(params):
+    with pytest.raises(NotImplementedError, match="Speculative"):
+        SpeculativeContinuousBatcher(eva_model(), eva_model(), params,
+                                     params, batch_size=2, max_len=64)
+
+
+def test_prime_and_submit_primed_are_refused(params):
+    srv = ContinuousBatcher(eva_model(), params, batch_size=2, max_len=64)
+    with pytest.raises(NotImplementedError, match="prime"):
+        srv.prime(np.arange(8, dtype=np.int32), 4)
+    primed = server.PrimedRequest(np.arange(8, dtype=np.int32), 1, 4, {})
+    with pytest.raises(NotImplementedError, match="submit_primed"):
+        srv.submit_primed(primed)
+
+
+@pytest.mark.parametrize("kw", [
+    dict(position="learned"), dict(sliding_window=8),
+    dict(num_kv_heads=2), dict(attn_logit_cap=30.0),
+])
+def test_the_layer_refuses_what_it_does_not_compute(kw):
+    model = eva_model().clone(**kw)
+    with pytest.raises(NotImplementedError, match="eva"):
+        jax.eval_shape(lambda: model.init(
+            jax.random.key(0), np.zeros((1, 8), np.int32)))
+
+
+def test_window_must_be_whole_chunks():
+    with pytest.raises(ValueError, match="multiple of chunk"):
+        eva_lib.prefill(*(jnp.zeros((1, 8, 1, 4)),) * 3, jnp.zeros((1, 4)),
+                        jnp.zeros((1, 4)), jnp.full((1,), 8), window=6,
+                        chunk=4, scale=1.0)
+
+
+# ---------------------------------------------------------------------------
+# capacity and least bytes, against hand arithmetic
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("n,live,visible,held", [
+    (0, 0, 0, 0),
+    (31, 31, 0, 7),       # first window: nothing remote yet
+    (32, 0, 8, 8),        # handed over: 8 summaries become visible
+    (37, 5, 8, 9),
+    (101, 5, 24, 25),     # 3 windows closed; chunk 24 written, not yet read
+])
+def test_ledger_counts_live_window_and_summaries(n, live, visible, held):
+    ledger = EvaCapacityLedger(4, W, 48, 4 * 80 * 1536, W, C)
+    assert ledger.attended(n) == (live, visible)
+    assert ledger.read_cells(n) == live + visible
+    assert ledger.row_cells(n) == live + held
+
+
+@pytest.mark.parametrize("before,after,decoding,written,turns", [
+    (0, 37, False, 9, 0),     # a prefill hands no window over
+    (37, 41, True, 1, 0),     # chunk 9 completes at 40
+    (62, 70, True, 2, 1),     # chunks 15 and 16, and the window at 64
+    (40, 40, True, 0, 0),     # a frozen row commits nothing
+])
+def test_ledger_counts_summaries_and_turns(before, after, decoding, written,
+                                           turns):
+    ledger = EvaCapacityLedger(4, W, 48, 4 * 80 * 1536, W, C)
+    ledger.note_commit(before, after, decoding=decoding)
+    ledger.note_scan([37, 101], 4)
+    assert ledger.counters == {
+        "eva_summaries_written": written, "eva_window_turns": turns,
+        "eva_window_cells_read": 4 * (5 + 5),
+        "eva_summary_cells_read": 4 * (8 + 24)}
+
+
+def test_the_model_chooses_the_ledger():
+    """`from_cache` reads the layout off the served model; a slab of one
+    cell per position has no counters of its own and its notes do nothing."""
+    eva = CapacityLedger.from_cache(init_cache(eva_model(), 2, 64), 2, 64,
+                                    model=eva_model())
+    assert type(eva) is EvaCapacityLedger
+    assert eva.cells_per_row == W + 64 // C and eva.attended(37) == (5, 8)
+    dense_model = gpt_tiny_test()
+    dense = CapacityLedger.from_cache(init_cache(dense_model, 2, 32), 2, 32,
+                                      model=dense_model)
+    assert type(dense) is CapacityLedger
+    dense.note_commit(0, 40)
+    dense.note_scan([40], 4)
+    assert dense.counters == {}
+
+
+def test_batcher_capacity_and_least_bytes(params):
+    srv = ContinuousBatcher(eva_model(), params, batch_size=4, max_len=192,
+                            scan_depth=4, prompt_buckets=(64, 128, 192))
+    # per row and layer: K and V of (32 window + 48 summary) cells of
+    # 4 heads x 16 float32 = 2 x 80 x 256 B
+    cell = 2 * HEADS * 16 * 4 * LAYERS
+    assert srv.kv_stats()["allocated_bytes"] == 4 * (W + 192 // C) * cell
+    for prompt in rows_of(2, [37, 101]):
+        srv.submit(prompt, 6)
+    srv.step()     # admits both, then one scan of 4 ticks at 37 and 101
+    stats, param_bytes = srv.stats(), server._count_params(params)[1]
+    assert stats["decode_least_bytes"] == 4 * (
+        param_bytes + ((5 + 8) + (5 + 24)) * cell)
+    assert stats["eva_window_cells_read"] == 4 * (5 + 5)
+    assert stats["eva_summary_cells_read"] == 4 * (8 + 24)
+    # now at 41 and 105 committed: 9 + 10 and 9 + 26 cells hold state
+    kv = srv.kv_stats()
+    assert kv["used_cells"] == (9 + 10) + (9 + 26)
+    assert kv["used_bytes"] == kv["used_cells"] * cell
+
+
+# ---------------------------------------------------------------------------
+# the block's other two switches
+# ---------------------------------------------------------------------------
+
+def test_unit_offset_norm_is_rms_norm_with_gain_one_plus_scale():
+    x = jax.random.normal(jax.random.key(0), (3, 5, 16)) * 3.0
+    g = 0.1 * jax.random.normal(jax.random.key(1), (16,))
+    got = UnitOffsetRMSNorm(epsilon=1e-5).apply({"params": {"scale": g}}, x)
+    want = x / jnp.sqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-5) * (1 + g)
+    assert np.abs(np.asarray(got - want)).max() < 1e-6
+
+
+def test_unit_offset_needs_rms():
+    model = gpt_tiny_test(norm_unit_offset=True)
+    with pytest.raises(ValueError, match="norm='rms'"):
+        model.init(jax.random.key(0), np.zeros((1, 8), np.int32))
+
+
+@pytest.mark.parametrize("fp32", [False, True])
+def test_residual_stream_dtype(fp32):
+    """With bfloat16 sublayers the stream is float32 only when asked."""
+    model = gpt_tiny_test(fp32_residual=fp32).clone(dtype=jnp.bfloat16)
+    params = model.init(jax.random.key(0), np.zeros((1, 8), np.int32))[
+        "params"]
+    _, state = model.apply({"params": params}, np.zeros((1, 8), np.int32),
+                           capture_intermediates=lambda m, _: m.name
+                           == "block_0")
+    (out,) = jax.tree.leaves(state["intermediates"])
+    assert out.dtype == (jnp.float32 if fp32 else jnp.bfloat16)

@@ -129,6 +129,35 @@ _PHASE_KEYS = (
 )
 
 
+def _refuse_eva(model, what: str) -> None:
+    """Features that rewind, share or re-encode cached state BY POSITION
+    have nothing to hold on to in the layout of attention='eva' (a
+    summary folds 16 positions into one cell; a window slot is reused
+    every 2,048)."""
+    if getattr(model, "attention", "full") == "eva":
+        raise NotImplementedError(
+            f"{what} is not built for attention='eva': its cached state "
+            f"is one window in progress and one summary per chunk, not a "
+            f"cell per position (models/transformer.py "
+            f"MultiHeadAttention._eva_attention)"
+        )
+
+
+def _set_feed_pad(cache, pad):
+    """Tell layers that derive state from the fed tokens how many
+    trailing tokens of the next call are padding, per row: every
+    `feed_pad` leaf becomes `pad` ([rows] int32). A cache without such a
+    leaf (position-indexed K/V, where the index rewind alone hides the
+    padding) comes back as it was, and the program is unchanged."""
+
+    def fix(path, leaf):
+        if str(getattr(path[-1], "key", path[-1])) == "feed_pad":
+            return jnp.asarray(pad, leaf.dtype)
+        return leaf
+
+    return jax.tree_util.tree_map_with_path(fix, cache)
+
+
 def _fetch(tree):
     """THE host sync: one blocking device->host fetch for everything the
     host loop needs this round. Kept as a module-level seam so tests can
@@ -157,7 +186,9 @@ def _decode_scan(model, cache, params, tok, idx, budget, done, seen, rng,
     (no separate sample_logits dispatch, no host `.at[]` seen update),
     and applies EOS/budget masking on device: a finishing row emits its
     last token, flips `done`, and thereafter feeds `pad_id` with a frozen
-    index (its pad K/V lands beyond the committed count — unreachable).
+    index (its pad K/V lands beyond the committed count — unreachable;
+    a layer that derives state from what it is fed is told through
+    `_set_feed_pad`).
 
     Returns (cache, tok, idx, budget, done, seen, rng, toks [B, K],
     emitted [B, K]): `toks[r]` masked to `pad_id` where not emitted;
@@ -177,6 +208,8 @@ def _decode_scan(model, cache, params, tok, idx, budget, done, seen, rng,
         # advance: frozen rows must NOT advance, and writing the [B]
         # vector here keeps the carry shape stable from tick one
         cache = _set_index_counters(cache, idx)
+        # a frozen row's feed is padding: it completes no chunk summary
+        cache = _set_feed_pad(cache, done)
         feed = jnp.where(done, jnp.int32(pad_id), tok)
         logits, mutated = model.apply(
             {"params": params, "cache": cache}, feed[:, None], train=False,
@@ -245,7 +278,11 @@ def _prefill_rows(model, row_cache, params, prompts, last, valid, rng,
     Returns (filled row cache, first tokens [R], seen rows [R, V] or
     None). Pad correctness rides the per-row index machinery: pad K/V
     lands beyond each row's committed count once the admission rewind
-    sets it to the TRUE prompt length."""
+    sets it to the TRUE prompt length. State that is DERIVED from the
+    tokens (attention='eva': which window is in progress, which chunks
+    are complete) cannot be hidden by a rewind, so the true lengths
+    reach such layers before the forward (`_set_feed_pad`)."""
+    row_cache = _set_feed_pad(row_cache, prompts.shape[1] - 1 - last)
     with jax.named_scope("prefill_rows"):
         logits, mutated = model.apply(
             {"params": params, "cache": row_cache}, prompts, train=False,
@@ -270,13 +307,15 @@ def _prefill_rows(model, row_cache, params, prompts, last, valid, rng,
 
 @functools.partial(jax.jit, donate_argnums=(0,))
 def _scatter_rows(cache, rows_cache, rows):
-    """Write an R-row prefill cache's K/V leaves into batch rows `rows`
+    """Write an R-row prefill cache's leaves into batch rows `rows`
     ([R] int32) in ONE donated update — the multi-row generalization of
-    the old per-row `.at[row].set` round-trip. Index counters pass
-    through (the decode scan rewrites them from the host's committed
-    counts every tick). Wave padding duplicates a real row verbatim, so
-    duplicate indices in `rows` write identical values and the scatter
-    stays deterministic."""
+    the old per-row `.at[row].set` round-trip. Every leaf whose first
+    axis is the row lands whole: K/V slabs, int8 scale sidecars, and for
+    attention='eva' the window in progress, the summary table and
+    `feed_pad`. Index counters pass through (the decode scan rewrites
+    them from the host's committed counts every tick). Wave padding
+    duplicates a real row verbatim, so duplicate indices in `rows` write
+    identical values and the scatter stays deterministic."""
 
     def merge(path, big, small):
         name = str(getattr(path[-1], "key", path[-1]))
@@ -638,6 +677,9 @@ class _BatcherBase:
         # the step's own account (_PHASE_KEYS): spans add the times, the
         # boundaries add the counts; stats() returns it
         self._phase = dict.fromkeys(_PHASE_KEYS, 0)
+        if role != "both":
+            _refuse_eva(model, f"role={role!r} (the primed hand-off ships "
+                               f"K/V by position)")
         # what one decode tick cannot avoid reading of the parameters
         self._param_bytes = _count_params(params)[1]
         # first tokens fetched in this step and not yet handed back: how
@@ -739,7 +781,7 @@ class _BatcherBase:
         cells = int(cells_per_row if cells_per_row is not None
                     else self._max_len)
         self._ledger = _capacity.CapacityLedger.from_cache(
-            cache, self._b, cells)
+            cache, self._b, cells, model=self._model)
         self._cap_model = _capacity.CapacityModel(self._ledger)
 
     def kv_stats(self) -> dict:
@@ -797,6 +839,7 @@ class _BatcherBase:
             raise RuntimeError(
                 f"{type(self).__name__} does not accept primed requests"
             )
+        _refuse_eva(self._model, "submit_primed() (K/V shipped by position)")
         if self._role == "prefill":
             raise RuntimeError("prefill-only replica cannot decode")
         prompt = self._check_request(primed.prompt, primed.max_new_tokens)
@@ -1030,9 +1073,12 @@ class _BatcherBase:
             return program()
 
     def _kv_read_bytes(self, active: list) -> int:
-        """Bytes of the cells the `active` rows have committed: what one
-        decode tick reads of the KV cache at the least, from shapes."""
-        cells = int(self._committed[active].sum())
+        """Bytes of the cells a decode tick of the `active` rows cannot
+        avoid reading (every committed cell of a K/V slab; the live
+        window and the visible summaries of attention='eva'), from
+        shapes."""
+        cells = sum(self._ledger.read_cells(n)
+                    for n in self._committed[active])
         return int(round(cells * self._ledger.cell_bytes))
 
     # -- hooks --------------------------------------------------------------
@@ -1301,6 +1347,8 @@ class _BatcherBase:
                     alloc, used = self._admission_cells(kind, key, group[i])
                     if self._ledger is not None:
                         self._ledger.note_admission(kind, alloc, int(used))
+                        self._ledger.note_commit(0, int(prompt.size),
+                                                 decoding=False)
                     phase["prefill_tokens"] += int(used)
                     # ladder padding repeats row 0, cells and all
                     copies = 1 + rp - n if i == 0 else 1
@@ -1462,6 +1510,8 @@ class ContinuousBatcher(_BatcherBase):
         kvq = (knobs.env_choice("TFDE_KV_QUANT") if kv_quant is None
                else str(kv_quant))
         self._kv_quant = None if kvq == "fp" else kvq
+        if self._kv_quant is not None:
+            _refuse_eva(model, f"kv_quant={self._kv_quant!r}")
         self._decode_model = _decode_clone(model, kv_quant=self._kv_quant)
         self._sampling = dict(
             temperature=float(temperature),
@@ -1489,6 +1539,7 @@ class ContinuousBatcher(_BatcherBase):
         self._paged = (knobs.env_flag("TFDE_PAGED_KV") if paged is None
                        else bool(paged))
         if self._paged:
+            _refuse_eva(model, "paged=True (the block pool)")
             block = DEFAULT_BLOCK
             self._kv_block = int(block)
             # +1 cell: the decode scan writes one-past-committed for
@@ -1579,6 +1630,8 @@ class ContinuousBatcher(_BatcherBase):
                 self._pool.set_evictor(self._prefix.evict)
         else:
             self._prefix = _resolve_prefix(prefix_cache)
+        if self._prefix is not None:
+            _refuse_eva(model, "the prefix cache")
         # device-resident loop state (tok/idx/budget/done); rebuilt from
         # host bookkeeping whenever admission desyncs it
         self._dev = None
@@ -1610,7 +1663,15 @@ class ContinuousBatcher(_BatcherBase):
         bucket; granted blocks under paging); `decode_least_bytes`, bytes:
         per scan, depth x (the parameters handed to the scan + the
         committed KV cells of its active rows), what the ticks cannot
-        avoid reading, computed from shapes and not measured."""
+        avoid reading, computed from shapes and not measured. Over a
+        model with attention='eva' those cells are the live window
+        positions and the visible summaries, and the ledger of that
+        layout adds its own counters (`EvaCapacityLedger.EVA_KEYS`):
+        `eva_summaries_written` (one per completed chunk and row,
+        prefill and decode), `eva_window_turns` (windows handed over in
+        decode), and per scan, depth x the cells its active rows attend
+        to at its start, `eva_window_cells_read` and
+        `eva_summary_cells_read`."""
         g = max(self._generated, 1)
         return {
             "rounds": self._rounds,
@@ -1621,6 +1682,7 @@ class ContinuousBatcher(_BatcherBase):
             "dispatches_per_token": self._dispatches / g,
             "syncs_per_token": self._syncs / g,
             **self._phase,
+            **self._ledger.counters,
         }
 
     def _round(self, active: list, finished: list) -> None:
@@ -1651,6 +1713,7 @@ class ContinuousBatcher(_BatcherBase):
             rng = self._rng if self._sampling["temperature"] != 0.0 else None
             self._phase["decode_least_bytes"] += depth * (
                 self._param_bytes + self._kv_read_bytes(active))
+            self._ledger.note_scan(self._committed[active], depth)
             with self._span("serving/decode/scan", "decode_dispatch_ns"):
                 self._mem_register(
                     f"serve/decode/k{depth}",
@@ -1696,7 +1759,9 @@ class ContinuousBatcher(_BatcherBase):
                 n_emitted += int(row.size)
                 # feeding each pending token committed it; the row's last
                 # sample stays pending
+                before = int(self._committed[r])
                 self._committed[r] += int(row.size)
+                self._ledger.note_commit(before, before + int(row.size))
                 for t in row:
                     finished.extend(self._take_token(r, int(t)))
             self._close_round(decode, traced, depth, len(active), n_emitted)
@@ -2278,6 +2343,7 @@ class ContinuousBatcher(_BatcherBase):
         long-prompt admissions without ever stalling a decode scan."""
         if self._role == "decode":
             raise RuntimeError("decode-only replica cannot prime")
+        _refuse_eva(self._model, "prime() (K/V shipped by position)")
         t_prime = now_ns()
         prompt = self._check_request(prompt, max_new_tokens)
         bucket = next(b for b in self._buckets if b >= prompt.size)
@@ -2407,6 +2473,9 @@ class SpeculativeContinuousBatcher(_BatcherBase):
     ):
         if num_draft < 1:
             raise ValueError(f"num_draft must be >= 1, got {num_draft}")
+        for m in (model, draft_model):
+            _refuse_eva(m, "SpeculativeContinuousBatcher (a rejected "
+                           "draft rewinds the cache by position)")
         super().__init__(model, params, batch_size, max_len, eos_id,
                          pad_id, rng, prompt_buckets)
         from tfde_tpu.inference.speculative import (

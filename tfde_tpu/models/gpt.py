@@ -124,6 +124,20 @@ class GPT(nn.Module):
     attn_logit_cap: Optional[float] = None
     # Gemma-2 final logit softcapping: logits = cap * tanh(logits / cap)
     final_logit_cap: Optional[float] = None
+    # 'full' | 'eva' (EvaByte: chunked linear attention, an exact softmax
+    # over the query's own aligned `eva_window` beside one learned summary
+    # per `eva_chunk` earlier positions; ops/eva_attention.py). Needs
+    # position='rope'; under decode=True it keeps windows and summaries,
+    # not position-indexed K/V (transformer.MultiHeadAttention)
+    attention: str = "full"
+    eva_window: int = 2048
+    eva_chunk: int = 16
+    # norm='rms' with the gain stored as 1 + scale (EvaByte's
+    # norm_add_unit_offset; transformer.UnitOffsetRMSNorm)
+    norm_unit_offset: bool = False
+    # residual stream and its adds in float32, the sublayers in `dtype`
+    # (EvaByte's fp32_skip_add)
+    fp32_residual: bool = False
 
     @nn.compact
     def __call__(self, input_ids: jax.Array, train: bool = False,
@@ -211,6 +225,11 @@ class GPT(nn.Module):
                     pos_index.value = pos_index.value + seq
             x = x + wpe(positions if positions.ndim == 2
                         else positions[None, :])
+        if self.fp32_residual:
+            # every block adds its sublayer's output to x: a float32 x
+            # keeps each sum in float32 (the norms read it in float32 and
+            # hand the sublayers `dtype`)
+            x = x.astype(jnp.float32)
         x = constrain(x, b, "seq")
         if self.dropout_rate > 0.0:
             x = nn.Dropout(self.dropout_rate, deterministic=not train)(x)
@@ -255,6 +274,10 @@ class GPT(nn.Module):
             moe_normalize_topk=self.moe_normalize_topk,
             moe_shared_expert_dim=self.moe_shared_expert_dim,
             router_z_loss_weight=self.router_z_loss_weight,
+            attention=self.attention,
+            eva_window=self.eva_window,
+            eva_chunk=self.eva_chunk,
+            norm_unit_offset=self.norm_unit_offset,
             name="decoder",
         )(x, mask=seg_mask, train=train)
         if self.tie_embeddings:

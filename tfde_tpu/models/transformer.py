@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from tfde_tpu.ops import attention as attn_lib
+from tfde_tpu.ops import eva_attention as eva_lib
 from tfde_tpu.ops.quant import QuantDenseGeneral, kv_dequantize, kv_quantize
 from tfde_tpu.ops.rotary import apply_rotary
 from tfde_tpu.parallel.axes import batch_axes, constrain
@@ -147,6 +148,15 @@ class MultiHeadAttention(nn.Module):
     # static program count as fp. Mutually exclusive with rolling_cache
     # (a rolling slot rewrites scales out of order with its payload).
     kv_quant: Optional[str] = None
+    # 'full' (softmax over every earlier key, optionally windowed) | 'eva'
+    # (ops/eva_attention.py: an exact softmax over the query's own aligned
+    # window of `eva_window` positions beside one learned summary per
+    # `eva_chunk` positions of everything before it). 'eva' adds two
+    # [heads, head_dim] parameters, `eva_phi` and `eva_mu`, and under
+    # decode=True a cache layout of its own (`_eva_decode_attention`).
+    attention: str = "full"
+    eva_window: int = 2048
+    eva_chunk: int = 16
 
     @property
     def kv_heads(self) -> int:
@@ -213,7 +223,13 @@ class MultiHeadAttention(nn.Module):
             q, k = self._rotate(q, k, jnp.zeros((), jnp.int32))
         # [B, S, H, D]: heads carry the tensor-parallel shard.
         q, k, v = (constrain(t, b, "seq", "tensor") for t in (q, k, v))
-        if self.decode:
+        if self.attention not in ("full", "eva"):
+            raise ValueError(
+                f"attention must be 'full' or 'eva', got {self.attention!r}"
+            )
+        if self.attention == "eva":
+            y = self._eva_attention(q, k, v, mask, b)
+        elif self.decode:
             if mask is not None:
                 raise NotImplementedError(
                     "decode mode builds its own cache-position mask; "
@@ -267,6 +283,98 @@ class MultiHeadAttention(nn.Module):
                 apply_rotary(k, pos, self.rope_theta,
                              rotary_dim=self.rope_dim,
                              scaling=self.rope_scaling))
+
+    def _eva_attention(self, q, k, v, mask, batch) -> jax.Array:
+        """attention='eva' (ops/eva_attention.py), forward and serving.
+
+        Under decode=True the "cache" collection holds, per row, what
+        the layer derives from the tokens and not the tokens' own K/V:
+        `eva_window_key/value` [B, min(W, max_len), H, D], the window in
+        progress (slot = position mod W, reused every W positions);
+        `eva_summary_key/value` [B, max_len // C, H, D], one summary per
+        chunk, written when the chunk's last key arrives; `cache_index`
+        as every layout has it; and `feed_pad` [B], how many trailing
+        tokens of THIS call are padding (0 unless the caller sets it;
+        read once and reset). A call with S > 1 is a prefill from
+        position 0 of right-padded rows (true length S - feed_pad); S = 1
+        is one decode step at `cache_index`, and a padded one (a finished
+        row held at a frozen index) completes no chunk. Which window is
+        live and which summaries a query sees follow from the index
+        alone, so a rewind of the index to the true prompt length after
+        a padded prefill is enough, as for the dense slab. What position-
+        indexed layouts offer (a rewind below a written summary, sharing
+        by position, int8 cells, a block pool) this layout does not."""
+        if (not self.causal or not self.rope or mask is not None
+                or self.kv_heads != self.num_heads
+                or self.window is not None
+                or self.attn_logit_cap is not None
+                or self.paged_blocks is not None or self.rolling_cache
+                or self.kv_quant is not None):
+            raise NotImplementedError(
+                "attention='eva' is causal, rotary, one K/V head per query "
+                "head, and keeps its own cache layout: no mask, sliding "
+                "window, logit cap, paged pool, rolling cache or int8 KV"
+            )
+        window, chunk = self.eva_window, self.eva_chunk
+        scale = (self.attn_scale if self.attn_scale is not None
+                 else self.head_dim ** -0.5)
+        shape = (self.num_heads, self.head_dim)
+        phi = self.param("eva_phi", nn.initializers.normal(0.02), shape,
+                         jnp.float32)
+        mu = self.param("eva_mu", nn.initializers.zeros, shape, jnp.float32)
+        bsz, sq = q.shape[:2]
+        kind = dict(window=window, chunk=chunk, scale=scale)
+
+        def plain_forward(q, k):
+            everything = jnp.full((bsz,), sq, jnp.int32)
+            return eva_lib.prefill(q, k, v, phi, mu, everything, **kind)[0]
+
+        if not self.decode:
+            return plain_forward(q, k)
+        is_filled = self.has_variable("cache", "eva_window_key")
+        wshape = (bsz, min(window, sq)) + shape
+        sshape = (bsz, max(1, sq // chunk)) + shape
+        win_k = self.variable("cache", "eva_window_key", jnp.zeros, wshape,
+                              k.dtype)
+        win_v = self.variable("cache", "eva_window_value", jnp.zeros, wshape,
+                              v.dtype)
+        sum_k = self.variable("cache", "eva_summary_key", jnp.zeros, sshape,
+                              k.dtype)
+        sum_v = self.variable("cache", "eva_summary_value", jnp.zeros,
+                              sshape, v.dtype)
+        cache_index = self.variable("cache", "cache_index",
+                                    lambda: jnp.zeros((), jnp.int32))
+        feed_pad = self.variable("cache", "feed_pad", jnp.zeros, (bsz,),
+                                 jnp.int32)
+        if not is_filled:
+            # init pass: the variables were just created from this call's
+            # [B, max_len] budget input; the plain forward
+            return plain_forward(*self._rotate(q, k, jnp.zeros((), jnp.int32)))
+        idx = cache_index.value
+        q, k = self._rotate(q, k, idx)
+        pad = feed_pad.value
+        if sq > 1:
+            lengths = sq - pad
+            y, kbar, vbar = eva_lib.prefill(q, k, v, phi, mu, lengths,
+                                            **kind)
+            size = win_k.value.shape[1]
+            new_wk = eva_lib.live_window(k, lengths, window, size)
+            new_wv = eva_lib.live_window(v, lengths, window, size)
+            n = min(kbar.shape[1], sum_k.value.shape[1])
+            new_sk = sum_k.value.at[:, :n].set(kbar[:, :n])
+            new_sv = sum_v.value.at[:, :n].set(vbar[:, :n])
+        else:
+            pos = jnp.broadcast_to(jnp.asarray(idx, jnp.int32), (bsz,))
+            y, new_wk, new_wv, new_sk, new_sv = eva_lib.decode_step(
+                q, k, v, win_k.value, win_v.value, sum_k.value, sum_v.value,
+                phi, mu, pos, pad == 0, **kind)
+        win_k.value = constrain(new_wk, batch, None, "tensor")
+        win_v.value = constrain(new_wv, batch, None, "tensor")
+        sum_k.value = constrain(new_sk, batch, None, "tensor")
+        sum_v.value = constrain(new_sv, batch, None, "tensor")
+        cache_index.value = idx + sq
+        feed_pad.value = jnp.zeros_like(pad)
+        return y
 
     def _decode_attention(self, q, k, v, batch) -> jax.Array:
         """Write this call's K/V into the cache, attend q over the filled
@@ -693,6 +801,39 @@ class Mlp(nn.Module):
         return h
 
 
+class UnitOffsetRMSNorm(nn.Module):
+    """RMSNorm whose gain is stored as its distance from one:
+    y = x / rms(x) * (1 + scale), `scale` starting at zero (the
+    `norm_add_unit_offset` convention of the Gemma and EvaByte releases).
+    Computed in float32 whatever the dtypes of `x` and `scale`."""
+
+    epsilon: float = 1e-6
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        scale = self.param("scale", nn.initializers.zeros, (x.shape[-1],),
+                           jnp.float32)
+        x = x.astype(jnp.float32)
+        rms = jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + self.epsilon)
+        return x * rms * (1.0 + scale.astype(jnp.float32))
+
+
+def make_norm(norm: str, eps: float, unit_offset: bool = False):
+    """The block's normalisation as a constructor taking `name=`:
+    'layer' | 'rms', float32 inside; `unit_offset` (rms only) stores the
+    gain as 1 + scale."""
+    if norm not in ("layer", "rms"):
+        raise ValueError(f"norm must be 'layer' or 'rms', got {norm!r}")
+    if unit_offset:
+        if norm != "rms":
+            raise ValueError("norm_unit_offset requires norm='rms'")
+        return functools.partial(UnitOffsetRMSNorm, epsilon=eps)
+    return functools.partial(
+        nn.RMSNorm if norm == "rms" else nn.LayerNorm,
+        epsilon=eps, dtype=jnp.float32, param_dtype=jnp.float32,
+    )
+
+
 class TransformerBlock(nn.Module):
     """Pre-LN (default): x + MHA(LN(x)); x + MLP(LN(x)) — the stable-training
     variant ViT/GPT use. `norm_style='post'`: LN(x + MHA(x)); LN(x + MLP(x))
@@ -736,6 +877,10 @@ class TransformerBlock(nn.Module):
     moe_normalize_topk: bool = True        # MoEMlp.normalize_topk
     moe_shared_expert_dim: Optional[int] = None  # MoEMlp.shared_expert_dim
     router_z_loss_weight: float = 0.0  # ST-MoE stabilizer (models/moe.py)
+    attention: str = "full"  # 'full' | 'eva' (MultiHeadAttention)
+    eva_window: int = 2048
+    eva_chunk: int = 16
+    norm_unit_offset: bool = False  # norm='rms' only (make_norm)
 
     @nn.compact
     def __call__(
@@ -744,12 +889,7 @@ class TransformerBlock(nn.Module):
         mask: Optional[jax.Array] = None,
         train: bool = False,
     ) -> jax.Array:
-        if self.norm not in ("layer", "rms"):
-            raise ValueError(f"norm must be 'layer' or 'rms', got {self.norm!r}")
-        ln = functools.partial(
-            nn.RMSNorm if self.norm == "rms" else nn.LayerNorm,
-            epsilon=self.ln_eps, dtype=jnp.float32, param_dtype=jnp.float32,
-        )
+        ln = make_norm(self.norm, self.ln_eps, self.norm_unit_offset)
         attn = MultiHeadAttention(
             num_heads=self.num_heads,
             head_dim=self.head_dim,
@@ -776,6 +916,9 @@ class TransformerBlock(nn.Module):
             qkv_bias=self.qkv_bias,
             qk_norm=self.qk_norm,
             ln_eps=self.ln_eps,
+            attention=self.attention,
+            eva_window=self.eva_window,
+            eva_chunk=self.eva_chunk,
             name="attn",
         )
         if self.num_experts > 0:
@@ -920,6 +1063,10 @@ class Encoder(nn.Module):
     moe_shared_expert_dim: Optional[int] = None
     router_z_loss_weight: float = 0.0
     moe_every: int = 2     # GShard convention: alternate dense / MoE
+    attention: str = "full"  # 'full' | 'eva' (MultiHeadAttention)
+    eva_window: int = 2048
+    eva_chunk: int = 16
+    norm_unit_offset: bool = False  # norm='rms' only (make_norm)
 
     @nn.compact
     def __call__(
@@ -990,13 +1137,14 @@ class Encoder(nn.Module):
                 moe_normalize_topk=self.moe_normalize_topk,
                 moe_shared_expert_dim=self.moe_shared_expert_dim,
                 router_z_loss_weight=self.router_z_loss_weight,
+                attention=self.attention,
+                eva_window=self.eva_window,
+                eva_chunk=self.eva_chunk,
+                norm_unit_offset=self.norm_unit_offset,
                 name=f"block_{i}",
             )
             x = body(block, x)
         if self.norm_style == "post":
             return x  # post-LN blocks already end normalized
-        norm_cls = nn.RMSNorm if self.norm == "rms" else nn.LayerNorm
-        return norm_cls(
-            epsilon=self.ln_eps, dtype=jnp.float32, param_dtype=jnp.float32,
-            name="ln_final",
-        )(x)
+        return make_norm(self.norm, self.ln_eps, self.norm_unit_offset)(
+            name="ln_final")(x)

@@ -50,9 +50,10 @@ from typing import Dict, Optional
 from tfde_tpu import knobs
 from tfde_tpu.observability import metrics
 
-#: cache-pytree bookkeeping leaves (prefix_cache.INDEX_LEAVES) — never
-#: K/V bytes; named here too so observability never imports inference
-_INDEX_LEAVES = ("cache_index", "position_index")
+#: cache-pytree bookkeeping leaves (prefix_cache.INDEX_LEAVES, and the
+#: per-call `feed_pad` of attention='eva') — never K/V bytes; named here
+#: too so observability never imports inference
+_INDEX_LEAVES = ("cache_index", "position_index", "feed_pad")
 
 #: unit-interval buckets for pad-waste fractions — the default registry
 #: ladder is a seconds scale and would collapse every observation into
@@ -155,9 +156,14 @@ class CapacityLedger:
 
     @classmethod
     def from_cache(cls, cache, batch_size: int, cells_per_row: int,
-                   registry: Optional[metrics.Registry] = None
-                   ) -> "CapacityLedger":
-        """Build a ledger from a freshly-initialized dense slab."""
+                   registry: Optional[metrics.Registry] = None,
+                   model=None) -> "CapacityLedger":
+        """Build a ledger from a freshly-initialized dense slab. The
+        served `model`, where given, says which layout the slab has:
+        attention='eva' gets the ledger of windows and summaries."""
+        if getattr(model, "attention", "full") == "eva":
+            return EvaCapacityLedger.of_model(cache, batch_size, model,
+                                              registry=registry)
         return cls(batch_size, cells_per_row, kv_slab_bytes(cache),
                    registry=registry, census=kv_dtype_census(cache))
 
@@ -184,6 +190,33 @@ class CapacityLedger:
         """The slab/pool dtype split (kv_dtype_census); {} when unknown."""
         return dict(self._census)
 
+    # -- what a row of `n` committed tokens holds and reads ------------------
+    def row_cells(self, n: int) -> int:
+        """Cells of a row's slab that hold live state once it has
+        committed `n` tokens: one per token here."""
+        return int(n)
+
+    def read_cells(self, n: int) -> int:
+        """Cells one decode tick of such a row cannot avoid reading:
+        every committed one here."""
+        return int(n)
+
+    # -- what the layout adds to the batcher's account ------------------------
+    @property
+    def counters(self) -> dict:
+        """Counters of this layout's own events, which the batcher hands
+        on in `stats()`: a slab of one cell per position has none."""
+        return {}
+
+    def note_commit(self, before: int, after: int,
+                    decoding: bool = True) -> None:
+        """A row went from `before` to `after` committed tokens, in a
+        decode scan or (`decoding` false) by its prefill."""
+
+    def note_scan(self, committed, depth: int) -> None:
+        """A decode scan of `depth` ticks starts over active rows at
+        these `committed` counts."""
+
     def _publish_census(self) -> dict:
         """Gauge + stats-dict surface of the dtype split: obs_dump's
         --capacity quantized-vs-fp columns read these (the dtype string
@@ -209,7 +242,7 @@ class CapacityLedger:
         for r in range(self._b):
             if req[r] is not None:
                 active += 1
-                used += int(committed[r])
+                used += self.row_cells(int(committed[r]))
         with self._lock:
             self._used_cells = used
             self._rows_active = active
@@ -272,6 +305,93 @@ class CapacityLedger:
                     for b in sorted(self._bucket_alloc)
                 },
             }
+
+
+class EvaCapacityLedger(CapacityLedger):
+    """Occupancy of the attention='eva' layout (models/transformer.py
+    `_eva_attention`): a row's slab is one window buffer of W positions
+    and a table of one summary per chunk of C positions, and a cell of
+    either kind is the same bytes (a key and a value of every head, in
+    every layer), so both count in one unit. A row that has committed
+    `n` tokens holds n mod W live window cells (the window is handed
+    over at every multiple of W) and n // C summaries; a decode tick
+    attends to the live window cells and to the summaries of the windows
+    already closed, (n // W) W / C of them: the summaries of the window
+    in progress are written and not yet read.
+
+    `counters` (EVA_KEYS): chunk summaries written (prefill and decode,
+    one per chunk and row, not per layer), windows handed over in
+    decode, and per scan, depth x what its active rows attend to at the
+    scan's start: live window positions and visible summaries."""
+
+    EVA_KEYS = ("eva_summaries_written", "eva_window_turns",
+                "eva_window_cells_read", "eva_summary_cells_read")
+
+    def __init__(self, batch_size: int, window_cells: int,
+                 summary_cells: int, slab_bytes: int, window: int,
+                 chunk: int, registry: Optional[metrics.Registry] = None,
+                 census: Optional[dict] = None):
+        super().__init__(batch_size, window_cells + summary_cells,
+                         slab_bytes, registry=registry, census=census)
+        self._window = int(window)
+        self._chunk = int(chunk)
+        self._counters = dict.fromkeys(self.EVA_KEYS, 0)
+
+    @classmethod
+    def of_model(cls, cache, batch_size: int, model,
+                 registry: Optional[metrics.Registry] = None
+                 ) -> "EvaCapacityLedger":
+        """From a freshly-initialized batch cache of `model`: the two
+        tables' lengths are read off one layer's leaves, window and
+        chunk off the model's fields."""
+        import jax
+
+        lengths = {str(getattr(path[-1], "key", path[-1])): leaf.shape[1]
+                   for path, leaf in jax.tree_util.tree_leaves_with_path(
+                       cache) if leaf.ndim > 1}
+        return cls(batch_size, lengths["eva_window_key"],
+                   lengths["eva_summary_key"], kv_slab_bytes(cache),
+                   model.eva_window, model.eva_chunk, registry=registry,
+                   census=kv_dtype_census(cache))
+
+    def attended(self, n: int) -> tuple:
+        """(live window cells, visible summaries) of a row at `n`."""
+        n = int(n)
+        return (n % self._window,
+                n // self._window * (self._window // self._chunk))
+
+    def row_cells(self, n: int) -> int:
+        return int(n) % self._window + int(n) // self._chunk
+
+    def read_cells(self, n: int) -> int:
+        return sum(self.attended(n))
+
+    @property
+    def counters(self) -> dict:
+        with self._lock:
+            return dict(self._counters)
+
+    def note_commit(self, before: int, after: int,
+                    decoding: bool = True) -> None:
+        """The chunk summaries that completed, and when `decoding` the
+        windows that were handed over (a prefill keeps the window its
+        true length ends in and hands none over)."""
+        with self._lock:
+            self._counters["eva_summaries_written"] += (
+                after // self._chunk - before // self._chunk)
+            if decoding:
+                self._counters["eva_window_turns"] += (
+                    after // self._window - before // self._window)
+
+    def note_scan(self, committed, depth: int) -> None:
+        local = remote = 0
+        for n in committed:
+            live, visible = self.attended(n)
+            local += live
+            remote += visible
+        with self._lock:
+            self._counters["eva_window_cells_read"] += depth * local
+            self._counters["eva_summary_cells_read"] += depth * remote
 
 
 class PagedCapacityLedger(CapacityLedger):
