@@ -178,8 +178,11 @@ def train_phase(model, seq: int, steps: int) -> dict:
     hlo = step_fn.lower(state, batch, rng).compile().as_text()
     kernel_lines = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
     head_dim = model.head_dim or model.hidden_size // model.num_heads
+    # the forward's operands are [b, heads, seq, d], the fused backward's
+    # the model's own layout flattened, [b, seq, heads * d]
     operand = re.compile(
-        r"\[(\d+),%d,%d,%d\]" % (model.num_heads, seq, head_dim))
+        r"\[(\d+),(?:%d,%d,%d|%d,%d)\]" % (
+            model.num_heads, seq, head_dim, seq, model.num_heads * head_dim))
     kernel_batch = sorted({int(b) for ln in kernel_lines
                            for b in operand.findall(ln)})
     outputs = jax.tree_util.tree_leaves((state.params, metrics))

@@ -184,8 +184,9 @@ def test_auto_dispatch_flash_on_tpu_threshold(monkeypatch):
 
 @pytest.mark.parametrize("causal", [False, True])
 def test_pallas_backward_matches_jax_backward(rng, causal, monkeypatch):
-    """The Pallas dKV/dQ kernels against the blockwise-JAX backward oracle
-    (TFDE_FLASH_BWD=jax), asymmetric tile sizes, bf16 inputs."""
+    """TFDE_FLASH_BWD=pallas (the fused kernel for causal, the recurrence
+    for non-causal) against the recurrence forced by TFDE_FLASH_BWD=jax,
+    asymmetric tile sizes, bf16 inputs."""
     q, k, v = _qkv(rng, s=128, d=8, dtype=jnp.bfloat16)
 
     def loss(q, k, v):
@@ -203,6 +204,174 @@ def test_pallas_backward_matches_jax_backward(rng, causal, monkeypatch):
             np.asarray(a, np.float32), np.asarray(b, np.float32),
             rtol=5e-2, atol=5e-2,  # bf16 grads
         )
+
+
+def _grads_and_path(q, k, v, causal, bq, bk, window=None, cap=None):
+    """Flash gradients (interpreted), the path `_bwd` took and what it
+    counted, beside autodiff through the float32 reference."""
+    from tfde_tpu.observability import counters
+    from tfde_tpu.ops import flash_attention as fa
+    from tfde_tpu.ops.attention import grouped_attention
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal, bq, bk, True, window, None,
+                              cap)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    def loss_ref(q, k, v):
+        return jnp.sum(grouped_attention(q, k, v, causal=causal,
+                                         window=window, logit_cap=cap) ** 2)
+
+    before = counters.snapshot()
+    with fa.record_tile_visits() as counts:
+        got = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+        jax.block_until_ready(got)
+        jax.effects_barrier()
+    after = counters.snapshot()
+    bumped = {
+        name: after.get(f"flash/{name}", 0) - before.get(f"flash/{name}", 0)
+        for name in ("bwd_kernel_traces", "bwd_recurrence_traces")
+    }
+    want = jax.grad(loss_ref, argnums=(0, 1, 2))(
+        *(t.astype(jnp.float32) for t in (q, k, v)))
+    return got, want, dict(counts), bumped
+
+
+def _rel(a, b):
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
+                                       (jnp.bfloat16, 2e-2)],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("bq,bk", [(128, 128), (128, 64), (64, 128)])
+def test_fused_backward_matches_reference(rng, bq, bk, dtype, tol):
+    """The fused kernel over several tiles, batch 2, a head pair in one
+    128-lane block (two heads of 64): all three gradients against autodiff
+    through the reference, and the pairs its loop ran against the plan."""
+    from tfde_tpu.ops.flash_attention import bwd_tile_plan
+
+    q, k, v = _qkv(rng, b=2, s=512, h=2, d=64, dtype=dtype)
+    got, want, counts, bumped = _grads_and_path(q, k, v, True, bq, bk)
+    assert counts["bwd_path"] == "kernel"
+    assert bumped == {"bwd_kernel_traces": 1, "bwd_recurrence_traces": 0}
+    plan = bwd_tile_plan(512, bq, bk)
+    assert counts["bwd_dq_visits"] == counts["bwd_dkv_visits"] \
+        == plan["visits"]
+    assert counts["bwd_steps_executed"] == plan["visits"]
+    for a, b in zip(got, want):
+        assert a.dtype == dtype
+        assert _rel(a, b) <= tol
+
+
+@pytest.mark.parametrize("window,cap", [(96, None), (None, 30.0),
+                                        (160, 20.0)])
+def test_fused_backward_takes_window_and_cap(rng, window, cap):
+    """Sliding windows and the logit cap run through the kernel (two
+    blocks of two heads): its Q-tile loop follows the band."""
+    from tfde_tpu.ops.flash_attention import bwd_tile_plan
+
+    q, k, v = _qkv(rng, b=1, s=256, h=4, d=64)
+    got, want, counts, _ = _grads_and_path(q, k, v, True, 64, 64, window,
+                                           cap)
+    assert counts["bwd_path"] == "kernel"
+    plan = bwd_tile_plan(256, 64, 64, window=window)
+    assert counts["bwd_steps_executed"] == plan["visits"]
+    for a, b in zip(got, want):
+        assert _rel(a, b) <= 1e-5
+
+
+@pytest.mark.parametrize("name,causal,kv,knob,path", [
+    ("mha_causal", True, 2, None, "kernel"),
+    ("one_head_of_128", True, 1, None, "kernel"),
+    ("grouped_query", True, 1, None, "recurrence"),
+    ("non_causal", False, 2, None, "recurrence"),
+    ("knob_jax", True, 2, "jax", "recurrence"),
+])
+def test_bwd_chooses_from_its_operands(rng, monkeypatch, name, causal, kv,
+                                       knob, path):
+    """`_bwd` counts the path it took at trace time: the kernel for causal
+    multi-head attention, the recurrence for grouped-query, non-causal,
+    and wherever TFDE_FLASH_BWD=jax forces it; gradients match either
+    way."""
+    if knob:
+        monkeypatch.setenv("TFDE_FLASH_BWD", knob)
+    h, d = (1, 128) if name == "one_head_of_128" else (2, 64)
+    q = jnp.asarray(rng.standard_normal((1, 128, h, d)), jnp.float32)
+    k, v = (jnp.asarray(rng.standard_normal((1, 128, kv, d)), jnp.float32)
+            for _ in range(2))
+    got, want, counts, bumped = _grads_and_path(q, k, v, causal, 64, 64)
+    assert counts["bwd_path"] == path
+    assert bumped == {"bwd_kernel_traces": int(path == "kernel"),
+                      "bwd_recurrence_traces": int(path == "recurrence")}
+    for a, b in zip(got, want):
+        assert _rel(a, b) <= 1e-5
+
+
+def test_bwd_leaves_what_does_not_fit_vmem_to_the_recurrence(
+        rng, monkeypatch):
+    """A head block's working set over the budget (here by shrinking the
+    budget) is the recurrence's."""
+    from tfde_tpu.ops import flash_attention as fa
+
+    assert fa._bwd_kernel_vmem_bytes(4096, 128, 2, 512, 512) \
+        <= fa._BWD_KERNEL_VMEM_BUDGET
+    assert fa._bwd_kernel_vmem_bytes(131072, 128, 2, 512, 512) \
+        > fa._BWD_KERNEL_VMEM_BUDGET
+    monkeypatch.setattr(fa, "_BWD_KERNEL_VMEM_BUDGET", 1 << 16)
+    q, k, v = _qkv(rng, b=1, s=128, h=2, d=64)
+    got, want, counts, _ = _grads_and_path(q, k, v, True, 64, 64)
+    assert counts["bwd_path"] == "recurrence"
+    for a, b in zip(got, want):
+        assert _rel(a, b) <= 1e-5
+
+
+@pytest.mark.parametrize("h,d,heads", [(16, 64, 2), (8, 128, 1), (4, 256, 1),
+                                       (2, 8, 2), (8, 32, 4), (3, 64, None),
+                                       (4, 96, None)])
+def test_bwd_heads_per_block(h, d, heads):
+    from tfde_tpu.ops.flash_attention import _bwd_heads_per_block
+
+    assert _bwd_heads_per_block(h, d) == heads
+
+
+@pytest.mark.parametrize("s,bq,bk,window", [(512, 64, 64, None),
+                                            (512, 128, 64, None),
+                                            (512, 64, 128, 100),
+                                            (1024, 64, 64, 128)])
+def test_q_tile_range_is_the_band(s, bq, bk, window):
+    """The kernel's loop bounds per K tile enumerate `_band_tile_pairs`."""
+    from tfde_tpu.ops import flash_attention as fa
+
+    n_q = s // bq
+    pairs = set()
+    for kb in range(s // bk):
+        lo, hi = fa._q_tile_range(kb, bq, bk, n_q, window)
+        pairs |= {(qi, kb) for qi in range(int(lo), int(hi) + 1)}
+    assert pairs == set(fa._band_tile_pairs(s, bq, bk, True, window))
+
+
+def test_model_gradient_bumps_the_kernel_once_a_layer(rng):
+    """Tracing a causal LM's gradient through attn_impl='flash' takes the
+    fused kernel in every layer and the recurrence in none."""
+    from tfde_tpu.models.gpt import gpt_tiny_test
+    from tfde_tpu.observability import counters
+
+    model = gpt_tiny_test(attn_impl="flash")
+    tokens = jnp.asarray(rng.integers(0, 97, size=(2, 64)), jnp.int32)
+    params = model.init(jax.random.key(0), tokens)["params"]
+
+    def loss(p):
+        return jnp.sum(model.apply({"params": p}, tokens, train=False) ** 2)
+
+    before = counters.snapshot()
+    jax.jit(jax.grad(loss)).lower(params)
+    after = counters.snapshot()
+    assert after.get("flash/bwd_kernel_traces", 0) \
+        - before.get("flash/bwd_kernel_traces", 0) == model.depth == 2
+    assert after.get("flash/bwd_recurrence_traces", 0) \
+        == before.get("flash/bwd_recurrence_traces", 0)
 
 
 def test_flash_dispatch_keeps_batch_sharded():

@@ -14,15 +14,17 @@ from tfde_tpu.ops.flash_attention import flash_attention
 from tfde_tpu.training.train_state import TrainState
 
 
-def _flash_grad_text(monkeypatch, bwd):
-    monkeypatch.setenv("TFDE_FLASH_BWD", bwd)
+def _flash_grad_text(monkeypatch, bwd, kv_heads=2):
+    if bwd:
+        monkeypatch.setenv("TFDE_FLASH_BWD", bwd)
     q = jnp.zeros((1, 256, 2, 64), jnp.float32)
+    k = jnp.zeros((1, 256, kv_heads, 64), jnp.float32)
 
     def loss(q, k, v):
         return flash_attention(q, k, v, True, 128, 128, True).sum()
 
     return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-        q, q, q).as_text(debug_info=True)
+        q, k, k).as_text(debug_info=True)
 
 
 def _train_text(_monkeypatch):
@@ -67,8 +69,9 @@ def _serve_text(which):
 @pytest.mark.parametrize("name,text", [
     ("flash_fwd", lambda mp: _flash_grad_text(mp, "jax")),
     ("flash_bwd_pair_scan", lambda mp: _flash_grad_text(mp, "jax")),
-    ("flash_bwd_dkv", lambda mp: _flash_grad_text(mp, "pallas")),
-    ("flash_bwd_dq", lambda mp: _flash_grad_text(mp, "pallas")),
+    ("flash_bwd", lambda mp: _flash_grad_text(mp, "pallas")),
+    # grouped-query stays the recurrence's whatever the knob says
+    ("flash_bwd_pair_scan", lambda mp: _flash_grad_text(mp, None, 1)),
     ("optimizer_update", _train_text),
     ("head_loss", _train_text),
     ("prefill_rows", lambda mp: _serve_text("prefill")),

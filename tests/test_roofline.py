@@ -169,14 +169,19 @@ def test_measured_windowed_backward_skips_out_of_band_tiles():
     assert m["bwd_steps_executed"] == st["bwd_dq"]
 
 
-def test_measured_pallas_backward_visits_match_plan(monkeypatch):
-    """The Pallas dq/dkv kernel pair (TFDE_FLASH_BWD=pallas) predicates on
-    the same band: its traced visit counts per pass must equal the plan."""
-    monkeypatch.setenv("TFDE_FLASH_BWD", "pallas")
+@pytest.mark.parametrize("bwd,path", [("pallas", "kernel"),
+                                      ("jax", "recurrence")])
+def test_measured_backward_visits_match_plan(monkeypatch, bwd, path):
+    """The fused kernel (TFDE_FLASH_BWD=pallas, the default) walks the
+    same band as the recurrence: the traced visit counts equal the plan,
+    and so do the pairs its in-kernel loop ran."""
+    monkeypatch.setenv("TFDE_FLASH_BWD", bwd)
     st = rl.tile_visits(256, 64, 64, causal=True, window=64)
     m = rl.measured_tile_visits(seq=256, block_q=64, block_k=64, window=64)
+    assert m["bwd_path"] == path
     assert m["bwd_dq_visits"] == st["bwd_dq"]
     assert m["bwd_dkv_visits"] == st["bwd_dkv"]
+    assert m["bwd_steps_executed"] == st["bwd_dq"]
 
 
 def test_check_tile_visits_gate_passes():

@@ -94,9 +94,11 @@ _register(
          "(min_seq=1024 legacy spelling), 'auto'/'' the built-in ladder, an "
          "integer sets min_seq explicitly.",
          "ops/attention.py"),
-    Knob("TFDE_FLASH_BWD", "choice", "jax", ("jax", "pallas"),
-         "Flash-attention backward: 'jax' blockwise recurrence (measured "
-         "faster on v5e) or the Pallas dKV/dQ kernel pair (MHA only).",
+    Knob("TFDE_FLASH_BWD", "choice", "pallas", ("jax", "pallas"),
+         "Flash-attention backward: 'pallas' runs the fused kernel where it "
+         "applies (causal multi-head; 2.55 ms against the recurrence's 6.67 "
+         "at [2,4096,16,64] on v5e, PR 27) and the recurrence elsewhere; "
+         "'jax' forces the blockwise recurrence.",
          "ops/flash_attention.py"),
     # --- training / runtime ----------------------------------------------
     Knob("TFDE_PROFILE", "spec", None,

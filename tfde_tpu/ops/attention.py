@@ -9,7 +9,7 @@ Three implementations behind one dispatcher:
 
 - ``reference``: einsum + fp32 softmax. The numerics oracle; also what XLA
   fuses perfectly well at short sequence lengths.
-- ``flash``: Pallas TPU forward + blockwise backward (ops/flash_attention.py)
+- ``flash``: Pallas TPU forward + fused Pallas backward (ops/flash_attention.py)
   — online softmax, O(S) memory, MXU-shaped tiles. Hardware-qualified on
   TPU v5e (r04 A/B, tools/flash_ab.py: causal fwd+bwd 1.15x/1.28x/1.30x
   over the reference einsum at S=2048/4096/8192) — auto-dispatch uses it

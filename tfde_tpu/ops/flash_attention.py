@@ -19,24 +19,39 @@ Forward is a Pallas kernel (per /opt/skills/guides/pallas_guide.md):
 - `scale` and `logit_cap` (Gemma-2 tanh softcapping) apply inside the
   kernel, so capped/scaled models stay on the fused path.
 
-Backward DEFAULTS to the blockwise-JAX recurrence (`_bwd_blockwise`):
-recompute P tile-by-tile from the saved logsumexp, O(S) memory,
-XLA-scheduled matmuls. For causal (and windowed) attention the recurrence
-is a `lax.scan` over the STATICALLY enumerated in-band (Q-tile, K-tile)
-pairs (`_band_tile_pairs`) — strictly-future tiles and tiles outside the
-sliding band are never visited, so compute and DMA drop to ~half for plain
-causal and to O(S * window) for windowed, in both the dq and dk/dv
-accumulations (they share the pair scan). The non-causal backward keeps
-the r04-measured full K-tile scan (tools/flash_ab.py on v5e: 1.15x/1.28x/
-1.30x of the XLA reference einsum at S=2048/4096/8192 causal fwd+bwd),
-while the round-3 Pallas dK/dV + dQ kernel pair (`TFDE_FLASH_BWD=pallas`,
-FlashAttention-2 arrangement, retained below with 128-lane lse/delta
-layout and band-aware prefetch index maps) lands at 0.6-0.73x — XLA's own
-scheduling of the same recurrence beats the hand pipeline on this chip
-generation, so the kernel pair is opt-in until it wins a measurement.
+Backward of causal multi-head attention is ONE fused Pallas kernel
+(`_bwd_kernel`, `pallas_call(name="flash_bwd")`): for every in-band
+(K tile, Q tile) pair it recomputes the scores from the saved logsumexp
+once and updates dV, dK and dQ from them — five matmuls and one exp a
+score, the score tiles never leave VMEM.
+- it reads and writes the model's [B, S, H, D] layout through the free
+  [B, S, H*D] view: a block of whole heads fills the 128 lanes (two heads
+  at D=64, each head's K/V with the other's lanes zeroed), so nothing is
+  transposed in HBM;
+- scores are K-major (z^T = K Q^T, [bk, bq]): dV += p^T dO and dK += ds^T Q
+  are plain contractions and lse/delta are [1, bq] lane vectors;
+- grid (batch, head block, K tile); Q, dO and the float32 dQ accumulator
+  of the head block stay in VMEM whole, dQ is written once; an in-kernel
+  loop walks the live Q tiles of the K tile (`_q_tile_range`), so out-of-
+  band pairs cost nothing and the body is compiled once;
+- same arithmetic as the recurrence: bf16 operands into the MXU, float32
+  accumulation, p and ds rounded to the input dtype before their second
+  matmuls; window and logit cap run inside it.
+`_bwd` decides from its operands: causal, as many K/V heads as Q heads,
+whole heads tiling the lanes, a head block's working set inside VMEM take
+the kernel; grouped-query, non-causal and what does not fit keep the
+blockwise-JAX recurrences (`_bwd_pair_scan` over the statically enumerated
+in-band pairs for causal, the full K-tile scan `_bwd_blockwise*` for
+non-causal), and `TFDE_FLASH_BWD=jax` forces them. Measured at the training
+cells' shape ([2, 4096, 16, 64] bf16, causal, 512 tiles, one v5e chip; my
+chip run, PR 27, 40 calls back to back on the host's clock): the pair scan
+6.67 ms a call, the kernel 2.55 ms with the same gradients to the bit, the
+forward 2.90 ms. The two-kernel dK/dV + dQ pair it replaces (seven matmuls,
+two exp a score, lse/delta broadcast to 128 lanes in HBM) was at parity
+with the scan.
 
 The band membership predicate (`_tile_in_band`) is shared by the forward
-kernel, both backward paths, the DMA-eliding index maps, and the roofline
+kernel, every backward path, the DMA-eliding index maps, and the roofline
 tile-visit counter (ops/roofline.py) — one source of truth, so a counter
 regression in tier-1 means the kernels' schedule actually changed.
 
@@ -69,12 +84,14 @@ def record_tile_visits():
     Yields a dict that the forward/backward builders populate at TRACE
     time with the statically-known schedule: number of grid steps, number
     of in-band (executed) tile visits per pass, and the resolved tile
-    sizes. Because `pl.when` predication and the backward pair-scan length
+    sizes, and `bwd_path` ("kernel" or "recurrence"). Because `pl.when`
+    predication, the fused backward's Q-tile loop and the pair-scan length
     are decided by the same `_tile_in_band` predicate recorded here, these
     numbers are exactly the tiles the compiled kernels execute. The
-    causal/windowed backward additionally bumps `bwd_steps_executed` from
-    inside the scan body via `jax.debug.callback`, giving a runtime-
-    executed corroboration of the static plan.
+    causal backward additionally bumps `bwd_steps_executed` from inside
+    its loop via `jax.debug.callback` (the scan's body; the kernel's, one
+    block of heads, when interpreted), a runtime-executed corroboration of
+    the static plan.
 
     Recording happens when the call is traced — call the kernels directly
     (or with fresh shapes) inside the context rather than through an
@@ -605,282 +622,208 @@ def _bwd_blockwise_grouped(res, g, *, block_k: int, scale=None,
     )
 
 
-def _dkv_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-    dk_acc, dv_acc, *, causal, scale, window, logit_cap,
+def _bwd_heads_per_block(h: int, d: int):
+    """How many heads share one lane block of the [B, S, H*D] view the
+    fused backward reads, or None where no block of whole heads tiles the
+    128 lanes: D a multiple of 128 takes one head, a head axis of at most
+    128 lanes goes whole (the block is the full dimension), otherwise
+    128/D heads sit side by side (two at D=64)."""
+    if d % 128 == 0:
+        return 1
+    if h * d <= 128:
+        return h
+    if 128 % d == 0 and h % (128 // d) == 0:
+        return 128 // d
+    return None
+
+
+def _bwd_kernel_vmem_bytes(s: int, width: int, itemsize: int,
+                           block_q: int, block_k: int) -> int:
+    """What `_bwd_kernel` keeps in VMEM for one block of heads: Q, dO and
+    the dQ output whole and double-buffered, the float32 dQ accumulator,
+    the K/V/dK/dV tiles with their accumulators, lse and delta padded to
+    8 sublanes, and a dozen float32 score tiles of the compiler's."""
+    w = max(width, 128)
+    whole = s * w * (3 * 2 * itemsize + 4)
+    tiles = block_k * w * (4 * 2 * itemsize + 2 * 4)
+    rows = 2 * 2 * 8 * s * 4
+    return whole + tiles + rows + 12 * block_q * block_k * 4
+
+
+# The fused backward runs where a head block's working set stays under
+# this; v5e has 128 MiB of VMEM, and the kernel asks for what it needs.
+_BWD_KERNEL_VMEM_BUDGET = 96 << 20
+
+
+def _q_tile_range(kb, block_q: int, block_k: int, n_q: int, window):
+    """First and last Q tile `_tile_in_band` keeps for K tile `kb`: both
+    of its conditions are monotone in the Q tile, so the live tiles are
+    one run. Python ints or traced scalars, as `_tile_in_band`."""
+    lo = (kb * block_k) // block_q
+    if window is None:
+        return lo, n_q - 1
+    hi = (kb * block_k + block_k + window - 2) // block_q
+    return lo, jnp.minimum(hi, n_q - 1)
+
+
+def _bwd_kernel(
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+    dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc,
+    *, scale, window, logit_cap, heads, block_q, on_pair=None,
 ):
-    # grid (B, H, Sk/bk, Sq/bq) with the Q dimension minor: one K/V tile's
-    # gradient accumulators live in VMEM scratch while every Q tile streams
-    # past; refs are BHSD tiles [1, 1, bq|bk, D], lse/delta [1, 1, bq, 1].
+    # grid (B, H/heads, S/bk), the K tile minor. Operands are blocks of
+    # the [B, S, H*D] view the model has: q/dO/dq whole in S for this
+    # block of heads (fetched once, dQ accumulates in scratch and is
+    # written once), k/v/dk/dv one K tile. Scores are K-major, z^T =
+    # K Q^T [bk, bq]: dV += p^T dO and dK += ds^T Q are plain
+    # contractions and lse/delta are [1, bq] lane vectors.
     kb = pl.program_id(2)
-    qi = pl.program_id(3)
-    num_qi = pl.num_programs(3)
-    bq = q_ref.shape[2]
-    bk = k_ref.shape[2]
+    bk, w = k_ref.shape[1], k_ref.shape[2]
+    bq = block_q
+    n_q = q_ref.shape[1] // bq
+    d = w // heads
 
-    @pl.when(qi == 0)
-    def _init():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
-
-    def _step():
-        q = q_ref[0, 0]          # [bq, D]
-        k_blk = k_ref[0, 0]      # [bk, D]
-        v_blk = v_ref[0, 0]
-        do = do_ref[0, 0]        # [bq, D]
-        # lse/delta arrive broadcast to 128 lanes (layout, not data — a
-        # [bq, 1]-minor tile would force Mosaic's degenerate-lane path);
-        # col 0 carries the value
-        lse = lse_ref[0, 0, :, 0:1]      # [bq, 1]
-        delta = delta_ref[0, 0, :, 0:1]  # [bq, 1]
-        z = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale  # [bq, bk]
-        if logit_cap is not None:
-            s, t = _apply_cap(z, logit_cap)
-        else:
-            s = z
-        if causal:
-            rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            cols = kb * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            keep = rows >= cols
-            if window is not None:
-                keep = jnp.logical_and(keep, rows - cols < window)
-            s = jnp.where(keep, s, _NEG)
-        p = jnp.exp(s - lse)  # [bq, bk]
-        # dV += P^T dO
-        dv_acc[:] = dv_acc[:] + jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        # dP = dO V^T ; dS = P * (dP - delta) [* (1 - tanh^2) under cap]
-        dp = jax.lax.dot_general(
-            do, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta)
-        if logit_cap is not None:
-            ds = ds * (1.0 - t * t)
-        ds = ds * scale  # [bq, bk]
-        # dK += dS^T Q
-        dk_acc[:] = dk_acc[:] + jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-
-    if causal:
-        # Q tiles strictly above this K tile's first column see none of it;
-        # with a window, neither do Q tiles entirely past the band
-        pl.when(_tile_in_band(qi, kb, bq, bk, True, window))(_step)
-    else:
-        _step()
-
-    @pl.when(qi == num_qi - 1)
-    def _finalize():
-        dk_ref[0, 0] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
-
-
-def _dq_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc,
-    *, causal, scale, window, logit_cap,
-):
-    # grid (B, H, Sq/bq, Sk/bk) with K minor: one Q tile's dQ accumulates in
-    # VMEM scratch while K/V tiles stream past (same traversal as forward).
-    qi = pl.program_id(2)
-    kb = pl.program_id(3)
-    num_kb = pl.num_programs(3)
-    bq = q_ref.shape[2]
-    bk = k_ref.shape[2]
+    first_block = (pl.program_id(0) == 0) & (pl.program_id(1) == 0)
 
     @pl.when(kb == 0)
     def _init():
-        dq_acc[:] = jnp.zeros_like(dq_acc)
+        dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    def _step():
-        q = q_ref[0, 0]
-        k_blk = k_ref[0, 0]
-        v_blk = v_ref[0, 0]
-        do = do_ref[0, 0]
-        lse = lse_ref[0, 0, :, 0:1]      # 128-lane broadcast, col 0 (see
-        delta = delta_ref[0, 0, :, 0:1]  # _dkv_kernel)
-        z = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        if logit_cap is not None:
-            s, t = _apply_cap(z, logit_cap)
-        else:
-            s = z
-        if causal:
-            rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            cols = kb * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            keep = rows >= cols
-            if window is not None:
-                keep = jnp.logical_and(keep, rows - cols < window)
-            s = jnp.where(keep, s, _NEG)
-        p = jnp.exp(s - lse)
-        dp = jax.lax.dot_general(
-            do, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta)
-        if logit_cap is not None:
-            ds = ds * (1.0 - t * t)
-        ds = ds * scale  # [bq, bk]
-        dq_acc[:] = dq_acc[:] + jax.lax.dot_general(
-            ds.astype(k_blk.dtype), k_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-
-    if causal:
-        pl.when(_tile_in_band(qi, kb, bq, bk, True, window))(_step)
+    dk_acc[...] = jnp.zeros_like(dk_acc)
+    dv_acc[...] = jnp.zeros_like(dv_acc)
+    k2 = k_ref[0]
+    v2 = v_ref[0]
+    # heads side by side in the lanes: a head's K and V with the other
+    # heads' lanes zeroed contract over the whole block against the
+    # unmasked Q/dO, and each head's dK/dV keeps its own lanes
+    lane_head = jax.lax.broadcasted_iota(jnp.int32, (1, w), 1) // d
+    own = [lane_head == h for h in range(heads)]    # [1, w] each
+    if heads > 1:
+        ks = [jnp.where(m, k2, 0) for m in own]
+        vs = [jnp.where(m, v2, 0) for m in own]
     else:
-        _step()
+        ks, vs = [k2], [v2]
+    cols = kb * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, bq), 0)
 
-    @pl.when(kb == num_kb - 1)
+    def nt(a, b):  # contract the lanes of both: a b^T
+        return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+
+    def pair(qi, carry):
+        if on_pair is not None:  # the interpreted recorder's runtime count
+            jax.debug.callback(on_pair, first_block)
+        qs = pl.multiple_of(qi * bq, bq)
+        q2 = q_ref[0, pl.ds(qs, bq), :]      # [bq, w]
+        do2 = do_ref[0, pl.ds(qs, bq), :]
+        rows = qs + jax.lax.broadcasted_iota(jnp.int32, (bk, bq), 1)
+        keep = rows >= cols
+        if window is not None:
+            keep = jnp.logical_and(keep, rows - cols < window)
+        dq = None
+        for h in range(heads):
+            z = nt(ks[h], q2) * scale        # [bk, bq]
+            if logit_cap is not None:
+                z, t = _apply_cap(z, logit_cap)
+            z = jnp.where(keep, z, _NEG)
+            p = jnp.exp(z - lse_ref[0, 0, qi, h:h + 1, :])
+            ds = p * (nt(vs[h], do2) - delta_ref[0, 0, qi, h:h + 1, :])
+            if logit_cap is not None:
+                ds = ds * (1.0 - t * t)
+            p = p.astype(do2.dtype)
+            ds = ds.astype(q2.dtype)
+            dv_h = jnp.dot(p, do2, preferred_element_type=jnp.float32)
+            dk_h = jnp.dot(ds, q2, preferred_element_type=jnp.float32)
+            dq_h = jax.lax.dot_general(      # ds^T^T K_h: [bq, w]
+                ds, ks[h], (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            if h == 0:
+                dv_n, dk_n, dq = dv_h, dk_h, dq_h
+            else:
+                dv_n = jnp.where(own[h], dv_h, dv_n)
+                dk_n = jnp.where(own[h], dk_h, dk_n)
+                dq = dq + dq_h
+        dq_acc[pl.ds(qs, bq), :] += dq
+        dk_acc[...] += dk_n
+        dv_acc[...] += dv_n
+        return carry
+
+    lo, hi = _q_tile_range(kb, bq, bk, n_q, window)
+    jax.lax.fori_loop(lo, hi + 1, pair, 0)
+    # the scale of z = scale * q k^T reaches dq and dk through ds: applied
+    # once to the sums, as the recurrence does
+    dk_ref[0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
+    dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+    @pl.when(kb == pl.num_programs(2) - 1)
     def _finalize():
-        dq_ref[0, 0] = dq_acc[:].astype(dq_ref.dtype)
+        dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
-def _bwd_pallas(res, g, *, causal: bool, block_q: int, block_k: int,
-                interpret: bool, window=None, scale=None, logit_cap=None):
-    """FlashAttention-2 backward: dK/dV kernel + dQ kernel, O(S) memory."""
+def _bwd_fused(res, g, *, heads: int, block_q: int, block_k: int,
+               interpret: bool, window, scale: float, logit_cap):
+    """Causal multi-head backward as one kernel: every in-band (K tile,
+    Q tile) pair computes z, p, dp and ds once, in VMEM, and updates dV,
+    dK and dQ from them. Reads and writes the [B, S, H, D] layout the
+    model has; nothing but the outputs is written to HBM."""
     q, k, v, out, lse = res
     b, s, h, d = q.shape
-    if scale is None:
-        scale = 1.0 / (d ** 0.5)
-    block_q = _resolve_block(block_q, s)
-    block_k = _resolve_block(block_k, s)
     from jax.experimental.pallas import tpu as pltpu
 
-    if _TILE_COUNTS is not None:
-        n_q, n_k = s // block_q, s // block_k
-        visits = len(_band_tile_pairs(s, block_q, block_k, causal, window))
-        _TILE_COUNTS["bwd_grid"] = n_q * n_k
-        _TILE_COUNTS["bwd_dq_visits"] = visits
-        _TILE_COUNTS["bwd_dkv_visits"] = visits
+    counts, on_pair = _TILE_COUNTS, None
+    if counts is not None:
+        visits = len(_band_tile_pairs(s, block_q, block_k, True, window))
+        counts["bwd_grid"] = (s // block_q) * (s // block_k)
+        counts["bwd_dq_visits"] = visits
+        counts["bwd_dkv_visits"] = visits
+        if interpret:
+            # one block of heads' pairs as the kernel's loop ran them,
+            # beside the static plan (Mosaic lowers no host callback)
+            def on_pair(first_block):
+                if first_block:
+                    counts["bwd_steps_executed"] = (
+                        counts.get("bwd_steps_executed", 0) + 1
+                    )
 
+    n_q, w = s // block_q, heads * d
     # delta[b,h,s] = rowsum(dO * O), fp32 — cheap elementwise, stays in JAX
     delta = jnp.einsum(
         "bshd,bshd->bhs", g.astype(jnp.float32), out.astype(jnp.float32)
     )
-    # BSHD -> BHSD tiles; lse/delta broadcast to 128 lanes (the official
-    # TPU-kernel convention, MIN_BLOCK_SIZE lanes): a [*, 1]-minor block
-    # would put every per-step load on Mosaic's degenerate-lane layout
-    lanes = 128
-    qt, kt, vt, gt = (jnp.swapaxes(t, 1, 2) for t in (q, k, v, g))
-    lse4 = jnp.broadcast_to(lse[..., None], (b, h, s, lanes))
-    delta4 = jnp.broadcast_to(delta[..., None], (b, h, s, lanes))
 
-    def tile(n, idx):
-        return pl.BlockSpec((1, 1, n, d), idx)
+    def rows(x):  # [B, H, S] -> [B, H/heads, n_q, heads, bq] lane vectors
+        return x.reshape(b, h // heads, heads, n_q, block_q).swapaxes(2, 3)
 
-    def col(n, idx):
-        return pl.BlockSpec((1, 1, n, lanes), idx)
-
-    num_qi = s // block_q
-    if causal:
-        # Q tiles strictly above the K tile's first column are masked off —
-        # prefetch the first contributing Q tile instead of a dead copy;
-        # with a window, Q tiles entirely past the band park on the
-        # just-used last in-band tile (fetch elided) the same way the
-        # forward parks post-diagonal K tiles
-        def kq_q(bi, hi, kb, qi):
-            run = (qi + 1) * block_q - 1 >= kb * block_k
-            first = (kb * block_k) // block_q
-            if window is None:
-                return (bi, hi, jax.lax.select(run, qi, first), 0)
-            post = qi * block_q > kb * block_k + block_k - 1 + (window - 1)
-            run = jnp.logical_and(run, jnp.logical_not(post))
-            last = jnp.minimum(
-                (kb * block_k + block_k - 1 + (window - 1)) // block_q,
-                num_qi - 1,
-            )
-            return (
-                bi, hi,
-                jnp.where(run, qi, jnp.where(post, last, first)),
-                0,
-            )
-    else:
-        def kq_q(bi, hi, kb, qi):
-            return (bi, hi, qi, 0)
-
-    kq_k = lambda bi, hi, kb, qi: (bi, hi, kb, 0)
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, causal=causal, scale=scale,
-                          window=window, logit_cap=logit_cap),
-        grid=(b, h, s // block_k, s // block_q),
-        in_specs=[
-            tile(block_q, kq_q),   # q
-            tile(block_k, kq_k),   # k
-            tile(block_k, kq_k),   # v
-            tile(block_q, kq_q),   # dO
-            col(block_q, kq_q),    # lse
-            col(block_q, kq_q),    # delta
-        ],
-        out_specs=[tile(block_k, kq_k), tile(block_k, kq_k)],
+    flat = lambda x: x.reshape(b, s, h * d)
+    whole = pl.BlockSpec((1, s, w), lambda bi, hi, kb: (bi, 0, hi))
+    tile = pl.BlockSpec((1, block_k, w), lambda bi, hi, kb: (bi, kb, hi))
+    row = pl.BlockSpec((1, 1, n_q, heads, block_q),
+                       lambda bi, hi, kb: (bi, hi, 0, 0, 0))
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_bwd_kernel, scale=scale, window=window,
+                          logit_cap=logit_cap, heads=heads, block_q=block_q,
+                          on_pair=on_pair),
+        grid=(b, h // heads, s // block_k),
+        in_specs=[whole, tile, tile, whole, row, row],
+        out_specs=[whole, tile, tile],
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, s, d), k.dtype),
-            jax.ShapeDtypeStruct((b, h, s, d), v.dtype),
+            jax.ShapeDtypeStruct((b, s, h * d), q.dtype),
+            jax.ShapeDtypeStruct((b, s, h * d), k.dtype),
+            jax.ShapeDtypeStruct((b, s, h * d), v.dtype),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((s, w), jnp.float32),        # dQ, whole
+            pltpu.VMEM((block_k, w), jnp.float32),  # dK of this K tile
+            pltpu.VMEM((block_k, w), jnp.float32),  # dV
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_bwd_kernel_vmem_bytes(
+                s, w, q.dtype.itemsize, block_q, block_k),
+        ),
         interpret=interpret,
-        name="flash_bwd_dkv",
-    )(qt, kt, vt, gt, lse4, delta4)
-
-    qk_q = lambda bi, hi, qi, kb: (bi, hi, qi, 0)
-    if causal:
-        # K tiles strictly past the Q tile's last row: prefetch the next
-        # needed tile instead of a dead copy — mirrors the forward's
-        # parking (plain causal: tile 0, the next Q tile's first step;
-        # windowed: pre-band parks on first(qi), post-diagonal parks on
-        # the just-used diagonal tile)
-        def qk_k(bi, hi, qi, kb):
-            run = kb * block_k <= (qi + 1) * block_q - 1
-            if window is None:
-                return (bi, hi, jax.lax.select(run, kb, 0), 0)
-            pre_band = (
-                kb * block_k + block_k - 1 < qi * block_q - (window - 1)
-            )
-            run = jnp.logical_and(run, jnp.logical_not(pre_band))
-            first = jnp.maximum((qi * block_q - (window - 1)) // block_k, 0)
-            diag = ((qi + 1) * block_q - 1) // block_k
-            return (
-                bi, hi,
-                jnp.where(run, kb, jnp.where(pre_band, first, diag)),
-                0,
-            )
-    else:
-        qk_k = lambda bi, hi, qi, kb: (bi, hi, kb, 0)
-    (dq,) = pl.pallas_call(
-        functools.partial(_dq_kernel, causal=causal, scale=scale,
-                          window=window, logit_cap=logit_cap),
-        grid=(b, h, s // block_q, s // block_k),
-        in_specs=[
-            tile(block_q, qk_q),
-            tile(block_k, qk_k),
-            tile(block_k, qk_k),
-            tile(block_q, qk_q),
-            col(block_q, qk_q),
-            col(block_q, qk_q),
-        ],
-        out_specs=[tile(block_q, qk_q)],
-        out_shape=[jax.ShapeDtypeStruct((b, h, s, d), q.dtype)],
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=interpret,
-        name="flash_bwd_dq",
-    )(qt, kt, vt, gt, lse4, delta4)
-
-    return (
-        jnp.swapaxes(dq, 1, 2),
-        jnp.swapaxes(dk, 1, 2),
-        jnp.swapaxes(dv, 1, 2),
-    )
+        name="flash_bwd",
+    )(flat(q), flat(k), flat(v), flat(g), rows(lse), rows(delta))
+    return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
@@ -926,21 +869,33 @@ def _fwd(q, k, v, causal, block_q, block_k, interpret, window, scale,
 def _bwd(causal, block_q, block_k, interpret, window, scale, logit_cap,
          res, g):
     from tfde_tpu import knobs
+    from tfde_tpu.observability import counters
 
-    # default 'jax' (blockwise): the r04 hardware A/B (tools/flash_ab.py,
-    # v5e) times it at 1.15-1.30x of the XLA reference einsum while the
-    # Pallas dKV/dQ pair — even with 128-lane lse/delta layout and causal
-    # prefetch maps — lands at 0.6-0.73x. Same O(S) memory either way;
-    # TFDE_FLASH_BWD=pallas keeps the kernel pair selectable.
+    # The fused kernel where it applies, the recurrences elsewhere, decided
+    # from the operands: causal, as many K/V heads as Q heads (the kernel's
+    # dK/dV blocks are per query head; grouped-query would need a reduction
+    # across heads), whole heads tiling the 128 lanes, and a head block's
+    # working set inside VMEM. TFDE_FLASH_BWD=jax forces the recurrence.
     q, k = res[0], res[1]
-    if (knobs.env_choice("TFDE_FLASH_BWD") == "pallas"
-            and k.shape[2] == q.shape[2]):
-        # the kernel pair is MHA-only (its dK/dV out specs are per-q-head;
-        # GQA would need a cross-head reduction) — GQA always takes the
-        # blockwise recurrence, which is also the measured-faster default
-        return _bwd_pallas(res, g, causal=causal, block_q=block_q,
-                           block_k=block_k, interpret=interpret,
-                           window=window, scale=scale, logit_cap=logit_cap)
+    s, h, d = q.shape[1:]
+    if scale is None:
+        scale = 1.0 / (d ** 0.5)
+    bq, bk = _resolve_block(block_q, s), _resolve_block(block_k, s)
+    heads = _bwd_heads_per_block(h, d)
+    kernel = (
+        knobs.env_choice("TFDE_FLASH_BWD") == "pallas"
+        and causal and k.shape[2] == h and heads is not None
+        and _bwd_kernel_vmem_bytes(s, heads * d, q.dtype.itemsize, bq, bk)
+        <= _BWD_KERNEL_VMEM_BUDGET
+    )
+    counters.incr("flash/bwd_kernel_traces" if kernel
+                  else "flash/bwd_recurrence_traces")
+    if _TILE_COUNTS is not None:
+        _TILE_COUNTS["bwd_path"] = "kernel" if kernel else "recurrence"
+    if kernel:
+        return _bwd_fused(res, g, heads=heads, block_q=bq, block_k=bk,
+                          interpret=interpret, window=window, scale=scale,
+                          logit_cap=logit_cap)
     return _bwd_blockwise(res, g, causal=causal, block_q=block_q,
                           block_k=block_k, window=window, scale=scale,
                           logit_cap=logit_cap)

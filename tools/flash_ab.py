@@ -1,12 +1,11 @@
 """Hardware A/B of the flash-attention backward implementations.
 
-Times causal fwd+bwd at the bench shapes for three implementations:
-XLA reference einsum (autodiff), Pallas forward + Pallas dKV/dQ backward
-(TFDE_FLASH_BWD=pallas), Pallas forward + blockwise-JAX backward
-(TFDE_FLASH_BWD=jax). Prints one JSON line. Run on the live chip to pick
-the default backward (BENCH_builder_r04.json showed the round-3 Pallas
-pair at 0.55-0.69x of XLA — slower than the blockwise backward it
-replaced).
+Times causal fwd+bwd on the host's clock for three implementations: XLA
+reference einsum (autodiff), Pallas forward + the fused Pallas backward
+(TFDE_FLASH_BWD=pallas, the default), Pallas forward + blockwise-JAX
+backward (TFDE_FLASH_BWD=jax). 16 heads of 64: the S=4096 row is the
+training cells' shape. Prints one JSON line. What the cells themselves
+measure is in PERF.md; this is the quick look at a kernel change.
 """
 
 import json
@@ -67,7 +66,7 @@ def main():
         return window / reps
 
     for b, s in ((4, 2048), (2, 4096), (1, 8192)):
-        q, k, v = make_qkv(b, s, 12, 64)
+        q, k, v = make_qkv(b, s, 16, 64)
         times = {}
         for name, g in impls.items():
             os.environ["TFDE_FLASH_BWD"] = (
