@@ -99,3 +99,15 @@ def _assert_fake_devices():
 @pytest.fixture()
 def rng():
     return np.random.default_rng(0)
+
+
+@pytest.fixture()
+def bert_base_shapes():
+    """BERT-base's parameters as shapes, nothing allocated: what the byte
+    accounting of parallel/comms.py and parallel/zero.py is counted on."""
+    from tfde_tpu.models.bert import BertBase
+
+    model = BertBase(dropout_rate=0.0, pad_vocab=True)
+    return jax.eval_shape(
+        lambda: model.init(jax.random.key(0), np.zeros((2, 8), np.int32),
+                           train=False))["params"]

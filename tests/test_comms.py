@@ -108,13 +108,24 @@ def test_compress_mask_and_residual_structure():
         jax.tree_util.tree_structure(params)
 
 
-def test_comm_bytes_ratio_under_bar():
+@pytest.mark.parametrize("name", ["toy", "bert_base"])
+def test_comm_bytes_ratio_under_bar(name, bert_base_shapes):
+    """Bytes a step on the wire, counted from shapes: the int8 transport
+    is under 0.3 of the fp32 ring on 8 shards, for a toy tree and for
+    BERT-base (110 M parameters, nearly all of them in leaves the
+    transport compresses)."""
     cfg = comms.CommsConfig(transport="int8")
-    tree = {"w": jnp.zeros((1024, 1024)), "b": jnp.zeros((1024,))}
+    if name == "toy":
+        tree = {"w": jnp.zeros((1024, 1024)), "b": jnp.zeros((1024,))}
+    else:
+        tree = bert_base_shapes
     b = comms.comm_bytes(tree, cfg, nshards=8)
     assert b["ratio"] <= 0.3, b
-    assert b["compressed_elems"] == 1024 * 1024
-    assert b["fp32_elems"] == 1024
+    if name == "toy":
+        assert b["compressed_elems"] == 1024 * 1024
+        assert b["fp32_elems"] == 1024
+    else:
+        assert b["compressed_elems"] > 100e6 > 1e6 > b["fp32_elems"] > 0
     # fp32 transport reports identical wire cost on both keys
     b32 = comms.comm_bytes(tree, comms.CommsConfig(), nshards=8)
     assert b32["int8"] == b32["fp32"]

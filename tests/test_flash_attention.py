@@ -126,9 +126,8 @@ def test_flash_rejects_cross_attention_shapes(rng):
 
 
 def test_auto_dispatch_flash_on_tpu_threshold(monkeypatch):
-    """Auto-dispatch (hardware A/B r04, tools/flash_ab.py): flash on TPU
-    from S>=2048, reference below; TFDE_FLASH=0 disables, =1 lowers the
-    threshold."""
+    """Auto-dispatch: flash on TPU from S>=2048, reference below;
+    TFDE_FLASH=0 disables, =1 lowers the threshold."""
     import tfde_tpu.ops.attention as att
     import tfde_tpu.ops.flash_attention as fa
 
@@ -184,8 +183,8 @@ def test_auto_dispatch_flash_on_tpu_threshold(monkeypatch):
 
 @pytest.mark.parametrize("causal", [False, True])
 def test_pallas_backward_matches_jax_backward(rng, causal, monkeypatch):
-    """TFDE_FLASH_BWD=pallas (the fused kernel for causal, the recurrence
-    for non-causal) against the recurrence forced by TFDE_FLASH_BWD=jax,
+    """What `_bwd` picks (the fused kernel for causal, the recurrence for
+    non-causal) against the recurrence it takes when nothing fits VMEM,
     asymmetric tile sizes, bf16 inputs."""
     q, k, v = _qkv(rng, s=128, d=8, dtype=jnp.bfloat16)
 
@@ -195,9 +194,9 @@ def test_pallas_backward_matches_jax_backward(rng, causal, monkeypatch):
             ** 2
         )
 
-    monkeypatch.setenv("TFDE_FLASH_BWD", "pallas")
     gp = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-    monkeypatch.setenv("TFDE_FLASH_BWD", "jax")
+    monkeypatch.setattr(
+        "tfde_tpu.ops.flash_attention._BWD_KERNEL_VMEM_BUDGET", 0)
     gj = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(gp, gj):
         np.testing.assert_allclose(
@@ -282,21 +281,21 @@ def test_fused_backward_takes_window_and_cap(rng, window, cap):
         assert _rel(a, b) <= 1e-5
 
 
-@pytest.mark.parametrize("name,causal,kv,knob,path", [
+@pytest.mark.parametrize("name,causal,kv,budget,path", [
     ("mha_causal", True, 2, None, "kernel"),
     ("one_head_of_128", True, 1, None, "kernel"),
     ("grouped_query", True, 1, None, "recurrence"),
     ("non_causal", False, 2, None, "recurrence"),
-    ("knob_jax", True, 2, "jax", "recurrence"),
+    ("past_vmem", True, 2, 0, "recurrence"),
 ])
 def test_bwd_chooses_from_its_operands(rng, monkeypatch, name, causal, kv,
-                                       knob, path):
+                                       budget, path):
     """`_bwd` counts the path it took at trace time: the kernel for causal
     multi-head attention, the recurrence for grouped-query, non-causal,
-    and wherever TFDE_FLASH_BWD=jax forces it; gradients match either
-    way."""
-    if knob:
-        monkeypatch.setenv("TFDE_FLASH_BWD", knob)
+    and a head block past the VMEM budget; gradients match either way."""
+    if budget is not None:
+        monkeypatch.setattr(
+            "tfde_tpu.ops.flash_attention._BWD_KERNEL_VMEM_BUDGET", budget)
     h, d = (1, 128) if name == "one_head_of_128" else (2, 64)
     q = jnp.asarray(rng.standard_normal((1, 128, h, d)), jnp.float32)
     k, v = (jnp.asarray(rng.standard_normal((1, 128, kv, d)), jnp.float32)
@@ -352,12 +351,18 @@ def test_q_tile_range_is_the_band(s, bq, bk, window):
     assert pairs == set(fa._band_tile_pairs(s, bq, bk, True, window))
 
 
-def test_model_gradient_bumps_the_kernel_once_a_layer(rng):
+@pytest.mark.parametrize("path", ["kernel", "recurrence"])
+def test_model_gradient_bumps_the_kernel_once_a_layer(rng, monkeypatch,
+                                                      path):
     """Tracing a causal LM's gradient through attn_impl='flash' takes the
-    fused kernel in every layer and the recurrence in none."""
+    fused kernel in every layer and the recurrence in none; with no room
+    in VMEM, the reverse."""
     from tfde_tpu.models.gpt import gpt_tiny_test
     from tfde_tpu.observability import counters
 
+    if path == "recurrence":
+        monkeypatch.setattr(
+            "tfde_tpu.ops.flash_attention._BWD_KERNEL_VMEM_BUDGET", 0)
     model = gpt_tiny_test(attn_impl="flash")
     tokens = jnp.asarray(rng.integers(0, 97, size=(2, 64)), jnp.int32)
     params = model.init(jax.random.key(0), tokens)["params"]
@@ -368,10 +373,11 @@ def test_model_gradient_bumps_the_kernel_once_a_layer(rng):
     before = counters.snapshot()
     jax.jit(jax.grad(loss)).lower(params)
     after = counters.snapshot()
-    assert after.get("flash/bwd_kernel_traces", 0) \
-        - before.get("flash/bwd_kernel_traces", 0) == model.depth == 2
-    assert after.get("flash/bwd_recurrence_traces", 0) \
-        == before.get("flash/bwd_recurrence_traces", 0)
+    other = "recurrence" if path == "kernel" else "kernel"
+    assert after.get(f"flash/bwd_{path}_traces", 0) \
+        - before.get(f"flash/bwd_{path}_traces", 0) == model.depth == 2
+    assert after.get(f"flash/bwd_{other}_traces", 0) \
+        == before.get(f"flash/bwd_{other}_traces", 0)
 
 
 def test_flash_dispatch_keeps_batch_sharded():

@@ -32,9 +32,8 @@ def one_chip():
     ("one_head_of_128", (1, 2048, 4, 128), jnp.bfloat16, None, None),
     ("float32", (1, 2048, 4, 64), jnp.float32, None, None),
 ])
-def test_flash_gradient_compiles_for_v5e(one_chip, monkeypatch, name, shape,
-                                         dtype, window, cap):
-    monkeypatch.delenv("TFDE_FLASH_BWD", raising=False)
+def test_flash_gradient_compiles_for_v5e(one_chip, name, shape, dtype,
+                                         window, cap):
     q = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     def loss(q, k, v):

@@ -397,7 +397,18 @@ _REPLICA_CHILD = textwrap.dedent(
         max(time.perf_counter() - t0, 1e-9))
     os.remove(ckpt)
     led.begin("compile")
-    b = ContinuousBatcher(model, params, kv_quant="fp", batch_size=2, max_len=64)
+    gate_file = os.environ.get("DRILL_GATE_FILE", "")
+
+    class _Batcher(ContinuousBatcher):
+        # the overload drill holds the step loop by a file: while it
+        # exists the loop sees an idle batcher, so submits queue up to
+        # the cap and nothing runs
+        @property
+        def idle(self):
+            return ((bool(gate_file) and os.path.exists(gate_file))
+                    or super().idle)
+
+    b = _Batcher(model, params, kv_quant="fp", batch_size=2, max_len=64)
     rng = np.random.default_rng(rid)
     for ln in (4, 6):   # warm the compiles before announcing the port
         b.submit(rng.integers(1, 90, ln), 6)
@@ -1070,13 +1081,18 @@ def test_killed_worker_leaves_flight_file_and_goes_stale(tmp_path):
 
 def test_open_loop_poisson_overload_drill(tmp_path):
     """The PR-14 acceptance drill: two REAL capped replica processes
-    (TFDE_ADMIT_MAX_QUEUE from env) behind the Router, driven with an
-    open-loop Poisson arrival stream at ~2x measured capacity. Every
-    request must end in exactly one of three orderly ways — completed
-    with tokens greedy-bit-identical to solo generate(), rejected with a
-    well-formed 429 + Retry-After, or deadline-shed in-band — with zero
-    in-flight drops, at least one well-formed rejection, and admitted
-    p99 TTFT holding near the unloaded baseline."""
+    (TFDE_ADMIT_MAX_QUEUE from env) behind the Router. An open-loop
+    Poisson arrival stream: every request must end in exactly one of
+    three orderly ways — completed with tokens greedy-bit-identical to
+    solo generate(), rejected with a well-formed 429 + Retry-After, or
+    deadline-shed in-band — with zero in-flight drops and admitted p99
+    TTFT holding near the unloaded baseline. Then overflow by counts the
+    drill sets: with both step loops held, the cluster holds 2 replicas x
+    cap 2 requests and refuses every one offered beyond them. (How many
+    of the Poisson stream are refused depends on how fast the replicas
+    are beside the sender, so nothing is asserted on it: the tiny model
+    answers a request in 12 ms and absorbed a stream offered at twice a
+    capacity estimated from one request at a time.)"""
     import signal
     import threading
     import time
@@ -1108,6 +1124,8 @@ def test_open_loop_poisson_overload_drill(tmp_path):
     script = tmp_path / "child_replica.py"
     script.write_text(_REPLICA_CHILD)
     port_files = [str(tmp_path / f"port{i}") for i in range(2)]
+    gate = str(tmp_path / "gate")
+    queue_cap = 2
     reg = metrics.default_registry()
     reg.reset("router/")
 
@@ -1121,9 +1139,11 @@ def test_open_loop_poisson_overload_drill(tmp_path):
                 [os.path.dirname(os.path.dirname(__file__))]
                 + env.get("PYTHONPATH", "").split(os.pathsep)
             )
-            # the overload levers: tight queue cap per replica so ~2x
-            # load MUST overflow into 429s instead of unbounded queueing
-            env["TFDE_ADMIT_MAX_QUEUE"] = "2"
+            # the overload levers: a tight queue cap per replica, so load
+            # overflows into 429s instead of unbounded queueing, and the
+            # file that holds both step loops in phase 3
+            env["TFDE_ADMIT_MAX_QUEUE"] = str(queue_cap)
+            env["DRILL_GATE_FILE"] = gate
             env.pop("TFDE_ADMIT_MAX_QUEUED_TOKENS", None)
             env.pop("TFDE_ADMIT_TTFT_DEADLINE_MS", None)
             procs.append(
@@ -1163,9 +1183,8 @@ def test_open_loop_poisson_overload_drill(tmp_path):
         base_p99 = float(np.percentile(base_ttfts, 99))
         svc_rate = 6.0 / base_elapsed      # req/s at concurrency 1
 
-        # -- phase 2: open-loop Poisson at ~2x capacity -----------------
-        # capacity ~= concurrency-1 throughput x (2 replicas x batch 2);
-        # offer twice that so the capped queues must overflow
+        # -- phase 2: open-loop Poisson, eight times the rate of one
+        # request at a time ----------------------------------------------
         offered = 2.0 * svc_rate * 4.0
         arrivals = np.cumsum(rng.exponential(1.0 / offered,
                                              len(prompts) - 6))
@@ -1175,67 +1194,95 @@ def test_open_loop_poisson_overload_drill(tmp_path):
         # that only genuinely stuck requests shed
         dl_ms = max(2000.0, base_p99 * 1e3 * 20.0)
 
-        def fire(k, prompt, at):
+        def fire(into, k, prompt, at, **kw):
             time.sleep(max(0.0, at - (time.perf_counter() - t_load)))
             try:
                 out = request_generate(
                     router.url, prompt, budget, timeout=120,
-                    priority=classes[k % 3], ttft_deadline_ms=dl_ms)
-                results[k] = ("ok", out)
+                    priority=classes[k % 3], **kw)
+                into[k] = ("ok", out)
             except urllib.error.HTTPError as e:
                 body = e.read().decode(errors="replace")
-                results[k] = ("http", e.code,
-                              e.headers.get("Retry-After"), body)
+                into[k] = ("http", e.code,
+                           e.headers.get("Retry-After"), body)
             except RuntimeError as e:
-                results[k] = ("runtime", str(e))
+                into[k] = ("runtime", str(e))
             except Exception as e:   # anything else is a dropped request
-                results[k] = ("drop", repr(e))
+                into[k] = ("drop", repr(e))
+
+        def start(into, at_times, **kw):
+            threads = [
+                threading.Thread(target=fire, daemon=True, kwargs=kw,
+                                 args=(into, k, prompts[6 + k], at))
+                for k, at in enumerate(at_times)
+            ]
+            for t in threads:
+                t.start()
+            return threads
+
+        def finish(threads):
+            for t in threads:
+                t.join(timeout=180)
+                assert not t.is_alive(), "drill request never finished"
+
+        def sort_out(outcomes):
+            completed, rejected, shed = [], [], []
+            for k, res in enumerate(outcomes):
+                assert res is not None, f"request {k} vanished"
+                kind = res[0]
+                if kind == "ok":
+                    out = res[1]
+                    # greedy bit-identity survives overload for every
+                    # admitted request
+                    assert out["tokens"] == want[6 + k], f"request {k}"
+                    completed.append(out)
+                elif kind == "http":
+                    _, code, retry_after, body = res
+                    assert code == 429, res
+                    assert retry_after is not None \
+                        and int(retry_after) >= 1
+                    parsed = json.loads(body)
+                    assert parsed.get("retriable", True) in (True,)
+                    assert float(parsed["retry_after_s"]) > 0
+                    rejected.append(parsed)
+                elif kind == "runtime":
+                    assert "deadline_shed" in res[1], res
+                    shed.append(res)
+                else:
+                    raise AssertionError(f"in-flight drop: {res}")
+            return completed, rejected, shed
 
         t_load = time.perf_counter()
-        threads = [
-            threading.Thread(target=fire, args=(k, prompts[6 + k], at),
-                             daemon=True)
-            for k, at in enumerate(arrivals)
-        ]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join(timeout=180)
-            assert not t.is_alive(), "drill request never finished"
-
-        completed, rejected, shed = [], [], []
-        for k, res in enumerate(results):
-            assert res is not None, f"request {k} vanished"
-            kind = res[0]
-            if kind == "ok":
-                out = res[1]
-                # greedy bit-identity survives overload for every
-                # admitted request
-                assert out["tokens"] == want[6 + k], f"request {k}"
-                completed.append(out)
-            elif kind == "http":
-                _, code, retry_after, body = res
-                assert code == 429, res
-                assert retry_after is not None and int(retry_after) >= 1
-                parsed = json.loads(body)
-                assert parsed.get("retriable", True) in (True,)
-                assert float(parsed["retry_after_s"]) > 0
-                rejected.append(parsed)
-            elif kind == "runtime":
-                assert "deadline_shed" in res[1], res
-                shed.append(res)
-            else:
-                raise AssertionError(f"in-flight drop: {res}")
-
-        # the drill only proves something if the cluster actually both
-        # served and shed under the 2x offered load
+        finish(start(results, arrivals, ttft_deadline_ms=dl_ms))
+        completed, _, _ = sort_out(results)
         assert completed, results
-        assert rejected, "2x overload produced no 429s"
         # admitted latency holds: p99 TTFT within 1.5x the unloaded
         # baseline plus absolute slack for CI scheduling noise
         adm_p99 = float(np.percentile(
             [o["ttft_s"] for o in completed], 99))
         assert adm_p99 <= 1.5 * base_p99 + 0.75, (adm_p99, base_p99)
+
+        # -- phase 3: overflow, by counts the drill sets -----------------
+        # With both step loops held nothing leaves a queue and no row
+        # fills, so the cluster holds 2 x queue_cap requests and not one
+        # more: of 12 offered at once, at least 8 come back 429 while the
+        # loops are held, and none completes before they run again.
+        held_room, n_offered = 2 * queue_cap, 12
+        held = [None] * n_offered
+        with open(gate, "w"):
+            pass
+        t_load = time.perf_counter()
+        threads = start(held, [0.0] * n_offered)
+        deadline = time.time() + 120
+        while sum(r is not None for r in held) < n_offered - held_room:
+            assert time.time() < deadline, held
+            time.sleep(0.02)
+        assert all(r is None or r[0] == "http" for r in held), held
+        os.remove(gate)
+        finish(threads)
+        completed, rejected, shed = sort_out(held)
+        assert len(rejected) >= n_offered - held_room, held
+        assert 1 <= len(completed) <= held_room and not shed, held
 
         # recovery: once the wave passes, the cluster admits again and
         # still decodes solo-correct

@@ -135,7 +135,7 @@ def test_apply_defrag_moves_pool_rows_and_tables():
 
 
 # --------------------------------------------------------------------------
-# Bit-exactness: table gather == dense slab, column for column
+# Table gather == dense slab, column for column
 # --------------------------------------------------------------------------
 
 def _kv_leaves(cache, names):
@@ -150,7 +150,14 @@ def _kv_leaves(cache, names):
 def test_paged_gather_bit_exact_vs_dense(lm):
     """After one admission wave + scan, gathering each row's block table
     into position order must reproduce the dense cached_key/cached_value
-    cells bit for bit (the docstring claim in _paged_attention)."""
+    cells: the same committed counts and a table that addresses every
+    one of them, exactly; the cells themselves to 1e-5 (the name is from
+    when they also agreed to the bit). Two batchers compute them in two
+    sets of compiled programs (the ladder's prefill and the chunked one,
+    two decode scans), so a K or V of magnitude 3 differs in its last
+    places: 1.7e-6 at most here, float32, two layers, jax 0.9.0. A cell
+    read through the wrong block or position is another token's, off
+    by about 1."""
     model, params = lm
     kw = dict(batch_size=3, max_len=48, scan_depth=4, prefix_cache=False)
     bd = ContinuousBatcher(model, params, paged=False, **kw)
@@ -167,6 +174,7 @@ def test_paged_gather_bit_exact_vs_dense(lm):
     # to the next program (_tables_dirty); the gather below uses the
     # DEVICE tables, the state the scan actually ran with
     assert bp._tables_dirty or (tables == bp._tables).all()
+    assert bp._committed.tolist() == bd._committed.tolist()
     for dname, pname in (("cached_key", "pool_key"),
                          ("cached_value", "pool_value")):
         for dl, pl in zip(dense[dname], pool[pname]):
@@ -174,7 +182,8 @@ def test_paged_gather_bit_exact_vs_dense(lm):
                                           *pl.shape[2:])
             for r in range(3):
                 c = int(bd._committed[r])
-                np.testing.assert_array_equal(dl[r, :c], gathered[r, :c])
+                np.testing.assert_allclose(gathered[r, :c], dl[r, :c],
+                                           rtol=1e-5, atol=1e-5)
 
 
 # --------------------------------------------------------------------------

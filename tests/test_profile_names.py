@@ -15,8 +15,9 @@ from tfde_tpu.training.train_state import TrainState
 
 
 def _flash_grad_text(monkeypatch, bwd, kv_heads=2):
-    if bwd:
-        monkeypatch.setenv("TFDE_FLASH_BWD", bwd)
+    if bwd == "recurrence":  # nothing fits: what `_bwd` observes
+        monkeypatch.setattr(
+            "tfde_tpu.ops.flash_attention._BWD_KERNEL_VMEM_BUDGET", 0)
     q = jnp.zeros((1, 256, 2, 64), jnp.float32)
     k = jnp.zeros((1, 256, kv_heads, 64), jnp.float32)
 
@@ -67,11 +68,11 @@ def _serve_text(which):
 
 
 @pytest.mark.parametrize("name,text", [
-    ("flash_fwd", lambda mp: _flash_grad_text(mp, "jax")),
-    ("flash_bwd_pair_scan", lambda mp: _flash_grad_text(mp, "jax")),
-    ("flash_bwd", lambda mp: _flash_grad_text(mp, "pallas")),
-    # grouped-query stays the recurrence's whatever the knob says
-    ("flash_bwd_pair_scan", lambda mp: _flash_grad_text(mp, None, 1)),
+    ("flash_fwd", lambda mp: _flash_grad_text(mp, "recurrence")),
+    ("flash_bwd_pair_scan", lambda mp: _flash_grad_text(mp, "recurrence")),
+    ("flash_bwd", lambda mp: _flash_grad_text(mp, "kernel")),
+    # grouped-query is the recurrence's with the budget as it is
+    ("flash_bwd_pair_scan", lambda mp: _flash_grad_text(mp, "kernel", 1)),
     ("optimizer_update", _train_text),
     ("head_loss", _train_text),
     ("prefill_rows", lambda mp: _serve_text("prefill")),

@@ -144,6 +144,39 @@ def test_suppress_routes_to_ledger_overhead():
     assert _flat()["compile/memwatch_seconds_total"] > 0
 
 
+@pytest.mark.parametrize("transport", ["fp32", "int8"])
+@pytest.mark.parametrize("opt_sharding", ["replicated", "shard"])
+def test_train_step_compiles_in_its_first_call_only(opt_sharding, transport):
+    """Each transport x sharding combination of the data-parallel step
+    compiles when first called and never again: the second and third
+    steps (their inputs are the first step's outputs, the shapes and
+    shardings a steady loop feeds back) add no process compile."""
+    import optax
+
+    from tfde_tpu.models.cnn import PlainCNN
+    from tfde_tpu.parallel.strategies import MirroredStrategy
+    from tfde_tpu.training.step import init_state, make_train_step
+
+    if not recompile.install():
+        pytest.skip("no jax.monitoring hook on this JAX")
+    strategy = MirroredStrategy(grad_transport=transport,
+                                opt_sharding=opt_sharding)
+    rng = np.random.default_rng(0)
+    images = rng.random((16, 784), np.float32)
+    labels = rng.integers(0, 10, (16, 1)).astype(np.int32)
+    state, _ = init_state(PlainCNN(), optax.adam(1e-2), strategy, images)
+    step = make_train_step(strategy, state, donate=False)
+    before = recompile.process_compiles()
+    state, m = step(state, (images, labels), jax.random.key(0))
+    jax.block_until_ready(m["loss"])
+    first = recompile.process_compiles()
+    assert first > before
+    for i in (1, 2):
+        state, m = step(state, (images, labels), jax.random.key(i))
+        jax.block_until_ready(m["loss"])
+    assert recompile.process_compiles() == first
+
+
 def test_steady_state_decode_has_zero_unexpected_misses(rng):
     from tfde_tpu.inference.server import ContinuousBatcher
     from tfde_tpu.models.gpt import GPT

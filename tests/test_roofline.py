@@ -1,5 +1,5 @@
 """Roofline accounting (ops/roofline.py): the analytic attention-flop
-model that bench.py credits MFU with, and the tile-visit pins proving the
+model that MFU is credited with, and the tile-visit pins proving the
 flash forward AND backward execute only in-band tiles — the acceptance
 gate for the tile-skipping backward (visits <= O(S * window / block^2)
 per Q tile for both the dq and dk/dv passes).
@@ -11,7 +11,6 @@ traced visit counts -> runtime-executed scan steps."""
 import numpy as np
 import pytest
 
-import bench
 from tfde_tpu.ops import flash_attention as fa
 from tfde_tpu.ops import roofline as rl
 
@@ -77,15 +76,15 @@ def test_stacked_rejects_unknown_pattern():
 
 
 def test_bench_flop_model_credits_windowed_configs():
-    """bench.gpt_train_flops_per_token must charge windowed/alternate
-    configs their true in-band work (the gpt_long_win MFU denominator),
-    and the delta from plain causal must be exactly the attention term."""
+    """gpt_train_flops_per_token must charge windowed/alternate configs
+    their true in-band work (the MFU denominator), and the delta from
+    plain causal must be exactly the attention term."""
     h, m, d, s, v = 768, 3072, 12, 4096, 50257
-    full = bench.gpt_train_flops_per_token(h, m, d, s, v)
-    alt = bench.gpt_train_flops_per_token(h, m, d, s, v, window=1024,
-                                          window_pattern="alternate")
-    allw = bench.gpt_train_flops_per_token(h, m, d, s, v, window=1024,
-                                           window_pattern="all")
+    full = rl.gpt_train_flops_per_token(h, m, d, s, v)
+    alt = rl.gpt_train_flops_per_token(h, m, d, s, v, window=1024,
+                                       window_pattern="alternate")
+    allw = rl.gpt_train_flops_per_token(h, m, d, s, v, window=1024,
+                                        window_pattern="all")
     assert allw < alt < full
     want_delta = 3.0 * (
         rl.stacked_attention_flops_per_token(h, s, d, True)
@@ -169,22 +168,23 @@ def test_measured_windowed_backward_skips_out_of_band_tiles():
     assert m["bwd_steps_executed"] == st["bwd_dq"]
 
 
-@pytest.mark.parametrize("bwd,path", [("pallas", "kernel"),
-                                      ("jax", "recurrence")])
-def test_measured_backward_visits_match_plan(monkeypatch, bwd, path):
-    """The fused kernel (TFDE_FLASH_BWD=pallas, the default) walks the
-    same band as the recurrence: the traced visit counts equal the plan,
-    and so do the pairs its in-kernel loop ran."""
-    monkeypatch.setenv("TFDE_FLASH_BWD", bwd)
+@pytest.mark.parametrize("bwd", ["kernel", "recurrence"])
+def test_measured_backward_visits_match_plan(monkeypatch, bwd):
+    """The fused kernel walks the same band as the recurrence: the traced
+    visit counts equal the plan, and so do the pairs its in-kernel loop
+    ran."""
+    if bwd == "recurrence":  # nothing fits: what `_bwd` observes
+        monkeypatch.setattr(
+            "tfde_tpu.ops.flash_attention._BWD_KERNEL_VMEM_BUDGET", 0)
     st = rl.tile_visits(256, 64, 64, causal=True, window=64)
     m = rl.measured_tile_visits(seq=256, block_q=64, block_k=64, window=64)
-    assert m["bwd_path"] == path
+    assert m["bwd_path"] == bwd
     assert m["bwd_dq_visits"] == st["bwd_dq"]
     assert m["bwd_dkv_visits"] == st["bwd_dkv"]
     assert m["bwd_steps_executed"] == st["bwd_dq"]
 
 
 def test_check_tile_visits_gate_passes():
-    """The same gate tools/tier1.sh runs via tools/roofline.py
-    --check-tiles (covers the GQA head-folded case too)."""
+    """The tile-visit gate, whole (covers the GQA head-folded case
+    too)."""
     assert rl.check_tile_visits() == []

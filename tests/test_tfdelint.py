@@ -201,6 +201,19 @@ def test_unregistered_knob_fixture(tl, tmp_path):
     assert "tfde_tpu/knobs.py" in violations[0]
 
 
+def test_unread_knob_fixture(tl):
+    """The reverse: a registered knob no literal reads is a violation. A
+    member is read through its family's prefix, a family through any
+    member."""
+    flagged = tl.lint_unread_knobs(
+        ["TFDE_TRACE", "TFDE_RETRY_", "TFDE_ADMIT_MAX_QUEUE"])
+    named = {v.split("'")[1] for v in flagged}
+    assert all("is registered but no file" in v for v in flagged)
+    assert "TFDE_FLASH" in named and "TFDE_SLO_" in named
+    assert not named & {"TFDE_TRACE", "TFDE_RETRY_", "TFDE_RETRY_DEADLINE",
+                        "TFDE_ADMIT_", "TFDE_ADMIT_MAX_QUEUE"}
+
+
 # -- lintgate diff logic ------------------------------------------------------
 def _census(**over):
     c = {"all_reduce": 2, "reduce_scatter": 1, "all_gather": 2,

@@ -201,13 +201,32 @@ def test_opt_gauges_exported_at_step_build():
     assert reg.gauge("opt/param_gather_bytes").value == 0.0
 
 
-def test_comm_bytes_accounts_param_gather_leg():
-    tree = {"w": jnp.zeros((64, 64)), "b": jnp.zeros((5,))}
+@pytest.mark.parametrize("name", ["toy", "bert_base"])
+def test_comm_bytes_accounts_param_gather_leg(name, bert_base_shapes):
+    """Counted from shapes. For BERT-base also what the sharding is for:
+    Adam's state a device on 8 shards, an eighth of the replicated 877 MB
+    but for the padding (the bar is a quarter), and the gather leg that
+    `comm_bytes` charges is the layout's own."""
+    if name == "toy":
+        tree = {"w": jnp.zeros((64, 64)), "b": jnp.zeros((5,))}
+    else:
+        tree = bert_base_shapes
     rep = comms.comm_bytes(tree, comms.CommsConfig(), 8)
     sh = comms.comm_bytes(tree, comms.CommsConfig(), 8,
                           opt_sharding="shard")
     assert rep["param_gather"] == 0.0
     assert sh["param_gather"] > 0.0
+    if name == "bert_base":
+        tx = optax.adam(1e-3)
+        layout = zero.build_layout(tree, comms.CommsConfig(), 8)
+        rep_bytes = zero.state_bytes(jax.eval_shape(tx.init, tree))
+        sh_bytes = zero.state_bytes(
+            jax.eval_shape(lambda p: tx.init(zero.pack_params(p, layout)),
+                           tree), layout)
+        assert rep_bytes == pytest.approx(877e6, rel=0.01)
+        assert sh_bytes <= rep_bytes / 4.0
+        assert sh_bytes == pytest.approx(rep_bytes / 8.0, rel=0.01)
+        assert sh["param_gather"] == zero.param_gather_bytes(layout)
 
 
 def test_sharded_step_census_budget_and_payloads():
@@ -268,17 +287,37 @@ def test_state_without_layout_falls_back(caplog):
 
 
 # -- step parity --------------------------------------------------------------
+def _assert_same_trajectory(got, want):
+    """Parameters after a few Adam steps of 1e-2 through two compiled
+    programs that compute the same float32 mathematics (psum against
+    psum-scatter + chunk update + all-gather). The two are not equal to
+    the bit: XLA orders the reductions of each program as it likes, a
+    gradient element near zero then differs in its last place, and Adam's
+    m / (sqrt(v) + eps) turns that into up to 2e-4 of one step (read here:
+    1.6e-6 absolute on Dense_0's kernel after 4 steps, jax 0.9.0). So:
+    1e-5 absolute, a thousandth of a step, beside 1e-5 relative. A shard
+    that missed its update or its gather is off by a whole step, 1e-2."""
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(
+            np.asarray(g), np.asarray(w), rtol=1e-5, atol=1e-5,
+            err_msg=jax.tree_util.keystr(path))
+
+
 def test_fp32_shard_trajectory_bitwise_matches_replicated():
-    """fp32 x shard must be BIT-IDENTICAL to the replicated fp32 oracle:
-    the psum-scatter + chunk update + all-gather computes the same fp32
-    numbers (power-of-two batch/shard scalings commute exactly)."""
+    """fp32 x shard follows the replicated fp32 oracle: the psum-scatter +
+    chunk update + all-gather computes the same fp32 mathematics, in
+    another program (the name is from when the two also agreed to the
+    bit): losses within 1e-6 relative, parameters within
+    `_assert_same_trajectory`'s tolerance."""
     _, rep_state, rep_step, batch = _setup("replicated")
     _, sh_state, sh_step, _ = _setup("shard")
     for i in range(4):
         rep_state, mr = rep_step(rep_state, batch, jax.random.key(i))
         sh_state, ms = sh_step(sh_state, batch, jax.random.key(i))
-        assert float(mr["loss"]) == float(ms["loss"])
-    assert _digest(rep_state.params) == _digest(sh_state.params)
+        assert float(ms["loss"]) == pytest.approx(float(mr["loss"]),
+                                                  rel=1e-6)
+    _assert_same_trajectory(sh_state.params, rep_state.params)
 
 
 def test_fp32_shard_with_grad_accum_tracks_replicated():
@@ -325,8 +364,11 @@ def _run_steps(state, step, batch, keys):
 def test_checkpoint_cross_format_resume_bit_exact(tmp_path, write_mode,
                                                   resume_mode):
     """A checkpoint written under one opt_sharding mode resumes under the
-    other and lands bit-exact on the uninterrupted oracle — pack/unpack
-    are pure reshapes of the same numbers."""
+    other: what the restore hands back is the writer's parameters and
+    step to the bit (pack/unpack are pure reshapes of the same numbers),
+    and two further steps under the other format land on the
+    uninterrupted oracle within `_assert_same_trajectory`'s tolerance
+    (another program computes them)."""
     _, oracle, oracle_step, batch = _setup(write_mode)
     oracle = _run_steps(oracle, oracle_step, batch, range(4))
 
@@ -342,5 +384,6 @@ def test_checkpoint_cross_format_resume_bit_exact(tmp_path, write_mode,
     assert resumed is not None
     assert int(resumed.step) == 2
     assert resumed.opt_sharded == (resume_mode == "shard")
+    assert _digest(resumed.params) == _digest(writer.params)
     resumed = _run_steps(resumed, resume_step, batch, range(2, 4))
-    assert _digest(resumed.params) == _digest(oracle.params)
+    _assert_same_trajectory(resumed.params, oracle.params)

@@ -195,9 +195,7 @@ def device_resident_feed(
     """Fully ON-DEVICE input pipeline for datasets that fit in HBM: stage
     the arrays once, then every batch is a device-side gather — ZERO
     per-step host->device traffic, the terminal answer to an input-bound
-    link (bench.py measured the MNIST e2e path 8.7x off the compute path
-    over a slow host link, with per-batch transfer as the attributed
-    cost).
+    link.
 
     Semantics match `Dataset.from_tensor_slices(arrays).shuffle(n, seed)
     .repeat().batch(global_batch, drop_remainder=True)`: a fresh

@@ -1272,7 +1272,7 @@ class Router:
             return
 
 
-# -- blocking client (tests / bench / examples) ------------------------------
+# -- blocking client (tests / examples) ---------------------------------------
 def request_generate(router_url: str, prompt, max_new_tokens: int,
                      stream: bool = False, timeout: float = 120.0,
                      priority: Optional[str] = None,

@@ -19,8 +19,7 @@ Admission is then per-row cache surgery:
   penalty, `seen`-mask update included), per-row EOS/budget masking and
   index bookkeeping all live inside ONE jitted `lax.scan`, so the host
   pays one dispatch and one sync per K tokens per row instead of three
-  or more per token (the 97x serve-vs-decode gap BENCH_r05 measured was
-  exactly this host overhead);
+  or more per token;
 - finished rows freeze mid-scan: they feed `pad_id`, their index stops
   advancing, and their sampled output is masked — on-device, no host
   round-trip (a frozen row's final pad writes land beyond its committed
@@ -617,8 +616,8 @@ class _BatcherBase:
     `SpeculativeContinuousBatcher`: the request queue, per-row host
     bookkeeping (`_take_token`), batched bucket admission (`_admit`
     drives the subclass `_prefill_wave` hook), stats publication, and
-    the dispatch/sync accounting the bench and the regression-guard test
-    read back.
+    the dispatch/sync accounting that `stats()` hands to the benchmark's
+    readers and the regression-guard test.
 
     Invariant per active row r (the speculative-decoding contract): the
     cache holds K/V for exactly `committed[r]` tokens and `tok[r]` is the
@@ -1360,7 +1359,7 @@ class _BatcherBase:
                     # token (idempotent after the first request)
                     _boot.note_first_token()
                     if t0 is not None:
-                        # the TTFT decomposition the bench reports:
+                        # the TTFT decomposition:
                         # queue_wait (submit -> wave start) + prefill
                         # (the serving/prefill span) = first token
                         phase["queue_wait_ns"] += t_wave - t0
@@ -1552,7 +1551,7 @@ class ContinuousBatcher(_BatcherBase):
             )
             # default pool: every row can hold a full table, plus the
             # null block — capacity-neutral vs the dense slab; size it
-            # DOWN (the bench's A/B) to serve more rows than the dense
+            # DOWN to serve more rows than the dense
             # slab could under the same byte envelope
             nblocks = (int(pool_blocks) if pool_blocks is not None
                        else batch_size * self._nmax + 1)
@@ -1962,8 +1961,8 @@ class ContinuousBatcher(_BatcherBase):
 
     @property
     def block_pool(self):
-        """The shared BlockPool (None when dense) — bench/tests read
-        its stats; nothing else should allocate from it."""
+        """The shared BlockPool (None when dense) — tests read its
+        stats; nothing else should allocate from it."""
         return self._pool
 
     def _init_capacity(self, cache, cells_per_row=None) -> None:

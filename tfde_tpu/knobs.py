@@ -94,12 +94,6 @@ _register(
          "(min_seq=1024 legacy spelling), 'auto'/'' the built-in ladder, an "
          "integer sets min_seq explicitly.",
          "ops/attention.py"),
-    Knob("TFDE_FLASH_BWD", "choice", "pallas", ("jax", "pallas"),
-         "Flash-attention backward: 'pallas' runs the fused kernel where it "
-         "applies (causal multi-head; 2.55 ms against the recurrence's 6.67 "
-         "at [2,4096,16,64] on v5e, PR 27) and the recurrence elsewhere; "
-         "'jax' forces the blockwise recurrence.",
-         "ops/flash_attention.py"),
     # --- training / runtime ----------------------------------------------
     Knob("TFDE_PROFILE", "spec", None,
          ("<start>", "<start>:<stop>", "every:N", "every:N:S"),
@@ -339,28 +333,13 @@ _register(
          "tfde_tpu/analysis/hlolint.py"),
     Knob("TFDE_MEMGATE_INJECT", "flag", False, (),
          "Memgate self-test: seed a deliberate extra compile so the gate "
-         "must fail (tools/tier1.sh uses it to prove the gate bites).",
+         "must fail (tests/test_recompile.py uses it to prove the gate "
+         "bites).",
          "tools/memgate.py"),
     Knob("TFDE_LINTGATE_INJECT", "flag", False, (),
          "Lintgate self-test: lint two seeded-broken programs (a stray "
          "host callback, a dropped donation) so the gate must fail.",
          "tools/lintgate.py"),
-    Knob("TFDE_TRENDGATE_INJECT", "flag", False, (),
-         "Trendgate self-test: append a synthetic BENCH round with every "
-         "gated metric regressed past twice its slack so the gate must "
-         "fail.",
-         "tools/trendgate.py"),
-    # --- bench -------------------------------------------------------------
-    Knob("TFDE_BENCH_", "spec", None, (),
-         "Bench family prefix (see members below).",
-         "bench.py", prefix=True),
-    Knob("TFDE_BENCH_ALLOW_CPU", "flag", False, (),
-         "Let the measurement run on CPU and say so in the output "
-         "(otherwise a CPU backend is refused with a non-zero exit).",
-         "bench.py"),
-    Knob("TFDE_BENCH_SMOKE", "flag", False, (),
-         "Tiny shapes, path validation only — numbers are not reportable.",
-         "bench.py"),
 )
 
 

@@ -334,7 +334,7 @@ def next_token_loss(state, params, batch, rng):
     `_forward`). Without this an MoE GPT would train with unbalanced
     routing: sow() into an immutable collection is a silent no-op. Each
     sown loss is also surfaced as a metric (summed over layers) so
-    telemetry and the bench can watch router balance.
+    telemetry can watch router balance.
     """
     from tfde_tpu.ops.losses import masked_lm_loss
 

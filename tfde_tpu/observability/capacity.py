@@ -86,8 +86,8 @@ def kv_dtype_census(cache) -> dict:
     """Dtype split of a KV cache tree (index leaves and block tables
     excluded): payload vs scale-sidecar bytes, the payload leaf dtype,
     and the fp32-equivalent payload cost — what the same cells would
-    occupy unquantized at fp32 (the quantized-vs-fp delta obs_dump and
-    the bench report; for a bf16 model halve it mentally). Scale leaves
+    occupy unquantized at fp32 (the quantized-vs-fp delta obs_dump
+    reports; for a bf16 model halve it mentally). Scale leaves
     are the ``*_scale`` sidecars the int8 KV cache rides
     (models/transformer.py); an fp cache has none, so its split is all
     payload and ``kv_dtype`` names the storage float type."""
@@ -550,7 +550,7 @@ class PagedCapacityModel(CapacityModel):
     request may claim; the actual per-request block gate lives in the
     batcher's `_admit_capacity`). A positive ``TFDE_CAPACITY_BUDGET_
     BYTES`` first caps the grantable blocks at what the budget buys —
-    the same-envelope dense-vs-paged comparison the bench A/B runs.
+    the same-envelope dense-vs-paged comparison.
     """
 
     def headroom(self, occ: dict) -> dict:

@@ -215,7 +215,7 @@ class MetricsServer:
     """Chief-only scrape endpoint: `/metrics` (Prometheus text),
     `/metrics.json` (flattened snapshot), `/healthz`. Runs a
     ThreadingHTTPServer in a daemon thread; `port=0` binds an ephemeral
-    port (read it back from `.port` — the test/bench pattern).
+    port (read it back from `.port`, as the tests do).
 
     With an `aggregator` (observability/aggregate.ClusterAggregator)
     attached, `POST /push` ingests worker snapshots and `/metrics` appends

@@ -1,7 +1,7 @@
 """Recompile sentinel: jit-cache-miss detection for the hot entry points.
 
-Recompiles are a silent perf hazard: bench.py once read 0.7 TFLOPs because
-a recompile landed inside a timed window, and the serving pad-ladder can
+Recompiles are a silent perf hazard: a recompile that lands inside a
+timed window is read as step time, and the serving pad-ladder can
 churn buckets into fresh compilations with nothing counting them. The
 pjit-on-TPUv4 experience is that compile time is a first-class budget at
 scale — so it gets the same treatment as wall-clock: measured, attributed,
@@ -15,9 +15,8 @@ C++ fast path never re-enters Python). One process-global listener
 (installed lazily, idempotent) turns those events into:
 
 - ``compile/seconds_total`` / ``compile/process_compiles`` — process-wide
-  compile time and count, site or no site. ``process_compiles()`` is what
-  bench.py's window guard diffs to assert a timed window was
-  compile-free.
+  compile time and count, site or no site. Diff ``process_compiles()``
+  around a window to assert it was compile-free.
 - per-**site** attribution via a thread-local: a `Site` wraps one hot jit
   entry point (train step, a serving pad-ladder bucket); every call runs
   under ``site.watch(*fingerprint)`` and any compile event fired during
@@ -261,7 +260,7 @@ def site(name: str, stable: bool = False, expect: Optional[int] = None,
 
 
 def sites() -> Dict[str, dict]:
-    """{site name: snapshot} — the memgate/bench readout surface."""
+    """{site name: snapshot} — what tools/memgate.py reads out."""
     with _lock:
         items = list(_sites.items())
     return {name: s.snapshot() for name, s in items}
@@ -269,7 +268,7 @@ def sites() -> Dict[str, dict]:
 
 def process_compiles() -> int:
     """Actual XLA compiles observed process-wide (site or not) — the
-    number bench.py diffs around a timed window."""
+    number to diff around a window that must be compile-free."""
     return int(metrics.counter("compile/process_compiles").value)
 
 

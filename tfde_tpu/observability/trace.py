@@ -25,8 +25,8 @@ Flag discipline (the `spans.set_trace_active` rule): tracing is OFF by
 default and every hook begins with a single module-global check
 (`active()`), so the steady-state serving cost of this file is one
 pointer compare per call site. Enable with ``TFDE_TRACE=on`` (or an
-integer ring capacity) in the environment — `tools/tier1.sh` forwards
-it so the whole suite doubles as a tracing-on parity sweep — or
+integer ring capacity) in the environment — so `TFDE_TRACE=on
+tools/tier1.sh` makes the whole suite a tracing-on parity sweep — or
 programmatically with `enable()`.
 
 Exemplar linking: `note_exemplar(metric, value, trace_id)` keeps the

@@ -10,15 +10,13 @@ Three implementations behind one dispatcher:
 - ``reference``: einsum + fp32 softmax. The numerics oracle; also what XLA
   fuses perfectly well at short sequence lengths.
 - ``flash``: Pallas TPU forward + fused Pallas backward (ops/flash_attention.py)
-  — online softmax, O(S) memory, MXU-shaped tiles. Hardware-qualified on
-  TPU v5e (r04 A/B, tools/flash_ab.py: causal fwd+bwd 1.15x/1.28x/1.30x
-  over the reference einsum at S=2048/4096/8192) — auto-dispatch uses it
+  — online softmax, O(S) memory, MXU-shaped tiles. Auto-dispatch uses it
   on TPU from S>=2048 causal / S>=4096 non-causal (where its O(S) memory,
-  not speed, is the win). ``TFDE_FLASH=0`` disables; ``TFDE_FLASH=1``
+  not speed, is the win); the thresholds rest on a host-timed A/B older
+  than the ledger, and S=4096 causal is the only length a benchmark cell
+  runs (ROADMAP Speed 2). ``TFDE_FLASH=0`` disables; ``TFDE_FLASH=1``
   lowers both thresholds to S>=1024. Takes GQA shapes (k/v with fewer
-  heads) directly — the kernel folds each q head onto its serving KV head
-  (r04 hardware A/B vs the grouped einsum, h=16 kv=4 S=2048/4096: 1.14x/
-  0.99x causal, 1.13x with window=1024, grads <1% Frobenius error).
+  heads) directly — the kernel folds each q head onto its serving KV head.
 - ``ring``: sequence-parallel blockwise attention over the mesh's 'seq' axis
   (ops/ring_attention.py) — KV blocks rotate around the ring via ppermute
   while compute overlaps, so sequence length scales with the number of chips.
@@ -255,13 +253,10 @@ def attention(
 
     impl: 'auto' | 'reference' | 'flash' | 'ring'. 'auto' picks ring when the
     active mesh shards 'seq'; on TPU it picks flash for CAUSAL
-    self-attention at S >= 2048 (no mask) — the r04 hardware A/B
-    (tools/flash_ab.py, v5e: causal fwd+bwd 1.15x at 2048, 1.28x at 4096,
-    1.30x at 8192 with the blockwise backward; the causal whole-tile skip
-    is where the kernel wins) — and for non-causal at S >= 4096, where the
-    same A/B measured 0.87-0.97x (slightly slower) but the O(S) memory
-    replaces the reference's O(S^2) score tensor, the binding constraint at
-    long S. Below those, the reference einsum (XLA fuses it optimally).
+    self-attention at S >= 2048 (no mask; the causal whole-tile skip is
+    where the kernel wins) and for non-causal at S >= 4096, where the O(S)
+    memory replaces the reference's O(S^2) score tensor, the binding
+    constraint at long S. Below those, the reference einsum.
     ``TFDE_FLASH=0`` disables the flash auto-pick; ``TFDE_FLASH=1`` lowers
     both thresholds to S >= 1024.
 

@@ -42,7 +42,7 @@ whole heads tiling the lanes, a head block's working set inside VMEM take
 the kernel; grouped-query, non-causal and what does not fit keep the
 blockwise-JAX recurrences (`_bwd_pair_scan` over the statically enumerated
 in-band pairs for causal, the full K-tile scan `_bwd_blockwise*` for
-non-causal), and `TFDE_FLASH_BWD=jax` forces them. Measured at the training
+non-causal). Measured at the training
 cells' shape ([2, 4096, 16, 64] bf16, causal, 512 tiles, one v5e chip; my
 chip run, PR 27, 40 calls back to back on the host's clock): the pair scan
 6.67 ms a call, the kernel 2.55 ms with the same gradients to the bit, the
@@ -868,14 +868,13 @@ def _fwd(q, k, v, causal, block_q, block_k, interpret, window, scale,
 
 def _bwd(causal, block_q, block_k, interpret, window, scale, logit_cap,
          res, g):
-    from tfde_tpu import knobs
     from tfde_tpu.observability import counters
 
     # The fused kernel where it applies, the recurrences elsewhere, decided
     # from the operands: causal, as many K/V heads as Q heads (the kernel's
     # dK/dV blocks are per query head; grouped-query would need a reduction
     # across heads), whole heads tiling the 128 lanes, and a head block's
-    # working set inside VMEM. TFDE_FLASH_BWD=jax forces the recurrence.
+    # working set inside VMEM.
     q, k = res[0], res[1]
     s, h, d = q.shape[1:]
     if scale is None:
@@ -883,8 +882,7 @@ def _bwd(causal, block_q, block_k, interpret, window, scale, logit_cap,
     bq, bk = _resolve_block(block_q, s), _resolve_block(block_k, s)
     heads = _bwd_heads_per_block(h, d)
     kernel = (
-        knobs.env_choice("TFDE_FLASH_BWD") == "pallas"
-        and causal and k.shape[2] == h and heads is not None
+        causal and k.shape[2] == h and heads is not None
         and _bwd_kernel_vmem_bytes(s, heads * d, q.dtype.itemsize, bq, bk)
         <= _BWD_KERNEL_VMEM_BUDGET
     )

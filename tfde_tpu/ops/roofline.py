@@ -5,11 +5,9 @@ Two halves, one source of truth:
 - **Analytic flop model** — `mean_attended_keys` credits causal and
   sliding-window attention with the flops the kernels actually have to do
   (exactly (S+1)/2 in-band keys per query for plain causal; the
-  triangle-plus-band mean for windowed), fixing the MFU accounting caveat
-  bench.py's `gpt_train_flops_per_token` used to carry ("half-counting is
-  ~1/(2n) conservative") and making windowed configs (`gpt_long_win`)
-  report MFU against their true useful work instead of the full-causal
-  figure.
+  triangle-plus-band mean for windowed), so a windowed model's MFU is
+  read against its true useful work instead of the full-causal figure;
+  `gpt_train_flops_per_token` is the whole step's count built on it.
 
 - **Tile-visit counter** — the flash kernels decide which (Q-tile, K-tile)
   pairs to execute from `flash_attention._tile_in_band`; the counter
@@ -19,12 +17,11 @@ Two halves, one source of truth:
   backward additionally bumps a runtime counter from inside its scan
   body). `check_tile_visits` pins the two against the analytic band bound,
   so an attention tile-count regression (e.g. a backward that quietly goes
-  back to scanning all tiles) gates in tier-1 the same way collective
-  counts already do (tools/tier1.sh runs it; tests/test_roofline.py
-  asserts the pins).
+  back to scanning all tiles) fails tier-1 the same way a collective
+  count does (tests/test_roofline.py runs it).
 
 The flop model is plain arithmetic on Python ints — importable with no
-device and usable from bench.py's flop accounting without tracing anything.
+device, nothing traced.
 """
 
 from __future__ import annotations
@@ -60,9 +57,9 @@ def attention_flops_per_token(attn_width: int, seq: int,
     Per (query, in-band key) pair each head does 2*head_dim flops in the
     score matmul and 2*head_dim in the value matmul -> 4 * heads *
     head_dim * mean_keys = 4 * attn_width * mean_keys per token
-    (attn_width = heads * head_dim, == hidden for every bench config).
+    (attn_width = heads * head_dim, == hidden for every model here).
     Training credit is conventionally 3x this (backward ~2x forward);
-    callers apply their own multiplier so fwd-only benches can use it too.
+    callers apply their own multiplier so a forward-only count can use it too.
     """
     return 4.0 * attn_width * mean_attended_keys(seq, causal, window)
 
@@ -86,6 +83,27 @@ def stacked_attention_flops_per_token(
         n_banded = (depth + 1) // 2  # even layer indices: 0, 2, ...
         return n_banded * banded + (depth - n_banded) * full
     return depth * banded
+
+
+def gpt_train_flops_per_token(hidden: int, mlp: int, depth: int,
+                              seq: int, vocab: int, window=None,
+                              window_pattern: str = "all") -> float:
+    """Analytic matmul FLOPs per token for one causal-LM fwd+bwd step: qkvo
+    + mlp per-layer terms as in BERT; attention matmuls credited by the
+    EXACT in-band count from ops/roofline.py — (S+1)/2 mean attended keys
+    for plain causal (the flash kernels skip future tiles in forward AND
+    backward, so counting full bidirectional attention would inflate MFU
+    by ~20% at S=4096; the old half-count 2*S*H was ~1/(2n) conservative
+    on the diagonal, now exact), the triangle-plus-band mean for a
+    sliding `window`, and the per-layer average when `window_pattern=
+    'alternate'` windows only even layers (Gemma-2). Plus
+    the tied LM head 2HV; training = 3x forward."""
+    per_layer = 8 * hidden * hidden + 4 * hidden * mlp
+    attn = stacked_attention_flops_per_token(
+        hidden, seq, depth, causal=True, window=window,
+        window_pattern=window_pattern,
+    )
+    return 3.0 * (depth * per_layer + attn + 2 * hidden * vocab)
 
 
 def tile_visits(seq: int, block_q: Optional[int] = None,
@@ -168,8 +186,8 @@ def measured_tile_visits(
 
 def check_tile_visits(verbose: bool = False) -> list:
     """Pin the flash tile schedule against the analytic band. Returns a
-    list of failure strings (empty = pass) so both the tier-1 smoke
-    (tools/roofline.py --check-tiles) and the unit tests share one gate.
+    list of failure strings (empty = pass); tests/test_roofline.py asserts
+    it is empty.
 
     What must hold, per case:
     - the traced forward/backward visit counts equal the static plan

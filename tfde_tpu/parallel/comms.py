@@ -65,8 +65,8 @@ from tfde_tpu.parallel import sharding as shd
 
 log = logging.getLogger(__name__)
 
-#: env default for the transport knob — tools/tier1.sh forwards it so the
-#: whole tier-1 suite can re-run under int8 transport in one command:
+#: env default for the transport knob, so the whole tier-1 suite can
+#: re-run under int8 transport in one command:
 #:   TFDE_GRAD_TRANSPORT=int8 tools/tier1.sh
 ENV_TRANSPORT = "TFDE_GRAD_TRANSPORT"
 
@@ -381,7 +381,7 @@ def comm_bytes(tree: Any, cfg: CommsConfig, nshards: int,
                opt_sharding: str = "replicated") -> dict:
     """Per-step gradient-exchange bytes on the wire, per device, for the
     fp32 ring vs the int8 transport — the numbers behind the
-    `comm/bytes_per_step_{fp32,int8}` gauges and the bench `comms` config.
+    `comm/bytes_per_step_{fp32,int8}` gauges.
 
     Ring cost model: an all-reduce moves 2(N-1)/N bytes-per-payload-byte,
     a reduce-scatter or all-gather (N-1)/N. The int8 path pays

@@ -58,8 +58,8 @@ from tfde_tpu.parallel import comms as comms_lib
 
 log = logging.getLogger(__name__)
 
-#: env default for the knob — tools/tier1.sh forwards it so the whole
-#: tier-1 suite can re-run with sharded weight updates in one command:
+#: env default for the knob, so the whole tier-1 suite can re-run with
+#: sharded weight updates in one command:
 #:   TFDE_OPT_SHARDING=shard tools/tier1.sh
 ENV_OPT_SHARDING = "TFDE_OPT_SHARDING"
 
@@ -362,7 +362,7 @@ def eligible_axis(strategy, abstract_params: Any) -> Optional[str]:
     return axis
 
 
-# -- accounting (opt/* gauges, bench) -----------------------------------------
+# -- accounting (opt/* gauges) ------------------------------------------------
 def state_bytes(opt_state: Any, layout: Optional[Layout] = None) -> float:
     """Per-device optimizer-state bytes. With a layout, [N, C] chunk leaves
     count 1/N (each device holds one row); without, everything is

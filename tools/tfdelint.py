@@ -45,7 +45,10 @@ the first two):
    in `tfde_tpu/` and `tools/` must be registered in
    `tfde_tpu/knobs.py` (prefix families like ``TFDE_RETRY_`` count);
    an unregistered name is a knob the operator cannot discover and the
-   import-time typo check cannot defend.
+   import-time typo check cannot defend. The reverse holds too: a
+   registered knob that no literal in those trees names (itself, its
+   family's prefix, or for a family one of its members) has lost its
+   last reader and goes with it.
 
 Run: ``python tools/tfdelint.py [--root DIR]`` — exits 1 and lists
 violations. `tools/lintgate.py` embeds the same pass and diffs its
@@ -435,6 +438,28 @@ def lint_knobs(root: str) -> Tuple[List[str], List[str]]:
     return violations, sorted(seen)
 
 
+def lint_unread_knobs(seen) -> List[str]:
+    """Registered knobs that none of the `seen` literals reads. A member
+    composed from its family's prefix (``TFDE_RETRY_`` + suffix) is read
+    by the prefix; a family is read by any member."""
+    from tfde_tpu import knobs
+
+    families = {s for s in seen
+                if s in knobs.REGISTRY and knobs.REGISTRY[s].prefix}
+    violations = []
+    for name, knob in sorted(knobs.REGISTRY.items()):
+        read = (name in seen
+                or any(name.startswith(f) for f in families)
+                or (knob.prefix and any(s.startswith(name) for s in seen)))
+        if not read:
+            violations.append(
+                f"tfde_tpu/knobs.py: knob {name!r} is registered but no "
+                f"file under tfde_tpu/ or tools/ reads it — delete the "
+                f"Knob entry with its last reader (tools/tfdelint.py "
+                f"knob-audit rule)")
+    return violations
+
+
 # -- entry points -------------------------------------------------------------
 def lint_repo(root: str = ROOT) -> dict:
     """Run all three rules; returns {violations: [...], audit: {...},
@@ -443,7 +468,7 @@ def lint_repo(root: str = ROOT) -> dict:
     split_v = lint_greedy_split(root)
     knob_v, seen = lint_knobs(root)
     return {
-        "violations": lock_v + split_v + knob_v,
+        "violations": lock_v + split_v + knob_v + lint_unread_knobs(seen),
         "lock_audit": audit,
         "knobs_seen": seen,
     }
