@@ -205,6 +205,17 @@ def test_pallas_backward_matches_jax_backward(rng, causal, monkeypatch):
         )
 
 
+def _bumped(before, *names):
+    """How far each `flash/<name>` counter moved since `before`."""
+    from tfde_tpu.observability import counters
+
+    after = counters.snapshot()
+    return {
+        name: after.get(f"flash/{name}", 0) - before.get(f"flash/{name}", 0)
+        for name in names
+    }
+
+
 def _grads_and_path(q, k, v, causal, bq, bk, window=None, cap=None):
     """Flash gradients (interpreted), the path `_bwd` took and what it
     counted, beside autodiff through the float32 reference."""
@@ -226,11 +237,7 @@ def _grads_and_path(q, k, v, causal, bq, bk, window=None, cap=None):
         got = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
         jax.block_until_ready(got)
         jax.effects_barrier()
-    after = counters.snapshot()
-    bumped = {
-        name: after.get(f"flash/{name}", 0) - before.get(f"flash/{name}", 0)
-        for name in ("bwd_kernel_traces", "bwd_recurrence_traces")
-    }
+    bumped = _bumped(before, "bwd_kernel_traces", "bwd_recurrence_traces")
     want = jax.grad(loss_ref, argnums=(0, 1, 2))(
         *(t.astype(jnp.float32) for t in (q, k, v)))
     return got, want, dict(counts), bumped
@@ -378,6 +385,247 @@ def test_model_gradient_bumps_the_kernel_once_a_layer(rng, monkeypatch,
         - before.get(f"flash/bwd_{path}_traces", 0) == model.depth == 2
     assert after.get(f"flash/bwd_{other}_traces", 0) \
         == before.get(f"flash/bwd_{other}_traces", 0)
+
+
+def _forward_and_path(q, k, v, causal, bq, bk, window=None, scale=None,
+                      cap=None):
+    """(out, lse [B, H, S]) of the interpreted forward, the path
+    `_flash_forward` took and what it counted."""
+    from tfde_tpu.observability import counters
+    from tfde_tpu.ops import flash_attention as fa
+
+    before = counters.snapshot()
+    with fa.record_tile_visits() as counts:
+        out, lse = fa._flash_forward(q, k, v, causal, bq, bk, True, window,
+                                     scale, cap)
+        jax.block_until_ready(out)
+        jax.effects_barrier()
+    return (out, fa._lse_bhs(lse), dict(counts),
+            _bumped(before, "fwd_lane_traces", "fwd_grid_traces"))
+
+
+def _reference_out_and_lse(q, k, v, causal, window, scale, cap):
+    """Plain float32 softmax attention with its log-sum-exp [B, H, S]."""
+    q, k, v = (np.asarray(t, np.float64) for t in (q, k, v))
+    s, d = q.shape[1], q.shape[3]
+    z = np.einsum("bqhd,bkhd->bhqk", q, k) * (
+        1.0 / d ** 0.5 if scale is None else scale)
+    if cap is not None:
+        z = cap * np.tanh(z / cap)
+    if causal:
+        i, j = np.arange(s)[:, None], np.arange(s)[None, :]
+        keep = i >= j
+        if window is not None:
+            keep &= i - j < window
+        z = np.where(keep, z, -np.inf)
+    m = z.max(-1, keepdims=True)
+    e = np.exp(z - m)
+    lse = (m + np.log(e.sum(-1, keepdims=True)))[..., 0]
+    out = np.einsum("bhqk,bkhd->bqhd", e / e.sum(-1, keepdims=True), v)
+    return out, lse
+
+
+@pytest.mark.parametrize(
+    "name,b,s,h,d,dtype,bq,bk,causal,window,scale,cap", [
+        ("head_pairs_of_64", 2, 512, 4, 64, jnp.float32, 128, 128, True,
+         None, None, None),
+        ("asymmetric_tiles", 1, 512, 2, 64, jnp.float32, 128, 64, True,
+         None, None, None),
+        ("wide_q_tile", 1, 512, 2, 64, jnp.float32, 256, 128, True,
+         None, None, None),
+        ("heads_of_128", 1, 256, 2, 128, jnp.float32, 64, 64, True,
+         None, None, None),
+        ("all_heads_in_one_block", 2, 256, 4, 16, jnp.float32, 64, 64, True,
+         None, None, None),
+        ("odd_count_of_head_pairs", 1, 256, 6, 64, jnp.float32, 64, 64,
+         True, None, None, None),
+        ("bf16", 2, 512, 2, 64, jnp.bfloat16, 128, 128, True,
+         None, None, None),
+        ("s2176_tiles_of_128", 1, 2176, 2, 64, jnp.float32, 128, 128, True,
+         None, None, None),
+        ("window_1000", 1, 2048, 2, 64, jnp.float32, 256, 256, True,
+         1000, None, None),
+        ("window_under_a_tile", 2, 512, 2, 64, jnp.float32, 128, 64, True,
+         96, None, None),
+        ("cap_30_with_a_scale", 1, 256, 2, 64, jnp.float32, 64, 64, True,
+         None, 0.25, 30.0),
+        ("window_cap_scale", 1, 512, 4, 64, jnp.float32, 64, 128, True,
+         160, 0.3, 20.0),
+        ("non_causal", 2, 256, 2, 64, jnp.float32, 64, 128, False,
+         None, None, None),
+        ("non_causal_cap", 1, 256, 4, 16, jnp.float32, 128, 64, False,
+         None, None, 30.0),
+    ])
+def test_lane_forward_matches_reference_and_grid(
+        rng, monkeypatch, name, b, s, h, d, dtype, bq, bk, causal, window,
+        scale, cap):
+    """The lane forward's out and lse against float32 softmax attention
+    and against the grid kernel, reached through what `_flash_forward`
+    observes (no room in VMEM); the K steps its loop ran against the
+    plan."""
+    from tfde_tpu.ops.flash_attention import bwd_tile_plan
+
+    q, k, v = _qkv(rng, b=b, s=s, h=h, d=d, dtype=dtype)
+    out, lse, counts, bumped = _forward_and_path(q, k, v, causal, bq, bk,
+                                                 window, scale, cap)
+    assert counts["fwd_path"] == "lane"
+    assert bumped == {"fwd_lane_traces": 1, "fwd_grid_traces": 0}
+    plan = bwd_tile_plan(s, bq, bk, causal, window)
+    assert counts["fwd_visits"] == plan["visits"]
+    assert counts["fwd_steps_executed"] == plan["visits"]
+    assert out.dtype == dtype and out.shape == q.shape
+    assert lse.dtype == jnp.float32 and lse.shape == (b, h, s)
+
+    monkeypatch.setattr(
+        "tfde_tpu.ops.flash_attention._FWD_KERNEL_VMEM_BUDGET", 0)
+    grid_out, grid_lse, grid_counts, _ = _forward_and_path(
+        q, k, v, causal, bq, bk, window, scale, cap)
+    assert grid_counts["fwd_path"] == "grid"
+    assert "fwd_steps_executed" not in grid_counts
+
+    want_out, want_lse = _reference_out_and_lse(q, k, v, causal, window,
+                                                scale, cap)
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    for got_out, got_lse in ((out, lse), (grid_out, grid_lse)):
+        np.testing.assert_allclose(np.asarray(got_out, np.float64),
+                                   want_out, rtol=tol, atol=tol)
+        np.testing.assert_allclose(np.asarray(got_lse, np.float64),
+                                   want_lse, rtol=tol, atol=tol)
+    # the two kernels against each other: the same tile order and
+    # arithmetic, only the order inside a tile's sums differs
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(grid_out, np.float32),
+        rtol=0, atol=1e-5 if dtype == jnp.float32 else 2 ** -7)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(grid_lse),
+                               rtol=0, atol=1e-5)
+
+
+@pytest.mark.parametrize("name,h,kv,d,causal,budget,path", [
+    ("mha_causal", 2, 2, 64, True, None, "lane"),
+    ("mha_non_causal", 2, 2, 64, False, None, "lane"),
+    ("one_head_of_128", 1, 1, 128, True, None, "lane"),
+    ("heads_under_a_lane_block", 4, 4, 8, True, None, "lane"),
+    ("grouped_query", 2, 1, 64, True, None, "grid"),
+    ("head_width_no_lane_block_tiles", 3, 3, 64, True, None, "grid"),
+    ("head_width_96", 4, 4, 96, True, None, "grid"),
+    ("past_vmem", 2, 2, 64, True, 0, "grid"),
+])
+def test_fwd_chooses_from_its_operands(rng, monkeypatch, name, h, kv, d,
+                                       causal, budget, path):
+    """`_flash_forward` counts the kernel it took at trace time: the lane
+    kernel for multi-head attention whose heads tile the lanes, the grid
+    kernel for grouped-query, head widths no lane block tiles, and a head
+    block past the VMEM budget; the output matches either way."""
+    from tfde_tpu.ops.attention import grouped_attention
+
+    if budget is not None:
+        monkeypatch.setattr(
+            "tfde_tpu.ops.flash_attention._FWD_KERNEL_VMEM_BUDGET", budget)
+    q = jnp.asarray(rng.standard_normal((1, 128, h, d)), jnp.float32)
+    k, v = (jnp.asarray(rng.standard_normal((1, 128, kv, d)), jnp.float32)
+            for _ in range(2))
+    out, lse, counts, bumped = _forward_and_path(q, k, v, causal, 64, 64)
+    assert counts["fwd_path"] == path
+    assert bumped == {"fwd_lane_traces": int(path == "lane"),
+                      "fwd_grid_traces": int(path == "grid")}
+    assert ("fwd_steps_executed" in counts) == (path == "lane")
+    assert lse.shape == (1, h, 128)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(grouped_attention(q, k, v,
+                                                      causal=causal)),
+        rtol=2e-5, atol=2e-5)
+
+
+def test_fwd_leaves_what_does_not_fit_vmem_to_the_grid():
+    """K and V of a head block whole in VMEM: the training cells' 4,096
+    fit many times over, 131,072 bf16 positions do not."""
+    from tfde_tpu.ops import flash_attention as fa
+
+    assert fa._fwd_lane_vmem_bytes(4096, 2, 128, 2, 512, 512) \
+        <= fa._FWD_KERNEL_VMEM_BUDGET // 4
+    assert fa._fwd_lane_vmem_bytes(65536, 2, 128, 2, 512, 512) \
+        <= fa._FWD_KERNEL_VMEM_BUDGET
+    assert fa._fwd_lane_vmem_bytes(131072, 2, 128, 2, 512, 512) \
+        > fa._FWD_KERNEL_VMEM_BUDGET
+    assert fa._fwd_lane_vmem_bytes(65536, 2, 128, 4, 512, 512) \
+        > fa._FWD_KERNEL_VMEM_BUDGET
+
+
+@pytest.mark.parametrize("s,bq,bk,causal,window", [
+    (512, 64, 64, True, None), (512, 128, 64, True, None),
+    (512, 64, 128, True, 100), (1024, 64, 64, True, 128),
+    (2048, 256, 256, True, 1000), (512, 128, 64, False, None)])
+def test_k_tile_range_is_the_band(s, bq, bk, causal, window):
+    """The lane forward's loop bounds per Q tile enumerate
+    `_band_tile_pairs`."""
+    from tfde_tpu.ops import flash_attention as fa
+
+    n_k = s // bk
+    pairs = set()
+    for qi in range(s // bq):
+        lo, hi = fa._k_tile_range(qi, bq, bk, n_k, causal, window)
+        pairs |= {(qi, kb) for kb in range(int(lo), int(hi) + 1)}
+    assert pairs == set(fa._band_tile_pairs(s, bq, bk, causal, window))
+
+
+@pytest.mark.parametrize("name,causal,fwd_budget,bwd_budget,paths", [
+    ("lane_into_fused", True, None, None, ("lane", "kernel")),
+    ("lane_into_pair_scan", True, None, 0, ("lane", "recurrence")),
+    ("lane_into_k_tile_scan", False, None, None, ("lane", "recurrence")),
+    ("grid_into_fused", True, 0, None, ("grid", "kernel")),
+    ("grid_into_pair_scan", True, 0, 0, ("grid", "recurrence")),
+])
+@pytest.mark.parametrize("window,cap", [(None, None), (96, 20.0)],
+                         ids=["plain", "window_and_cap"])
+def test_gradients_through_each_forward_and_backward(
+        rng, monkeypatch, name, causal, fwd_budget, bwd_budget, paths,
+        window, cap):
+    """`jax.grad` of `flash_attention` against autodiff through the
+    reference, for each forward handing its lse (rows or [B, H, S]) to
+    each backward."""
+    if not causal:
+        window = None
+    if fwd_budget is not None:
+        monkeypatch.setattr(
+            "tfde_tpu.ops.flash_attention._FWD_KERNEL_VMEM_BUDGET",
+            fwd_budget)
+    if bwd_budget is not None:
+        monkeypatch.setattr(
+            "tfde_tpu.ops.flash_attention._BWD_KERNEL_VMEM_BUDGET",
+            bwd_budget)
+    q, k, v = _qkv(rng, b=2, s=256, h=4, d=64)
+    got, want, counts, _ = _grads_and_path(q, k, v, causal, 64, 128, window,
+                                           cap)
+    assert (counts["fwd_path"], counts["bwd_path"]) == paths
+    for a, b in zip(got, want):
+        assert _rel(a, b) <= 1e-5
+
+
+@pytest.mark.parametrize("path", ["lane", "grid"])
+def test_model_forward_bumps_the_lane_kernel_once_a_layer(rng, monkeypatch,
+                                                          path):
+    """Tracing a causal LM's step through attn_impl='flash' takes the lane
+    forward in every layer and the grid forward in none; with no room in
+    VMEM, the reverse."""
+    from tfde_tpu.models.gpt import gpt_tiny_test
+    from tfde_tpu.observability import counters
+
+    if path == "grid":
+        monkeypatch.setattr(
+            "tfde_tpu.ops.flash_attention._FWD_KERNEL_VMEM_BUDGET", 0)
+    model = gpt_tiny_test(attn_impl="flash")
+    tokens = jnp.asarray(rng.integers(0, 97, size=(2, 64)), jnp.int32)
+    params = model.init(jax.random.key(0), tokens)["params"]
+
+    def loss(p):
+        return jnp.sum(model.apply({"params": p}, tokens, train=False) ** 2)
+
+    before = counters.snapshot()
+    jax.jit(jax.grad(loss)).lower(params)
+    other = "grid" if path == "lane" else "lane"
+    assert model.depth == 2
+    assert _bumped(before, "fwd_lane_traces", "fwd_grid_traces") == {
+        f"fwd_{path}_traces": 2, f"fwd_{other}_traces": 0}
 
 
 def test_flash_dispatch_keeps_batch_sharded():

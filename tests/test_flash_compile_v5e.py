@@ -45,5 +45,40 @@ def test_flash_gradient_compiles_for_v5e(one_chip, name, shape, dtype,
         q, q, q).compile().as_text()
     # forward and the fused backward are Mosaic calls; no recurrence loop
     assert text.count('custom_call_target="tpu_custom_call"') == 2
-    assert "flash_bwd" in text
+    assert "flash_fwd" in text and "flash_bwd" in text
     assert "while(" not in text
+    # both kernels read the model's layout: no [B, H, S, D] array exists,
+    # so nothing transposes or copies q, k, v or out into one
+    b, s, h, d = shape
+    assert f"[{b},{h},{s},{d}]" not in text
+
+
+@pytest.mark.parametrize("name,shape,kv_heads,causal,window", [
+    ("non_causal", (1, 4096, 4, 64), 4, False, None),
+    ("four_heads_of_32", (1, 2048, 4, 32), 4, True, None),
+    ("a_lane_block_of_64", (1, 2048, 2, 32), 2, True, None),
+    ("heads_of_256", (1, 2048, 2, 256), 2, True, None),
+    ("s2176_window_1000", (1, 2176, 4, 64), 4, True, 1000),
+    ("s65536", (1, 65536, 2, 64), 2, True, None),
+    ("grouped_query_grid", (1, 2048, 4, 64), 2, True, None),
+    ("s131072_grid", (1, 131072, 2, 64), 2, True, None),
+])
+def test_flash_forward_compiles_for_v5e(one_chip, name, shape, kv_heads,
+                                        causal, window):
+    """The forward alone, over operands the gradient cases above do not
+    reach: each arrangement `_flash_forward` can choose is one Mosaic call
+    the v5e compiler takes (lane widths under and over 128, K and V of a
+    long sequence whole in VMEM, and the grid kernel where they are
+    not)."""
+    b, s, h, d = shape
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    k = jax.ShapeDtypeStruct((b, s, kv_heads, d), jnp.bfloat16,
+                             sharding=one_chip)
+    text = jax.jit(lambda q, k, v: flash_attention(
+        q, k, v, causal=causal, window=window)).lower(
+            q, k, k).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "flash_fwd" in text
+    assert "while(" not in text
+    grid = name.endswith("_grid")
+    assert (f"[{b},{h},{s},{d}]" in text) == grid

@@ -69,6 +69,8 @@ def _serve_text(which):
 
 @pytest.mark.parametrize("name,text", [
     ("flash_fwd", lambda mp: _flash_grad_text(mp, "recurrence")),
+    # grouped-query takes the grid forward: the same name
+    ("flash_fwd", lambda mp: _flash_grad_text(mp, "kernel", 1)),
     ("flash_bwd_pair_scan", lambda mp: _flash_grad_text(mp, "recurrence")),
     ("flash_bwd", lambda mp: _flash_grad_text(mp, "kernel")),
     # grouped-query is the recurrence's with the budget as it is

@@ -184,6 +184,25 @@ def test_measured_backward_visits_match_plan(monkeypatch, bwd):
     assert m["bwd_steps_executed"] == st["bwd_dq"]
 
 
+@pytest.mark.parametrize("fwd", ["lane", "grid"])
+@pytest.mark.parametrize("window", [None, 64, 200])
+def test_measured_forward_visits_match_plan(monkeypatch, fwd, window):
+    """The lane forward walks the band the grid kernel predicates: the
+    traced visit count equals the plan either way, and the K steps the
+    lane kernel's loop ran for one block of heads equal it too (the grid
+    kernel has no loop to count)."""
+    if fwd == "grid":  # nothing fits: what `_flash_forward` observes
+        monkeypatch.setattr(
+            "tfde_tpu.ops.flash_attention._FWD_KERNEL_VMEM_BUDGET", 0)
+    st = rl.tile_visits(256, 64, 64, causal=True, window=window)
+    m = rl.measured_tile_visits(seq=256, block_q=64, block_k=64,
+                                window=window)
+    assert m["fwd_path"] == fwd
+    assert m["fwd_visits"] == st["fwd"]
+    assert m.get("fwd_steps_executed") == (st["fwd"] if fwd == "lane"
+                                           else None)
+
+
 def test_check_tile_visits_gate_passes():
     """The tile-visit gate, whole (covers the GQA head-folded case
     too)."""

@@ -4,20 +4,43 @@ Hot-op kernel scope (the reference delegates all kernels to TF's C++ library,
 SURVEY.md §2b "Dense/conv/BN kernel library"; here the transformer configs'
 attention gets a hand kernel where XLA's default fusion stops helping).
 
-Forward is a Pallas kernel (per /opt/skills/guides/pallas_guide.md):
-- grid (batch, heads, Sq/block_q, Sk/block_k) with K minor: one Q tile and
-  one K/V tile are VMEM-resident per step (VMEM stays O(block) at any S);
-  the online-softmax state persists in VMEM scratch across the K-tile steps
-  that revisit the same output block — the [Sq, Sk] score matrix never
-  materializes (O(S) memory instead of O(S^2)).
-- score matmuls hit the MXU with fp32 accumulation (preferred_element_type),
-  tiles default to the largest MXU multiple of 512/256/128 dividing S
+Forward is a Pallas kernel (per /opt/skills/guides/pallas_guide.md), in one
+of two arrangements of the same online softmax (float32 scores and
+statistics, p rounded to the input dtype before p V, the [Sq, Sk] score
+matrix never materialized: O(S) memory). `_flash_forward` decides from
+its operands, as `_bwd` does: as many K/V heads as Q heads, whole heads
+tiling the 128 lanes (`_bwd_heads_per_block`) and a head block's K and V
+inside VMEM take the lane kernel; grouped-query, head widths no lane block
+tiles and longer sequences keep the grid kernel.
+- the lane kernel (`_fwd_lane_kernel`) reads q, k, v and writes out as
+  blocks of the free [B, S, H*D] view of the model's [B, S, H, D] layout,
+  so nothing is transposed in HBM; a block of whole heads fills the lanes
+  (two heads at D=64, each head's Q with the other's lanes zeroed); scores
+  are K-major (z^T = K Q^T, [bk, bq]), so the running max and sum are
+  [1, bq] lane vectors reduced down the sublanes and the accumulator is
+  kept transposed; grid (batch, head block, Q tile) with K and V of the
+  head block resident in VMEM (fetched once a head block) and an in-kernel
+  loop over the K tiles `_tile_in_band` keeps (`_k_tile_range`), so
+  out-of-band tiles cost nothing and the body is compiled once. lse leaves
+  it as the lane vectors the fused backward reads.
+- the grid kernel (`_fwd_kernel`) works in BHSD (three `swapaxes` in, one
+  out): grid (batch, heads, Sq/block_q, Sk/block_k) with K minor, one Q
+  tile and one K/V tile VMEM-resident per step (VMEM stays O(block) at any
+  S), the online-softmax state in VMEM scratch across the K steps of an
+  output block; out-of-band K tiles are predicated off (pl.when) with
+  their DMA elided; grouped-query heads fold onto their K/V head in the
+  index maps.
+- tiles default to the largest MXU multiple of 512/256/128 dividing S
   (`_auto_block`: the r04 hardware sweep measured 512-edge tiles
-  1.25-1.45x over 128 at every shape tried).
-- causal masking predicates whole future K-tiles off (pl.when), halving the
-  work for causal models rather than masking it.
-- `scale` and `logit_cap` (Gemma-2 tanh softcapping) apply inside the
-  kernel, so capped/scaled models stay on the fused path.
+  1.25-1.45x over 128 at every shape tried; the lane kernel again, PR 30).
+- `scale` and `logit_cap` (Gemma-2 tanh softcapping) and the sliding
+  window apply inside both, so capped/scaled/windowed models stay fused.
+Measured at the training cells' shape ([2, 4096, 16, 64] bf16, causal, 512
+tiles, one v5e chip; my chip runs, PR 30, 40 calls back to back on the
+host's clock): the grid kernel with its copies 3.09 ms a call, the lane
+kernel 1.30 ms; the in-kernel loop with resident K/V alone, on the BHSD
+kernel, 1.73. As device time inside the GPT-2-medium step: 2.75 -> 1.16
+ms a call, 30 % of the forward's FLOP roofline.
 
 Backward of causal multi-head attention is ONE fused Pallas kernel
 (`_bwd_kernel`, `pallas_call(name="flash_bwd")`): for every in-band
@@ -84,13 +107,15 @@ def record_tile_visits():
     Yields a dict that the forward/backward builders populate at TRACE
     time with the statically-known schedule: number of grid steps, number
     of in-band (executed) tile visits per pass, and the resolved tile
-    sizes, and `bwd_path` ("kernel" or "recurrence"). Because `pl.when`
-    predication, the fused backward's Q-tile loop and the pair-scan length
+    sizes, `fwd_path` ("lane" or "grid") and `bwd_path` ("kernel" or
+    "recurrence"). Because `pl.when` predication, the lane forward's
+    K-tile loop, the fused backward's Q-tile loop and the pair-scan length
     are decided by the same `_tile_in_band` predicate recorded here, these
     numbers are exactly the tiles the compiled kernels execute. The
     causal backward additionally bumps `bwd_steps_executed` from inside
     its loop via `jax.debug.callback` (the scan's body; the kernel's, one
-    block of heads, when interpreted), a runtime-executed corroboration of
+    block of heads, when interpreted), and the interpreted lane forward
+    `fwd_steps_executed` likewise: a runtime-executed corroboration of
     the static plan.
 
     Recording happens when the call is traced — call the kernels directly
@@ -103,6 +128,16 @@ def record_tile_visits():
         yield _TILE_COUNTS
     finally:
         _TILE_COUNTS = prev
+
+
+def _first_block_counter(counts: dict, key: str):
+    """Host callback for an interpreted kernel's loop body: counts under
+    `key` the steps one block of heads ran, beside the static plan (Mosaic
+    lowers no host callback, so only interpreted calls are given it)."""
+    def bump(first_block):
+        if first_block:
+            counts[key] = counts.get(key, 0) + 1
+    return bump
 
 
 def _auto_block(s: int) -> int:
@@ -273,6 +308,181 @@ def _fwd_kernel(
         lse_ref[0, 0] = m_ref[:, 0:1] + jnp.log(l)
 
 
+def _k_tile_range(qi, block_q: int, block_k: int, n_k: int, causal, window):
+    """First and last K tile `_tile_in_band` keeps for Q tile `qi`: both
+    of its conditions are monotone in the K tile, so the live tiles are
+    one run (all of them when not causal). Python ints or traced scalars,
+    as `_tile_in_band`."""
+    if not causal:
+        return 0, n_k - 1
+    hi = ((qi + 1) * block_q - 1) // block_k
+    if window is None:
+        return 0, hi
+    return jnp.maximum(qi * block_q - (window - 1), 0) // block_k, hi
+
+
+def _fwd_lane_vmem_bytes(s: int, heads: int, width: int, itemsize: int,
+                         block_q: int, block_k: int) -> int:
+    """What `_fwd_lane_kernel` keeps in VMEM for one block of `heads`
+    heads: K and V whole and double-buffered, the Q and out tiles
+    double-buffered, the float32 accumulator, lse and the statistics
+    padded to 8 sublanes, and the float32 score tiles: every head's at
+    once beside the compiler's own (a dozen at two heads)."""
+    w = max(width, 128)
+    whole = 2 * 2 * s * w * itemsize
+    tiles = block_q * w * (2 * 2 * itemsize + 4)
+    rows = 4 * 8 * block_q * 4
+    return whole + tiles + rows + (8 + 2 * heads) * block_q * block_k * 4
+
+
+# The lane forward runs where a head block's working set stays under this
+# (the same room the fused backward is given, below).
+_FWD_KERNEL_VMEM_BUDGET = 96 << 20
+
+
+def _fwd_lane_kernel(
+    q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
+    *, causal, scale, window, logit_cap, heads, block_k, on_step=None,
+):
+    # grid (B, H/heads, S/bq). Operands are blocks of the [B, S, H*D] view
+    # the model has: q/out one Q tile of this block of heads, k/v whole in
+    # S (their block index does not move with the Q tile, so they are
+    # fetched once a head block). Scores are K-major, z^T = K Q^T
+    # [bk, bq]: the running max and sum are [1, bq] lane vectors reduced
+    # down the sublanes, and the accumulator is kept transposed [w, bq] so
+    # the correction broadcasts along its sublanes; one transpose a Q tile
+    # puts the output back in the model's layout.
+    qi = pl.program_id(2)
+    bq, w = q_ref.shape[1], q_ref.shape[2]
+    bk = block_k
+    n_k = k_ref.shape[1] // bk
+    d = w // heads
+
+    first_block = (pl.program_id(0) == 0) & (pl.program_id(1) == 0)
+
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    q2 = q_ref[0]
+    # heads side by side in the lanes: a head's Q with the other heads'
+    # lanes zeroed contracts over the whole block against the unmasked K,
+    # and each head's output keeps its own rows of V^T p
+    if heads > 1:
+        lane_head = jax.lax.broadcasted_iota(jnp.int32, (1, w), 1) // d
+        qs = [jnp.where(lane_head == h, q2, 0) for h in range(heads)]
+    else:
+        qs = [q2]
+    rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bk, bq), 1)
+    col0 = jax.lax.broadcasted_iota(jnp.int32, (bk, bq), 0)
+
+    def step(kb, stats):
+        if on_step is not None:  # the interpreted recorder's runtime count
+            jax.debug.callback(on_step, first_block)
+        ks = pl.multiple_of(kb * bk, bk)
+        k2 = k_ref[0, pl.ds(ks, bk), :]      # [bk, w]
+        v2 = v_ref[0, pl.ds(ks, bk), :]
+        if causal:
+            cols = ks + col0
+            keep = rows >= cols
+            if window is not None:
+                # sliding band: row i sees cols in (i - window, i]
+                keep = jnp.logical_and(keep, rows - cols < window)
+        # every head's K Q_h^T before any softmax: the next head's matmul
+        # then runs under this head's exponentials (1.43 -> 1.30 ms a call
+        # at the training cells' shape against one head after the other)
+        zs = [
+            jax.lax.dot_general(k2, q_h, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+            for q_h in qs
+        ]                                    # [bk, bq] each
+        new = []
+        for h in range(heads):
+            z = zs[h] * scale
+            if logit_cap is not None:
+                z, _ = _apply_cap(z, logit_cap)
+            if causal:
+                z = jnp.where(keep, z, _NEG)
+            m_prev, l_prev = stats[h]        # [1, bq]
+            m_new = jnp.maximum(m_prev, jnp.max(z, axis=0, keepdims=True))
+            p = jnp.exp(z - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            new.append(
+                (m_new, l_prev * corr + jnp.sum(p, axis=0, keepdims=True)))
+            pv = jax.lax.dot_general(        # V^T p: [w, bq]
+                v2, p.astype(v2.dtype), (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            own = slice(h * d, (h + 1) * d)
+            acc_ref[own, :] = acc_ref[own, :] * corr + pv[own, :]
+        return tuple(new)
+
+    lo, hi = _k_tile_range(qi, bq, bk, n_k, causal, window)
+    stats = jax.lax.fori_loop(lo, hi + 1, step, tuple(
+        (jnp.full((1, bq), _NEG, jnp.float32),
+         jnp.zeros((1, bq), jnp.float32))
+        for _ in range(heads)))
+    for h, (m, l) in enumerate(stats):
+        own = slice(h * d, (h + 1) * d)
+        l = jnp.maximum(l, 1e-20)
+        acc_ref[own, :] = acc_ref[own, :] / l
+        lse_ref[0, 0, 0, h:h + 1, :] = m + jnp.log(l)
+    o_ref[0] = acc_ref[...].T.astype(o_ref.dtype)
+
+
+def _flash_forward_lanes(q, k, v, *, heads: int, causal: bool, block_q: int,
+                         block_k: int, interpret: bool, window, scale: float,
+                         logit_cap):
+    """Multi-head forward in the model's own layout: q, k, v and out are
+    blocks of the [B, S, H*D] view (nothing is transposed in HBM), a block
+    of whole heads fills the lanes, and an in-kernel loop walks the K
+    tiles `_tile_in_band` keeps for the Q tile. Returns out [B, S, H, D]
+    and lse as the lane vectors the kernel wrote,
+    [B, H/heads, S/block_q, heads, block_q]: what the fused backward
+    reads as it is, and `_lse_bhs` puts in order for the recurrences."""
+    b, s, h, d = q.shape
+    from jax.experimental.pallas import tpu as pltpu
+
+    on_step = None
+    if _TILE_COUNTS is not None and interpret:
+        on_step = _first_block_counter(_TILE_COUNTS, "fwd_steps_executed")
+    n_q, w = s // block_q, heads * d
+    flat = lambda x: x.reshape(b, s, h * d)
+    tile = pl.BlockSpec((1, block_q, w), lambda bi, hi, qi: (bi, qi, hi))
+    whole = pl.BlockSpec((1, s, w), lambda bi, hi, qi: (bi, 0, hi))
+    out, lse = pl.pallas_call(
+        functools.partial(_fwd_lane_kernel, causal=causal, scale=scale,
+                          window=window, logit_cap=logit_cap, heads=heads,
+                          block_k=block_k, on_step=on_step),
+        grid=(b, h // heads, n_q),
+        in_specs=[tile, whole, whole],
+        out_specs=[
+            tile,
+            pl.BlockSpec((1, 1, 1, heads, block_q),
+                         lambda bi, hi, qi: (bi, hi, qi, 0, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((b, s, h * d), q.dtype),
+            jax.ShapeDtypeStruct((b, h // heads, n_q, heads, block_q),
+                                 jnp.float32),
+        ],
+        scratch_shapes=[pltpu.VMEM((w, block_q), jnp.float32)],  # acc^T
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel"),
+            vmem_limit_bytes=_fwd_lane_vmem_bytes(
+                s, heads, w, q.dtype.itemsize, block_q, block_k),
+        ),
+        interpret=interpret,
+        name="flash_fwd",
+    )(flat(q), flat(k), flat(v))
+    return out.reshape(q.shape), lse
+
+
+def _lse_bhs(lse):
+    """lse as [B, H, S]: the grid forward's as it is, the lane forward's
+    rows [B, H/heads, S/block_q, heads, block_q] put back in order."""
+    if lse.ndim == 3:
+        return lse
+    b, blocks, n_q, heads, bq = lse.shape
+    return lse.swapaxes(2, 3).reshape(b, blocks * heads, n_q * bq)
+
+
 def _flash_forward(
     q: jax.Array, k: jax.Array, v: jax.Array,
     causal: bool, block_q: int, block_k: int, interpret: bool,
@@ -318,14 +528,34 @@ def _flash_forward(
         raise ValueError(f"logit_cap={logit_cap} must be positive")
     if scale is None:
         scale = 1.0 / (d ** 0.5)
+    from tfde_tpu.observability import counters
+
+    # The lane kernel where it applies, the grid kernel elsewhere, decided
+    # from the operands as `_bwd` decides: as many K/V heads as Q heads (a
+    # lane block holds a head's own K/V), whole heads tiling the 128
+    # lanes, and a head block's working set (K and V whole) inside VMEM.
+    heads = _bwd_heads_per_block(h, d)
+    lanes = (
+        kv == h and heads is not None
+        and _fwd_lane_vmem_bytes(s, heads, heads * d, q.dtype.itemsize,
+                                 block_q, block_k) <= _FWD_KERNEL_VMEM_BUDGET
+    )
+    counters.incr("flash/fwd_lane_traces" if lanes
+                  else "flash/fwd_grid_traces")
     if _TILE_COUNTS is not None:
         n_q, n_k = s // block_q, s // block_k
+        _TILE_COUNTS["fwd_path"] = "lane" if lanes else "grid"
         _TILE_COUNTS["fwd_grid"] = n_q * n_k
         _TILE_COUNTS["fwd_visits"] = len(
             _band_tile_pairs(s, block_q, block_k, causal, window)
         )
         _TILE_COUNTS["block_q"] = block_q
         _TILE_COUNTS["block_k"] = block_k
+    if lanes:
+        return _flash_forward_lanes(
+            q, k, v, heads=heads, causal=causal, block_q=block_q,
+            block_k=block_k, interpret=interpret, window=window, scale=scale,
+            logit_cap=logit_cap)
     kernel = functools.partial(_fwd_kernel, causal=causal, scale=scale,
                                window=window, logit_cap=logit_cap)
     # BSHD -> BHSD so the S/D dims are the TPU-tiled trailing pair
@@ -764,7 +994,9 @@ def _bwd_fused(res, g, *, heads: int, block_q: int, block_k: int,
     """Causal multi-head backward as one kernel: every in-band (K tile,
     Q tile) pair computes z, p, dp and ds once, in VMEM, and updates dV,
     dK and dQ from them. Reads and writes the [B, S, H, D] layout the
-    model has; nothing but the outputs is written to HBM."""
+    model has; nothing but the outputs is written to HBM. lse comes as
+    the lane forward's rows (same heads a block and block_q, both from the
+    operands) or as the grid forward's [B, H, S]."""
     q, k, v, out, lse = res
     b, s, h, d = q.shape
     from jax.experimental.pallas import tpu as pltpu
@@ -776,13 +1008,7 @@ def _bwd_fused(res, g, *, heads: int, block_q: int, block_k: int,
         counts["bwd_dq_visits"] = visits
         counts["bwd_dkv_visits"] = visits
         if interpret:
-            # one block of heads' pairs as the kernel's loop ran them,
-            # beside the static plan (Mosaic lowers no host callback)
-            def on_pair(first_block):
-                if first_block:
-                    counts["bwd_steps_executed"] = (
-                        counts.get("bwd_steps_executed", 0) + 1
-                    )
+            on_pair = _first_block_counter(counts, "bwd_steps_executed")
 
     n_q, w = s // block_q, heads * d
     # delta[b,h,s] = rowsum(dO * O), fp32 — cheap elementwise, stays in JAX
@@ -793,6 +1019,8 @@ def _bwd_fused(res, g, *, heads: int, block_q: int, block_k: int,
     def rows(x):  # [B, H, S] -> [B, H/heads, n_q, heads, bq] lane vectors
         return x.reshape(b, h // heads, heads, n_q, block_q).swapaxes(2, 3)
 
+    if lse.ndim == 3:  # the grid forward's; the lane forward wrote rows
+        lse = rows(lse)
     flat = lambda x: x.reshape(b, s, h * d)
     whole = pl.BlockSpec((1, s, w), lambda bi, hi, kb: (bi, 0, hi))
     tile = pl.BlockSpec((1, block_k, w), lambda bi, hi, kb: (bi, kb, hi))
@@ -822,7 +1050,7 @@ def _bwd_fused(res, g, *, heads: int, block_q: int, block_k: int,
         ),
         interpret=interpret,
         name="flash_bwd",
-    )(flat(q), flat(k), flat(v), flat(g), rows(lse), rows(delta))
+    )(flat(q), flat(k), flat(v), flat(g), lse, rows(delta))
     return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape))
 
 
@@ -894,9 +1122,9 @@ def _bwd(causal, block_q, block_k, interpret, window, scale, logit_cap,
         return _bwd_fused(res, g, heads=heads, block_q=bq, block_k=bk,
                           interpret=interpret, window=window, scale=scale,
                           logit_cap=logit_cap)
-    return _bwd_blockwise(res, g, causal=causal, block_q=block_q,
-                          block_k=block_k, window=window, scale=scale,
-                          logit_cap=logit_cap)
+    return _bwd_blockwise((*res[:4], _lse_bhs(res[4])), g, causal=causal,
+                          block_q=block_q, block_k=block_k, window=window,
+                          scale=scale, logit_cap=logit_cap)
 
 
 flash_attention.defvjp(_fwd, _bwd)

@@ -113,7 +113,8 @@ def tile_visits(seq: int, block_q: Optional[int] = None,
 
     Derived from the SAME `_tile_in_band` predicate the kernels branch on
     (via `flash_attention.bwd_tile_plan`), so these are the tiles the
-    compiled forward executes (`pl.when`) and the causal backward scans
+    compiled forward executes (the lane kernel's loop, the grid kernel's
+    `pl.when`) and the causal backward scans
     (the in-band pair list IS its scan schedule). The forward, dq and
     dk/dv passes share one band, hence one count."""
     from tfde_tpu.ops import flash_attention as fa
@@ -153,7 +154,8 @@ def measured_tile_visits(
     recorder and return what the kernels actually scheduled: the traced
     forward/backward visit counts plus `bwd_steps_executed` — a runtime
     counter bumped from inside the causal backward's scan body, i.e. the
-    number of tile computations that genuinely ran."""
+    number of tile computations that genuinely ran — and, where the lane
+    forward ran, `fwd_steps_executed` from inside its K-tile loop."""
     import numpy as np
     import jax
     import jax.numpy as jnp
@@ -193,7 +195,8 @@ def check_tile_visits(verbose: bool = False) -> list:
     - the traced forward/backward visit counts equal the static plan
       (same predicate, so a mismatch means the kernels' schedule drifted);
     - the causal backward's runtime-executed scan steps equal the plan
-      (the backward provably does NOT visit out-of-band tiles);
+      (the backward provably does NOT visit out-of-band tiles), and so do
+      the K steps the lane forward's loop ran;
     - causal visits are the exact triangle count (~half the grid);
     - windowed visits respect the O(S * window / block^2) ceiling per
       Q tile.
@@ -239,6 +242,13 @@ def check_tile_visits(verbose: bool = False) -> list:
                     f"{name}: traced {key} visits {got} != static plan "
                     f"{static[key]}"
                 )
+        if measured.get("fwd_path") == "lane" \
+                and measured.get("fwd_steps_executed") != static["fwd"]:
+            failures.append(
+                f"{name}: the lane forward executed "
+                f"{measured.get('fwd_steps_executed')} K steps for one "
+                f"block of heads, plan says {static['fwd']}"
+            )
         executed = measured.get("bwd_steps_executed")
         if executed != static["bwd_dq"]:
             failures.append(
