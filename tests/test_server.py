@@ -477,7 +477,7 @@ def test_row_template_is_one_fresh_zero_program(lm, rp, kv_quant, monkeypatch):
             for leaf in jax.tree.leaves((first, second))]
     assert len(set(ptrs)) == len(ptrs)
 
-    filled, _tok, _seen = server_mod._prefill_rows(
+    filled, _tok, _seen, _routed = server_mod._prefill_rows(
         srv._decode_model, first, params,
         jnp.ones((rp, 8), jnp.int32), jnp.full((rp,), 7, jnp.int32),
         None, None, **srv._sampling,

@@ -128,18 +128,30 @@ _PHASE_KEYS = (
 )
 
 
-def _refuse_eva(model, what: str) -> None:
-    """Features that rewind, share or re-encode cached state BY POSITION
-    have nothing to hold on to in the layout of attention='eva' (a
-    summary folds 16 positions into one cell; a window slot is reused
-    every 2,048)."""
+def _state_not_by_position(model) -> Optional[str]:
+    """Why `model`'s cached state is not one cell per position, or None
+    where it is (a K/V slab): asked of the model's own fields."""
     if getattr(model, "attention", "full") == "eva":
+        return ("attention='eva' caches one window in progress and one "
+                "summary per chunk (models/transformer.py "
+                "MultiHeadAttention._eva_attention)")
+    if "mamba" in (getattr(model, "mixers", None) or ()):
+        return ("its 'mamba' layers cache a running state and a "
+                "convolution tail with no axis of positions "
+                "(models/transformer.py Mamba2Mixer)")
+    return None
+
+
+def _refuse_stateful(model, what: str) -> None:
+    """Features that rewind, share or re-encode cached state BY POSITION
+    have nothing to hold on to in a layout that is not a cell per
+    position (a summary folds 16 positions into one cell, a window slot
+    is reused every 2,048, a state-space layer keeps one state)."""
+    why = _state_not_by_position(model)
+    if why is not None:
         raise NotImplementedError(
-            f"{what} is not built for attention='eva': its cached state "
-            f"is one window in progress and one summary per chunk, not a "
-            f"cell per position (models/transformer.py "
-            f"MultiHeadAttention._eva_attention)"
-        )
+            f"{what} is not built for this model: {why}, not a cell per "
+            f"position")
 
 
 def _set_feed_pad(cache, pad):
@@ -155,6 +167,14 @@ def _set_feed_pad(cache, pad):
         return leaf
 
     return jax.tree_util.tree_map_with_path(fix, cache)
+
+
+def _sown_counters(mutated):
+    """The sum of what the layers sowed into "counters" in one apply (the
+    expert layers' routing counts, models/moe.py), or None for a model
+    that sows nothing: the program is then unchanged."""
+    leaves = jax.tree_util.tree_leaves(mutated.get("counters", {}))
+    return sum(leaves[1:], leaves[0]) if leaves else None
 
 
 def _fetch(tree):
@@ -190,10 +210,12 @@ def _decode_scan(model, cache, params, tok, idx, budget, done, seen, rng,
     `_set_feed_pad`).
 
     Returns (cache, tok, idx, budget, done, seen, rng, toks [B, K],
-    emitted [B, K]): `toks[r]` masked to `pad_id` where not emitted;
-    `emitted[r]` is a True-prefix per row (rows freeze monotonically), so
-    the host replays exactly `emitted[r].sum()` tokens into its
-    bookkeeping after the ONE fetch.
+    emitted [B, K], counters): `toks[r]` masked to `pad_id` where not
+    emitted; `emitted[r]` is a True-prefix per row (rows freeze
+    monotonically), so the host replays exactly `emitted[r].sum()` tokens
+    into its bookkeeping after the ONE fetch, which also brings
+    `counters`: what the layers sowed, summed over layers and ticks
+    (`_sown_counters`; None for a model that sows nothing).
 
     The greedy path (temperature == 0.0) carries `rng=None` and performs
     no `jax.random.split` at all — dead device work the per-tick loop
@@ -212,7 +234,7 @@ def _decode_scan(model, cache, params, tok, idx, budget, done, seen, rng,
         feed = jnp.where(done, jnp.int32(pad_id), tok)
         logits, mutated = model.apply(
             {"params": params, "cache": cache}, feed[:, None], train=False,
-            mutable=["cache"],
+            mutable=["cache", "counters"],
         )
         cache = mutated["cache"]
         logits = logits[:, -1].astype(jnp.float32)
@@ -238,13 +260,17 @@ def _decode_scan(model, cache, params, tok, idx, budget, done, seen, rng,
             fin = fin | (nxt == eos_id)
         done = done | (live & fin)
         tok = jnp.where(live, nxt, tok)
-        return (cache, tok, idx, budget, done, seen, rng), (nxt, live)
+        return ((cache, tok, idx, budget, done, seen, rng),
+                (nxt, live, _sown_counters(mutated)))
 
     carry = (cache, tok, idx, budget, done, seen, rng)
-    carry, (toks, emitted) = jax.lax.scan(body, carry, length=depth)
+    carry, (toks, emitted, counters) = jax.lax.scan(body, carry,
+                                                    length=depth)
     cache, tok, idx, budget, done, seen, rng = carry
+    if counters is not None:
+        counters = counters.sum(0)
     return (cache, tok, idx, budget, done, seen, rng,
-            jnp.moveaxis(toks, 0, 1), jnp.moveaxis(emitted, 0, 1))
+            jnp.moveaxis(toks, 0, 1), jnp.moveaxis(emitted, 0, 1), counters)
 
 
 @functools.partial(
@@ -274,22 +300,28 @@ def _prefill_rows(model, row_cache, params, prompts, last, valid, rng,
     runs the width's one compiled zero-fill program (`_zero_rows`), whose
     outputs are new buffers every call.
 
+    The model applies its head at `last` alone (`GPT.__call__`'s `last`):
+    the logits of the other positions, which nobody reads, are never
+    computed.
+
     Returns (filled row cache, first tokens [R], seen rows [R, V] or
-    None). Pad correctness rides the per-row index machinery: pad K/V
+    None, counters: what the layers sowed, `_sown_counters`, or None).
+    Pad correctness rides the per-row index machinery: pad K/V
     lands beyond each row's committed count once the admission rewind
     sets it to the TRUE prompt length. State that is DERIVED from the
     tokens (attention='eva': which window is in progress, which chunks
-    are complete) cannot be hidden by a rewind, so the true lengths
-    reach such layers before the forward (`_set_feed_pad`)."""
+    are complete; a state-space layer's running state) cannot be hidden
+    by a rewind, so the true lengths reach such layers before the
+    forward (`_set_feed_pad`)."""
     row_cache = _set_feed_pad(row_cache, prompts.shape[1] - 1 - last)
     with jax.named_scope("prefill_rows"):
         logits, mutated = model.apply(
             {"params": params, "cache": row_cache}, prompts, train=False,
-            mutable=["cache"],
+            last=last, mutable=["cache", "counters"],
         )
     r = prompts.shape[0]
     ar = jnp.arange(r)
-    logits = logits[ar, last].astype(jnp.float32)
+    logits = logits[:, 0].astype(jnp.float32)
     row_seen = None
     if repetition_penalty != 1.0:
         hits = jnp.zeros((r, model.vocab_size), jnp.int32)
@@ -301,7 +333,7 @@ def _prefill_rows(model, row_cache, params, prompts, last, valid, rng,
     )
     if row_seen is not None:
         row_seen = row_seen.at[ar, tok].set(True)
-    return mutated["cache"], tok, row_seen
+    return mutated["cache"], tok, row_seen, _sown_counters(mutated)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -677,7 +709,7 @@ class _BatcherBase:
         # boundaries add the counts; stats() returns it
         self._phase = dict.fromkeys(_PHASE_KEYS, 0)
         if role != "both":
-            _refuse_eva(model, f"role={role!r} (the primed hand-off ships "
+            _refuse_stateful(model, f"role={role!r} (the primed hand-off ships "
                                f"K/V by position)")
         # what one decode tick cannot avoid reading of the parameters
         self._param_bytes = _count_params(params)[1]
@@ -780,7 +812,7 @@ class _BatcherBase:
         cells = int(cells_per_row if cells_per_row is not None
                     else self._max_len)
         self._ledger = _capacity.CapacityLedger.from_cache(
-            cache, self._b, cells, model=self._model)
+            cache, self._b, cells, model=self._model, params=self._params)
         self._cap_model = _capacity.CapacityModel(self._ledger)
 
     def kv_stats(self) -> dict:
@@ -838,7 +870,7 @@ class _BatcherBase:
             raise RuntimeError(
                 f"{type(self).__name__} does not accept primed requests"
             )
-        _refuse_eva(self._model, "submit_primed() (K/V shipped by position)")
+        _refuse_stateful(self._model, "submit_primed() (K/V shipped by position)")
         if self._role == "prefill":
             raise RuntimeError("prefill-only replica cannot decode")
         prompt = self._check_request(primed.prompt, primed.max_new_tokens)
@@ -1510,7 +1542,7 @@ class ContinuousBatcher(_BatcherBase):
                else str(kv_quant))
         self._kv_quant = None if kvq == "fp" else kvq
         if self._kv_quant is not None:
-            _refuse_eva(model, f"kv_quant={self._kv_quant!r}")
+            _refuse_stateful(model, f"kv_quant={self._kv_quant!r}")
         self._decode_model = _decode_clone(model, kv_quant=self._kv_quant)
         self._sampling = dict(
             temperature=float(temperature),
@@ -1538,7 +1570,7 @@ class ContinuousBatcher(_BatcherBase):
         self._paged = (knobs.env_flag("TFDE_PAGED_KV") if paged is None
                        else bool(paged))
         if self._paged:
-            _refuse_eva(model, "paged=True (the block pool)")
+            _refuse_stateful(model, "paged=True (the block pool)")
             block = DEFAULT_BLOCK
             self._kv_block = int(block)
             # +1 cell: the decode scan writes one-past-committed for
@@ -1630,7 +1662,7 @@ class ContinuousBatcher(_BatcherBase):
         else:
             self._prefix = _resolve_prefix(prefix_cache)
         if self._prefix is not None:
-            _refuse_eva(model, "the prefix cache")
+            _refuse_stateful(model, "the prefix cache")
         # device-resident loop state (tok/idx/budget/done); rebuilt from
         # host bookkeeping whenever admission desyncs it
         self._dev = None
@@ -1710,8 +1742,7 @@ class ContinuousBatcher(_BatcherBase):
                     self._upload_state()
             tok, idx, budget, done = self._dev
             rng = self._rng if self._sampling["temperature"] != 0.0 else None
-            self._phase["decode_least_bytes"] += depth * (
-                self._param_bytes + self._kv_read_bytes(active))
+            read_bytes = self._kv_read_bytes(active)
             self._ledger.note_scan(self._committed[active], depth)
             with self._span("serving/decode/scan", "decode_dispatch_ns"):
                 self._mem_register(
@@ -1740,13 +1771,21 @@ class ContinuousBatcher(_BatcherBase):
                     )
             self._dispatches += 1
             (self._cache, tok, idx, budget, done, self._seen, rng,
-             toks, emitted) = out
+             toks, emitted, routed) = out
             self._dev = (tok, idx, budget, done)
             if rng is not None:
                 self._rng = rng
             with self._span("serving/decode/fetch", "device_wait_ns"):
-                toks_np, emitted_np = _fetch((toks, emitted))
+                # what the layers counted rides the scan's one fetch
+                toks_np, emitted_np, *routed = _fetch(
+                    (toks, emitted) if routed is None
+                    else (toks, emitted, routed))
+                routed = routed[0] if routed else None
             self._syncs += 1
+            self._ledger.note_routed(routed)
+            self._phase["decode_least_bytes"] += (
+                self._ledger.scan_least_bytes(self._param_bytes, read_bytes,
+                                              depth, routed))
         with self._span("serving/emit", "emit_ns"):
             self._rounds += depth
             self._profiler_round(traced)
@@ -1848,7 +1887,7 @@ class ContinuousBatcher(_BatcherBase):
                 (tmpl, self._params, prompts_dev, last_dev, valid, rng),
                 donated=tmpl,
             )
-            row_cache, tok, row_seen = _prefill_rows(
+            row_cache, tok, row_seen, routed = _prefill_rows(
                 self._decode_model, tmpl, self._params,
                 prompts_dev, last_dev, valid, rng,
                 **self._sampling,
@@ -1863,7 +1902,12 @@ class ContinuousBatcher(_BatcherBase):
                 for i in range(n):
                     self._prefix.insert(prompts[i, :plens[i]], row_cache, i)
             self._scatter_wave(row_cache, row_seen, rows, n)
-        return self._fetch_first(tok)
+        if routed is None:
+            return self._fetch_first(tok)
+        # what the layers counted rides the wave's one fetch
+        tok, routed = self._fetch_first((tok, routed))
+        self._ledger.note_routed(routed)
+        return tok
 
     def _scatter_wave(self, row_cache, row_seen, rows, n: int) -> None:
         """Land a prefilled wave (`rows` padded to the ladder, `n` of them
@@ -2342,7 +2386,7 @@ class ContinuousBatcher(_BatcherBase):
         long-prompt admissions without ever stalling a decode scan."""
         if self._role == "decode":
             raise RuntimeError("decode-only replica cannot prime")
-        _refuse_eva(self._model, "prime() (K/V shipped by position)")
+        _refuse_stateful(self._model, "prime() (K/V shipped by position)")
         t_prime = now_ns()
         prompt = self._check_request(prompt, max_new_tokens)
         bucket = next(b for b in self._buckets if b >= prompt.size)
@@ -2355,7 +2399,7 @@ class ContinuousBatcher(_BatcherBase):
         rng = None
         if self._sampling["temperature"] != 0.0:
             self._rng, rng = jax.random.split(self._rng)
-        row_cache, tok, _ = _prefill_rows(
+        row_cache, tok, _, _ = _prefill_rows(
             self._decode_model, self._row_template(1), self._params,
             jnp.asarray(prompts), jnp.asarray(last), valid, rng,
             **self._sampling,
@@ -2473,7 +2517,7 @@ class SpeculativeContinuousBatcher(_BatcherBase):
         if num_draft < 1:
             raise ValueError(f"num_draft must be >= 1, got {num_draft}")
         for m in (model, draft_model):
-            _refuse_eva(m, "SpeculativeContinuousBatcher (a rejected "
+            _refuse_stateful(m, "SpeculativeContinuousBatcher (a rejected "
                            "draft rewinds the cache by position)")
         super().__init__(model, params, batch_size, max_len, eos_id,
                          pad_id, rng, prompt_buckets)
@@ -2544,14 +2588,14 @@ class SpeculativeContinuousBatcher(_BatcherBase):
         with self._span("serving/prefill/run", "prefill_run_ns"):
             prompts_dev = jnp.asarray(prompts)
             last_dev = jnp.asarray(last)
-            tgt_rows, tok, _ = _prefill_rows(
+            tgt_rows, tok, _, _ = _prefill_rows(
                 self._tgt, tgt_tmpl, self._params, prompts_dev, last_dev,
                 None, rng, temperature=self._temperature, top_k=None,
                 top_p=None, min_p=None, repetition_penalty=1.0,
             )
             # the draft prefill only needs its cache filled; its sampled
             # token is discarded (greedy argmax — no rng consumed)
-            drf_rows, _, _ = _prefill_rows(
+            drf_rows, _, _, _ = _prefill_rows(
                 self._drf, drf_tmpl, self._dparams, prompts_dev, last_dev,
                 None, None, temperature=0.0, top_k=None, top_p=None,
                 min_p=None, repetition_penalty=1.0,

@@ -43,9 +43,16 @@ class GPT(nn.Module):
     num_experts: int = 0
     moe_every: int = 2
     experts_per_token: int = 2
-    moe_capacity_factor: float = 1.25  # models/moe.py MoEMlp
+    # None: no capacity, no token dropped (serving; models/moe.py MoEMlp)
+    moe_capacity_factor: Optional[float] = 1.25
     moe_normalize_topk: bool = True        # models/moe.py MoEMlp
     moe_shared_expert_dim: Optional[int] = None  # Qwen2-MoE shared expert
+    # False (Granite): the shared expert is added as it is, no sigmoid gate
+    moe_shared_expert_gated: bool = True
+    # (first, end) of the contiguous range of experts this program holds
+    # (a chip's share under expert parallelism): the router stays
+    # `num_experts` wide, pairs routed elsewhere add nothing (MoEMlp)
+    moe_held_experts: Optional[tuple] = None
     router_z_loss_weight: float = 0.0  # ST-MoE stabilizer (models/moe.py)
     # autoregressive serving mode (inference/decode.py): KV caches in the
     # "cache" collection; positions continue from the cached prefix
@@ -69,7 +76,8 @@ class GPT(nn.Module):
     ln_eps: float = 1e-6  # GPT-2 checkpoints use 1e-5 (models/convert.py)
     # 'learned' = GPT-2 absolute wpe table; 'rope' = rotary q/k rotation
     # (ops/rotary.py) — no position table, relative-position attention,
-    # better length extrapolation
+    # better length extrapolation; 'none' = no positions at all (Granite
+    # 4.0-H: the state-space layers carry the order)
     position: str = "learned"
     rope_theta: float = 10_000.0
     # RoPE frequency rescaling (ops/rotary.scale_frequencies tuple):
@@ -138,11 +146,26 @@ class GPT(nn.Module):
     # residual stream and its adds in float32, the sublayers in `dtype`
     # (EvaByte's fp32_skip_add)
     fp32_residual: bool = False
+    # one mixer kind per layer, 'attention' | 'mamba' (a config's
+    # `layer_types`), as long as `depth`; None: every layer is attention.
+    # 'mamba' layers are ops/ssm.py's Mamba-2 mixer at the widths of `ssm`
+    # (an ops/ssm.SSMShape) and cache a running state, not positions
+    mixers: Optional[tuple] = None
+    ssm: Optional[Any] = None
+    # Granite: each sublayer's output times this before the residual add,
+    # and the logits divided by `logits_scaling`
+    residual_multiplier: Optional[float] = None
+    logits_scaling: Optional[float] = None
 
     @nn.compact
     def __call__(self, input_ids: jax.Array, train: bool = False,
-                 segment_ids: Optional[jax.Array] = None) -> jax.Array:
-        """segment_ids [B, S]: sequence-packing support (data/packing.py)
+                 segment_ids: Optional[jax.Array] = None,
+                 last: Optional[jax.Array] = None) -> jax.Array:
+        """last [B]: the head is applied at that one position of each row
+        and the logits are [B, 1, vocab] (a prefill that samples one first
+        token reads no other position's logits).
+
+        segment_ids [B, S]: sequence-packing support (data/packing.py)
         — tokens attend only within their own segment (block-diagonal
         causal mask; padding is segment 0 and attends only other padding,
         keeping its softmax rows finite). Positions stay GLOBAL within
@@ -194,9 +217,10 @@ class GPT(nn.Module):
                 self.vocab_size, self.hidden_size, dtype=self.dtype,
                 param_dtype=jnp.float32, name="wte",
             )
-        if self.position not in ("learned", "rope"):
+        if self.position not in ("learned", "rope", "none"):
             raise ValueError(
-                f"position must be 'learned' or 'rope', got {self.position!r}"
+                f"position must be 'learned', 'rope' or 'none', got "
+                f"{self.position!r}"
             )
         x = wte(input_ids)
         if self.embed_scale is not None:
@@ -278,8 +302,16 @@ class GPT(nn.Module):
             eva_window=self.eva_window,
             eva_chunk=self.eva_chunk,
             norm_unit_offset=self.norm_unit_offset,
+            moe_held_experts=(tuple(self.moe_held_experts)
+                              if self.moe_held_experts is not None else None),
+            moe_shared_expert_gated=self.moe_shared_expert_gated,
+            mixers=tuple(self.mixers) if self.mixers is not None else None,
+            ssm=self.ssm,
+            residual_multiplier=self.residual_multiplier,
             name="decoder",
         )(x, mask=seg_mask, train=train)
+        if last is not None:
+            x = x[jnp.arange(x.shape[0]), last][:, None]
         if self.tie_embeddings:
             if self.head_bias:
                 raise ValueError(
@@ -301,6 +333,8 @@ class GPT(nn.Module):
                 self.vocab_size, use_bias=self.head_bias, dtype=self.dtype,
                 param_dtype=jnp.float32, name="lm_head",
             )(x.astype(self.dtype)).astype(jnp.float32)
+        if self.logits_scaling is not None:
+            logits = logits / self.logits_scaling
         if self.final_logit_cap is not None:
             logits = self.final_logit_cap * jnp.tanh(
                 logits / self.final_logit_cap

@@ -26,6 +26,16 @@ standard Switch behavior.
 Load-balance auxiliary loss (Switch eq. 4): E * sum_e f_e * P_e, sown into
 the 'losses' collection; training/step.py adds every sown loss to the
 objective automatically when the model mutates that collection.
+
+**Without a capacity** (`capacity_factor=None`, the serving form): no token
+is dropped and no [.., capacity] tensor exists. The (token, choice) pairs
+are sorted by expert and each expert multiplies its own run of rows
+(`jax.lax.ragged_dot`), so the work follows the pairs routed and a token's
+result does not depend on which other tokens share its call. Such a layer
+may hold a contiguous range of the experts (`held_experts`, a chip's share
+under expert parallelism): the router keeps all `num_experts` outputs and
+its top k, and pairs whose expert lives elsewhere add nothing; there is no
+stand-in for the absent chips or their exchange.
 """
 
 from __future__ import annotations
@@ -64,6 +74,11 @@ def dispatch_shape(batch: int, seq: int, num_experts: int,
     return (g, m, num_experts, c)
 
 
+#: tokens whose pairs are sorted and multiplied at a time without a
+#: capacity: the sorted copy is this x experts_per_token rows
+_TOKEN_BLOCK = 2048
+
+
 class MoEMlp(nn.Module):
     """Top-k routed expert MLP: fc1 -> gelu -> fc2 per expert.
 
@@ -75,7 +90,8 @@ class MoEMlp(nn.Module):
     num_experts: int
     mlp_dim: int
     experts_per_token: int = 2
-    capacity_factor: float = 1.25
+    # None: no capacity (module docstring); the training default keeps one
+    capacity_factor: Optional[float] = 1.25
     # 'gelu' (Switch/GShard) | 'swiglu' (Mixtral: per-expert gated-silu,
     # bias-free — a parallel experts_gate projection beside the up
     # projection, the expert-wise analog of transformer.Mlp's swiglu)
@@ -89,6 +105,15 @@ class MoEMlp(nn.Module):
     # runs on every token beside the routed experts, its output scaled by
     # a learned sigmoid gate — replicated weights (no expert axis)
     shared_expert_dim: Optional[int] = None
+    # False (Granite): y = experts(x) + shared(x), no sigmoid gate
+    shared_expert_gated: bool = True
+    # (first, end): the experts whose weights this layer holds, of
+    # `num_experts` routed over; None holds all. capacity_factor=None only
+    held_experts: Optional[tuple] = None
+    # serving: a "cache" variable `feed_pad` [rows] (how many trailing
+    # tokens of this call are padding, set by the caller, read once and
+    # reset) keeps padding out of the routing counts sown into "counters"
+    decode: bool = False
     aux_loss_weight: float = 0.01
     # router z-loss (ST-MoE): penalizes mean(logsumexp(router logits)^2),
     # keeping logit magnitudes bounded so fp32 routing stays stable over
@@ -108,7 +133,15 @@ class MoEMlp(nn.Module):
         if n % g:
             raise ValueError(f"{n} tokens not divisible into {g} groups")
         m = n // g
-        capacity = group_capacity(m, e, k, self.capacity_factor)
+        lo, hi = self.held_experts or (0, e)
+        if not 0 <= lo < hi <= e:
+            raise ValueError(
+                f"held_experts={self.held_experts} is no range of the "
+                f"{e} experts")
+        if self.capacity_factor is not None and hi - lo != e:
+            raise NotImplementedError(
+                "a layer that holds a share of the experts routes without "
+                "a capacity (capacity_factor=None)")
 
         # [G, m, d] token groups; with the default g=bsz the group dim IS the
         # batch dim, so groups inherit the data sharding unchanged.
@@ -126,6 +159,23 @@ class MoEMlp(nn.Module):
                 jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9
             )
 
+        # Switch load-balance aux loss: fraction routed x mean prob, top-1,
+        # averaged over ALL tokens (global, not per-group)
+        top1 = jax.nn.one_hot(gate_idx[..., 0], e, dtype=jnp.float32)
+        f = jnp.mean(top1, axis=(0, 1))
+        p = jnp.mean(probs, axis=(0, 1))
+        aux = self.aux_loss_weight * e * jnp.sum(f * p)
+        self.sow("losses", "moe_aux", aux)  # default tuple-append reduce
+        if self.router_z_loss_weight > 0.0:
+            z = jax.nn.logsumexp(logits, axis=-1)  # [g, m]
+            self.sow("losses", "moe_z",
+                     self.router_z_loss_weight * jnp.mean(z * z))
+
+        if self.capacity_factor is None:
+            y = self._uncapped(x, gate_vals, gate_idx, lo, hi)
+            return self._finish(x, y, train)
+        capacity = group_capacity(m, e, k, self.capacity_factor)
+
         # position of each (token, choice) within its expert's per-group
         # capacity: cumsum over the group's choice-major token stream
         choice_mask = jax.nn.one_hot(gate_idx, e, dtype=jnp.float32)  # [g,m,k,e]
@@ -140,43 +190,7 @@ class MoEMlp(nn.Module):
         dispatch = jnp.sum(pos_oh, axis=1)
         combine = jnp.sum(pos_oh * gates, axis=1)
 
-        # Switch load-balance aux loss: fraction routed x mean prob, top-1,
-        # averaged over ALL tokens (global, not per-group)
-        top1 = jax.nn.one_hot(gate_idx[..., 0], e, dtype=jnp.float32)
-        f = jnp.mean(top1, axis=(0, 1))
-        p = jnp.mean(probs, axis=(0, 1))
-        aux = self.aux_loss_weight * e * jnp.sum(f * p)
-        self.sow("losses", "moe_aux", aux)  # default tuple-append reduce
-        if self.router_z_loss_weight > 0.0:
-            z = jax.nn.logsumexp(logits, axis=-1)  # [g, m]
-            self.sow("losses", "moe_z",
-                     self.router_z_loss_weight * jnp.mean(z * z))
-
-        if self.act not in ("gelu", "swiglu"):
-            raise ValueError(
-                f"act must be 'gelu' or 'swiglu', got {self.act!r}"
-            )
-        w1 = self.param(
-            "experts_fc1",
-            nn.initializers.lecun_normal(batch_axis=0),
-            (e, d, self.mlp_dim), jnp.float32,
-        )
-        w2 = self.param(
-            "experts_fc2",
-            nn.initializers.lecun_normal(batch_axis=0),
-            (e, self.mlp_dim, d), jnp.float32,
-        )
-        if self.use_bias:
-            b1 = self.param("experts_b1", nn.initializers.zeros,
-                            (e, 1, self.mlp_dim), jnp.float32)
-            b2 = self.param("experts_b2", nn.initializers.zeros,
-                            (e, 1, d), jnp.float32)
-        if self.act == "swiglu":
-            wg = self.param(
-                "experts_gate",
-                nn.initializers.lecun_normal(batch_axis=0),
-                (e, d, self.mlp_dim), jnp.float32,
-            )
+        w1, w2, b1, b2, wg = self._expert_params(e, d)
 
         # [e, g, c, d]: expert-major so the expert shard is dim 0, the
         # (data-sharded) group dim rides along — the token<->expert layout
@@ -215,7 +229,109 @@ class MoEMlp(nn.Module):
             "gmec,egcd->gmd", combine.astype(self.dtype), out_e,
             preferred_element_type=jnp.float32,
         )
-        y = y.astype(x.dtype).reshape(bsz, seq, d)
+        return self._finish(x, y.astype(x.dtype).reshape(bsz, seq, d), train)
+
+    def _expert_params(self, held: int, d: int) -> tuple:
+        """(fc1, fc2, b1, b2, gate) of the `held` experts; the biases and
+        the gate are None where the arrangement has none."""
+        if self.act not in ("gelu", "swiglu"):
+            raise ValueError(
+                f"act must be 'gelu' or 'swiglu', got {self.act!r}"
+            )
+        init = nn.initializers.lecun_normal(batch_axis=0)
+        w1 = self.param("experts_fc1", init, (held, d, self.mlp_dim),
+                        jnp.float32)
+        w2 = self.param("experts_fc2", init, (held, self.mlp_dim, d),
+                        jnp.float32)
+        b1 = b2 = wg = None
+        if self.use_bias:
+            b1 = self.param("experts_b1", nn.initializers.zeros,
+                            (held, 1, self.mlp_dim), jnp.float32)
+            b2 = self.param("experts_b2", nn.initializers.zeros,
+                            (held, 1, d), jnp.float32)
+        if self.act == "swiglu":
+            wg = self.param("experts_gate", init, (held, d, self.mlp_dim),
+                            jnp.float32)
+        return w1, w2, b1, b2, wg
+
+    def _uncapped(self, x, gate_vals, gate_idx, lo: int, hi: int):
+        """The routed experts' part of the result with no capacity: x
+        [B, S, d], gate_vals / gate_idx [.., k] of all B S tokens. A block
+        of tokens at a time (the sorted copy of a block's pairs is
+        block x k rows of d), each block's pairs sorted by expert with
+        those routed elsewhere last, three grouped matmuls over the held
+        experts' runs, and a gather back into token order."""
+        bsz, seq, d = x.shape
+        k, held = self.experts_per_token, hi - lo
+        if self.act != "swiglu" or self.use_bias:
+            raise NotImplementedError(
+                "routing without a capacity is built for bias-free swiglu "
+                "experts")
+        w1, w2, _, _, wg = self._expert_params(held, d)
+        n = bsz * seq
+        valid = jnp.ones((bsz, seq), bool)
+        if self.decode:
+            filled = self.has_variable("cache", "feed_pad")
+            feed_pad = self.variable("cache", "feed_pad", jnp.zeros, (bsz,),
+                                     jnp.int32)
+            if filled:
+                valid = (jnp.arange(seq)[None, :]
+                         < seq - feed_pad.value[:, None])
+                feed_pad.value = jnp.zeros_like(feed_pad.value)
+        block = min(n, _TOKEN_BLOCK)
+        grown = -(-n // block) * block
+
+        def blocks(t, fill):
+            t = t.reshape((n,) + t.shape[2:])
+            t = jnp.pad(t, ((0, grown - n),) + ((0, 0),) * (t.ndim - 1),
+                        constant_values=fill)
+            return t.reshape((grown // block, block) + t.shape[1:])
+
+        def one(args):
+            xb, idx, vals, ok = args    # [block, d], [block, k] x2, [block]
+            with jax.named_scope("moe_route"):
+                local = idx - lo
+                here = (local >= 0) & (local < held)
+                key = jnp.where(here, local, held).reshape(-1)
+                order = jnp.argsort(key, stable=True)
+                sizes = jnp.zeros((held + 1,), jnp.int32).at[key].add(1)
+                counted = jnp.zeros((held + 1,), jnp.int32).at[key].add(
+                    jnp.repeat(ok, k).astype(jnp.int32))[:held]
+                back = jnp.zeros_like(order).at[order].set(
+                    jnp.arange(order.size, dtype=order.dtype))
+            with jax.named_scope("moe_experts"):
+                rows = xb.astype(self.dtype)[order // k]
+                grouped = lambda lhs, w, out: jax.lax.ragged_dot(
+                    lhs, w.astype(self.dtype), sizes[:held],
+                    preferred_element_type=out)
+                h = nn.silu(grouped(rows, wg, jnp.float32)) * grouped(
+                    rows, w1, jnp.float32)
+                out = grouped(h.astype(self.dtype), w2, self.dtype)
+                # rows past the held experts' runs are never written
+                pairs = jnp.where(
+                    here[..., None],
+                    out[back].reshape(block, k, d).astype(jnp.float32)
+                    * vals[..., None], 0.0)
+                return pairs.sum(1).astype(x.dtype), counted, ok.sum() * k
+
+        y, counted, routed = jax.lax.map(
+            one, (blocks(x, 0), blocks(gate_idx, -1),
+                  blocks(gate_vals.astype(jnp.float32), 0),
+                  blocks(valid, False)))
+        counted = counted.sum(0)
+        # pairs routed, pairs whose expert is held, held experts with a
+        # pair, the busiest held expert's pairs: of this call's real tokens
+        self.sow("counters", "moe_routing",
+                 jnp.stack([routed.sum().astype(jnp.int32), counted.sum(),
+                            (counted > 0).sum().astype(jnp.int32),
+                            counted.max()]),
+                 reduce_fn=lambda a, b: a + b,
+                 init_fn=lambda: jnp.zeros((4,), jnp.int32))
+        return y.reshape(grown, d)[:n].reshape(bsz, seq, d)
+
+    def _finish(self, x, y, train: bool):
+        """Routed part `y` plus the shared expert, dropout, sharding."""
+        d = x.shape[-1]
         if self.shared_expert_dim is not None:
             if self.act != "swiglu" or self.use_bias:
                 raise NotImplementedError(
@@ -228,17 +344,18 @@ class MoEMlp(nn.Module):
             )
             sh = nn.silu(dense(self.shared_expert_dim, "shared_gate")(x)) \
                 * dense(self.shared_expert_dim, "shared_fc1")(x)
-            sh = dense(d, "shared_fc2")(sh)
-            # scalar sigmoid gate per token (fp32: a saturating gate is
-            # precision-sensitive)
-            gate = jax.nn.sigmoid(
-                nn.Dense(1, use_bias=False, dtype=jnp.float32,
-                         param_dtype=jnp.float32,
-                         name="shared_expert_gate")(
-                    x.astype(jnp.float32)
+            sh = dense(d, "shared_fc2")(sh).astype(jnp.float32)
+            if self.shared_expert_gated:
+                # scalar sigmoid gate per token (fp32: a saturating gate
+                # is precision-sensitive)
+                sh = sh * jax.nn.sigmoid(
+                    nn.Dense(1, use_bias=False, dtype=jnp.float32,
+                             param_dtype=jnp.float32,
+                             name="shared_expert_gate")(
+                        x.astype(jnp.float32)
+                    )
                 )
-            )
-            y = y + (gate * sh.astype(jnp.float32)).astype(x.dtype)
+            y = y + sh.astype(x.dtype)
         if self.dropout_rate > 0.0:
             y = nn.Dropout(self.dropout_rate, deterministic=not train)(y)
-        return constrain(y, b_axes, "seq")
+        return constrain(y, batch_axes(), "seq")

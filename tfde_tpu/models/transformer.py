@@ -27,6 +27,7 @@ import jax.numpy as jnp
 
 from tfde_tpu.ops import attention as attn_lib
 from tfde_tpu.ops import eva_attention as eva_lib
+from tfde_tpu.ops import ssm as ssm_lib
 from tfde_tpu.ops.quant import QuantDenseGeneral, kv_dequantize, kv_quantize
 from tfde_tpu.ops.rotary import apply_rotary
 from tfde_tpu.parallel.axes import batch_axes, constrain
@@ -46,6 +47,13 @@ def _check_quant(quant, train: bool = False) -> bool:
             "gradient) — train the fp model, then quantize_model it"
         )
     return quant == "int8"
+
+
+#: a prefill into the dense K/V slab attends all its queries at once while
+#: their float32 scores stay under this many bytes, and otherwise this many
+#: queries at a time (`MultiHeadAttention._decode_attention`)
+_PREFILL_SCORES_BYTES = 2 ** 30
+_PREFILL_QUERY_BLOCK = 256
 
 
 class MultiHeadAttention(nn.Module):
@@ -532,10 +540,41 @@ class MultiHeadAttention(nn.Module):
         # grouped_attention == reference_attention at kv_heads == num_heads;
         # with GQA the kv_heads-shaped cache feeds the einsum directly (no
         # expanded copy on the bandwidth-bound decode path)
-        return attn_lib.grouped_attention(
-            q, k_all, v_all, mask=valid, scale=self.attn_scale,
-            logit_cap=self.attn_logit_cap,
-        )
+        attend = functools.partial(
+            attn_lib.grouped_attention, scale=self.attn_scale,
+            logit_cap=self.attn_logit_cap)
+        block = _PREFILL_QUERY_BLOCK
+        if (4 * q.shape[0] * self.num_heads * sq * max_len
+                <= _PREFILL_SCORES_BYTES or sq % block):
+            return attend(q, k_all, v_all, mask=valid)
+        # a long prefill over a long slab: the float32 scores of all its
+        # queries against the whole slab ([rows, heads, Sq, max_len]: 6 GB
+        # a row at 6,144 over 8,192) do not fit. Into an empty cache (a
+        # cold prompt: every key is one of this call's own) the dispatcher
+        # attends causally over the call's tokens alone, on the chip with
+        # the flash kernel; behind a cached prefix, a block of queries at
+        # a time over the slab.
+
+        def fresh():
+            return attn_lib.attention(
+                q, k, v, causal=True, impl=self.attn_impl,
+                window=self.window, scale=self.attn_scale,
+                logit_cap=self.attn_logit_cap).astype(q.dtype)
+
+        def behind_a_prefix():
+            def some(i):
+                rows = functools.partial(
+                    jax.lax.dynamic_slice_in_dim, start_index=i * block,
+                    slice_size=block)
+                return attend(rows(q, axis=1), k_all, v_all,
+                              mask=rows(valid, axis=2))
+
+            out = jax.lax.map(some, jnp.arange(sq // block))
+            return jnp.moveaxis(out, 0, 1).reshape(q.shape).astype(q.dtype)
+
+        if idx.ndim:
+            return behind_a_prefix()
+        return jax.lax.cond(idx == 0, fresh, behind_a_prefix)
 
     def _paged_attention(self, q, k, v, batch) -> jax.Array:
         """Paged decode attention: write this call's K/V into pool blocks
@@ -749,6 +788,99 @@ class MultiHeadAttention(nn.Module):
         return y
 
 
+class Mamba2Mixer(nn.Module):
+    """The Mamba-2 state-space mixer (ops/ssm.py) in MultiHeadAttention's
+    place: `in_proj` to [z, xBC, dt], a causal depthwise `conv1d` over
+    xBC, the recurrence with `A_log`, `D` and `dt_bias` per head, a gated
+    RMSNorm (`norm_scale`) and `out_proj`.
+
+    Under decode=True the "cache" collection holds per row a running
+    state and no axis of positions: `ssm_state` [B, H, P, N] float32,
+    `conv_tail` [B, K-1, C] (the last K-1 raw xBC inputs) and `feed_pad`
+    [B], how many trailing tokens of THIS call are padding (0 unless the
+    caller sets it; read once and reset). A call continues from the
+    cached state: S > 1 feeds right-padded rows of true length S -
+    feed_pad, past which the state stands and the tail kept ends at the
+    true length; S = 1 is one step, and a padded one changes neither. A
+    state cannot be rewound, shared or re-encoded by position."""
+
+    ssm: ssm_lib.SSMShape
+    dtype: jnp.dtype = jnp.bfloat16
+    decode: bool = False
+    ln_eps: float = 1e-6
+
+    @nn.compact
+    def __call__(self, x: jax.Array, mask: Optional[jax.Array] = None,
+                 train: bool = False) -> jax.Array:
+        if mask is not None:
+            raise NotImplementedError(
+                "a state-space mixer takes no attention mask")
+        b = batch_axes()
+        shape = self.ssm
+        bsz, sq, width = x.shape
+        dense = functools.partial(nn.Dense, dtype=self.dtype,
+                                  param_dtype=jnp.float32, use_bias=False)
+        conv_kernel = self.param(
+            "conv_kernel", nn.initializers.lecun_normal(),
+            (shape.conv, shape.conv_channels), jnp.float32)
+        conv_bias = (self.param("conv_bias", nn.initializers.zeros,
+                                (shape.conv_channels,), jnp.float32)
+                     if shape.conv_bias else None)
+        a_log = self.param(
+            "A_log", lambda _k, s: jnp.log(jnp.arange(1, s[0] + 1,
+                                                      dtype=jnp.float32)),
+            (shape.heads,))
+        skip = self.param("D", nn.initializers.ones, (shape.heads,),
+                          jnp.float32)
+        dt_bias = self.param("dt_bias", nn.initializers.zeros,
+                             (shape.heads,), jnp.float32)
+        gain = self.param("norm_scale", nn.initializers.ones,
+                          (shape.inner,), jnp.float32)
+
+        zxd = dense(shape.in_features, name="in_proj")(x)
+        z, xbc, dt = jnp.split(
+            zxd, [shape.inner, shape.inner + shape.conv_channels], axis=-1)
+        dt = jax.nn.softplus(dt.astype(jnp.float32)
+                             + dt_bias.astype(jnp.float32))
+
+        tail0 = jnp.zeros((bsz, shape.conv - 1, shape.conv_channels),
+                          xbc.dtype)
+        state0 = jnp.zeros((bsz, shape.heads, shape.head_dim, shape.state),
+                           jnp.float32)
+        filled = self.decode and self.has_variable("cache", "ssm_state")
+        if self.decode:
+            state = self.variable("cache", "ssm_state", lambda: state0)
+            tail = self.variable("cache", "conv_tail", lambda: tail0)
+            feed_pad = self.variable("cache", "feed_pad", jnp.zeros, (bsz,),
+                                     jnp.int32)
+        if filled:
+            pad = feed_pad.value
+            state0, tail0 = state.value, tail.value
+        else:
+            # the plain forward, and decode's init pass (the variables
+            # were just created; flax convention)
+            pad = jnp.zeros((bsz,), jnp.int32)
+        lengths = sq - pad
+        xbc, new_tail = ssm_lib.causal_conv(xbc, tail0, conv_kernel,
+                                            conv_bias, lengths)
+        if sq > 1 or not filled:
+            y, new_state = ssm_lib.prefill(xbc, dt, a_log, skip, state0,
+                                           lengths, shape)
+        else:
+            y, new_state = ssm_lib.decode_step(
+                xbc[:, 0], dt[:, 0], a_log, skip, state0, pad == 0, shape)
+            y = y[:, None]
+        if filled:
+            state.value = constrain(new_state, b, "tensor")
+            tail.value = constrain(new_tail, b)
+            feed_pad.value = jnp.zeros_like(pad)
+        y = ssm_lib.gated_rms_norm(y.reshape(bsz, sq, shape.inner), z, gain,
+                                   self.ln_eps).astype(self.dtype)
+        y = constrain(y, b, "seq", "tensor")
+        y = dense(width, name="out_proj")(y)
+        return constrain(y, b, "seq")
+
+
 class Mlp(nn.Module):
     """fc1 -> act -> fc2; hidden dim carries the tensor-parallel shard.
 
@@ -873,14 +1005,25 @@ class TransformerBlock(nn.Module):
     ln_eps: float = 1e-6  # checkpoint fidelity: GPT-2 1e-5, BERT 1e-12
     num_experts: int = 0  # > 0 swaps the dense MLP for a routed MoE MLP
     experts_per_token: int = 2
-    moe_capacity_factor: float = 1.25  # MoEMlp.capacity_factor
+    moe_capacity_factor: Optional[float] = 1.25  # MoEMlp.capacity_factor
     moe_normalize_topk: bool = True        # MoEMlp.normalize_topk
     moe_shared_expert_dim: Optional[int] = None  # MoEMlp.shared_expert_dim
     router_z_loss_weight: float = 0.0  # ST-MoE stabilizer (models/moe.py)
+    # MoEMlp.held_experts / shared_expert_gated; moe_capacity_factor=None
+    # is the drop-free routing
+    moe_held_experts: Optional[tuple] = None
+    moe_shared_expert_gated: bool = True
     attention: str = "full"  # 'full' | 'eva' (MultiHeadAttention)
     eva_window: int = 2048
     eva_chunk: int = 16
     norm_unit_offset: bool = False  # norm='rms' only (make_norm)
+    # what mixes positions in this block: 'attention' (MultiHeadAttention)
+    # | 'mamba' (Mamba2Mixer over `ssm`, an ops/ssm.SSMShape)
+    mixer: str = "attention"
+    ssm: Optional[ssm_lib.SSMShape] = None
+    # both sublayers' outputs are scaled by this before the residual add
+    # (Granite's residual_multiplier); None adds them as they are
+    residual_multiplier: Optional[float] = None
 
     @nn.compact
     def __call__(
@@ -890,37 +1033,49 @@ class TransformerBlock(nn.Module):
         train: bool = False,
     ) -> jax.Array:
         ln = make_norm(self.norm, self.ln_eps, self.norm_unit_offset)
-        attn = MultiHeadAttention(
-            num_heads=self.num_heads,
-            head_dim=self.head_dim,
-            dtype=self.dtype,
-            dropout_rate=self.dropout_rate,
-            attn_impl=self.attn_impl,
-            causal=self.causal,
-            decode=self.decode,
-            rope=self.rope,
-            rope_theta=self.rope_theta,
-            rope_scaling=self.rope_scaling,
-            rope_dim=self.rope_dim,
-            num_kv_heads=self.num_kv_heads,
-            fused_qkv=self.fused_qkv,
-            quant=self.quant,
-            window=self.window,
-            rolling_cache=self.rolling_cache,
-            paged_blocks=self.paged_blocks,
-            kv_block=self.kv_block,
-            kv_quant=self.kv_quant,
-            attn_scale=self.attn_scale,
-            attn_logit_cap=self.attn_logit_cap,
-            use_bias=self.use_bias,
-            qkv_bias=self.qkv_bias,
-            qk_norm=self.qk_norm,
-            ln_eps=self.ln_eps,
-            attention=self.attention,
-            eva_window=self.eva_window,
-            eva_chunk=self.eva_chunk,
-            name="attn",
-        )
+        if self.mixer not in ("attention", "mamba"):
+            raise ValueError(
+                f"mixer must be 'attention' or 'mamba', got {self.mixer!r}")
+        if self.mixer == "mamba":
+            if self.ssm is None or self.norm_style != "pre":
+                raise ValueError(
+                    "mixer='mamba' needs its widths (`ssm`) and the pre-norm "
+                    "block")
+            attn = Mamba2Mixer(ssm=self.ssm, dtype=self.dtype,
+                               decode=self.decode, ln_eps=self.ln_eps,
+                               name="mamba")
+        else:
+            attn = MultiHeadAttention(
+                num_heads=self.num_heads,
+                head_dim=self.head_dim,
+                dtype=self.dtype,
+                dropout_rate=self.dropout_rate,
+                attn_impl=self.attn_impl,
+                causal=self.causal,
+                decode=self.decode,
+                rope=self.rope,
+                rope_theta=self.rope_theta,
+                rope_scaling=self.rope_scaling,
+                rope_dim=self.rope_dim,
+                num_kv_heads=self.num_kv_heads,
+                fused_qkv=self.fused_qkv,
+                quant=self.quant,
+                window=self.window,
+                rolling_cache=self.rolling_cache,
+                paged_blocks=self.paged_blocks,
+                kv_block=self.kv_block,
+                kv_quant=self.kv_quant,
+                attn_scale=self.attn_scale,
+                attn_logit_cap=self.attn_logit_cap,
+                use_bias=self.use_bias,
+                qkv_bias=self.qkv_bias,
+                qk_norm=self.qk_norm,
+                ln_eps=self.ln_eps,
+                attention=self.attention,
+                eva_window=self.eva_window,
+                eva_chunk=self.eva_chunk,
+                name="attn",
+            )
         if self.num_experts > 0:
             if (self.mlp_act, self.use_bias) not in (
                 ("gelu", True), ("swiglu", False),
@@ -945,6 +1100,9 @@ class TransformerBlock(nn.Module):
                 capacity_factor=self.moe_capacity_factor,
                 normalize_topk=self.moe_normalize_topk,
                 shared_expert_dim=self.moe_shared_expert_dim,
+                shared_expert_gated=self.moe_shared_expert_gated,
+                held_experts=self.moe_held_experts,
+                decode=self.decode,
                 act=self.mlp_act,
                 use_bias=self.use_bias,
                 router_z_loss_weight=self.router_z_loss_weight,
@@ -962,11 +1120,17 @@ class TransformerBlock(nn.Module):
                 quant=self.quant,
                 name="mlp",
             )
+        if self.residual_multiplier is not None and self.norm_style != "pre":
+            raise NotImplementedError(
+                "residual_multiplier is built for the pre-norm block")
         if self.norm_style == "pre":
+            r = self.residual_multiplier
+            scaled = (lambda t: t) if r is None else (
+                lambda t: t * jnp.asarray(r, t.dtype))
             y = ln(name="ln_attn")(x).astype(self.dtype)
-            x = x + attn(y, mask=mask, train=train)
+            x = x + scaled(attn(y, mask=mask, train=train))
             y = ln(name="ln_mlp")(x).astype(self.dtype)
-            return x + mlp(y, train=train)
+            return x + scaled(mlp(y, train=train))
         if self.norm_style == "post":
             x = ln(name="ln_attn")(x + attn(x, mask=mask, train=train))
             x = x.astype(self.dtype)
@@ -1058,15 +1222,22 @@ class Encoder(nn.Module):
     remat: Any = False
     num_experts: int = 0   # > 0: MoE MLP in every `moe_every`-th block
     experts_per_token: int = 2
-    moe_capacity_factor: float = 1.25
+    moe_capacity_factor: Optional[float] = 1.25
     moe_normalize_topk: bool = True
     moe_shared_expert_dim: Optional[int] = None
     router_z_loss_weight: float = 0.0
     moe_every: int = 2     # GShard convention: alternate dense / MoE
+    moe_held_experts: Optional[tuple] = None
+    moe_shared_expert_gated: bool = True
     attention: str = "full"  # 'full' | 'eva' (MultiHeadAttention)
     eva_window: int = 2048
     eva_chunk: int = 16
     norm_unit_offset: bool = False  # norm='rms' only (make_norm)
+    # one mixer kind per block, 'attention' | 'mamba' (TransformerBlock.
+    # mixer), as long as the depth; None builds every block with attention
+    mixers: Optional[tuple] = None
+    ssm: Optional[ssm_lib.SSMShape] = None  # the 'mamba' blocks' widths
+    residual_multiplier: Optional[float] = None  # TransformerBlock
 
     @nn.compact
     def __call__(
@@ -1080,6 +1251,11 @@ class Encoder(nn.Module):
                 f"window_pattern must be 'all' or 'alternate', got "
                 f"{self.window_pattern!r}"
             )
+
+        if self.mixers is not None and len(self.mixers) != self.depth:
+            raise ValueError(
+                f"mixers names {len(self.mixers)} blocks, depth is "
+                f"{self.depth}")
 
         def body(mdl: TransformerBlock, h: jax.Array) -> jax.Array:
             # mask/train close over: constants to jax.checkpoint (no grads
@@ -1137,10 +1313,16 @@ class Encoder(nn.Module):
                 moe_normalize_topk=self.moe_normalize_topk,
                 moe_shared_expert_dim=self.moe_shared_expert_dim,
                 router_z_loss_weight=self.router_z_loss_weight,
+                moe_held_experts=self.moe_held_experts,
+                moe_shared_expert_gated=self.moe_shared_expert_gated,
                 attention=self.attention,
                 eva_window=self.eva_window,
                 eva_chunk=self.eva_chunk,
                 norm_unit_offset=self.norm_unit_offset,
+                mixer=(self.mixers[i] if self.mixers is not None
+                       else "attention"),
+                ssm=self.ssm,
+                residual_multiplier=self.residual_multiplier,
                 name=f"block_{i}",
             )
             x = body(block, x)

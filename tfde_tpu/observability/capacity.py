@@ -157,13 +157,21 @@ class CapacityLedger:
     @classmethod
     def from_cache(cls, cache, batch_size: int, cells_per_row: int,
                    registry: Optional[metrics.Registry] = None,
-                   model=None) -> "CapacityLedger":
+                   model=None, params=None) -> "CapacityLedger":
         """Build a ledger from a freshly-initialized dense slab. The
         served `model`, where given, says which layout the slab has:
-        attention='eva' gets the ledger of windows and summaries."""
+        attention='eva' gets the ledger of windows and summaries, a model
+        with state-space layers (whose rows cost the same whatever their
+        length) or with experts routed without a capacity the hybrid
+        one, which reads the experts' bytes off `params`."""
         if getattr(model, "attention", "full") == "eva":
             return EvaCapacityLedger.of_model(cache, batch_size, model,
                                               registry=registry)
+        if "mamba" in (getattr(model, "mixers", None) or ()) or (
+                getattr(model, "num_experts", 0)
+                and getattr(model, "moe_capacity_factor", 1.0) is None):
+            return HybridCapacityLedger.of_model(
+                cache, batch_size, cells_per_row, params, registry=registry)
         return cls(batch_size, cells_per_row, kv_slab_bytes(cache),
                    registry=registry, census=kv_dtype_census(cache))
 
@@ -216,6 +224,18 @@ class CapacityLedger:
     def note_scan(self, committed, depth: int) -> None:
         """A decode scan of `depth` ticks starts over active rows at
         these `committed` counts."""
+
+    def note_routed(self, routed) -> None:
+        """What the expert layers of one program counted on the device
+        ([pairs, pairs held, experts touched, busiest expert's pairs],
+        summed over layers and ticks): nothing to a layout without
+        experts."""
+
+    def scan_least_bytes(self, param_bytes: int, read_bytes: int,
+                         depth: int, routed=None) -> int:
+        """Bytes `depth` decode ticks cannot avoid reading: every
+        parameter and the rows' cells (`read_bytes`, a tick's), a tick."""
+        return depth * (int(param_bytes) + int(read_bytes))
 
     def _publish_census(self) -> dict:
         """Gauge + stats-dict surface of the dtype split: obs_dump's
@@ -392,6 +412,115 @@ class EvaCapacityLedger(CapacityLedger):
         with self._lock:
             self._counters["eva_window_cells_read"] += depth * local
             self._counters["eva_summary_cells_read"] += depth * remote
+
+
+class HybridCapacityLedger(CapacityLedger):
+    """Occupancy of a cache in which some layers keep a running state per
+    row (models/transformer.py `Mamba2Mixer`: `ssm_state`, `conv_tail`;
+    the same bytes whatever the row's length) beside layers that keep a
+    K/V cell per position, and the account of expert layers that route
+    without a capacity (models/moe.py).
+
+    The unit is one position's K/V over the attention layers; a row's
+    state counts as the `state_cells` such cells its bytes come to, live
+    from admission on. A decode tick reads every committed K/V cell and
+    reads and writes the state: `read_cells(n)` = n + 2 x state cells.
+    Of the parameters a tick cannot avoid those outside the experts and,
+    of the held experts, the ones that received a pair that tick
+    (`scan_least_bytes`, from the device's own count).
+
+    `counters` (HYBRID_KEYS): per scan, depth x (twice the active rows'
+    state bytes; their committed K/V cells), and what the expert layers
+    counted of real tokens in prefill waves and scans alike, summed over
+    layers and ticks: pairs routed, pairs whose expert is held, held
+    experts with at least one pair, the busiest held expert's pairs."""
+
+    HYBRID_KEYS = ("ssm_state_bytes_touched", "kv_cells_read", "moe_pairs",
+                   "moe_pairs_held", "moe_experts_touched",
+                   "moe_pairs_busiest")
+    _STATE_LEAVES = ("ssm_state", "conv_tail")
+
+    def __init__(self, batch_size: int, positions: int, slab_bytes: int,
+                 state_row_bytes: int, expert_bytes: int,
+                 expert_slots: int,
+                 registry: Optional[metrics.Registry] = None,
+                 census: Optional[dict] = None):
+        state_total = int(state_row_bytes) * int(batch_size)
+        per_position = (int(slab_bytes) - state_total) / float(
+            batch_size * positions)
+        # no attention layer: the one cell of a row is its state
+        self._state_cells = (int(round(state_row_bytes / per_position))
+                             if per_position else 1)
+        self._positions = int(positions) if per_position else 0
+        super().__init__(batch_size, self._state_cells + self._positions,
+                         slab_bytes, registry=registry, census=census)
+        self._state_row_bytes = int(state_row_bytes)
+        self._expert_bytes = int(expert_bytes)
+        #: bytes of one expert of one layer
+        self._slot_bytes = (int(expert_bytes) / expert_slots
+                            if expert_slots else 0.0)
+        self._counters = dict.fromkeys(self.HYBRID_KEYS, 0)
+
+    @classmethod
+    def of_model(cls, cache, batch_size: int, positions: int, params,
+                 registry: Optional[metrics.Registry] = None
+                 ) -> "HybridCapacityLedger":
+        """From a freshly-initialized batch cache and the served
+        parameters: the state's bytes are those of the leaves named
+        `ssm_state` / `conv_tail`, the experts' those of the leaves
+        `experts_*` (their first axis counts the experts held)."""
+        import jax
+
+        name = lambda path: str(getattr(path[-1], "key", path[-1]))
+        state = sum(int(leaf.nbytes) for path, leaf in
+                    jax.tree_util.tree_leaves_with_path(cache)
+                    if name(path) in cls._STATE_LEAVES)
+        experts = [(leaf.shape[0], int(leaf.size) * leaf.dtype.itemsize)
+                   for path, leaf in
+                   jax.tree_util.tree_leaves_with_path(params or {})
+                   if name(path).startswith("experts_")]
+        layers = sum(1 for path, _ in
+                     jax.tree_util.tree_leaves_with_path(params or {})
+                     if name(path) == "experts_fc1")
+        held = experts[0][0] if experts else 0
+        return cls(batch_size, positions, kv_slab_bytes(cache),
+                   state // batch_size, sum(b for _, b in experts),
+                   layers * held, registry=registry,
+                   census=kv_dtype_census(cache))
+
+    def row_cells(self, n: int) -> int:
+        return self._state_cells + (int(n) if self._positions else 0)
+
+    def read_cells(self, n: int) -> int:
+        return 2 * self._state_cells + (int(n) if self._positions else 0)
+
+    @property
+    def counters(self) -> dict:
+        with self._lock:
+            return dict(self._counters)
+
+    def note_scan(self, committed, depth: int) -> None:
+        rows = len(committed)
+        cells = int(sum(int(n) for n in committed)) if self._positions else 0
+        with self._lock:
+            self._counters["ssm_state_bytes_touched"] += (
+                depth * 2 * rows * self._state_row_bytes)
+            self._counters["kv_cells_read"] += depth * cells
+
+    def note_routed(self, routed) -> None:
+        if routed is None:
+            return
+        with self._lock:
+            for key, n in zip(self.HYBRID_KEYS[2:], routed):
+                self._counters[key] += int(n)
+
+    def scan_least_bytes(self, param_bytes: int, read_bytes: int,
+                         depth: int, routed=None) -> int:
+        if routed is None:
+            return super().scan_least_bytes(param_bytes, read_bytes, depth)
+        return int(depth * (int(param_bytes) - self._expert_bytes
+                            + int(read_bytes))
+                   + int(routed[2]) * self._slot_bytes)
 
 
 class PagedCapacityLedger(CapacityLedger):
