@@ -1,7 +1,8 @@
-"""The flash kernels of the training cells, compiled for a described v5e at
-the cells' widths: what Mosaic refuses (tiling, VMEM, a lowering it lacks)
-shows here, with no chip. Nothing runs, so this says nothing about results
-or times. All in this one file: the worker that gets it loads libtpu."""
+"""The flash kernels of the training cells and the hybrid's grouped matmul
+(`ops/moe_gmm.py`), compiled for a described v5e at the cells' widths: what
+Mosaic refuses (tiling, VMEM, a lowering it lacks) shows here, with no chip.
+Nothing runs, so this says nothing about results or times. All in this one
+file: the worker that gets it loads libtpu."""
 
 import os
 
@@ -10,6 +11,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from tfde_tpu.ops import moe_gmm
 from tfde_tpu.ops.flash_attention import flash_attention
 
 
@@ -82,3 +84,25 @@ def test_flash_forward_compiles_for_v5e(one_chip, name, shape, kv_heads,
     assert "while(" not in text
     grid = name.endswith("_grid")
     assert (f"[{b},{h},{s},{d}]" in text) == grid
+
+
+@pytest.mark.parametrize("name,tokens", [("a_prefill_block", 2048),
+                                         ("a_decode_tick", 32)])
+def test_moe_gmm_compiles_for_v5e(one_chip, name, tokens):
+    """The hybrid cell's expert layer: 36 held experts of 4096 x 768, ten
+    choices a token over 72. An expert's three matrices, twice buffered,
+    are 38 MB of VMEM beside the row tiles: the kernel asks for its own
+    limit, and the v5e compiler has to grant it."""
+    held, experts, k, d, f = 36, 72, 10, 4096, 768
+    pairs = tokens * k
+    tile = moe_gmm.tile_rows(pairs, experts)
+    tiles = moe_gmm.tiles_bound(pairs, held, tile)
+    on = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                   sharding=one_chip)
+    text = moe_gmm.expert_mlps.lower(
+        on((tiles * tile, d), jnp.bfloat16), on((held, d, f), jnp.bfloat16),
+        on((held, d, f), jnp.bfloat16), on((held, f, d), jnp.bfloat16),
+        on((tiles,), jnp.int32), on((1,), jnp.int32),
+        tile=tile).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "moe_gmm" in text

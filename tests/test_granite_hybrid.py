@@ -389,6 +389,36 @@ def test_no_token_is_dropped_when_all_choose_one_expert(weights, params):
     assert dropped.shape == got.shape       # it runs, and loses tokens
 
 
+@pytest.mark.parametrize("shape,held", [
+    ((1, 1), (0, 4)), ((32, 1), (0, 4)), ((32, 1), (2, 6)),
+    ((2, 24), (4, 8)), ((3, 50), (1, 5))])
+def test_the_expert_layer_alone_matches_the_reference(weights, params, shape,
+                                                      held, monkeypatch):
+    """One token, a tick of 32 rows, held ranges that start above 0, and
+    150 tokens in blocks of 64 (the last one padded): the layer with its
+    shared expert against the reference's, which walks the held experts
+    one by one over all tokens."""
+    from tfde_tpu.models import moe as moe_lib
+
+    monkeypatch.setattr(moe_lib, "_TOKEN_BLOCK", 64)
+    lw = weights["layers"][0]
+    v = jax.random.normal(jax.random.key(5), shape + (64,))
+    want = _layer_reference(lw, v.reshape(-1, 64),
+                            dict(DIMS, held_experts=held)).reshape(v.shape)
+    got, sown = _moe_layer(held).apply(
+        {"params": params["decoder"]["block_0"]["moe"]}, v,
+        mutable=["counters"])
+    assert np.abs(np.asarray(got) - want).max() < TOL
+    pairs, held_pairs, touched, busiest, moved = np.asarray(
+        jax.tree.leaves(sown)[0])
+    tokens = shape[0] * shape[1]
+    assert pairs == PER_TOKEN * tokens and 0 < held_pairs < pairs
+    assert 0 < touched <= 4 and held_pairs / 4 <= busiest <= tokens
+    # every pair is fetched back, and a slot for every pair and more is
+    # filled on the way in
+    assert moved >= 2 * pairs
+
+
 def test_a_rows_logits_are_the_same_alone_and_in_a_wave_of_four(params):
     rows = np.stack(rows_of(9, [24] * 4))
     model = hybrid_model()
@@ -501,6 +531,10 @@ def test_batcher_counts_state_cells_and_routing(served):
     assert 0 < stats["moe_experts_touched"] <= 4 * 4 * (
         stats["rounds"] + stats["prefill_waves"])
     assert stats["ssm_state_bytes_touched"] > 0 and stats["kv_cells_read"] > 0
+    # rows copied into and out of sorted order: every pair comes back and
+    # at least as many slots are filled, padding tokens' among them
+    assert stats["moe_rows_moved"] >= 2 * stats["moe_pairs"]
+    assert stats["moe_rows_moved"] < 40 * stats["moe_pairs"]
     # the counts ride the fetch each wave and each scan already makes
     assert fetches == stats["syncs"] == stats["prefill_waves"] + stats["scans"]
 
@@ -581,18 +615,19 @@ def test_least_bytes_count_the_touched_experts_only():
     params = 100_000 + 16 * expert
     assert ledger.read_cells(10) == 2 * 16 + 10
     # two ticks in which 5 and 7 (layer, expert) slots received a pair
-    got = ledger.scan_least_bytes(params, 9_000, 2, [60, 30, 12, 9])
+    got = ledger.scan_least_bytes(params, 9_000, 2, [60, 30, 12, 9, 400])
     assert got == 2 * (100_000 + 9_000) + 12 * expert
     ledger.note_scan([10, 20], 2)
-    ledger.note_routed([60, 30, 12, 9])
+    ledger.note_routed([60, 30, 12, 9, 400])
     ledger.note_routed(None)
     assert ledger.counters == {
         "ssm_state_bytes_touched": 2 * 2 * 2 * 4096,
         "kv_cells_read": 2 * 30, "moe_pairs": 60, "moe_pairs_held": 30,
-        "moe_experts_touched": 12, "moe_pairs_busiest": 9}
+        "moe_experts_touched": 12, "moe_pairs_busiest": 9,
+        "moe_rows_moved": 400}
     dense = CapacityLedger(2, 64, 2 * 64 * 256)
     assert dense.scan_least_bytes(1000, 50, 3, None) == 3 * 1050
-    dense.note_routed([1, 1, 1, 1])
+    dense.note_routed([1, 1, 1, 1, 1])
     assert dense.counters == {}
 
 

@@ -9,8 +9,10 @@ import optax
 import pytest
 from jax.sharding import PartitionSpec as P
 
+from tfde_tpu.models import moe as moe_lib
 from tfde_tpu.models.moe import MoEMlp, dispatch_shape, group_capacity
 from tfde_tpu.models.transformer import Encoder
+from tfde_tpu.ops import moe_gmm
 from tfde_tpu.parallel.strategies import (
     ExpertParallelStrategy,
     MirroredStrategy,
@@ -245,3 +247,166 @@ def test_moe_gpt_custom_path_trains_with_sown_losses():
     step2 = make_custom_train_step(s, state2, next_token_loss)
     _, metr2 = step2(state2, (toks,), jax.random.key(0))
     assert "moe_aux" not in metr2
+
+
+# ---------------------------------------------------------------------------
+# Without a capacity (capacity_factor=None): the layout by expert, the
+# Mosaic grouped matmul (interpreted here) and the way back, against a plain
+# per-token loop. 24 experts, TEN a token as the served model; float32 at
+# the highest matmul precision, so layer and loop differ by the order of
+# float32 sums alone: measured 2e-7 .. 6e-7 on outputs of magnitude 0.3 .. 2.
+# The tolerance is 1e-5; a broken form must miss it by 100 x (1e-3).
+# ---------------------------------------------------------------------------
+
+UNCAPPED_TOL = 1e-5
+_E, _K, _D, _F = 24, 10, 16, 8
+
+#: name -> (x's [rows, seq], held range, token block, what to do to the
+#: router, feed_pad per row or None)
+UNCAPPED_CASES = {
+    "every_token_chooses_one_expert": ((2, 24), (0, 12), 2048, "one", None),
+    "no_pair_held": ((2, 24), (0, 4), 2048, "none", None),
+    "held_range_starts_above_zero": ((2, 24), (7, 19), 2048, None, None),
+    "every_pair_held": ((2, 24), None, 2048, None, None),
+    "n_not_a_multiple_of_the_block": ((3, 50), (0, 12), 32, None, None),
+    "rows_with_feed_pad": ((3, 20), (0, 12), 2048, None, (0, 5, 19)),
+    "one_token": ((1, 1), (0, 12), 2048, None, None),
+    "a_tick_of_32_rows": ((32, 1), (0, 12), 2048, None, None),
+}
+
+
+def _uncapped_layer(held, **kw):
+    return MoEMlp(num_experts=_E, mlp_dim=_F, experts_per_token=_K,
+                  capacity_factor=None, act="swiglu", use_bias=False,
+                  held_experts=held, dtype=jnp.float32, **kw)
+
+
+def _uncapped_case(name):
+    (rows, seq), held, block, router, pad = UNCAPPED_CASES[name]
+    x = jnp.abs(jax.random.normal(jax.random.key(3), (rows, seq, _D))) + 0.1
+    layer = _uncapped_layer(held, decode=pad is not None)
+    params = jax.tree.map(np.asarray, layer.init(
+        jax.random.key(4), x)["params"])
+    kernel = np.array(params["router"]["kernel"])
+    if router == "one":         # x is positive: every token's first choice
+        kernel[:, 2] = 0.5
+    if router == "none":        # ... and nobody's choice is a held expert
+        kernel[:, :held[1]] = -0.5
+    params["router"]["kernel"] = kernel
+    return layer, params, x, held or (0, _E), block, pad
+
+
+def _loop_reference(params, x, lo, hi, real):
+    """Token by token, choice by choice, in float32: (y [rows, seq, d],
+    the four routing counts over the tokens marked `real`)."""
+    x2 = np.asarray(x, np.float32).reshape(-1, x.shape[-1])
+    silu = lambda a: a / (np.float32(1) + np.exp(-a))
+    y = np.zeros_like(x2)
+    per_expert = np.zeros(hi - lo, np.int64)
+    for t, v in enumerate(x2):
+        logits = v @ params["router"]["kernel"]
+        probs = np.exp(logits - logits.max())
+        probs /= probs.sum()
+        chosen = np.argsort(-probs, kind="stable")[:_K]
+        gates = probs[chosen] / probs[chosen].sum()
+        for e, g in zip(chosen, gates):
+            if lo <= e < hi:
+                h = silu(v @ params["experts_gate"][e - lo]) * (
+                    v @ params["experts_fc1"][e - lo])
+                y[t] += g * (h @ params["experts_fc2"][e - lo])
+                per_expert[e - lo] += bool(real[t])
+    counts = [int(real.sum()) * _K, int(per_expert.sum()),
+              int((per_expert > 0).sum()), int(per_expert.max())]
+    return y.reshape(x.shape), counts
+
+
+def _apply_uncapped(layer, params, x, block, pad, monkeypatch):
+    monkeypatch.setattr(moe_lib, "_TOKEN_BLOCK", block)
+    variables = {"params": params}
+    if pad is not None:
+        variables["cache"] = {"feed_pad": jnp.asarray(pad, jnp.int32)}
+    with jax.default_matmul_precision("highest"):
+        y, mutated = layer.apply(variables, x, mutable=["counters", "cache"])
+    return np.asarray(y), np.asarray(jax.tree.leaves(mutated["counters"])[0])
+
+
+def _real_tokens(x, pad):
+    rows, seq = x.shape[:2]
+    pad = np.zeros(rows, int) if pad is None else np.asarray(pad)
+    return (np.arange(seq)[None, :] < seq - pad[:, None]).reshape(-1)
+
+
+@pytest.mark.parametrize("name", list(UNCAPPED_CASES))
+def test_uncapped_layer_matches_the_per_token_loop(name, monkeypatch):
+    layer, params, x, (lo, hi), block, pad = _uncapped_case(name)
+    want, counts = _loop_reference(params, x, lo, hi, _real_tokens(x, pad))
+    got, counted = _apply_uncapped(layer, params, x, block, pad, monkeypatch)
+    assert np.abs(got - want).max() < UNCAPPED_TOL
+    if name == "no_pair_held":
+        assert counts[1] == 0 and not got.any()
+    if name == "every_token_chooses_one_expert":
+        assert counts[3] == x.shape[0] * x.shape[1]
+    # the four routing counts are the loop's, whatever the dispatch
+    assert counted[:4].tolist() == counts
+
+
+@pytest.mark.parametrize("name", list(UNCAPPED_CASES))
+def test_rows_moved_reads_what_the_form_moves(name, monkeypatch):
+    """Every slot of every block's layout is gathered on the way in (pads
+    and unused tiles too), every pair fetched on the way back."""
+    layer, params, x, (lo, hi), block, pad = _uncapped_case(name)
+    _, counted = _apply_uncapped(layer, params, x, block, pad, monkeypatch)
+    n = x.shape[0] * x.shape[1]
+    block = min(n, block)
+    pairs = block * _K
+    tile = moe_gmm.tile_rows(pairs, _E)
+    slots = moe_gmm.tiles_bound(pairs, hi - lo, tile) * tile
+    assert counted[4] == -(-n // block) * (slots + pairs)
+    assert slots >= pairs and tile % 16 == 0
+
+
+def _one_tile_left_out(monkeypatch):
+    real = moe_lib._layout
+
+    def broken(*args):
+        slot, tile_expert, live, counted = real(*args)
+        return slot, tile_expert, live - 1, counted
+
+    monkeypatch.setattr(moe_lib, "_layout", broken)
+
+
+def _choices_unweighted(monkeypatch):
+    real = moe_lib._rows_back
+    monkeypatch.setattr(
+        moe_lib, "_rows_back",
+        lambda out, slot, here, vals: real(out, slot, here,
+                                           jnp.ones_like(vals)))
+
+
+@pytest.mark.parametrize("break_it", [_one_tile_left_out,
+                                      _choices_unweighted])
+@pytest.mark.parametrize("name", [n for n in UNCAPPED_CASES
+                                  if n != "no_pair_held"])
+def test_a_broken_dispatch_fails_the_tolerance_a_hundredfold(
+        name, break_it, monkeypatch):
+    """The last tile of held rows never multiplied; the k results summed
+    without their gate weights. (With no pair held there is nothing to
+    break: that case is left out.)"""
+    layer, params, x, (lo, hi), block, pad = _uncapped_case(name)
+    want, _ = _loop_reference(params, x, lo, hi, _real_tokens(x, pad))
+    break_it(monkeypatch)
+    # `_held_pairs` is jitted: a trace from before the break (or with it)
+    # must not serve this test (or the next)
+    moe_lib._held_pairs.clear_cache()
+    try:
+        got, _ = _apply_uncapped(layer, params, x, block, pad, monkeypatch)
+    finally:
+        moe_lib._held_pairs.clear_cache()
+    assert not np.abs(got - want).max() < 100 * UNCAPPED_TOL
+
+
+@pytest.mark.parametrize("pairs,experts,tile", [
+    (20480, 72, 128), (320, 72, 16), (10, 24, 16), (40960, 72, 256),
+    (2048 * 2, 8, 256)])
+def test_the_tile_follows_the_block(pairs, experts, tile):
+    assert moe_gmm.tile_rows(pairs, experts) == tile

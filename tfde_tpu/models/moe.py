@@ -28,24 +28,51 @@ the 'losses' collection; training/step.py adds every sown loss to the
 objective automatically when the model mutates that collection.
 
 **Without a capacity** (`capacity_factor=None`, the serving form): no token
-is dropped and no [.., capacity] tensor exists. The (token, choice) pairs
-are sorted by expert and each expert multiplies its own run of rows
-(`jax.lax.ragged_dot`), so the work follows the pairs routed and a token's
-result does not depend on which other tokens share its call. Such a layer
-may hold a contiguous range of the experts (`held_experts`, a chip's share
-under expert parallelism): the router keeps all `num_experts` outputs and
-its top k, and pairs whose expert lives elsewhere add nothing; there is no
-stand-in for the absent chips or their exchange.
+is dropped and no [.., capacity] tensor exists; the work follows the pairs
+routed and a token's result does not depend on which other tokens share
+its call. A block of `_TOKEN_BLOCK` tokens at a time (`_uncapped` cuts the
+blocks, `_held_pairs`, jitted on its own so that all layers and programs
+of one block shape share a trace, walks them):
+
+- nothing is sorted. Each of the block's (token, choice) pairs whose expert
+  is held gets a row of a layout in which an expert's pairs stand together
+  and every expert starts on a multiple of a tile (`_layout`: the expert's
+  first row plus the pairs of that expert before this one, a running sum
+  down one-hot columns; counts are compares and sums; no sort, no
+  scatter-add). Pairs are numbered choice-major (pair j x block + t), so
+  that nothing is ever laid out with k in the sublanes;
+- what is moved: x's rows are gathered into that layout, EVERY slot of it
+  (one scatter of a block's slot numbers, then one gather; the pads that
+  complete an expert's last tile and the tiles no expert uses come along:
+  a loop over the rows in use alone measured slower on the chip than
+  gathering them all), `ops/moe_gmm.py` multiplies the tiles in use by
+  their expert's three matrices in one Mosaic kernel, and every pair's
+  result row is fetched back (`_rows_back`), weighted by its gate in
+  float32 and summed over the token's k choices;
+- the loops and their bounds: `lax.map` over the call's blocks; the
+  kernel's grid over `pairs // tile + held` tiles, of which it runs the
+  `live` first (held pairs / tile, plus up to one an expert); `_rows_back`'s
+  loop over the k choices, `_ROW_CHUNK // block` of them a step (one step
+  for a decode tick). The tile follows the block (`moe_gmm.tile_rows`):
+  128 rows for 2,048 tokens of ten choices over 72 experts, 16 for a tick.
+
+Such a layer may hold a contiguous range of the experts (`held_experts`, a
+chip's share under expert parallelism): the router keeps all `num_experts`
+outputs and its top k, and pairs whose expert lives elsewhere get no row
+and add nothing; there is no stand-in for the absent chips or their
+exchange. Five counts are sown into "counters" (`_uncapped`).
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from tfde_tpu.ops import moe_gmm
 from tfde_tpu.parallel.axes import batch_axes, constrain
 
 
@@ -74,9 +101,102 @@ def dispatch_shape(batch: int, seq: int, num_experts: int,
     return (g, m, num_experts, c)
 
 
-#: tokens whose pairs are sorted and multiplied at a time without a
-#: capacity: the sorted copy is this x experts_per_token rows
+#: tokens whose pairs are laid out and multiplied at a time without a
+#: capacity: the sorted copy is about this x experts_per_token rows
 _TOKEN_BLOCK = 2048
+#: rows of d that one step of `_rows_back` gathers
+_ROW_CHUNK = 2048
+
+
+def _layout(key, held: int, tile: int, real):
+    """Rows for a block's pairs, by expert, each expert's run starting at
+    a multiple of `tile`: key [pairs] names a held expert (0 .. held - 1)
+    or `held` for one routed elsewhere, which gets no row. No sort and no
+    scatter: a pair's row is its expert's first row plus the pairs of
+    that expert before it (a running sum down the one-hot columns).
+
+    Returns (slot [pairs]: the row, meaningless where key == held;
+    tile_expert [tiles]: whose rows tile t holds; live: the tiles in use,
+    the first `live` of them; counted [held]: each expert's pairs among
+    those marked `real` [pairs])."""
+    hit = key[:, None] == jnp.arange(held, dtype=key.dtype)
+    ones = hit.astype(jnp.int32)
+    before = ((jnp.cumsum(ones, axis=0) - ones) * ones).sum(1)
+    runs = (ones.sum(0) + tile - 1) // tile
+    ends = jnp.cumsum(runs)
+    slot = (ones * (ends - runs)[None, :]).sum(1) * tile + before
+    tiles = moe_gmm.tiles_bound(key.shape[0], held, tile)
+    tile_expert = jnp.minimum(
+        (ends[None, :] <= jnp.arange(tiles)[:, None]).sum(1), held - 1)
+    counted = (hit & real[:, None]).sum(0, dtype=jnp.int32)
+    return slot, tile_expert.astype(jnp.int32), ends[-1], counted
+
+
+def _rows_back(out, slot, here, vals):
+    """Token t's result: the sum over its k choices of vals[j, t] x
+    out[slot[j, t]] in float32, a choice routed elsewhere adding nothing.
+    slot / here / vals [k, block], choice-major so that no [block, k, d]
+    array is ever laid out with k in the sublanes; a few choices a step,
+    so that a step gathers about `_ROW_CHUNK` rows and the float32 copy
+    of all block x k rows is never made."""
+    k, block = slot.shape
+    per = max(p for p in range(1, k + 1)
+              if k % p == 0 and (p == 1 or p * block <= _ROW_CHUNK))
+
+    def add(i, acc):
+        cut = lambda t: jax.lax.dynamic_slice_in_dim(t, i * per, per, 0)
+        got = out[cut(slot)].astype(jnp.float32) * cut(vals)[..., None]
+        return acc + jnp.where(cut(here)[..., None], got, 0.0).sum(0)
+
+    return jax.lax.fori_loop(
+        0, k // per, add, jnp.zeros((block, out.shape[1]), jnp.float32))
+
+
+@functools.partial(jax.jit, static_argnames=("lo", "tile", "dtype"))
+def _held_pairs(x, idx, vals, ok, wg, w1, w2, *, lo: int, tile: int, dtype):
+    """The held experts' part of the result for [blocks, block] tokens, a
+    block at a time under `lax.map`: x [blocks, block, d], idx / vals
+    [blocks, block, k] (vals float32; idx -1 on a token that only fills
+    the last block), ok [blocks, block] (a real token, for the counts),
+    wg / w1 [held, d, f], w2 [held, f, d], the layer holding experts lo ..
+    lo + held - 1. Returns (y [blocks, block, d] in x's dtype, each held
+    expert's pairs of real tokens [blocks, held], the real tokens' pairs
+    [blocks]).
+
+    Jitted on its own so that the layers of a program, and the programs
+    whose blocks have one shape, share ONE trace and one lowering of the
+    layout, the kernel and the way back: traced inline, ten layers in each
+    of the served cell's 32 programs added 10 s to every start."""
+    wg, w1, w2 = (w.astype(dtype) for w in (wg, w1, w2))
+    held = wg.shape[0]
+    _, block, k = idx.shape
+    pairs = block * k
+    slots = moe_gmm.tiles_bound(pairs, held, tile) * tile
+
+    def one(args):
+        xb, idx, vals, ok = args    # [block, d], [block, k] x2, [block]
+        with jax.named_scope("moe_route"):
+            # choice-major: pair j * block + t is token t's choice j
+            local = idx.T.reshape(-1) - lo
+            here = (local >= 0) & (local < held)
+            slot, tile_expert, live, counted = _layout(
+                jnp.where(here, local, held), held, tile, jnp.tile(ok, k))
+        with jax.named_scope("moe_rows_in"):
+            token = jnp.arange(pairs, dtype=jnp.int32) % block
+            source = jnp.zeros((slots,), jnp.int32).at[
+                jnp.where(here, slot, slots)].set(
+                    token, mode="drop", unique_indices=True)
+            rows = xb.astype(dtype)[source]
+        with jax.named_scope("moe_experts"):
+            out = moe_gmm.expert_mlps(
+                rows, wg, w1, w2, tile_expert, live[None], tile=tile,
+                interpret=jax.default_backend() == "cpu")
+        with jax.named_scope("moe_rows_back"):
+            y = _rows_back(out, jnp.where(here, slot, 0).reshape(k, block),
+                           here.reshape(k, block), vals.T)
+        return y.astype(x.dtype), counted, ok.sum() * k
+
+    return jax.lax.map(one, (x, idx, vals, ok))
 
 
 class MoEMlp(nn.Module):
@@ -257,10 +377,13 @@ class MoEMlp(nn.Module):
     def _uncapped(self, x, gate_vals, gate_idx, lo: int, hi: int):
         """The routed experts' part of the result with no capacity: x
         [B, S, d], gate_vals / gate_idx [.., k] of all B S tokens. A block
-        of tokens at a time (the sorted copy of a block's pairs is
-        block x k rows of d), each block's pairs sorted by expert with
-        those routed elsewhere last, three grouped matmuls over the held
-        experts' runs, and a gather back into token order."""
+        of tokens at a time (module docstring, "Without a capacity"),
+        in `_held_pairs`: `_layout` gives every held pair of the block its
+        row, by expert and each expert from a tile's first row; x's rows
+        are gathered into that order, every slot of it; `ops/moe_gmm.py`
+        multiplies the tiles in use; `_rows_back` fetches each token's k
+        results, weights and sums them. Here: the blocks are cut, the
+        tile chosen from the block's shape, and the five counts sown."""
         bsz, seq, d = x.shape
         k, held = self.experts_per_token, hi - lo
         if self.act != "swiglu" or self.use_bias:
@@ -280,6 +403,9 @@ class MoEMlp(nn.Module):
                 feed_pad.value = jnp.zeros_like(feed_pad.value)
         block = min(n, _TOKEN_BLOCK)
         grown = -(-n // block) * block
+        pairs = block * k
+        tile = moe_gmm.tile_rows(pairs, self.num_experts)
+        slots = moe_gmm.tiles_bound(pairs, held, tile) * tile
 
         def blocks(t, fill):
             t = t.reshape((n,) + t.shape[2:])
@@ -287,46 +413,22 @@ class MoEMlp(nn.Module):
                         constant_values=fill)
             return t.reshape((grown // block, block) + t.shape[1:])
 
-        def one(args):
-            xb, idx, vals, ok = args    # [block, d], [block, k] x2, [block]
-            with jax.named_scope("moe_route"):
-                local = idx - lo
-                here = (local >= 0) & (local < held)
-                key = jnp.where(here, local, held).reshape(-1)
-                order = jnp.argsort(key, stable=True)
-                sizes = jnp.zeros((held + 1,), jnp.int32).at[key].add(1)
-                counted = jnp.zeros((held + 1,), jnp.int32).at[key].add(
-                    jnp.repeat(ok, k).astype(jnp.int32))[:held]
-                back = jnp.zeros_like(order).at[order].set(
-                    jnp.arange(order.size, dtype=order.dtype))
-            with jax.named_scope("moe_experts"):
-                rows = xb.astype(self.dtype)[order // k]
-                grouped = lambda lhs, w, out: jax.lax.ragged_dot(
-                    lhs, w.astype(self.dtype), sizes[:held],
-                    preferred_element_type=out)
-                h = nn.silu(grouped(rows, wg, jnp.float32)) * grouped(
-                    rows, w1, jnp.float32)
-                out = grouped(h.astype(self.dtype), w2, self.dtype)
-                # rows past the held experts' runs are never written
-                pairs = jnp.where(
-                    here[..., None],
-                    out[back].reshape(block, k, d).astype(jnp.float32)
-                    * vals[..., None], 0.0)
-                return pairs.sum(1).astype(x.dtype), counted, ok.sum() * k
-
-        y, counted, routed = jax.lax.map(
-            one, (blocks(x, 0), blocks(gate_idx, -1),
-                  blocks(gate_vals.astype(jnp.float32), 0),
-                  blocks(valid, False)))
+        y, counted, routed = _held_pairs(
+            blocks(x, 0), blocks(gate_idx, -1),
+            blocks(gate_vals.astype(jnp.float32), 0), blocks(valid, False),
+            wg, w1, w2, lo=lo, tile=tile, dtype=self.dtype)
         counted = counted.sum(0)
-        # pairs routed, pairs whose expert is held, held experts with a
-        # pair, the busiest held expert's pairs: of this call's real tokens
+        # of this call's real tokens: pairs routed, pairs whose expert is
+        # held, held experts with a pair, the busiest held expert's pairs;
+        # and the rows of d the call copied into sorted order (every slot
+        # of every block's layout) and fetched back from it (every pair)
         self.sow("counters", "moe_routing",
                  jnp.stack([routed.sum().astype(jnp.int32), counted.sum(),
                             (counted > 0).sum().astype(jnp.int32),
-                            counted.max()]),
+                            counted.max(),
+                            jnp.int32(grown // block * (slots + pairs))]),
                  reduce_fn=lambda a, b: a + b,
-                 init_fn=lambda: jnp.zeros((4,), jnp.int32))
+                 init_fn=lambda: jnp.zeros((5,), jnp.int32))
         return y.reshape(grown, d)[:n].reshape(bsz, seq, d)
 
     def _finish(self, x, y, train: bool):

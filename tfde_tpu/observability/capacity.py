@@ -433,11 +433,13 @@ class HybridCapacityLedger(CapacityLedger):
     state bytes; their committed K/V cells), and what the expert layers
     counted of real tokens in prefill waves and scans alike, summed over
     layers and ticks: pairs routed, pairs whose expert is held, held
-    experts with at least one pair, the busiest held expert's pairs."""
+    experts with at least one pair, the busiest held expert's pairs; and,
+    of every token they were handed, the rows of the hidden width they
+    copied into sorted order and fetched back from it."""
 
     HYBRID_KEYS = ("ssm_state_bytes_touched", "kv_cells_read", "moe_pairs",
                    "moe_pairs_held", "moe_experts_touched",
-                   "moe_pairs_busiest")
+                   "moe_pairs_busiest", "moe_rows_moved")
     _STATE_LEAVES = ("ssm_state", "conv_tail")
 
     def __init__(self, batch_size: int, positions: int, slab_bytes: int,
