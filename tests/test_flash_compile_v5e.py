@@ -86,14 +86,22 @@ def test_flash_forward_compiles_for_v5e(one_chip, name, shape, kv_heads,
     assert (f"[{b},{h},{s},{d}]" in text) == grid
 
 
+#: (experts held, experts routed over, choices a token, hidden, expert
+#: width, the gate's activation) of the two cells that run the kernel
+_EXPERT_LAYERS = {"hybrid": (36, 72, 10, 4096, 768, "silu"),
+                  "window_and_global": (64, 64, 6, 2560, 768, "relu")}
+
+
+@pytest.mark.parametrize("layer", sorted(_EXPERT_LAYERS))
 @pytest.mark.parametrize("name,tokens", [("a_prefill_block", 2048),
                                          ("a_decode_tick", 32)])
-def test_moe_gmm_compiles_for_v5e(one_chip, name, tokens):
+def test_moe_gmm_compiles_for_v5e(one_chip, name, tokens, layer):
     """The hybrid cell's expert layer: 36 held experts of 4096 x 768, ten
-    choices a token over 72. An expert's three matrices, twice buffered,
-    are 38 MB of VMEM beside the row tiles: the kernel asks for its own
-    limit, and the v5e compiler has to grant it."""
-    held, experts, k, d, f = 36, 72, 10, 4096, 768
+    choices a token over 72, SwiGLU; and the window-and-global cell's: all
+    64 experts of 2560 x 768, six a token, ReGLU. An expert's three
+    matrices, twice buffered, are 38 MB of VMEM beside the row tiles: the
+    kernel asks for its own limit, and the v5e compiler has to grant it."""
+    held, experts, k, d, f, act = _EXPERT_LAYERS[layer]
     pairs = tokens * k
     tile = moe_gmm.tile_rows(pairs, experts)
     tiles = moe_gmm.tiles_bound(pairs, held, tile)
@@ -103,6 +111,6 @@ def test_moe_gmm_compiles_for_v5e(one_chip, name, tokens):
         on((tiles * tile, d), jnp.bfloat16), on((held, d, f), jnp.bfloat16),
         on((held, d, f), jnp.bfloat16), on((held, f, d), jnp.bfloat16),
         on((tiles,), jnp.int32), on((1,), jnp.int32),
-        tile=tile).compile().as_text()
+        tile=tile, act=act).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert "moe_gmm" in text

@@ -272,19 +272,39 @@ UNCAPPED_CASES = {
     "rows_with_feed_pad": ((3, 20), (0, 12), 2048, None, (0, 5, 19)),
     "one_token": ((1, 1), (0, 12), 2048, None, None),
     "a_tick_of_32_rows": ((32, 1), (0, 12), 2048, None, None),
+    # relu on the gate, and a router that reads an input of its own (the
+    # attention sublayer's, in the block that has one): the two shares
+    "reglu_own_router_input_lower_share": ((2, 24), (0, 12), 2048, None,
+                                           None),
+    "reglu_own_router_input_upper_share": ((2, 24), (12, 24), 2048, None,
+                                           None),
 }
 
 
+def _reglu(name) -> bool:
+    return name.startswith("reglu_own_router_input")
+
+
+def _router_input(name, x):
+    """What the router reads in case `name`: another array than x in the
+    cases named so, x itself (None) in the others."""
+    if not _reglu(name):
+        return None
+    return jax.random.normal(jax.random.key(9), x.shape)
+
+
 def _uncapped_layer(held, **kw):
-    return MoEMlp(num_experts=_E, mlp_dim=_F, experts_per_token=_K,
-                  capacity_factor=None, act="swiglu", use_bias=False,
-                  held_experts=held, dtype=jnp.float32, **kw)
+    return MoEMlp(**{**dict(
+        num_experts=_E, mlp_dim=_F, experts_per_token=_K,
+        capacity_factor=None, act="swiglu", use_bias=False,
+        held_experts=held, dtype=jnp.float32), **kw})
 
 
 def _uncapped_case(name):
     (rows, seq), held, block, router, pad = UNCAPPED_CASES[name]
     x = jnp.abs(jax.random.normal(jax.random.key(3), (rows, seq, _D))) + 0.1
-    layer = _uncapped_layer(held, decode=pad is not None)
+    layer = _uncapped_layer(held, decode=pad is not None,
+                            act="reglu" if _reglu(name) else "swiglu")
     params = jax.tree.map(np.asarray, layer.init(
         jax.random.key(4), x)["params"])
     kernel = np.array(params["router"]["kernel"])
@@ -296,15 +316,19 @@ def _uncapped_case(name):
     return layer, params, x, held or (0, _E), block, pad
 
 
-def _loop_reference(params, x, lo, hi, real):
+def _loop_reference(params, x, lo, hi, real, router_input=None,
+                    relu=False):
     """Token by token, choice by choice, in float32: (y [rows, seq, d],
     the four routing counts over the tokens marked `real`)."""
     x2 = np.asarray(x, np.float32).reshape(-1, x.shape[-1])
-    silu = lambda a: a / (np.float32(1) + np.exp(-a))
+    r2 = x2 if router_input is None else np.asarray(
+        router_input, np.float32).reshape(x2.shape)
+    silu = (lambda a: np.maximum(a, 0)) if relu else (
+        lambda a: a / (np.float32(1) + np.exp(-a)))
     y = np.zeros_like(x2)
     per_expert = np.zeros(hi - lo, np.int64)
     for t, v in enumerate(x2):
-        logits = v @ params["router"]["kernel"]
+        logits = r2[t] @ params["router"]["kernel"]
         probs = np.exp(logits - logits.max())
         probs /= probs.sum()
         chosen = np.argsort(-probs, kind="stable")[:_K]
@@ -320,13 +344,15 @@ def _loop_reference(params, x, lo, hi, real):
     return y.reshape(x.shape), counts
 
 
-def _apply_uncapped(layer, params, x, block, pad, monkeypatch):
+def _apply_uncapped(layer, params, x, block, pad, monkeypatch,
+                    router_input=None):
     monkeypatch.setattr(moe_lib, "_TOKEN_BLOCK", block)
     variables = {"params": params}
     if pad is not None:
         variables["cache"] = {"feed_pad": jnp.asarray(pad, jnp.int32)}
     with jax.default_matmul_precision("highest"):
-        y, mutated = layer.apply(variables, x, mutable=["counters", "cache"])
+        y, mutated = layer.apply(variables, x, router_input=router_input,
+                                 mutable=["counters", "cache"])
     return np.asarray(y), np.asarray(jax.tree.leaves(mutated["counters"])[0])
 
 
@@ -339,8 +365,11 @@ def _real_tokens(x, pad):
 @pytest.mark.parametrize("name", list(UNCAPPED_CASES))
 def test_uncapped_layer_matches_the_per_token_loop(name, monkeypatch):
     layer, params, x, (lo, hi), block, pad = _uncapped_case(name)
-    want, counts = _loop_reference(params, x, lo, hi, _real_tokens(x, pad))
-    got, counted = _apply_uncapped(layer, params, x, block, pad, monkeypatch)
+    r = _router_input(name, x)
+    want, counts = _loop_reference(params, x, lo, hi, _real_tokens(x, pad),
+                                   r, _reglu(name))
+    got, counted = _apply_uncapped(layer, params, x, block, pad, monkeypatch,
+                                   r)
     assert np.abs(got - want).max() < UNCAPPED_TOL
     if name == "no_pair_held":
         assert counts[1] == 0 and not got.any()
@@ -393,16 +422,133 @@ def test_a_broken_dispatch_fails_the_tolerance_a_hundredfold(
     without their gate weights. (With no pair held there is nothing to
     break: that case is left out.)"""
     layer, params, x, (lo, hi), block, pad = _uncapped_case(name)
-    want, _ = _loop_reference(params, x, lo, hi, _real_tokens(x, pad))
+    r = _router_input(name, x)
+    want, _ = _loop_reference(params, x, lo, hi, _real_tokens(x, pad), r,
+                              _reglu(name))
     break_it(monkeypatch)
     # `_held_pairs` is jitted: a trace from before the break (or with it)
     # must not serve this test (or the next)
     moe_lib._held_pairs.clear_cache()
     try:
-        got, _ = _apply_uncapped(layer, params, x, block, pad, monkeypatch)
+        got, _ = _apply_uncapped(layer, params, x, block, pad, monkeypatch,
+                                 r)
     finally:
         moe_lib._held_pairs.clear_cache()
     assert not np.abs(got - want).max() < 100 * UNCAPPED_TOL
+
+
+@pytest.mark.parametrize("act", ["swiglu", "reglu"])
+def test_two_shares_add_up_to_the_uncut_layer(act, monkeypatch):
+    """Experts 0-11 here and 12-23 on the partner, the router reading an
+    input of its own on both: the two partial results add up to what the
+    layer that holds all 24 gives, and neither is the whole."""
+    x = jax.random.normal(jax.random.key(3), (2, 24, _D))
+    r = jax.random.normal(jax.random.key(9), x.shape)
+    whole = _uncapped_layer(None, act=act)
+    params = whole.init(jax.random.key(4), x)["params"]
+    want, _ = _apply_uncapped(whole, params, x, 2048, None, monkeypatch, r)
+    parts = []
+    for lo, hi in ((0, 12), (12, 24)):
+        mine = {k: (v[lo:hi] if k.startswith("experts_") else v)
+                for k, v in params.items()}
+        parts.append(_apply_uncapped(_uncapped_layer((lo, hi), act=act),
+                                     mine, x, 2048, None, monkeypatch, r)[0])
+    assert np.abs(parts[0] + parts[1] - want).max() < UNCAPPED_TOL
+    assert np.abs(parts[0] - want).max() > 100 * UNCAPPED_TOL
+    # the router's own input decided: x in its place is another result
+    other, _ = _apply_uncapped(whole, params, x, 2048, None, monkeypatch)
+    assert np.abs(other - want).max() > 100 * UNCAPPED_TOL
+
+
+#: sha256 of the Mosaic module `expert_mlps` lowers to with `silu` (the
+#: locations left out: they hold this file tree's line numbers), taken on
+#: the tree before the activation became a parameter, at the hybrid cell's
+#: widths (36 held experts of 4096 x 768, ten of 72 a token)
+_SILU_KERNEL = {
+    2048: ("5f689ecd408248887090541ef07a364c"
+           "1c7bb48549fa8d2e395dd48106364a2c"),
+    32: ("1551b925523d2f0289bfec6695ced6ec"
+         "a040173dc18c512316c0cc59325c13c5"),
+}
+
+
+def _mosaic_module(lowered_text: str) -> str:
+    """The kernel of the one `tpu_custom_call` in a lowered text, as MLIR
+    assembly without locations."""
+    import base64
+    import json
+    import re
+
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    config = re.search(r'backend_config = "(.*?)"\s*[,}]', lowered_text,
+                       re.S).group(1).replace("\\22", '"')
+    body = base64.b64decode(
+        json.loads(config)["custom_call_config"]["body"])
+    context = jax_mlir.make_ir_context()
+    tpu.register_dialect(context)
+    context.allow_unregistered_dialects = True
+    with context:
+        return ir.Module.parse(body).operation.get_asm(
+            enable_debug_info=False)
+
+
+def _lowered_for_tpu(tokens: int, held=36, experts=72, k=10, d=4096, f=768,
+                     **kw) -> str:
+    pairs = tokens * k
+    tile = moe_gmm.tile_rows(pairs, experts)
+    tiles = moe_gmm.tiles_bound(pairs, held, tile)
+    of = jax.ShapeDtypeStruct
+    return moe_gmm.expert_mlps.trace(
+        of((tiles * tile, d), jnp.bfloat16), of((held, d, f), jnp.bfloat16),
+        of((held, d, f), jnp.bfloat16), of((held, f, d), jnp.bfloat16),
+        of((tiles,), jnp.int32), of((1,), jnp.int32), tile=tile, **kw,
+    ).lower(lowering_platforms=("tpu",)).as_text()
+
+
+@pytest.mark.parametrize("tokens", sorted(_SILU_KERNEL))
+def test_the_silu_kernel_is_the_module_it_was(tokens):
+    """The activation is a static parameter of the one kernel: with `silu`
+    (the default, what the hybrid cell runs) the Mosaic module is, to the
+    character, the one the kernel lowered to while `silu` was written into
+    it; with `relu` it is another."""
+    import hashlib
+
+    silu = _mosaic_module(_lowered_for_tpu(tokens))
+    assert hashlib.sha256(silu.encode()).hexdigest() == _SILU_KERNEL[tokens]
+    assert silu == _mosaic_module(_lowered_for_tpu(tokens, act="silu"))
+    relu = _mosaic_module(_lowered_for_tpu(tokens, act="relu"))
+    assert relu != silu and "maximumf" in relu and "maximumf" not in silu
+
+
+def test_the_relu_kernel_matches_its_jax_numpy_twin():
+    """`(relu(x Wg) * (x W1)) W2` a tile's expert at a time, interpreted,
+    against the same in plain jax.numpy: two experts' tiles in use, one
+    tile beyond `live` left alone."""
+    tile, d, f, experts = 16, 32, 24, 3
+    key = jax.random.split(jax.random.key(0), 4)
+    rows = jax.random.normal(key[0], (3 * tile, d), jnp.float32)
+    wg, w1 = (jax.random.normal(k, (experts, d, f), jnp.float32) / 6
+              for k in key[1:3])
+    w2 = jax.random.normal(key[3], (experts, f, d), jnp.float32) / 5
+    group = jnp.asarray([2, 0, 0], jnp.int32)
+    with jax.default_matmul_precision("highest"):
+        got = moe_gmm.expert_mlps(rows, wg, w1, w2, group,
+                                  jnp.asarray([2], jnp.int32), tile=tile,
+                                  act="relu", interpret=True)
+        for t, e in enumerate((2, 0)):
+            x = rows[t * tile:(t + 1) * tile]
+            want = (jnp.maximum(x @ wg[e], 0) * (x @ w1[e])) @ w2[e]
+            assert np.abs(np.asarray(got[t * tile:(t + 1) * tile])
+                          - np.asarray(want)).max() < 1e-5
+            silu = (jax.nn.silu(x @ wg[e]) * (x @ w1[e])) @ w2[e]
+            assert np.abs(np.asarray(want - silu)).max() > 1e-2
+    with pytest.raises(ValueError, match="act"):
+        moe_gmm.expert_mlps(rows, wg, w1, w2, group,
+                            jnp.asarray([2], jnp.int32), tile=tile,
+                            act="gelu", interpret=True)
 
 
 @pytest.mark.parametrize("pairs,experts,tile", [

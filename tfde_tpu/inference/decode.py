@@ -87,8 +87,8 @@ def _decode_clone(model, rolling: bool = False, paged_blocks=None,
     kw = {"decode": True}
     if getattr(model, "remat", False):
         kw["remat"] = False
-    if (rolling and getattr(model, "sliding_window", None)
-            and hasattr(model, "rolling_cache")):
+    if (rolling and hasattr(model, "rolling_cache")
+            and model.layer_windows() is not None):
         kw["rolling_cache"] = True
     if paged_blocks is not None:
         if rolling:

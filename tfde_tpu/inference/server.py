@@ -128,9 +128,29 @@ _PHASE_KEYS = (
 )
 
 
-def _state_not_by_position(model) -> Optional[str]:
-    """Why `model`'s cached state is not one cell per position, or None
-    where it is (a K/V slab): asked of the model's own fields."""
+def _rings(model, max_len: Optional[int] = None) -> bool:
+    """Does any layer of `model` attend through a sliding window shorter
+    than `max_len` (None: than some length a batcher could be given)?
+    Under the batcher such a layer keeps a ring of `window` cells
+    (`_decode_clone(rolling=True)`). A window of `max_len` or more is
+    never left behind: every layer then keeps the slab, under its band
+    mask, and everything built on a cell per position."""
+    windows = getattr(model, "layer_windows", None)
+    windows = windows() if windows is not None else None
+    return windows is not None and any(
+        w is not None and (max_len is None or w < max_len) for w in windows)
+
+
+def _state_not_by_position(model,
+                           max_len: Optional[int] = None) -> Optional[str]:
+    """Why `model`'s cached state is not one cell per position under a
+    batcher of `max_len` positions a row, or None where it is (a K/V
+    slab): asked of the model's own fields."""
+    if _rings(model, max_len):
+        return ("its window layers keep a ring of `window` cells, slot = "
+                "position mod window, and a ring cannot give back an "
+                "overwritten cell (models/transformer.py "
+                "MultiHeadAttention._rolling_attention)")
     if getattr(model, "attention", "full") == "eva":
         return ("attention='eva' caches one window in progress and one "
                 "summary per chunk (models/transformer.py "
@@ -142,12 +162,14 @@ def _state_not_by_position(model) -> Optional[str]:
     return None
 
 
-def _refuse_stateful(model, what: str) -> None:
+def _refuse_stateful(model, what: str,
+                     max_len: Optional[int] = None) -> None:
     """Features that rewind, share or re-encode cached state BY POSITION
     have nothing to hold on to in a layout that is not a cell per
     position (a summary folds 16 positions into one cell, a window slot
-    is reused every 2,048, a state-space layer keeps one state)."""
-    why = _state_not_by_position(model)
+    is reused every 2,048, a state-space layer keeps one state, a ring's
+    cell is overwritten one window on)."""
+    why = _state_not_by_position(model, max_len)
     if why is not None:
         raise NotImplementedError(
             f"{what} is not built for this model: {why}, not a cell per "
@@ -672,6 +694,13 @@ class _BatcherBase:
         self._params = params
         self._b = batch_size
         self._max_len = int(max_len)
+        # a layer whose window is shorter than `max_len` keeps a ring of
+        # `window` cells beside the other layers' slabs: slot = position
+        # mod window under the per-row indices the slab uses (what rewinds,
+        # shares or re-encodes cells by position is refused for such a
+        # model, `_refuse_stateful`); a window no row can outgrow leaves
+        # the slab, its band mask and all of those as they were
+        self._ring = _rings(model, self._max_len)
         self._eos = eos_id
         self._pad = pad_id
         self._rng = rng if rng is not None else jax.random.key(0)
@@ -710,7 +739,7 @@ class _BatcherBase:
         self._phase = dict.fromkeys(_PHASE_KEYS, 0)
         if role != "both":
             _refuse_stateful(model, f"role={role!r} (the primed hand-off ships "
-                               f"K/V by position)")
+                             f"K/V by position)", self._max_len)
         # what one decode tick cannot avoid reading of the parameters
         self._param_bytes = _count_params(params)[1]
         # first tokens fetched in this step and not yet handed back: how
@@ -870,7 +899,8 @@ class _BatcherBase:
             raise RuntimeError(
                 f"{type(self).__name__} does not accept primed requests"
             )
-        _refuse_stateful(self._model, "submit_primed() (K/V shipped by position)")
+        _refuse_stateful(self._model, "submit_primed() (K/V shipped by "
+                         "position)", self._max_len)
         if self._role == "prefill":
             raise RuntimeError("prefill-only replica cannot decode")
         prompt = self._check_request(primed.prompt, primed.max_new_tokens)
@@ -1099,7 +1129,8 @@ class _BatcherBase:
             if program is None:
                 program = self._zero_programs[key] = _zeros_program(
                     jax.eval_shape(functools.partial(
-                        init_cache, model, rp, length, kv_quant=kv_quant)))
+                        init_cache, model, rp, length,
+                        rolling=self._ring, kv_quant=kv_quant)))
             self._dispatches += 1
             return program()
 
@@ -1542,8 +1573,10 @@ class ContinuousBatcher(_BatcherBase):
                else str(kv_quant))
         self._kv_quant = None if kvq == "fp" else kvq
         if self._kv_quant is not None:
-            _refuse_stateful(model, f"kv_quant={self._kv_quant!r}")
-        self._decode_model = _decode_clone(model, kv_quant=self._kv_quant)
+            _refuse_stateful(model, f"kv_quant={self._kv_quant!r}",
+                             self._max_len)
+        self._decode_model = _decode_clone(model, rolling=self._ring,
+                                           kv_quant=self._kv_quant)
         self._sampling = dict(
             temperature=float(temperature),
             top_k=top_k, top_p=top_p, min_p=min_p,
@@ -1570,7 +1603,8 @@ class ContinuousBatcher(_BatcherBase):
         self._paged = (knobs.env_flag("TFDE_PAGED_KV") if paged is None
                        else bool(paged))
         if self._paged:
-            _refuse_stateful(model, "paged=True (the block pool)")
+            _refuse_stateful(model, "paged=True (the block pool)",
+                             self._max_len)
             block = DEFAULT_BLOCK
             self._kv_block = int(block)
             # +1 cell: the decode scan writes one-past-committed for
@@ -1613,7 +1647,7 @@ class ContinuousBatcher(_BatcherBase):
             self._paged_model = None
             self._pool = None
             raw = init_cache(model, batch_size, self._max_len,
-                             kv_quant=self._kv_quant)
+                             rolling=self._ring, kv_quant=self._kv_quant)
             raw_shapes = raw
         # the decode scan's model: paged clone when on, dense otherwise
         self._scan_model = self._paged_model or self._decode_model
@@ -1634,7 +1668,8 @@ class ContinuousBatcher(_BatcherBase):
         # zero-fill program per width (`_zero_rows`), built here from the
         # shapes and compiled at the width's first wave.
         one = jax.eval_shape(functools.partial(
-            init_cache, model, 1, self._max_len, kv_quant=self._kv_quant))
+            init_cache, model, 1, self._max_len, rolling=self._ring,
+            kv_quant=self._kv_quant))
         rp = 1
         while True:
             self._zero_programs[
@@ -1662,7 +1697,7 @@ class ContinuousBatcher(_BatcherBase):
         else:
             self._prefix = _resolve_prefix(prefix_cache)
         if self._prefix is not None:
-            _refuse_stateful(model, "the prefix cache")
+            _refuse_stateful(model, "the prefix cache", self._max_len)
         # device-resident loop state (tok/idx/budget/done); rebuilt from
         # host bookkeeping whenever admission desyncs it
         self._dev = None
@@ -1702,7 +1737,14 @@ class ContinuousBatcher(_BatcherBase):
         prefill and decode), `eva_window_turns` (windows handed over in
         decode), and per scan, depth x the cells its active rows attend
         to at its start, `eva_window_cells_read` and
-        `eva_summary_cells_read`."""
+        `eva_summary_cells_read`. Over a model with window layers, whose
+        cache is a ring of `window` cells beside the other layers' slabs,
+        the ledger counts a layer's cell at a time
+        (`RingCapacityLedger.RING_KEYS`): per scan, depth x the committed
+        cells of its active rows summed over the layers without a window,
+        `kv_full_cells_read`, and min(committed, ring) summed over the
+        window layers, `kv_window_cells_read`; and `kv_window_wraps`, the
+        rows of a scan whose ring has turned."""
         g = max(self._generated, 1)
         return {
             "rounds": self._rounds,
@@ -2386,7 +2428,8 @@ class ContinuousBatcher(_BatcherBase):
         long-prompt admissions without ever stalling a decode scan."""
         if self._role == "decode":
             raise RuntimeError("decode-only replica cannot prime")
-        _refuse_stateful(self._model, "prime() (K/V shipped by position)")
+        _refuse_stateful(self._model, "prime() (K/V shipped by position)",
+                         self._max_len)
         t_prime = now_ns()
         prompt = self._check_request(prompt, max_new_tokens)
         bucket = next(b for b in self._buckets if b >= prompt.size)
@@ -2518,7 +2561,7 @@ class SpeculativeContinuousBatcher(_BatcherBase):
             raise ValueError(f"num_draft must be >= 1, got {num_draft}")
         for m in (model, draft_model):
             _refuse_stateful(m, "SpeculativeContinuousBatcher (a rejected "
-                           "draft rewinds the cache by position)")
+                             "draft rewinds the cache by position)", max_len)
         super().__init__(model, params, batch_size, max_len, eos_id,
                          pad_id, rng, prompt_buckets)
         from tfde_tpu.inference.speculative import (

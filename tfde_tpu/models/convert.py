@@ -24,6 +24,7 @@ the logit-match test bounds it.
 
 from __future__ import annotations
 
+import functools
 from typing import Tuple
 
 import numpy as np
@@ -31,6 +32,24 @@ import numpy as np
 
 def _np(t) -> np.ndarray:
     return t.detach().cpu().numpy().astype(np.float32)
+
+
+def _one_layout(to_hf):
+    """An exporter of a GPT writes ONE window and one position scheme for
+    the whole model (`sliding_window` under its pattern, `position`). A
+    model described layer by layer (`windows`, `rope_layers`) has no twin
+    among them: exporting it would silently drop its bands."""
+    @functools.wraps(to_hf)
+    def checked(model, params):
+        if (getattr(model, "windows", None) is not None
+                or getattr(model, "rope_layers", None) is not None):
+            raise NotImplementedError(
+                f"{to_hf.__name__} writes one sliding window and one "
+                f"position scheme for the model; this one gives them per "
+                f"layer (windows={model.windows!r}, "
+                f"rope_layers={model.rope_layers!r})")
+        return to_hf(model, params)
+    return checked
 
 
 def gpt2_from_hf(hf_model, dtype=None) -> Tuple[object, dict]:
@@ -456,6 +475,7 @@ def gemma2_from_hf(hf_model, dtype=None) -> Tuple[object, dict]:
     return model, params
 
 
+@_one_layout
 def gemma2_to_hf(model, params):
     """A transformers Gemma2ForCausalLM carrying `params` — the inverse
     of `gemma2_from_hf` (all five norm kinds un-fold 1+w)."""
@@ -720,6 +740,7 @@ def qwen2moe_from_hf(hf_model, dtype=None) -> Tuple[object, dict]:
     return model, params
 
 
+@_one_layout
 def qwen2moe_to_hf(model, params):
     """A transformers Qwen2MoeForCausalLM carrying `params` — the inverse
     of `qwen2moe_from_hf`."""
@@ -922,6 +943,7 @@ def phi3_from_hf(hf_model, dtype=None) -> Tuple[object, dict]:
     return model, params
 
 
+@_one_layout
 def phi3_to_hf(model, params):
     """A transformers Phi3ForCausalLM carrying `params` — the inverse of
     `phi3_from_hf`: the shared LLaMA-style state dict with q/k/v fused
@@ -1012,6 +1034,7 @@ def qwen3_from_hf(hf_model, dtype=None) -> Tuple[object, dict]:
     return model, params
 
 
+@_one_layout
 def qwen3_to_hf(model, params):
     """A transformers Qwen3ForCausalLM carrying `params` — the inverse of
     `qwen3_from_hf`: the LLaMA-style state dict plus the per-layer
@@ -1694,6 +1717,7 @@ def mixtral_from_hf(hf_model, dtype=None) -> Tuple[object, dict]:
     return model, params
 
 
+@_one_layout
 def mixtral_to_hf(model, params):
     """A transformers MixtralForCausalLM carrying `params` — the inverse
     of `mixtral_from_hf`: expert stacks unstack into per-expert w1/w2/w3
@@ -2069,6 +2093,7 @@ def _t(a) -> "object":
     return torch.from_numpy(np.ascontiguousarray(np.asarray(a, np.float32)))
 
 
+@_one_layout
 def gpt2_to_hf(model, params):
     """A transformers GPT2LMHeadModel carrying `params` — the inverse of
     `gpt2_from_hf`. Requires the GPT-2 arrangement (learned positions,
@@ -2207,6 +2232,7 @@ def _llama_style_sd(model, params, mlp_fn=None) -> dict:
     return sd
 
 
+@_one_layout
 def llama_to_hf(model, params):
     """A transformers LlamaForCausalLM (or Qwen2 twin when
     model.qkv_bias) carrying `params` — the inverse of `llama_from_hf` /
@@ -2267,6 +2293,7 @@ def llama_to_hf(model, params):
     return hf
 
 
+@_one_layout
 def gemma_to_hf(model, params):
     """A transformers GemmaForCausalLM carrying `params` — the inverse of
     `gemma_from_hf`: the LLaMA-style state dict with the two Gemma folds
@@ -2323,6 +2350,7 @@ def gemma_to_hf(model, params):
     return hf
 
 
+@_one_layout
 def phi_to_hf(model, params):
     """A transformers PhiForCausalLM carrying `params` — the inverse of
     `phi_from_hf` (parallel blocks, partial rotary, biased everything)."""
@@ -2395,6 +2423,7 @@ def phi_to_hf(model, params):
     return hf
 
 
+@_one_layout
 def neox_to_hf(model, params):
     """A transformers GPTNeoXForCausalLM carrying `params` — the inverse
     of `neox_from_hf`: the three projection kernels re-interleave into
@@ -2488,6 +2517,7 @@ def neox_to_hf(model, params):
     return hf
 
 
+@_one_layout
 def bigcode_to_hf(model, params):
     """A transformers GPTBigCodeForCausalLM carrying `params` — the
     inverse of `bigcode_from_hf`: q/k/v kernels re-fuse into c_attn with
@@ -2585,6 +2615,7 @@ def bigcode_to_hf(model, params):
     return hf
 
 
+@_one_layout
 def opt_to_hf(model, params):
     """A transformers OPTForCausalLM carrying `params` — the inverse of
     `opt_from_hf`. The legacy offset-2 position table is rebuilt by
@@ -2812,6 +2843,7 @@ def bert_classifier_to_hf(model, params):
     return hf
 
 
+@_one_layout
 def falcon_to_hf(model, params):
     """A transformers FalconForCausalLM carrying `params` — the inverse of
     `falcon_from_hf`: q/k/v kernels re-fuse into query_key_value per

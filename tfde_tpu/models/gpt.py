@@ -49,6 +49,9 @@ class GPT(nn.Module):
     moe_shared_expert_dim: Optional[int] = None  # Qwen2-MoE shared expert
     # False (Granite): the shared expert is added as it is, no sigmoid gate
     moe_shared_expert_gated: bool = True
+    # True (SmallThinker): the router reads the attention sublayer's
+    # normalised input, not the expert layer's (transformer.TransformerBlock)
+    moe_router_pre_attention: bool = False
     # (first, end) of the contiguous range of experts this program holds
     # (a chip's share under expert parallelism): the router stays
     # `num_experts` wide, pairs routed elsewhere add nothing (MoEMlp)
@@ -92,7 +95,7 @@ class GPT(nn.Module):
     num_kv_heads: Optional[int] = None
     norm: str = "layer"      # 'layer' | 'rms' (LLaMA)
     mlp_act: str = "gelu"    # 'gelu' | 'relu' (OPT) | 'swiglu' (LLaMA) |
-    #                          'geglu' (Gemma)
+    #                          'geglu' (Gemma) | 'reglu' (SmallThinker)
     use_bias: bool = True    # False: LLaMA bias-free projections
     # Qwen2: biased q/k/v projections beside bias-free out/MLP
     qkv_bias: bool = False
@@ -127,6 +130,17 @@ class GPT(nn.Module):
     sliding_window: Optional[int] = None
     # 'all' | 'alternate' (Gemma-2: even blocks windowed, odd blocks full)
     sliding_window_pattern: str = "all"
+    # the per-layer form, of which the two fields above are two ways of
+    # writing (`layer_windows`): one window per layer, None (full causal)
+    # or an int, as long as `depth` (a config's `sliding_window_layout`
+    # times its `sliding_window_size`). Under decode a layer with a window
+    # may keep a ring of `window` cells beside the other layers' slabs
+    # (transformer.MultiHeadAttention.rolling_cache)
+    windows: Optional[tuple] = None
+    # position='rope' only: which layers rotate q and k, one truth value
+    # per layer (a config's `rope_layout`); a layer that does not has no
+    # positions. None: every layer rotates
+    rope_layers: Optional[tuple] = None
     # Gemma-2 attention deltas (transformer.MultiHeadAttention)
     attn_scale: Optional[float] = None
     attn_logit_cap: Optional[float] = None
@@ -157,6 +171,29 @@ class GPT(nn.Module):
     residual_multiplier: Optional[float] = None
     logits_scaling: Optional[float] = None
 
+    def layer_windows(self) -> Optional[tuple]:
+        """One sliding window per layer (None: full causal), or None where
+        no layer has one: `windows` as given, else `sliding_window` under
+        `sliding_window_pattern`: 'all' every layer, 'alternate' layers 0,
+        2, ... (the Gemma-2 local/global interleave)."""
+        if self.windows is not None:
+            if self.sliding_window is not None:
+                raise ValueError(
+                    "give the windows per layer (`windows`) or one for a "
+                    "pattern (`sliding_window`), not both")
+            windows = tuple(None if not w else int(w) for w in self.windows)
+            return windows if any(windows) else None
+        if self.sliding_window_pattern not in ("all", "alternate"):
+            raise ValueError(
+                f"sliding_window_pattern must be 'all' or 'alternate', got "
+                f"{self.sliding_window_pattern!r}")
+        if self.sliding_window is None:
+            return None
+        return tuple(
+            self.sliding_window
+            if self.sliding_window_pattern == "all" or i % 2 == 0 else None
+            for i in range(self.depth))
+
     @nn.compact
     def __call__(self, input_ids: jax.Array, train: bool = False,
                  segment_ids: Optional[jax.Array] = None,
@@ -185,7 +222,7 @@ class GPT(nn.Module):
                     "segment_ids (sequence packing) is a training-side "
                     "capability; the decode cache has no segment plane"
                 )
-            if self.sliding_window is not None:
+            if self.layer_windows() is not None:
                 raise NotImplementedError(
                     "segment_ids does not compose with sliding_window "
                     "yet (the band would need per-segment offsets)"
@@ -222,6 +259,10 @@ class GPT(nn.Module):
                 f"position must be 'learned', 'rope' or 'none', got "
                 f"{self.position!r}"
             )
+        if self.rope_layers is not None and self.position != "rope":
+            raise ValueError(
+                "rope_layers says which layers rotate under position='rope'; "
+                f"position is {self.position!r}")
         x = wte(input_ids)
         if self.embed_scale is not None:
             x = x * jnp.asarray(self.embed_scale, self.dtype)
@@ -275,8 +316,9 @@ class GPT(nn.Module):
             num_kv_heads=self.num_kv_heads,
             fused_qkv=self.fused_qkv,
             quant=self.quant,
-            window=self.sliding_window,
-            window_pattern=self.sliding_window_pattern,
+            windows=self.layer_windows(),
+            rope_layers=(tuple(bool(r) for r in self.rope_layers)
+                         if self.rope_layers is not None else None),
             rolling_cache=self.rolling_cache,
             paged_blocks=self.paged_blocks,
             kv_block=self.kv_block,
@@ -305,6 +347,7 @@ class GPT(nn.Module):
             moe_held_experts=(tuple(self.moe_held_experts)
                               if self.moe_held_experts is not None else None),
             moe_shared_expert_gated=self.moe_shared_expert_gated,
+            moe_router_pre_attention=self.moe_router_pre_attention,
             mixers=tuple(self.mixers) if self.mixers is not None else None,
             ssm=self.ssm,
             residual_multiplier=self.residual_multiplier,

@@ -101,6 +101,10 @@ def dispatch_shape(batch: int, seq: int, num_experts: int,
     return (g, m, num_experts, c)
 
 
+#: the gated expert forms: what the gate goes through, by `MoEMlp.act`,
+#: under the name `ops/moe_gmm.py` has for it
+_GATE_ACTS = {"swiglu": "silu", "reglu": "relu"}
+
 #: tokens whose pairs are laid out and multiplied at a time without a
 #: capacity: the sorted copy is about this x experts_per_token rows
 _TOKEN_BLOCK = 2048
@@ -152,14 +156,16 @@ def _rows_back(out, slot, here, vals):
         0, k // per, add, jnp.zeros((block, out.shape[1]), jnp.float32))
 
 
-@functools.partial(jax.jit, static_argnames=("lo", "tile", "dtype"))
-def _held_pairs(x, idx, vals, ok, wg, w1, w2, *, lo: int, tile: int, dtype):
+@functools.partial(jax.jit, static_argnames=("lo", "tile", "dtype", "act"))
+def _held_pairs(x, idx, vals, ok, wg, w1, w2, *, lo: int, tile: int, dtype,
+                act: str = "silu"):
     """The held experts' part of the result for [blocks, block] tokens, a
     block at a time under `lax.map`: x [blocks, block, d], idx / vals
     [blocks, block, k] (vals float32; idx -1 on a token that only fills
     the last block), ok [blocks, block] (a real token, for the counts),
     wg / w1 [held, d, f], w2 [held, f, d], the layer holding experts lo ..
-    lo + held - 1. Returns (y [blocks, block, d] in x's dtype, each held
+    lo + held - 1, their gate through `act` (`moe_gmm.expert_mlps`).
+    Returns (y [blocks, block, d] in x's dtype, each held
     expert's pairs of real tokens [blocks, held], the real tokens' pairs
     [blocks]).
 
@@ -190,7 +196,7 @@ def _held_pairs(x, idx, vals, ok, wg, w1, w2, *, lo: int, tile: int, dtype):
         with jax.named_scope("moe_experts"):
             out = moe_gmm.expert_mlps(
                 rows, wg, w1, w2, tile_expert, live[None], tile=tile,
-                interpret=jax.default_backend() == "cpu")
+                act=act, interpret=jax.default_backend() == "cpu")
         with jax.named_scope("moe_rows_back"):
             y = _rows_back(out, jnp.where(here, slot, 0).reshape(k, block),
                            here.reshape(k, block), vals.T)
@@ -214,7 +220,8 @@ class MoEMlp(nn.Module):
     capacity_factor: Optional[float] = 1.25
     # 'gelu' (Switch/GShard) | 'swiglu' (Mixtral: per-expert gated-silu,
     # bias-free — a parallel experts_gate projection beside the up
-    # projection, the expert-wise analog of transformer.Mlp's swiglu)
+    # projection, the expert-wise analog of transformer.Mlp's swiglu) |
+    # 'reglu' (SmallThinker: the same gated form with relu on the gate)
     act: str = "gelu"
     use_bias: bool = True
     # False (Qwen2-MoE): combine with the RAW softmax probabilities of the
@@ -244,7 +251,10 @@ class MoEMlp(nn.Module):
     num_groups: Optional[int] = None
 
     @nn.compact
-    def __call__(self, x: jax.Array, train: bool = False) -> jax.Array:
+    def __call__(self, x: jax.Array, train: bool = False,
+                 router_input: Optional[jax.Array] = None) -> jax.Array:
+        """x [B, S, d] is what the experts read; the router reads
+        `router_input` [B, S, d], `x` itself unless one is given."""
         b_axes = batch_axes()
         bsz, seq, d = x.shape
         e, k = self.num_experts, self.experts_per_token
@@ -270,7 +280,8 @@ class MoEMlp(nn.Module):
         logits = nn.Dense(
             e, use_bias=False, dtype=jnp.float32, param_dtype=jnp.float32,
             name="router",
-        )(tokens.astype(jnp.float32))
+        )((tokens if router_input is None
+           else router_input.reshape(g, m, d)).astype(jnp.float32))
         probs = jax.nn.softmax(logits, axis=-1)  # [g, m, e]
 
         gate_vals, gate_idx = jax.lax.top_k(probs, k)  # [g, m, k]
@@ -330,11 +341,13 @@ class MoEMlp(nn.Module):
         h = expert_dense(w1, xin)
         if self.use_bias:
             h = h + b1.astype(jnp.float32)[:, None]
-        if self.act == "swiglu":
-            # gated-silu (Mixtral): gate and up are both expert-sharded on
-            # dim 0, so the product crosses no shard boundary
+        if wg is not None:
+            # gated (Mixtral's silu, SmallThinker's relu): gate and up are
+            # both expert-sharded on dim 0, so the product crosses no shard
+            # boundary
             gate = expert_dense(wg, xin)
-            h = nn.silu(gate.astype(self.dtype)) * h.astype(self.dtype)
+            h = moe_gmm.ACTS[_GATE_ACTS[self.act]](
+                gate.astype(self.dtype)) * h.astype(self.dtype)
         else:
             h = nn.gelu(h.astype(self.dtype))
         h = constrain(h, "expert", b_axes)
@@ -354,9 +367,9 @@ class MoEMlp(nn.Module):
     def _expert_params(self, held: int, d: int) -> tuple:
         """(fc1, fc2, b1, b2, gate) of the `held` experts; the biases and
         the gate are None where the arrangement has none."""
-        if self.act not in ("gelu", "swiglu"):
+        if self.act != "gelu" and self.act not in _GATE_ACTS:
             raise ValueError(
-                f"act must be 'gelu' or 'swiglu', got {self.act!r}"
+                f"act must be 'gelu', 'swiglu' or 'reglu', got {self.act!r}"
             )
         init = nn.initializers.lecun_normal(batch_axis=0)
         w1 = self.param("experts_fc1", init, (held, d, self.mlp_dim),
@@ -369,7 +382,7 @@ class MoEMlp(nn.Module):
                             (held, 1, self.mlp_dim), jnp.float32)
             b2 = self.param("experts_b2", nn.initializers.zeros,
                             (held, 1, d), jnp.float32)
-        if self.act == "swiglu":
+        if self.act in _GATE_ACTS:
             wg = self.param("experts_gate", init, (held, d, self.mlp_dim),
                             jnp.float32)
         return w1, w2, b1, b2, wg
@@ -386,10 +399,10 @@ class MoEMlp(nn.Module):
         tile chosen from the block's shape, and the five counts sown."""
         bsz, seq, d = x.shape
         k, held = self.experts_per_token, hi - lo
-        if self.act != "swiglu" or self.use_bias:
+        if self.act not in _GATE_ACTS or self.use_bias:
             raise NotImplementedError(
-                "routing without a capacity is built for bias-free swiglu "
-                "experts")
+                "routing without a capacity is built for bias-free gated "
+                "experts ('swiglu', 'reglu')")
         w1, w2, _, _, wg = self._expert_params(held, d)
         n = bsz * seq
         valid = jnp.ones((bsz, seq), bool)
@@ -416,7 +429,8 @@ class MoEMlp(nn.Module):
         y, counted, routed = _held_pairs(
             blocks(x, 0), blocks(gate_idx, -1),
             blocks(gate_vals.astype(jnp.float32), 0), blocks(valid, False),
-            wg, w1, w2, lo=lo, tile=tile, dtype=self.dtype)
+            wg, w1, w2, lo=lo, tile=tile, dtype=self.dtype,
+            act=_GATE_ACTS[self.act])
         counted = counted.sum(0)
         # of this call's real tokens: pairs routed, pairs whose expert is
         # held, held experts with a pair, the busiest held expert's pairs;

@@ -122,14 +122,15 @@ class MultiHeadAttention(nn.Module):
     # chunk step, so capped models train fused and sequence-parallel.
     attn_scale: Optional[float] = None
     attn_logit_cap: Optional[float] = None
-    # rolling KV cache (decode + window only): the cache holds min(budget,
-    # window) slots, each token writing slot (position mod len) — decode
-    # memory bounded by the window, not the generation budget (the Mistral
-    # rolling-buffer serving lever). OPT-IN because cache REWIND
-    # (speculative decoding) breaks it: a rejected draft's write can alias
-    # the slot of a committed token one window back; paths that never
-    # rewind (inference/decode.generate/generate_ragged/beam_search) turn
-    # it on via _decode_clone(rolling=True).
+    # rolling KV cache (decode + window only): the cache is a ring of
+    # min(budget, window) cells, each token writing slot (position mod
+    # len) — decode memory bounded by the window, not the generation
+    # budget (the Mistral rolling-buffer serving lever). OPT-IN because
+    # cache REWIND (speculative decoding) breaks it: a rejected draft's
+    # write can alias the slot of a committed token one window back; paths
+    # that never rewind (inference/decode.generate/generate_ragged/
+    # beam_search, and inference/server.ContinuousBatcher with its per-row
+    # indices) turn it on via _decode_clone(rolling=True).
     rolling_cache: bool = False
     # paged KV cache (decode only, TFDE_PAGED_KV): K/V live in ONE shared
     # physical pool of `paged_blocks` blocks x `kv_block` tokens
@@ -438,6 +439,13 @@ class MultiHeadAttention(nn.Module):
                                         jnp.zeros, scale_shape, jnp.float32)
         cache_index = self.variable("cache", "cache_index",
                                     lambda: jnp.zeros((), jnp.int32))
+        if rolling:
+            # how many trailing tokens of THIS call are padding, per row
+            # (0 unless the caller sets it; read once and reset): a ring
+            # keeps a prefill's last TRUE tokens, which an index rewind
+            # cannot recover once the padded tail has overwritten them
+            feed_pad = self.variable("cache", "feed_pad", jnp.zeros,
+                                     (k.shape[0],), jnp.int32)
 
         if not is_filled:
             # init pass: variables were just created from this call's shapes
@@ -458,7 +466,8 @@ class MultiHeadAttention(nn.Module):
         q, k = self._rotate(q, k, idx)
         if rolling:
             return self._rolling_attention(
-                q, k, v, batch, cached_key, cached_value, cache_index
+                q, k, v, batch, cached_key, cached_value, cache_index,
+                feed_pad
             )
         if quant:
             # quantize-on-write: the int8 payload + fp32 per-(position,
@@ -699,93 +708,103 @@ class MultiHeadAttention(nn.Module):
         )
 
     def _rolling_attention(self, q, k, v, batch, cached_key, cached_value,
-                           cache_index) -> jax.Array:
-        """Window-bounded rolling KV cache: the token at absolute position
-        p lives in slot p mod Wc (Wc = min(budget, window)), so decode
-        memory is O(window) regardless of how long the generation runs.
-
-        The mask is reconstructed from slot arithmetic instead of stored
-        positions: after this call's writes the newest absolute position
-        is P, so slot j's content is the token at b_j = P - ((P - j) mod
-        Wc) — the latest position congruent to j. A query at position p
-        attends slot j iff 0 <= b_j <= p and p - b_j < window.
+                           cache_index, feed_pad) -> jax.Array:
+        """A ring of Wc = min(budget, window) cells beside the other
+        layers' slabs: the token at absolute position p lives in slot
+        p mod Wc, so a window layer's decode memory is O(window) however
+        long the row runs.
 
         Caller invariant (STRICTER than "no rewind"): ONE prefill from
-        position 0, then single-token (sq == 1) steps. A multi-token
-        write onto a filled cache would clobber in-window keys its own
-        earlier queries still need (e.g. a 4-token chunk at positions
-        8-11 with window 4 destroys keys 5-7 before the query at 8 reads
-        them), and cache_index is traced so no runtime check can fire.
-        generate / generate_ragged / beam_search all satisfy this (their
-        scans are strictly one token per step after the prefill);
-        speculative decoding violates it twice over (multi-token verify
-        steps AND rewind) and therefore never enables rolling.
+        position 0 into an empty ring, then single-token steps; a
+        multi-token write onto a filled ring would clobber in-window keys
+        its own earlier queries still need, and `cache_index` is traced
+        so no runtime check can fire. generate / generate_ragged /
+        beam_search (one shared index) and the batcher (a fresh row cache
+        per wave, then per-row indices) satisfy it; speculative decoding
+        violates it twice over (multi-token verify steps AND rewind) and
+        never rolls.
 
-        A prompt longer than the cache (sq > Wc) attends in-batch (valid
-        only at cache position 0 — the generate prefill; every key a
-        band-limited query needs is in the batch) and keeps the last Wc
-        tokens.
+        The prefill (S > 1) attends over the call's own tokens, banded
+        and causal: every key a query needs is in the call (through the
+        dispatcher once the float32 scores pass `_PREFILL_SCORES_BYTES`,
+        so on the chip a long wave takes the flash forward and no S x S
+        array exists). It keeps each row's last min(true length, Wc)
+        tokens, true length S - `feed_pad`: slot j holds the latest true
+        position congruent to j. Where S <= Wc nothing wraps, the padded
+        tail lands past the true length as in a slab and the index rewind
+        hides it.
+
+        A step (S = 1) at position p, a row's own under per-row indices,
+        overwrites slot p mod Wc, the cell of position p - Wc, and attends
+        over the slots written so far: all of them once p >= Wc, slots
+        0 .. p before. Wc <= window, so every cell in the ring is inside
+        the band. A frozen row of a scan (its feed is padding) writes its
+        slot too; nothing reads that row again before a wave replaces it.
         """
-        sq = q.shape[1]
+        bsz, sq = q.shape[:2]
         wc = cached_key.value.shape[1]
         idx = cache_index.value
-        kd = cached_key.value.dtype
-
-        if sq > wc:
+        kd, vd = cached_key.value.dtype, cached_value.value.dtype
+        if sq > 1:
             if idx.ndim != 0:
                 raise ValueError(
-                    "per-row prefill longer than the rolling window cache "
-                    "is unsupported (rows would need in-batch keys beyond "
-                    "their own cache)"
-                )
-            # long prefill from position 0: band-limited queries only need
-            # in-batch keys; keep the newest Wc tokens
-            y = attn_lib.grouped_attention(
-                q, k, v, causal=True, window=self.window,
-                scale=self.attn_scale, logit_cap=self.attn_logit_cap,
-            )
-            pos_last = idx + jnp.arange(sq - wc, sq, dtype=jnp.int32)
-            slots = pos_last % wc
-            k_all = cached_key.value.at[:, slots].set(
-                k[:, -wc:].astype(kd)
-            )
-            v_all = cached_value.value.at[:, slots].set(
-                v[:, -wc:].astype(cached_value.value.dtype)
-            )
+                    "a ring is prefilled once, from position 0 of a fresh "
+                    "row cache (one shared index); per-row indices are the "
+                    "single-token steps after it")
+            lengths = sq - feed_pad.value
+            with jax.named_scope("attn_window_prefill"):
+                attend = (
+                    attn_lib.grouped_attention
+                    if (4 * bsz * self.num_heads * sq * sq
+                        <= _PREFILL_SCORES_BYTES or sq % _PREFILL_QUERY_BLOCK)
+                    else functools.partial(attn_lib.attention,
+                                           impl=self.attn_impl))
+                y = attend(q, k, v, causal=True, window=self.window,
+                           scale=self.attn_scale,
+                           logit_cap=self.attn_logit_cap).astype(q.dtype)
+            with jax.named_scope("attn_ring_write"):
+                k_all = _ring_of(k.astype(kd), lengths, cached_key.value)
+                v_all = _ring_of(v.astype(vd), lengths, cached_value.value)
         else:
-            cols = jnp.arange(wc, dtype=jnp.int32)
-            if idx.ndim == 0:
-                pos_q = idx + jnp.arange(sq, dtype=jnp.int32)
-                slots = pos_q % wc
-                k_all = cached_key.value.at[:, slots].set(k.astype(kd))
-                v_all = cached_value.value.at[:, slots].set(
-                    v.astype(cached_value.value.dtype)
+            pos = jnp.broadcast_to(jnp.asarray(idx, jnp.int32), (bsz,))
+            with jax.named_scope("attn_ring_write"):
+                k_all = _ring_put(cached_key.value, k.astype(kd), pos)
+                v_all = _ring_put(cached_value.value, v.astype(vd), pos)
+            with jax.named_scope("attn_ring_decode"):
+                cols = jnp.arange(wc, dtype=jnp.int32)
+                valid = cols[None, :] <= pos[:, None]       # [B, Wc]
+                y = attn_lib.grouped_attention(
+                    q, k_all, v_all, mask=valid[:, None, None, :],
+                    scale=self.attn_scale, logit_cap=self.attn_logit_cap,
                 )
-                last = idx + sq - 1
-                b = last - ((last - cols) % wc)  # [Wc] slot -> abs position
-                valid = ((b[None, :] >= 0)
-                         & (b[None, :] <= pos_q[:, None])
-                         & (pos_q[:, None] - b[None, :] < self.window))
-                valid = valid[None, None]  # [1, 1, Sq, Wc]
-            else:
-                # no rolling-enabled driver produces per-row indices:
-                # generate/ragged/beam share a scalar cache_index, and the
-                # [B]-index producer (speculative rewind) never rolls.
-                # Refuse rather than ship a never-executed branch.
-                raise NotImplementedError(
-                    "rolling_cache with per-row cache indices is "
-                    "unsupported — the per-row paths (speculative "
-                    "decoding, row-recycling servers) use the full-budget "
-                    "cache"
-                )
-            y = attn_lib.grouped_attention(
-                q, k_all, v_all, mask=valid, scale=self.attn_scale,
-                logit_cap=self.attn_logit_cap,
-            )
         cached_key.value = constrain(k_all, batch, None, "tensor")
         cached_value.value = constrain(v_all, batch, None, "tensor")
         cache_index.value = idx + sq
+        feed_pad.value = jnp.zeros_like(feed_pad.value)
         return y
+
+
+def _ring_put(ring, new, pos):
+    """One step's key or value new [B, 1, Kv, D] at position `pos` [B]
+    into ring [B, Wc, Kv, D]: slot pos mod Wc, over the cell of position
+    pos - Wc; an in-place scatter of the one cell a row."""
+    return eva_lib._rows_update(ring, new, pos % ring.shape[1])
+
+
+def _ring_of(x, lengths, ring):
+    """A prefill's keys or values x [B, S, Kv, D] (positions 0 .. S-1,
+    true length `lengths` [B]) as the ring [B, Wc, Kv, D] holds them: slot
+    j the latest true position congruent to j mod Wc. S <= Wc: the tokens
+    as they stand, from slot 0 of `ring` (a fresh one). Otherwise each
+    row's last Wc true tokens, a contiguous run from `start`, turned by
+    start mod Wc so that position p stands in slot p mod Wc."""
+    s, wc = x.shape[1], ring.shape[1]
+    if s <= wc:
+        return jax.lax.dynamic_update_slice(ring, x, (0, 0, 0, 0))
+    start = jnp.clip(lengths - wc, 0, s - wc)
+    run = eva_lib._rows_slice(x, start, wc)
+    return eva_lib._rows_slice(jnp.concatenate([run, run], axis=1),
+                               (wc - start % wc) % wc, wc)
 
 
 class Mamba2Mixer(nn.Module):
@@ -891,7 +910,9 @@ class Mlp(nn.Module):
     mlp_dim: int
     dtype: jnp.dtype = jnp.bfloat16
     dropout_rate: float = 0.0
-    act: str = "gelu"  # 'gelu' (tanh approx, == GPT-2 gelu_new) | 'swiglu'
+    # 'gelu' (tanh approx, == GPT-2 gelu_new) | 'relu' | 'swiglu' | 'geglu'
+    # | 'reglu' (relu(gate) * up)
+    act: str = "gelu"
     use_bias: bool = True
     quant: Optional[str] = None  # see MultiHeadAttention.quant
 
@@ -920,10 +941,13 @@ class Mlp(nn.Module):
             # gate, matching HF's gelu_pytorch_tanh
             gate = dense(self.mlp_dim, name="gate")(x)
             h = nn.gelu(gate, approximate=True) * h
+        elif self.act == "reglu":
+            gate = dense(self.mlp_dim, name="gate")(x)
+            h = nn.relu(gate) * h
         else:
             raise ValueError(
-                f"act must be 'gelu', 'relu', 'swiglu' or 'geglu', got "
-                f"{self.act!r}"
+                f"act must be 'gelu', 'relu', 'swiglu', 'geglu' or 'reglu', "
+                f"got {self.act!r}"
             )
         h = constrain(h, b, "seq", "tensor")
         h = dense(x.shape[-1], name="fc2")(h)
@@ -1013,6 +1037,10 @@ class TransformerBlock(nn.Module):
     # is the drop-free routing
     moe_held_experts: Optional[tuple] = None
     moe_shared_expert_gated: bool = True
+    # True (SmallThinker): the router reads the NORMALISED INPUT OF THE
+    # ATTENTION sublayer, the experts the normalised output of the
+    # attention residual; False: both read the latter
+    moe_router_pre_attention: bool = False
     attention: str = "full"  # 'full' | 'eva' (MultiHeadAttention)
     eva_window: int = 2048
     eva_chunk: int = 16
@@ -1078,13 +1106,13 @@ class TransformerBlock(nn.Module):
             )
         if self.num_experts > 0:
             if (self.mlp_act, self.use_bias) not in (
-                ("gelu", True), ("swiglu", False),
+                ("gelu", True), ("swiglu", False), ("reglu", False),
             ):
                 raise NotImplementedError(
                     "MoE expert MLPs are gelu+bias (Switch/GShard) or "
-                    "bias-free swiglu (Mixtral); other mlp_act/use_bias "
-                    "combinations would silently build a different "
-                    "architecture than requested"
+                    "bias-free swiglu (Mixtral) or reglu (SmallThinker); "
+                    "other mlp_act/use_bias combinations would silently "
+                    "build a different architecture than requested"
                 )
             if self.quant is not None:
                 raise NotImplementedError(
@@ -1123,14 +1151,20 @@ class TransformerBlock(nn.Module):
         if self.residual_multiplier is not None and self.norm_style != "pre":
             raise NotImplementedError(
                 "residual_multiplier is built for the pre-norm block")
+        if self.moe_router_pre_attention and (
+                self.norm_style != "pre" or self.num_experts <= 0):
+            raise NotImplementedError(
+                "moe_router_pre_attention is a routed pre-norm block's")
         if self.norm_style == "pre":
             r = self.residual_multiplier
             scaled = (lambda t: t) if r is None else (
                 lambda t: t * jnp.asarray(r, t.dtype))
             y = ln(name="ln_attn")(x).astype(self.dtype)
             x = x + scaled(attn(y, mask=mask, train=train))
+            routed_by = ({"router_input": y}
+                         if self.moe_router_pre_attention else {})
             y = ln(name="ln_mlp")(x).astype(self.dtype)
-            return x + scaled(mlp(y, train=train))
+            return x + scaled(mlp(y, train=train, **routed_by))
         if self.norm_style == "post":
             x = ln(name="ln_attn")(x + attn(x, mask=mask, train=train))
             x = x.astype(self.dtype)
@@ -1202,10 +1236,14 @@ class Encoder(nn.Module):
     num_kv_heads: Optional[int] = None
     fused_qkv: bool = False
     quant: Optional[str] = None
-    window: Optional[int] = None
-    # 'all': every block windowed; 'alternate': blocks 0, 2, ... windowed,
-    # odd blocks full attention (the Gemma-2 local/global interleave)
-    window_pattern: str = "all"
+    # one sliding window per block, None (full causal) or an int, as long
+    # as the depth; None: no block is windowed (models/gpt.py writes
+    # 'every block' and 'blocks 0, 2, ...' as such tuples)
+    windows: Optional[tuple] = None
+    # which blocks rotate q/k where `rope` is on, one truth value per
+    # block; None: every block. A block that does not rotate has no
+    # positions at all
+    rope_layers: Optional[tuple] = None
     rolling_cache: bool = False
     paged_blocks: Optional[int] = None
     kv_block: int = 16
@@ -1229,6 +1267,7 @@ class Encoder(nn.Module):
     moe_every: int = 2     # GShard convention: alternate dense / MoE
     moe_held_experts: Optional[tuple] = None
     moe_shared_expert_gated: bool = True
+    moe_router_pre_attention: bool = False  # TransformerBlock
     attention: str = "full"  # 'full' | 'eva' (MultiHeadAttention)
     eva_window: int = 2048
     eva_chunk: int = 16
@@ -1246,16 +1285,12 @@ class Encoder(nn.Module):
         mask: Optional[jax.Array] = None,
         train: bool = False,
     ) -> jax.Array:
-        if self.window_pattern not in ("all", "alternate"):
-            raise ValueError(
-                f"window_pattern must be 'all' or 'alternate', got "
-                f"{self.window_pattern!r}"
-            )
-
-        if self.mixers is not None and len(self.mixers) != self.depth:
-            raise ValueError(
-                f"mixers names {len(self.mixers)} blocks, depth is "
-                f"{self.depth}")
+        for name in ("mixers", "windows", "rope_layers"):
+            per_block = getattr(self, name)
+            if per_block is not None and len(per_block) != self.depth:
+                raise ValueError(
+                    f"{name} names {len(per_block)} blocks, depth is "
+                    f"{self.depth}")
 
         def body(mdl: TransformerBlock, h: jax.Array) -> jax.Array:
             # mask/train close over: constants to jax.checkpoint (no grads
@@ -1284,16 +1319,15 @@ class Encoder(nn.Module):
                 attn_impl=self.attn_impl,
                 causal=self.causal,
                 decode=self.decode,
-                rope=self.rope,
+                rope=self.rope and (self.rope_layers is None
+                                    or bool(self.rope_layers[i])),
                 rope_theta=self.rope_theta,
                 rope_scaling=self.rope_scaling,
                 rope_dim=self.rope_dim,
                 num_kv_heads=self.num_kv_heads,
                 fused_qkv=self.fused_qkv,
                 quant=self.quant,
-                window=(self.window
-                        if self.window_pattern == "all" or i % 2 == 0
-                        else None),
+                window=self.windows[i] if self.windows is not None else None,
                 rolling_cache=self.rolling_cache,
                 paged_blocks=self.paged_blocks,
                 kv_block=self.kv_block,
@@ -1315,6 +1349,8 @@ class Encoder(nn.Module):
                 router_z_loss_weight=self.router_z_loss_weight,
                 moe_held_experts=self.moe_held_experts,
                 moe_shared_expert_gated=self.moe_shared_expert_gated,
+                moe_router_pre_attention=(self.moe_router_pre_attention
+                                          and is_moe),
                 attention=self.attention,
                 eva_window=self.eva_window,
                 eva_chunk=self.eva_chunk,

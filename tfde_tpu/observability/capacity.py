@@ -82,6 +82,16 @@ def kv_slab_bytes(cache) -> int:
     return total
 
 
+def _kv_layer_cells(cache) -> list:
+    """Cells a row holds in each layer that caches keys by position, in
+    the tree's order: the second axis of every `cached_key` leaf."""
+    import jax
+
+    return [int(leaf.shape[1]) for path, leaf in
+            jax.tree_util.tree_leaves_with_path(cache)
+            if str(getattr(path[-1], "key", path[-1])) == "cached_key"]
+
+
 def kv_dtype_census(cache) -> dict:
     """Dtype split of a KV cache tree (index leaves and block tables
     excluded): payload vs scale-sidecar bytes, the payload leaf dtype,
@@ -160,13 +170,21 @@ class CapacityLedger:
                    model=None, params=None) -> "CapacityLedger":
         """Build a ledger from a freshly-initialized dense slab. The
         served `model`, where given, says which layout the slab has:
-        attention='eva' gets the ledger of windows and summaries, a model
+        attention='eva' gets the ledger of windows and summaries, a cache
+        in which some layer holds fewer cells a row than `cells_per_row`
+        (a window layer's ring) the ledger that counts a layer's cells at
+        a time, a model
         with state-space layers (whose rows cost the same whatever their
         length) or with experts routed without a capacity the hybrid
         one, which reads the experts' bytes off `params`."""
         if getattr(model, "attention", "full") == "eva":
             return EvaCapacityLedger.of_model(cache, batch_size, model,
                                               registry=registry)
+        if any(n < cells_per_row for n in _kv_layer_cells(cache)):
+            # some layer keeps a ring shorter than the row: the cache's
+            # own leaves say which, and how long
+            return RingCapacityLedger.of_model(
+                cache, batch_size, cells_per_row, params, registry=registry)
         if "mamba" in (getattr(model, "mixers", None) or ()) or (
                 getattr(model, "num_experts", 0)
                 and getattr(model, "moe_capacity_factor", 1.0) is None):
@@ -471,6 +489,15 @@ class HybridCapacityLedger(CapacityLedger):
         parameters: the state's bytes are those of the leaves named
         `ssm_state` / `conv_tail`, the experts' those of the leaves
         `experts_*` (their first axis counts the experts held)."""
+        return cls(batch_size, positions, kv_slab_bytes(cache),
+                   *cls._state_and_experts(cache, batch_size, params),
+                   registry=registry, census=kv_dtype_census(cache))
+
+    @classmethod
+    def _state_and_experts(cls, cache, batch_size: int, params) -> tuple:
+        """(a row's state bytes, the experts' bytes, how many (layer,
+        expert) slots they are) off the leaves' names, as `of_model`
+        says."""
         import jax
 
         name = lambda path: str(getattr(path[-1], "key", path[-1]))
@@ -485,10 +512,8 @@ class HybridCapacityLedger(CapacityLedger):
                      jax.tree_util.tree_leaves_with_path(params or {})
                      if name(path) == "experts_fc1")
         held = experts[0][0] if experts else 0
-        return cls(batch_size, positions, kv_slab_bytes(cache),
-                   state // batch_size, sum(b for _, b in experts),
-                   layers * held, registry=registry,
-                   census=kv_dtype_census(cache))
+        return (state // batch_size, sum(b for _, b in experts),
+                layers * held)
 
     def row_cells(self, n: int) -> int:
         return self._state_cells + (int(n) if self._positions else 0)
@@ -503,7 +528,7 @@ class HybridCapacityLedger(CapacityLedger):
 
     def note_scan(self, committed, depth: int) -> None:
         rows = len(committed)
-        cells = int(sum(int(n) for n in committed)) if self._positions else 0
+        cells = sum(self.row_cells(n) - self._state_cells for n in committed)
         with self._lock:
             self._counters["ssm_state_bytes_touched"] += (
                 depth * 2 * rows * self._state_row_bytes)
@@ -523,6 +548,79 @@ class HybridCapacityLedger(CapacityLedger):
         return int(depth * (int(param_bytes) - self._expert_bytes
                             + int(read_bytes))
                    + int(routed[2]) * self._slot_bytes)
+
+
+class RingCapacityLedger(HybridCapacityLedger):
+    """Occupancy of a cache whose window layers keep a ring of `window`
+    cells a row (models/transformer.py `_rolling_attention`: slot =
+    position mod window) beside the slabs of the layers without a window.
+
+    The unit is ONE layer's K and V of one position. A row that has
+    committed `n` tokens holds, and a decode tick reads, n cells in each
+    layer without a window and min(n, ring) in each window layer: which
+    layers are which, and each ring's length, are read off the cache's own
+    leaves (`of_model`). State-space state beside them and expert layers
+    routed without a capacity are the parent's account.
+
+    `counters` adds RING_KEYS to the parent's: per scan, depth x the cells
+    its active rows hold at its start, summed over the layers without a
+    window (`kv_full_cells_read`) and over the window layers
+    (`kv_window_cells_read`; the two make up `kv_cells_read`), and the
+    rows of a scan whose next write lands on a cell one window back: whose
+    shortest ring has turned (`kv_window_wraps`)."""
+
+    RING_KEYS = ("kv_full_cells_read", "kv_window_cells_read",
+                 "kv_window_wraps")
+
+    def __init__(self, batch_size: int, positions: int, layer_cells,
+                 slab_bytes: int, state_row_bytes: int = 0,
+                 expert_bytes: int = 0, expert_slots: int = 0,
+                 registry: Optional[metrics.Registry] = None,
+                 census: Optional[dict] = None):
+        layer_cells = [int(n) for n in layer_cells]
+        self._full_layers = sum(n >= positions for n in layer_cells)
+        self._rings = tuple(n for n in layer_cells if n < positions)
+        super().__init__(batch_size, sum(layer_cells), slab_bytes,
+                         state_row_bytes, expert_bytes, expert_slots,
+                         registry=registry, census=census)
+        self._counters.update(dict.fromkeys(self.RING_KEYS, 0))
+
+    @classmethod
+    def of_model(cls, cache, batch_size: int, positions: int, params,
+                 registry: Optional[metrics.Registry] = None
+                 ) -> "RingCapacityLedger":
+        """From a freshly-initialized batch cache and the served
+        parameters: every `cached_key` leaf gives one layer's cells a
+        row (`positions` of them in a layer without a window), the rest
+        as the parent reads it."""
+        return cls(batch_size, positions, _kv_layer_cells(cache),
+                   kv_slab_bytes(cache),
+                   *cls._state_and_experts(cache, batch_size, params),
+                   registry=registry, census=kv_dtype_census(cache))
+
+    def _kv_cells(self, n: int) -> tuple:
+        """(cells of the layers without a window, cells of the window
+        layers) a row holds once it has committed `n` tokens."""
+        return (self._full_layers * int(n),
+                sum(min(int(n), ring) for ring in self._rings))
+
+    def row_cells(self, n: int) -> int:
+        return self._state_cells + sum(self._kv_cells(n))
+
+    def read_cells(self, n: int) -> int:
+        return 2 * self._state_cells + sum(self._kv_cells(n))
+
+    def note_scan(self, committed, depth: int) -> None:
+        super().note_scan(committed, depth)
+        full = window = wraps = 0
+        for n in committed:
+            a, b = self._kv_cells(n)
+            full, window = full + a, window + b
+            wraps += int(n) >= min(self._rings, default=n + 1)
+        with self._lock:
+            self._counters["kv_full_cells_read"] += depth * full
+            self._counters["kv_window_cells_read"] += depth * window
+            self._counters["kv_window_wraps"] += wraps
 
 
 class PagedCapacityLedger(CapacityLedger):

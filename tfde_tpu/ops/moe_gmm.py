@@ -4,8 +4,9 @@
 pairs of a block out by expert, each expert's run starting at a multiple
 of `tile` rows, so that a tile of rows belongs to ONE expert. The kernel
 (`pallas_call(name="moe_gmm")`) walks the tiles in use: for a tile of rows
-x of expert e it computes `(silu(x Wg[e]) * (x W1[e])) W2[e]`, bfloat16 (the
-layer's dtype) operands, float32 accumulation in all three products, the
+x of expert e it computes `(act(x Wg[e]) * (x W1[e])) W2[e]` (`act` is
+silu, SwiGLU, or relu, ReGLU: a static parameter of the one kernel),
+bfloat16 (the layer's dtype) operands, float32 accumulation in all three products, the
 gated product in float32 before its cast: the arithmetic of three grouped
 matmuls, without the two float32 round trips through memory between them.
 
@@ -52,7 +53,12 @@ def tiles_bound(pairs: int, held: int, tile: int) -> int:
     return pairs // tile + held
 
 
-def _kernel(group_ref, live_ref, x_ref, wg_ref, w1_ref, w2_ref, o_ref):
+#: the gate's activation by name: 'silu' (SwiGLU) | 'relu' (ReGLU)
+ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def _kernel(group_ref, live_ref, x_ref, wg_ref, w1_ref, w2_ref, o_ref, *,
+            act):
     del group_ref
 
     @pl.when(pl.program_id(0) < live_ref[0])
@@ -60,7 +66,7 @@ def _kernel(group_ref, live_ref, x_ref, wg_ref, w1_ref, w2_ref, o_ref):
         x = x_ref[...]
         gate = jnp.dot(x, wg_ref[...], preferred_element_type=jnp.float32)
         up = jnp.dot(x, w1_ref[...], preferred_element_type=jnp.float32)
-        h = (jax.nn.silu(gate) * up).astype(x.dtype)
+        h = (act(gate) * up).astype(x.dtype)
         o_ref[...] = jnp.dot(
             h, w2_ref[...], preferred_element_type=jnp.float32
         ).astype(o_ref.dtype)
@@ -75,13 +81,16 @@ def _vmem_bytes(tile: int, d: int, f: int, itemsize: int) -> int:
     return weights + tiles + scratch + (8 << 20)
 
 
-@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
+@functools.partial(jax.jit, static_argnames=("tile", "act", "interpret"))
 def expert_mlps(rows, wg, w1, w2, tile_group, live, *, tile: int,
-                interpret: bool = False):
+                act: str = "silu", interpret: bool = False):
     """rows [T * tile, d], tile t of them belonging to expert
     `tile_group[t]` for t < `live` ([1] int32); wg / w1 [E, d, f], w2
-    [E, f, d]. Returns [T * tile, d]: the gated MLP of its tile's expert
-    for every row of a tile in use, undefined rows beyond."""
+    [E, f, d]; `act` names the gate's activation (`ACTS`). Returns
+    [T * tile, d]: the gated MLP of its tile's expert for every row of a
+    tile in use, undefined rows beyond."""
+    if act not in ACTS:
+        raise ValueError(f"act must be one of {sorted(ACTS)}, got {act!r}")
     m, d = rows.shape
     f = wg.shape[-1]
     tiles = m // tile
@@ -89,7 +98,7 @@ def expert_mlps(rows, wg, w1, w2, tile_group, live, *, tile: int,
     row_spec = pl.BlockSpec((tile, d), lambda t, g, n: (at(t, n), 0))
     expert = lambda t, g, n: (g[at(t, n)], 0, 0)
     return pl.pallas_call(
-        _kernel,
+        functools.partial(_kernel, act=ACTS[act]),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(tiles,),
