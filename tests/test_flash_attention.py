@@ -500,40 +500,89 @@ def test_lane_forward_matches_reference_and_grid(
                                rtol=0, atol=1e-5)
 
 
-@pytest.mark.parametrize("name,h,kv,d,causal,budget,path", [
-    ("mha_causal", 2, 2, 64, True, None, "lane"),
-    ("mha_non_causal", 2, 2, 64, False, None, "lane"),
-    ("one_head_of_128", 1, 1, 128, True, None, "lane"),
-    ("heads_under_a_lane_block", 4, 4, 8, True, None, "lane"),
-    ("grouped_query", 2, 1, 64, True, None, "grid"),
-    ("head_width_no_lane_block_tiles", 3, 3, 64, True, None, "grid"),
-    ("head_width_96", 4, 4, 96, True, None, "grid"),
-    ("past_vmem", 2, 2, 64, True, 0, "grid"),
+@pytest.mark.parametrize("name,s,h,kv,d,causal,window,budget,path", [
+    ("mha_causal", 128, 2, 2, 64, True, None, None, "lane"),
+    ("mha_non_causal", 128, 2, 2, 64, False, None, None, "lane"),
+    ("one_head_of_128", 128, 1, 1, 128, True, None, None, "lane"),
+    ("heads_under_a_lane_block", 128, 4, 4, 8, True, None, None, "lane"),
+    ("grouped_query", 128, 2, 1, 64, True, None, None, "grid"),
+    ("head_width_no_lane_block_tiles", 128, 3, 3, 64, True, None, None,
+     "grid"),
+    ("head_width_96", 128, 4, 4, 96, True, None, None, "grid"),
+    ("past_vmem", 128, 2, 2, 64, True, None, 0, "grid"),
+    ("grouped_query_of_128", 128, 4, 2, 128, True, None, None, "lane"),
+    ("seven_over_one_of_128", 128, 7, 1, 128, True, None, None, "lane"),
+    ("grouped_query_of_128_non_causal", 128, 4, 2, 128, False, None, None,
+     "lane"),
+    ("grouped_query_of_256", 128, 4, 1, 256, True, None, None, "lane"),
+    ("grouped_window_across_tile_edges", 384, 6, 2, 128, True, 100, None,
+     "lane"),
+    ("grouped_window_of_two_tiles", 256, 4, 2, 128, True, 128, None, "lane"),
+    ("grouped_query_of_64_pairs", 128, 4, 2, 64, True, None, None, "grid"),
+    ("grouped_query_of_128_past_vmem", 128, 4, 2, 128, True, None, 0,
+     "grid"),
 ])
-def test_fwd_chooses_from_its_operands(rng, monkeypatch, name, h, kv, d,
-                                       causal, budget, path):
+def test_fwd_chooses_from_its_operands(rng, monkeypatch, name, s, h, kv, d,
+                                       causal, window, budget, path):
     """`_flash_forward` counts the kernel it took at trace time: the lane
-    kernel for multi-head attention whose heads tile the lanes, the grid
-    kernel for grouped-query, head widths no lane block tiles, and a head
-    block past the VMEM budget; the output matches either way."""
+    kernel for multi-head attention whose heads tile the lanes and for
+    grouped-query attention whose K/V heads are whole lane blocks (heads
+    of 128), the grid kernel for grouped-query heads of 64, head widths no
+    lane block tiles, and a block past the VMEM budget; out and lse match
+    the float32 reference either way, and the lane kernel's loop ran the
+    K steps of the plan, once for the whole group."""
     from tfde_tpu.ops.attention import grouped_attention
 
     if budget is not None:
         monkeypatch.setattr(
             "tfde_tpu.ops.flash_attention._FWD_KERNEL_VMEM_BUDGET", budget)
-    q = jnp.asarray(rng.standard_normal((1, 128, h, d)), jnp.float32)
-    k, v = (jnp.asarray(rng.standard_normal((1, 128, kv, d)), jnp.float32)
+    q = jnp.asarray(rng.standard_normal((1, s, h, d)), jnp.float32)
+    k, v = (jnp.asarray(rng.standard_normal((1, s, kv, d)), jnp.float32)
             for _ in range(2))
-    out, lse, counts, bumped = _forward_and_path(q, k, v, causal, 64, 64)
+    out, lse, counts, bumped = _forward_and_path(q, k, v, causal, 64, 64,
+                                                 window)
     assert counts["fwd_path"] == path
     assert bumped == {"fwd_lane_traces": int(path == "lane"),
                       "fwd_grid_traces": int(path == "grid")}
     assert ("fwd_steps_executed" in counts) == (path == "lane")
-    assert lse.shape == (1, h, 128)
+    if path == "lane":
+        assert counts["fwd_steps_executed"] == counts["fwd_visits"]
+    assert out.shape == q.shape and lse.shape == (1, h, s)
     np.testing.assert_allclose(
-        np.asarray(out), np.asarray(grouped_attention(q, k, v,
-                                                      causal=causal)),
+        np.asarray(out), np.asarray(grouped_attention(
+            q, k, v, causal=causal, window=window)),
         rtol=2e-5, atol=2e-5)
+    # a query head h reads K/V head h // group: lse in that order
+    _, want_lse = _reference_out_and_lse(
+        q, np.repeat(k, h // kv, axis=2), np.repeat(v, h // kv, axis=2),
+        causal, window, None, None)
+    np.testing.assert_allclose(np.asarray(lse, np.float64), want_lse,
+                               rtol=2e-5, atol=2e-5)
+
+
+#: sha256 of the Mosaic module the forward lowers to at the training
+#: cells' shape ([2, 4096, 16, 64] bf16, causal, two heads of 64 a lane
+#: block), without locations; taken on the tree before grouped-query heads
+#: of 128 took the lane kernel (PR 35's parent)
+_TRAINING_CELLS_FORWARD = ("f126b7619c156011bee783b575dd0b38"
+                           "d0cdf5819b4bbe38e5de4fea39233433")
+
+
+def test_the_training_cells_forward_lowers_to_the_module_it_lowered_to():
+    """What the group adds to the lane kernel is decided while it is
+    traced: at as many K/V heads as query heads the kernel Mosaic is
+    handed is the same to the character."""
+    import hashlib
+
+    from test_moe import _mosaic_module
+
+    q = jax.ShapeDtypeStruct((2, 4096, 16, 64), jnp.bfloat16)
+    text = jax.jit(lambda q, k, v: flash_attention(
+        q, k, v, causal=True)).trace(q, q, q).lower(
+            lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert hashlib.sha256(_mosaic_module(text).encode()).hexdigest() \
+        == _TRAINING_CELLS_FORWARD
 
 
 def test_fwd_leaves_what_does_not_fit_vmem_to_the_grid():
@@ -548,6 +597,12 @@ def test_fwd_leaves_what_does_not_fit_vmem_to_the_grid():
     assert fa._fwd_lane_vmem_bytes(131072, 2, 128, 2, 512, 512) \
         > fa._FWD_KERNEL_VMEM_BUDGET
     assert fa._fwd_lane_vmem_bytes(65536, 2, 128, 4, 512, 512) \
+        > fa._FWD_KERNEL_VMEM_BUDGET
+    # grouped-query: one K/V head of 128 whole, seven query heads' tiles
+    # and scores beside it (the window-and-global cell's longest wave)
+    assert fa._fwd_lane_vmem_bytes(14336, 1, 128, 2, 512, 512, 7) \
+        <= fa._FWD_KERNEL_VMEM_BUDGET // 2
+    assert fa._fwd_lane_vmem_bytes(131072, 1, 128, 2, 512, 512, 7) \
         > fa._FWD_KERNEL_VMEM_BUDGET
 
 
@@ -568,21 +623,33 @@ def test_k_tile_range_is_the_band(s, bq, bk, causal, window):
     assert pairs == set(fa._band_tile_pairs(s, bq, bk, causal, window))
 
 
-@pytest.mark.parametrize("name,causal,fwd_budget,bwd_budget,paths", [
-    ("lane_into_fused", True, None, None, ("lane", "kernel")),
-    ("lane_into_pair_scan", True, None, 0, ("lane", "recurrence")),
-    ("lane_into_k_tile_scan", False, None, None, ("lane", "recurrence")),
-    ("grid_into_fused", True, 0, None, ("grid", "kernel")),
-    ("grid_into_pair_scan", True, 0, 0, ("grid", "recurrence")),
+@pytest.mark.parametrize("name,heads,causal,fwd_budget,bwd_budget,paths", [
+    ("lane_into_fused", (4, 4, 64), True, None, None, ("lane", "kernel")),
+    ("lane_into_pair_scan", (4, 4, 64), True, None, 0,
+     ("lane", "recurrence")),
+    ("lane_into_k_tile_scan", (4, 4, 64), False, None, None,
+     ("lane", "recurrence")),
+    ("grid_into_fused", (4, 4, 64), True, 0, None, ("grid", "kernel")),
+    ("grid_into_pair_scan", (4, 4, 64), True, 0, 0, ("grid", "recurrence")),
+    ("grouped_lane_into_pair_scan", (4, 2, 128), True, None, None,
+     ("lane", "recurrence")),
+    ("seven_over_one_lane_into_pair_scan", (7, 1, 128), True, None, None,
+     ("lane", "recurrence")),
+    ("grouped_lane_into_k_tile_scan", (4, 2, 128), False, None, None,
+     ("lane", "recurrence")),
+    ("grouped_grid_into_pair_scan", (4, 2, 128), True, 0, None,
+     ("grid", "recurrence")),
 ])
 @pytest.mark.parametrize("window,cap", [(None, None), (96, 20.0)],
                          ids=["plain", "window_and_cap"])
 def test_gradients_through_each_forward_and_backward(
-        rng, monkeypatch, name, causal, fwd_budget, bwd_budget, paths,
+        rng, monkeypatch, name, heads, causal, fwd_budget, bwd_budget, paths,
         window, cap):
     """`jax.grad` of `flash_attention` against autodiff through the
     reference, for each forward handing its lse (rows or [B, H, S]) to
-    each backward."""
+    each backward; under grouped-query (query heads, K/V heads, width) the
+    recurrence reads the lane forward's rows through `_lse_bhs`, a K/V
+    head's group side by side."""
     if not causal:
         window = None
     if fwd_budget is not None:
@@ -593,7 +660,10 @@ def test_gradients_through_each_forward_and_backward(
         monkeypatch.setattr(
             "tfde_tpu.ops.flash_attention._BWD_KERNEL_VMEM_BUDGET",
             bwd_budget)
-    q, k, v = _qkv(rng, b=2, s=256, h=4, d=64)
+    h, kv, d = heads
+    q = jnp.asarray(rng.standard_normal((2, 256, h, d)), jnp.float32)
+    k, v = (jnp.asarray(rng.standard_normal((2, 256, kv, d)), jnp.float32)
+            for _ in range(2))
     got, want, counts, _ = _grads_and_path(q, k, v, causal, 64, 128, window,
                                            cap)
     assert (counts["fwd_path"], counts["bwd_path"]) == paths

@@ -64,14 +64,19 @@ def test_flash_gradient_compiles_for_v5e(one_chip, name, shape, dtype,
     ("s65536", (1, 65536, 2, 64), 2, True, None),
     ("grouped_query_grid", (1, 2048, 4, 64), 2, True, None),
     ("s131072_grid", (1, 131072, 2, 64), 2, True, None),
+    ("window_layer_wave", (1, 14336, 28, 128), 4, True, 4096),
+    ("global_layer_wave", (1, 14336, 28, 128), 4, True, None),
+    ("two_rows_of_9216", (2, 9216, 28, 128), 4, True, None),
+    ("hybrid_attention_wave", (1, 6144, 32, 128), 8, True, None),
 ])
 def test_flash_forward_compiles_for_v5e(one_chip, name, shape, kv_heads,
                                         causal, window):
     """The forward alone, over operands the gradient cases above do not
     reach: each arrangement `_flash_forward` can choose is one Mosaic call
     the v5e compiler takes (lane widths under and over 128, K and V of a
-    long sequence whole in VMEM, and the grid kernel where they are
-    not)."""
+    long sequence whole in VMEM, the window-and-global cell's seven query
+    heads of 128 and the hybrid's four against the K/V head they share, in
+    their longest waves, and the grid kernel where none of that holds)."""
     b, s, h, d = shape
     q = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
     k = jax.ShapeDtypeStruct((b, s, kv_heads, d), jnp.bfloat16,
@@ -82,8 +87,12 @@ def test_flash_forward_compiles_for_v5e(one_chip, name, shape, kv_heads,
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert "flash_fwd" in text
     assert "while(" not in text
-    grid = name.endswith("_grid")
-    assert (f"[{b},{h},{s},{d}]" in text) == grid
+    # the grid kernel and the lane kernel's grouped-query form read the
+    # [B, H, S, D] view; multi-head attention reads the model's layout
+    head_major = name.endswith("_grid") or kv_heads < h
+    assert (f"[{b},{h},{s},{d}]" in text) == head_major
+    # the grid kernel's lse is a column a head, the lane kernel's rows
+    assert (f"f32[{b},{h},{s},1]" in text) == name.endswith("_grid")
 
 
 #: (experts held, experts routed over, choices a token, hidden, expert

@@ -293,6 +293,37 @@ def test_a_long_prefill_takes_the_other_attention_paths(weights, params,
     assert np.abs(got - reference_logits(weights, row)).max() < TOL
 
 
+def test_a_long_prefill_of_heads_of_128_takes_the_lane_forward(monkeypatch):
+    """Two query heads of 128 over one K/V head, a cold wave of 384
+    positions (three tiles of 128) past `_PREFILL_SCORES_BYTES`: the one
+    attention layer traces the lane flash forward once and the grid
+    forward never, and the wave and the steps after it serve the
+    reference's logits."""
+    from tfde_tpu.models import transformer
+    from tfde_tpu.observability import counters
+
+    dims = dict(DIMS, hidden_size=256, num_attention_heads=2,
+                num_key_value_heads=1, mamba_n_heads=16)
+    weights = ref.make_weights(11, dims)
+    params = as_float32(ref.to_program_params(weights, dims))
+    model = hybrid_model(
+        hidden_size=256, num_heads=2, num_kv_heads=1, attn_impl="flash",
+        ssm=ssm_lib.SSMShape(heads=16, head_dim=16, state=16, groups=1,
+                             conv=4, chunk=CHUNK))
+    monkeypatch.setattr(transformer, "_PREFILL_SCORES_BYTES", 0)
+    monkeypatch.setattr(transformer, "_PREFILL_QUERY_BLOCK", 128)
+    rows, lengths = rows_of(5, [300, 386]), [298, 384]
+    before = counters.snapshot()
+    got, _ = served_logits(model, params, rows, lengths, 384, 400)
+    traced = {k: counters.value(f"flash/{k}") - before.get(f"flash/{k}", 0)
+              for k in ("fwd_lane_traces", "fwd_grid_traces")}
+    assert traced == {"fwd_lane_traces": LAYERS.count("attention"),
+                      "fwd_grid_traces": 0}
+    for row, n, logits in zip(rows, lengths, got):
+        want = reference_logits(weights, row, dims)[n - 1:]
+        assert np.abs(logits - want).max() < TOL
+
+
 # ways to get the model wrong, each of which must show
 def _dt_not_zeroed_on_the_pads(monkeypatch):
     real = ssm_lib.prefill

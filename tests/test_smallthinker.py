@@ -291,6 +291,32 @@ def test_a_long_prefill_goes_through_the_dispatcher(weights, params,
     assert seen.count(WINDOW) == 6 and seen.count(None) == 2
 
 
+def test_a_long_prefill_of_heads_of_128_takes_the_lane_forward(monkeypatch):
+    """Four query heads of 128 over two K/V heads, a wave of 384 positions
+    (three tiles of 128, the window of 8 across their edges) past
+    `_PREFILL_SCORES_BYTES`: each of the eight layers traces the lane flash
+    forward once and the grid forward never, and the wave and the steps
+    after it serve the reference's logits."""
+    from tfde_tpu.observability import counters
+
+    dims = dict(DIMS, head_dim=128)
+    weights = ref.make_weights(11, dims)
+    params = jax.tree.map(lambda x: x.astype(jnp.float32),
+                          ref.to_program_params(weights))
+    monkeypatch.setattr(transformer, "_PREFILL_SCORES_BYTES", 0)
+    monkeypatch.setattr(transformer, "_PREFILL_QUERY_BLOCK", 128)
+    rows, lengths = rows_of(5, [300, 386]), [298, 384]
+    before = counters.snapshot()
+    got, _ = served_logits(window_model(head_dim=128, attn_impl="flash"),
+                           params, rows, lengths, 384, 400)
+    traced = {k: counters.value(f"flash/{k}") - before.get(f"flash/{k}", 0)
+              for k in ("fwd_lane_traces", "fwd_grid_traces")}
+    assert traced == {"fwd_lane_traces": len(LAYOUT), "fwd_grid_traces": 0}
+    for row, n, logits in zip(rows, lengths, got):
+        want = np.asarray(ref.forward(weights, jnp.asarray(row), dims))
+        assert np.abs(logits - want[n - 1:]).max() < TOL
+
+
 # ways to get the model wrong, each of which must show
 def _band_dropped_on_a_window_layer(monkeypatch):
     return dict(windows=(None,) * 5 + (WINDOW,) * 3)

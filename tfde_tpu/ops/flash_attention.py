@@ -8,28 +8,36 @@ Forward is a Pallas kernel (per /opt/skills/guides/pallas_guide.md), in one
 of two arrangements of the same online softmax (float32 scores and
 statistics, p rounded to the input dtype before p V, the [Sq, Sk] score
 matrix never materialized: O(S) memory). `_flash_forward` decides from
-its operands, as `_bwd` does: as many K/V heads as Q heads, whole heads
-tiling the 128 lanes (`_bwd_heads_per_block`) and a head block's K and V
-inside VMEM take the lane kernel; grouped-query, head widths no lane block
-tiles and longer sequences keep the grid kernel.
-- the lane kernel (`_fwd_lane_kernel`) reads q, k, v and writes out as
-  blocks of the free [B, S, H*D] view of the model's [B, S, H, D] layout,
-  so nothing is transposed in HBM; a block of whole heads fills the lanes
-  (two heads at D=64, each head's Q with the other's lanes zeroed); scores
-  are K-major (z^T = K Q^T, [bk, bq]), so the running max and sum are
-  [1, bq] lane vectors reduced down the sublanes and the accumulator is
-  kept transposed; grid (batch, head block, Q tile) with K and V of the
-  head block resident in VMEM (fetched once a head block) and an in-kernel
-  loop over the K tiles `_tile_in_band` keeps (`_k_tile_range`), so
-  out-of-band tiles cost nothing and the body is compiled once. lse leaves
-  it as the lane vectors the fused backward reads.
+its operands, as `_bwd` does: whole K/V heads tiling the 128 lanes
+(`_bwd_heads_per_block`), as many of them as Q heads or, under
+grouped-query, each a lane block of its own (heads of 128 or 256), and a
+lane block's K and V inside VMEM take the lane kernel; grouped-query with
+heads of 64, head widths no lane block tiles and longer sequences keep the
+grid kernel.
+- the lane kernel (`_fwd_lane_kernel`): grid (batch, K/V lane block, Q
+  tile) with K and V of the lane block resident in VMEM (fetched once a
+  block) and an in-kernel loop over the K tiles `_tile_in_band` keeps
+  (`_k_tile_range`), so out-of-band tiles cost nothing and the body is
+  compiled once; scores are K-major (z^T = K Q^T, [bk, bq]), so the
+  running max and sum are [1, bq] lane vectors reduced down the sublanes
+  and the accumulator is kept transposed. Multi-head attention reads q,
+  k, v and writes out as blocks of the free [B, S, H*D] view of the
+  model's [B, S, H, D] layout, so nothing is transposed in HBM; a block
+  of whole heads fills the lanes (two heads at D=64, each head's Q with
+  the other's lanes zeroed). Grouped-query attention takes the
+  [B, H, S, D] view and all the query heads of a K/V head a step (seven
+  at 28 over 4), so each K and V tile is read once for the group. lse
+  leaves it as the lane vectors the fused backward reads.
 - the grid kernel (`_fwd_kernel`) works in BHSD (three `swapaxes` in, one
   out): grid (batch, heads, Sq/block_q, Sk/block_k) with K minor, one Q
   tile and one K/V tile VMEM-resident per step (VMEM stays O(block) at any
   S), the online-softmax state in VMEM scratch across the K steps of an
   output block; out-of-band K tiles are predicated off (pl.when) with
   their DMA elided; grouped-query heads fold onto their K/V head in the
-  index maps.
+  index maps, a K/V tile fetched again for each of them. It stays for
+  what the lane kernel cannot hold: grouped-query with heads of 64 (a
+  K/V head is half a lane block), head widths that tile no lane block,
+  and K and V past the VMEM budget.
 - tiles default to the largest MXU multiple of 512/256/128 dividing S
   (`_auto_block`: the r04 hardware sweep measured 512-edge tiles
   1.25-1.45x over 128 at every shape tried; the lane kernel again, PR 30).
@@ -322,17 +330,19 @@ def _k_tile_range(qi, block_q: int, block_k: int, n_k: int, causal, window):
 
 
 def _fwd_lane_vmem_bytes(s: int, heads: int, width: int, itemsize: int,
-                         block_q: int, block_k: int) -> int:
-    """What `_fwd_lane_kernel` keeps in VMEM for one block of `heads`
-    heads: K and V whole and double-buffered, the Q and out tiles
-    double-buffered, the float32 accumulator, lse and the statistics
-    padded to 8 sublanes, and the float32 score tiles: every head's at
-    once beside the compiler's own (a dozen at two heads)."""
+                         block_q: int, block_k: int, group: int = 1) -> int:
+    """What `_fwd_lane_kernel` keeps in VMEM for one K/V lane block of
+    `width` lanes (`heads` K/V heads) and the `group` query heads of each:
+    K and V whole and double-buffered, the Q and out tiles (`group` of
+    them) double-buffered, the float32 accumulator, lse and the statistics
+    padded to 8 sublanes, and the float32 score tiles: every query head's
+    at once beside the compiler's own (a dozen at two heads)."""
     w = max(width, 128)
     whole = 2 * 2 * s * w * itemsize
-    tiles = block_q * w * (2 * 2 * itemsize + 4)
-    rows = 4 * 8 * block_q * 4
-    return whole + tiles + rows + (8 + 2 * heads) * block_q * block_k * 4
+    tiles = block_q * w * group * (2 * 2 * itemsize + 4)
+    rows = 4 * 8 * block_q * 4 * group
+    return (whole + tiles + rows
+            + (8 + 2 * heads * group) * block_q * block_k * 4)
 
 
 # The lane forward runs where a head block's working set stays under this
@@ -344,16 +354,25 @@ def _fwd_lane_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
     *, causal, scale, window, logit_cap, heads, block_k, on_step=None,
 ):
-    # grid (B, H/heads, S/bq). Operands are blocks of the [B, S, H*D] view
-    # the model has: q/out one Q tile of this block of heads, k/v whole in
-    # S (their block index does not move with the Q tile, so they are
-    # fetched once a head block). Scores are K-major, z^T = K Q^T
-    # [bk, bq]: the running max and sum are [1, bq] lane vectors reduced
-    # down the sublanes, and the accumulator is kept transposed [w, bq] so
-    # the correction broadcasts along its sublanes; one transpose a Q tile
-    # puts the output back in the model's layout.
+    # grid (B, Kv/heads, S/bq); k/v are whole in S (their block index does
+    # not move with the Q tile, so they are fetched once a block) and
+    # q/out one Q tile of the query heads that read them, in one of two
+    # views of the model's [B, S, H, D]:
+    # - multi-head: blocks of the free [B, S, H*D] view, `heads` whole
+    #   heads side by side in the lanes, q/out [1, bq, w] over the same
+    #   lanes as k/v [1, S, w];
+    # - grouped-query with heads of whole lane blocks: the [B, H, S, D]
+    #   view, q/out [1, group, bq, d] the query heads of the one K/V head
+    #   k/v [1, S, d], so each K and V tile is read from VMEM once for the
+    #   whole group.
+    # Scores are K-major, z^T = K Q^T [bk, bq]: the running max and sum
+    # are [1, bq] lane vectors reduced down the sublanes, and the
+    # accumulator is kept transposed [query heads * d, bq] so the
+    # correction broadcasts along its sublanes; one transpose a Q tile
+    # puts the output back in the operands' layout.
     qi = pl.program_id(2)
-    bq, w = q_ref.shape[1], q_ref.shape[2]
+    grouped = len(q_ref.shape) == 4
+    bq, w = q_ref.shape[-2], q_ref.shape[-1]
     bk = block_k
     n_k = k_ref.shape[1] // bk
     d = w // heads
@@ -362,10 +381,12 @@ def _fwd_lane_kernel(
 
     acc_ref[...] = jnp.zeros_like(acc_ref)
     q2 = q_ref[0]
-    # heads side by side in the lanes: a head's Q with the other heads'
-    # lanes zeroed contracts over the whole block against the unmasked K,
-    # and each head's output keeps its own rows of V^T p
-    if heads > 1:
+    if grouped:
+        qs = [q2[g] for g in range(q2.shape[0])]
+    elif heads > 1:
+        # heads side by side in the lanes: a head's Q with the other
+        # heads' lanes zeroed contracts over the whole block against the
+        # unmasked K, and each head's output keeps its own rows of V^T p
         lane_head = jax.lax.broadcasted_iota(jnp.int32, (1, w), 1) // d
         qs = [jnp.where(lane_head == h, q2, 0) for h in range(heads)]
     else:
@@ -394,7 +415,7 @@ def _fwd_lane_kernel(
             for q_h in qs
         ]                                    # [bk, bq] each
         new = []
-        for h in range(heads):
+        for h in range(len(qs)):
             z = zs[h] * scale
             if logit_cap is not None:
                 z, _ = _apply_cap(z, logit_cap)
@@ -410,73 +431,109 @@ def _fwd_lane_kernel(
                 v2, p.astype(v2.dtype), (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
             own = slice(h * d, (h + 1) * d)
-            acc_ref[own, :] = acc_ref[own, :] * corr + pv[own, :]
+            acc_ref[own, :] = acc_ref[own, :] * corr + (
+                pv if grouped else pv[own, :])
         return tuple(new)
 
     lo, hi = _k_tile_range(qi, bq, bk, n_k, causal, window)
     stats = jax.lax.fori_loop(lo, hi + 1, step, tuple(
         (jnp.full((1, bq), _NEG, jnp.float32),
          jnp.zeros((1, bq), jnp.float32))
-        for _ in range(heads)))
+        for _ in range(len(qs))))
     for h, (m, l) in enumerate(stats):
         own = slice(h * d, (h + 1) * d)
         l = jnp.maximum(l, 1e-20)
-        acc_ref[own, :] = acc_ref[own, :] / l
+        if grouped:
+            o_ref[0, h] = (acc_ref[own, :] / l).T.astype(o_ref.dtype)
+        else:
+            acc_ref[own, :] = acc_ref[own, :] / l
         lse_ref[0, 0, 0, h:h + 1, :] = m + jnp.log(l)
-    o_ref[0] = acc_ref[...].T.astype(o_ref.dtype)
+    if not grouped:
+        o_ref[0] = acc_ref[...].T.astype(o_ref.dtype)
 
 
+@functools.partial(jax.jit, static_argnames=(
+    "heads", "causal", "block_q", "block_k", "interpret", "window", "scale",
+    "logit_cap", "on_step"))
 def _flash_forward_lanes(q, k, v, *, heads: int, causal: bool, block_q: int,
                          block_k: int, interpret: bool, window, scale: float,
-                         logit_cap):
-    """Multi-head forward in the model's own layout: q, k, v and out are
-    blocks of the [B, S, H*D] view (nothing is transposed in HBM), a block
-    of whole heads fills the lanes, and an in-kernel loop walks the K
-    tiles `_tile_in_band` keeps for the Q tile. Returns out [B, S, H, D]
-    and lse as the lane vectors the kernel wrote,
-    [B, H/heads, S/block_q, heads, block_q]: what the fused backward
-    reads as it is, and `_lse_bhs` puts in order for the recurrences."""
+                         logit_cap, on_step=None):
+    """The forward with K and V of a lane block (`heads` whole K/V heads)
+    resident in VMEM and an in-kernel loop over the K tiles
+    `_tile_in_band` keeps for the Q tile.
+
+    Multi-head attention reads q, k, v and writes out as blocks of the
+    free [B, S, H*D] view of the model's layout: nothing is transposed in
+    HBM. Grouped-query attention (heads of whole lane blocks: `heads` is
+    1) takes the [B, H, S, D] view, a K/V head's whole group of query
+    heads a step, so K and V are fetched once a K/V head and each of their
+    tiles read once for the group. The `swapaxes` around it are the grid
+    kernel's: in the serving prefill XLA lays q and out heads-outside
+    around the call, so they are bitcasts there, where the [B, S, H*D]
+    view cost a transposing copy of q and of out a layer (PERF.md
+    section 6, PR 35).
+
+    Returns out [B, S, H, D] and lse as the lane vectors the kernel
+    wrote, [B, lane blocks, S/block_q, query heads a block, block_q],
+    query heads in order: what the fused backward reads as it is
+    (multi-head), and `_lse_bhs` puts in order for the recurrences.
+
+    Jitted on its own so that the layers of a program, and the programs
+    of a process, that call it on one shape share a trace and a lowering:
+    a group of seven unrolled is a body seven times as long to lower, and
+    a serving start lowers it some seventy times (`setup_s`). `on_step`
+    is the interpreted recorder's callback (`_first_block_counter`)."""
     b, s, h, d = q.shape
+    kv = k.shape[2]
+    group = h // kv
     from jax.experimental.pallas import tpu as pltpu
 
-    on_step = None
-    if _TILE_COUNTS is not None and interpret:
-        on_step = _first_block_counter(_TILE_COUNTS, "fwd_steps_executed")
     n_q, w = s // block_q, heads * d
-    flat = lambda x: x.reshape(b, s, h * d)
-    tile = pl.BlockSpec((1, block_q, w), lambda bi, hi, qi: (bi, qi, hi))
-    whole = pl.BlockSpec((1, s, w), lambda bi, hi, qi: (bi, 0, hi))
+    if group > 1:
+        operands = [jnp.swapaxes(t, 1, 2) for t in (q, k, v)]
+        tile = pl.BlockSpec((1, group, block_q, d),
+                            lambda bi, hi, qi: (bi, hi, qi, 0))
+        whole = pl.BlockSpec((1, None, s, d),
+                             lambda bi, hi, qi: (bi, hi, 0, 0))
+    else:
+        operands = [t.reshape(b, s, h * d) for t in (q, k, v)]
+        tile = pl.BlockSpec((1, block_q, w), lambda bi, hi, qi: (bi, qi, hi))
+        whole = pl.BlockSpec((1, s, w), lambda bi, hi, qi: (bi, 0, hi))
+    members = heads * group
     out, lse = pl.pallas_call(
         functools.partial(_fwd_lane_kernel, causal=causal, scale=scale,
                           window=window, logit_cap=logit_cap, heads=heads,
                           block_k=block_k, on_step=on_step),
-        grid=(b, h // heads, n_q),
+        grid=(b, kv // heads, n_q),
         in_specs=[tile, whole, whole],
         out_specs=[
             tile,
-            pl.BlockSpec((1, 1, 1, heads, block_q),
+            pl.BlockSpec((1, 1, 1, members, block_q),
                          lambda bi, hi, qi: (bi, hi, qi, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, s, h * d), q.dtype),
-            jax.ShapeDtypeStruct((b, h // heads, n_q, heads, block_q),
+            jax.ShapeDtypeStruct(operands[0].shape, q.dtype),
+            jax.ShapeDtypeStruct((b, kv // heads, n_q, members, block_q),
                                  jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((w, block_q), jnp.float32)],  # acc^T
+        scratch_shapes=[pltpu.VMEM((members * d, block_q), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel"),
             vmem_limit_bytes=_fwd_lane_vmem_bytes(
-                s, heads, w, q.dtype.itemsize, block_q, block_k),
+                s, heads, w, q.dtype.itemsize, block_q, block_k, group),
         ),
         interpret=interpret,
         name="flash_fwd",
-    )(flat(q), flat(k), flat(v))
+    )(*operands)
+    if group > 1:
+        return jnp.swapaxes(out, 1, 2), lse
     return out.reshape(q.shape), lse
 
 
 def _lse_bhs(lse):
     """lse as [B, H, S]: the grid forward's as it is, the lane forward's
-    rows [B, H/heads, S/block_q, heads, block_q] put back in order."""
+    rows [B, lane blocks, S/block_q, query heads a block, block_q] put
+    back in order."""
     if lse.ndim == 3:
         return lse
     b, blocks, n_q, heads, bq = lse.shape
@@ -504,10 +561,6 @@ def _flash_forward(
         raise ValueError(
             f"query heads {h} must be a multiple of kv heads {kv} (GQA)"
         )
-    # GQA: the grid stays per-QUERY-head; each q head's K/V index map folds
-    # onto its serving KV head (hi // group). The kernel body never sees the
-    # grouping, and the [B,S,H,D] K/V expansion of a repeat-then-attend
-    # formulation never exists in HBM — the bandwidth saving GQA is for.
     group = h // kv
     auto_blocks = block_q is None and block_k is None
     block_q = _resolve_block(block_q, s)
@@ -531,14 +584,16 @@ def _flash_forward(
     from tfde_tpu.observability import counters
 
     # The lane kernel where it applies, the grid kernel elsewhere, decided
-    # from the operands as `_bwd` decides: as many K/V heads as Q heads (a
-    # lane block holds a head's own K/V), whole heads tiling the 128
-    # lanes, and a head block's working set (K and V whole) inside VMEM.
-    heads = _bwd_heads_per_block(h, d)
+    # from the operands as `_bwd` decides: whole K/V heads tiling the 128
+    # lanes, as many of them as Q heads (a lane block holds its heads' own
+    # K/V) or each a lane block of its own under a group of Q heads, and a
+    # lane block's working set (K and V whole) inside VMEM.
+    heads = _bwd_heads_per_block(kv, d)
     lanes = (
-        kv == h and heads is not None
+        heads is not None and (group == 1 or d % 128 == 0)
         and _fwd_lane_vmem_bytes(s, heads, heads * d, q.dtype.itemsize,
-                                 block_q, block_k) <= _FWD_KERNEL_VMEM_BUDGET
+                                 block_q, block_k, group)
+        <= _FWD_KERNEL_VMEM_BUDGET
     )
     counters.incr("flash/fwd_lane_traces" if lanes
                   else "flash/fwd_grid_traces")
@@ -552,10 +607,18 @@ def _flash_forward(
         _TILE_COUNTS["block_q"] = block_q
         _TILE_COUNTS["block_k"] = block_k
     if lanes:
+        on_step = None
+        if _TILE_COUNTS is not None and interpret:
+            on_step = _first_block_counter(_TILE_COUNTS,
+                                           "fwd_steps_executed")
         return _flash_forward_lanes(
             q, k, v, heads=heads, causal=causal, block_q=block_q,
             block_k=block_k, interpret=interpret, window=window, scale=scale,
-            logit_cap=logit_cap)
+            logit_cap=logit_cap, on_step=on_step)
+    # The grid stays per QUERY head; under grouped-query each q head's K/V
+    # index map folds onto its serving KV head (hi // group), so the kernel
+    # body never sees the grouping and the [B,S,H,D] K/V expansion of a
+    # repeat-then-attend formulation never exists in HBM.
     kernel = functools.partial(_fwd_kernel, causal=causal, scale=scale,
                                window=window, logit_cap=logit_cap)
     # BSHD -> BHSD so the S/D dims are the TPU-tiled trailing pair
@@ -1070,8 +1133,11 @@ def flash_attention(
     """softmax(cap(QK^T * scale))V over [B, S, H, D], O(S) memory.
 
     GQA: k/v may carry fewer heads [B, S, Kv, D] with H a multiple of Kv —
-    the grid stays per-query-head and each q head's K/V DMA folds onto its
-    serving KV head, so the repeat-expanded K/V never exists in HBM.
+    query head h reads K/V head h // (H / Kv) and the repeat-expanded K/V
+    never exists in HBM: heads of whole lane blocks run a K/V head's group
+    a step of the lane forward against its resident K and V, narrower ones
+    the per-query-head grid whose K/V DMA folds onto the serving head. The
+    backward under GQA is the recurrence, whichever forward ran.
 
     window: sliding-window band (requires causal) — position i attends the
     last `window` positions inclusive; out-of-band K tiles are skipped
