@@ -760,6 +760,10 @@ class _BatcherBase:
         # pad-ladder bucket, not per wave)
         self._rc_tag = next(_BATCHER_TAGS)
         self._mem_programs: set = set()
+        # the scan is traced where the memory ledger interrogates it, just
+        # before its watch opens: by name that trace is serve/decode's too
+        _recompile.site("serve/decode", stable=True).claim(
+            _decode_scan.__name__)
         # (model, wave width, cache length, kv_quant) -> the compiled
         # zero-fill program of that row cache (`_zero_rows`)
         self._zero_programs: dict = {}

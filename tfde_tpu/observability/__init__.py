@@ -12,7 +12,7 @@
 - flightrec.py   crash-dump flight recorder ring
 - aggregate.py   cross-host metric aggregation + trace stitching
 - memwatch.py    measured memory ledger (mem/*, TFDE_MEMWATCH)
-- recompile.py   jit-cache-miss sentinel (compile/*)
+- recompile.py   jit-cache-miss sentinel and set-up ledger (compile/*, setup())
 """
 
 from tfde_tpu.observability.tensorboard import SummaryWriter  # noqa: F401
