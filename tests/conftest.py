@@ -6,18 +6,37 @@ backend is created, which is lazy — so configuring it here, before first
 device use, takes effect.
 """
 
+import contextlib
+import faulthandler
+import hashlib
 import os
+import signal
+import tempfile
+import threading
+import time
+
+#: The most seconds one test may run, its own fixtures' set-up included.
+#: The slowest honest test of a run beside a second suite took 113 s (59 s
+#: on an idle box; CHANGES.md, PR 38): three times that, held to 300.
+#: Every wait the tests make themselves (a child's `communicate`, a `join`,
+#: a poll's deadline) is shorter, and so is XLA's rendezvous limit below: a
+#: signal's handler runs only once the interpreter has control again, so it
+#: ends a wait in Python and not one inside XLA. tests/test_suite_limit.py
+#: pins both.
+TEST_LIMIT_S = 300
 
 # XLA's in-process CPU collective rendezvous SIGABRTs the whole pytest
 # process when the box is oversubscribed (8 virtual devices on 1-2 cores
-# under a loaded CI: "Expected 8 threads to join ... only N arrived").
-# Raise the warn/terminate timeouts well past any scheduler hiccup. The
-# device count rides XLA_FLAGS as well as the config option below because
-# subprocess-isolated tests inherit the environment, not the config.
+# under a loaded CI: "Expected 8 threads to join ... only N arrived"), at
+# 40 s by default. Raised past any scheduler hiccup, and held under
+# TEST_LIMIT_S so that a rendezvous that is truly stuck ends inside the
+# test that made it. The device count rides XLA_FLAGS as well as the
+# config option below because subprocess-isolated tests inherit the
+# environment, not the config.
 os.environ["XLA_FLAGS"] = os.environ.get("XLA_FLAGS", "") + (
     " --xla_force_host_platform_device_count=8"
-    " --xla_cpu_collective_call_warn_stuck_timeout_seconds=300"
-    " --xla_cpu_collective_call_terminate_timeout_seconds=1200"
+    " --xla_cpu_collective_call_warn_stuck_timeout_seconds=60"
+    " --xla_cpu_collective_call_terminate_timeout_seconds=240"
 )
 # No persistent compile cache under the suite (children inherit the env):
 # test_recompile/test_boot/test_memwatch pin compile counts and seconds that
@@ -88,6 +107,117 @@ def pytest_collection_modifyitems(config, items):
         base = base.split("tests/")[-1]
         if base in SMOKE or base.split("::")[0] in SMOKE:
             item.add_marker(pytest.mark.smoke)
+
+
+# -- a limit of its own for every test ---------------------------------------
+_REAL_STDERR = 2          # the fd pytest's capture stands in front of
+_DEADLINE = pytest.StashKey[float]()
+#: seconds past TEST_LIMIT_S at which a test that never gave the
+#: interpreter control back (a wait in native code) takes its worker down
+_BACKSTOP_S = 20
+
+
+def pytest_configure(config):
+    # global capture is suspended while plugins configure: this is the
+    # terminal's (under xdist the master's) stderr, where a dump survives
+    # the worker that wrote it
+    global _REAL_STDERR
+    _REAL_STDERR = os.dup(2)
+
+
+@contextlib.contextmanager
+def limited(seconds: float, what: str):
+    """Run the body under an interval timer: past `seconds` every
+    thread's stack goes to stderr and the body fails with the limit's
+    message, at the next bytecode the main thread runs. A timer that was
+    already armed (the test's own, around a nested use) is put back with
+    what it had left."""
+    if threading.current_thread() is not threading.main_thread():
+        yield
+        return
+
+    def expired(signum, frame):
+        os.write(_REAL_STDERR, f"\n{what} ran past its limit:\n".encode())
+        faulthandler.dump_traceback(file=_REAL_STDERR)
+        pytest.fail(f"{what} ran past TEST_LIMIT_S = {TEST_LIMIT_S} s (this "
+                    f"phase had {seconds:.1f} s of it); stacks on stderr")
+
+    start = time.monotonic()
+    handler = signal.signal(signal.SIGALRM, expired)
+    outer, _ = signal.setitimer(signal.ITIMER_REAL, max(seconds, 1e-3))
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, handler)
+        if outer:
+            signal.setitimer(signal.ITIMER_REAL, max(
+                outer - (time.monotonic() - start), 1e-3))
+
+
+def _died_here(item) -> str:
+    """Where a worker leaves word of the test it is inside. xdist's
+    `loadfile` hands a dead worker's file, from the test it died in on, to
+    a new worker: without this word a test that kills its worker (the
+    backstop below, XLA's own SIGABRT) would kill every worker xdist is
+    willing to start in its place."""
+    run = os.environ.get("PYTEST_XDIST_TESTRUNUID")
+    if run is None:
+        return ""         # one process: its death is the run's
+    name = hashlib.sha1(item.nodeid.encode()).hexdigest()
+    return os.path.join(tempfile.gettempdir(), f"tfde-t1-{run}-{name}")
+
+
+def _phase(item, least: float = 0.0):
+    """What is left of the test's TEST_LIMIT_S (and no less than `least`),
+    as a timer around one of its phases: module and session fixtures are
+    built inside the set-up of the first test that asks for them, so their
+    waits are inside it too."""
+    if _DEADLINE not in item.stash:     # a `slow` test
+        return contextlib.nullcontext()
+    left = item.stash[_DEADLINE] - time.monotonic()
+    return limited(max(left, least), item.nodeid)
+
+
+@pytest.hookimpl(wrapper=True, trylast=True)
+def pytest_runtest_setup(item):
+    # the innermost wrapper, around the runner's own set-up alone: pytest's
+    # other plugins have readied what their teardowns expect by now, so
+    # this may fail the test before any fixture of it is built
+    if item.get_closest_marker("slow"):
+        return (yield)      # outside tier-1: it runs as long as it takes
+    item.stash[_DEADLINE] = time.monotonic() + TEST_LIMIT_S
+    word = _died_here(item)
+    if word and os.path.exists(word):
+        pytest.fail("a worker died inside this test (see 'node down' "
+                    "above): it is not run again")
+    if word:
+        with open(word, "w") as f:
+            f.write(item.nodeid)
+    faulthandler.dump_traceback_later(
+        TEST_LIMIT_S + _BACKSTOP_S, exit=True, file=_REAL_STDERR)
+    with _phase(item):
+        return (yield)
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_call(item):
+    with _phase(item):
+        return (yield)
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_teardown(item):
+    try:
+        # a test that used its limit up still undoes what it set up
+        with _phase(item, least=_BACKSTOP_S / 2):
+            return (yield)
+    finally:
+        faulthandler.cancel_dump_traceback_later()
+        word = _died_here(item)
+        if word:
+            with contextlib.suppress(FileNotFoundError):
+                os.remove(word)
 
 
 @pytest.fixture(scope="session", autouse=True)

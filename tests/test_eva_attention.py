@@ -15,12 +15,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from functools import partial
+
 from benchmarks.reference import evabyte as ref
+from teacher_forced import programs, served_logits, worst_gap
 from tfde_tpu.inference import server
-from tfde_tpu.inference.decode import _decode_clone, generate, init_cache
+from tfde_tpu.inference.decode import generate, init_cache
 from tfde_tpu.inference.server import (ContinuousBatcher,
                                        SpeculativeContinuousBatcher)
-from tfde_tpu.inference.speculative import _set_index_counters
 from tfde_tpu.models.gpt import GPT, gpt_tiny_test
 from tfde_tpu.models.transformer import UnitOffsetRMSNorm
 from tfde_tpu.observability.capacity import (CapacityLedger,
@@ -60,6 +62,14 @@ def highest_precision():
         yield
 
 
+@pytest.fixture(scope="module")
+def forward():
+    """The whole forward of the model as it is written, jitted: a shape
+    compiles once, where an eager apply compiles every primitive."""
+    model = eva_model()
+    return jax.jit(lambda params, rows: model.apply({"params": params}, rows))
+
+
 def rows_of(seed: int, lengths) -> list:
     rng = np.random.default_rng(seed)
     return [rng.integers(0, VOCAB, n).astype(np.int32) for n in lengths]
@@ -74,16 +84,18 @@ def reference_logits(weights, row) -> np.ndarray:
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("length", [3, 31, 32, 33, 100, 128])
-def test_full_forward_matches_the_reference(weights, params, length):
+def test_full_forward_matches_the_reference(weights, params, forward,
+                                            length):
     (row,) = rows_of(length, [length])
-    got = eva_model().apply({"params": params}, row[None])[0]
+    got = forward(params, row[None])[0]
     want = reference_logits(weights, row)
     assert np.abs(np.asarray(got) - want).max() < TOL
 
 
 def test_bfloat16_for_float32_fails_the_tolerance(weights, params):
     (row,) = rows_of(1, [100])
-    got = eva_model(jnp.bfloat16).apply({"params": params}, row[None])[0]
+    got = jax.jit(eva_model(jnp.bfloat16).apply)(
+        {"params": params}, row[None])[0]
     assert np.abs(np.asarray(got) - reference_logits(weights, row)).max() \
         > 10 * TOL
 
@@ -102,57 +114,6 @@ def test_init_creates_the_two_pooling_vectors_per_layer():
 # vector a step: rows of different true lengths, a row frozen half way
 # ---------------------------------------------------------------------------
 
-def served_logits(model, params, rows, lengths, bucket, max_len,
-                  freeze=None):
-    """Teacher-forced serving of `rows` (each a full sequence): prefill
-    the first lengths[r] tokens right-padded to `bucket`, rewind the index
-    to the true lengths as admission does, then feed the rest one token a
-    step as `_decode_scan` does. `freeze` = (row, step): from that step on
-    the row is fed padding at a frozen index. Returns per row the logits
-    at positions lengths[r]-1 .. (one vector a fed position)."""
-    decode_model = _decode_clone(model)
-    n = len(rows)
-    lengths = np.asarray(lengths, np.int32)
-    prompts = np.zeros((n, bucket), np.int32)
-    for r, row in enumerate(rows):
-        prompts[r, :lengths[r]] = row[:lengths[r]]
-
-    @jax.jit
-    def prefill(cache, prompts, last):
-        cache = server._set_feed_pad(cache, bucket - 1 - last)
-        logits, mutated = decode_model.apply(
-            {"params": params, "cache": cache}, prompts, mutable=["cache"])
-        return mutated["cache"], logits[jnp.arange(n), last]
-
-    @jax.jit
-    def step(cache, feed, idx, done):
-        cache = _set_index_counters(cache, idx)
-        cache = server._set_feed_pad(cache, done)
-        logits, mutated = decode_model.apply(
-            {"params": params, "cache": cache}, feed[:, None],
-            mutable=["cache"])
-        return mutated["cache"], logits[:, 0]
-
-    cache, first = prefill(init_cache(model, n, max_len),
-                           jnp.asarray(prompts), jnp.asarray(lengths - 1))
-    out = [[np.asarray(first[r])] for r in range(n)]
-    idx = lengths.copy()
-    steps = max(len(row) for row in rows) - int(lengths.min())
-    for t in range(steps):
-        done = np.asarray([idx[r] >= len(rows[r]) or (
-            freeze is not None and r == freeze[0] and t >= freeze[1])
-            for r in range(n)])
-        feed = np.asarray([0 if done[r] else rows[r][idx[r]]
-                           for r in range(n)], np.int32)
-        cache, logits = step(cache, jnp.asarray(feed), jnp.asarray(idx),
-                             jnp.asarray(done))
-        for r in range(n):
-            if not done[r]:
-                out[r].append(np.asarray(logits[r]))
-                idx[r] += 1
-    return [np.stack(o) for o in out], cache
-
-
 # true lengths that end inside a chunk, on a chunk edge, on a window edge,
 # and in the bucket's last window; every row decodes across several chunk
 # edges, rows 0-2 across a window edge
@@ -160,45 +121,48 @@ SERVED = dict(lengths=[37, 60, 64, 101], totals=[80, 75, 100, 130],
               bucket=128, max_len=160)
 
 
-def worst_gap(weights, rows, lengths, got) -> float:
-    worst = 0.0
-    for row, n, logits in zip(rows, lengths, got):
-        want = reference_logits(weights, row)[n - 1:n - 1 + len(logits)]
-        worst = max(worst, float(np.abs(logits - want).max()))
-    return worst
+@pytest.fixture(scope="module")
+def honest():
+    """The model as it is written, traced once for the tests that only
+    read what it serves."""
+    return programs(eva_model())
 
 
-def test_prefill_and_decode_match_the_reference(weights, params):
+def serve(weights, params, progs, **kw):
     rows = rows_of(3, SERVED["totals"])
-    got, _ = served_logits(eva_model(), params, rows, SERVED["lengths"],
-                           SERVED["bucket"], SERVED["max_len"])
+    got, cache = served_logits(progs, params, rows, SERVED["lengths"],
+                               SERVED["bucket"], SERVED["max_len"], **kw)
+    gap = worst_gap(partial(reference_logits, weights), rows,
+                    SERVED["lengths"], got)
+    return gap, got, cache
+
+
+def test_prefill_and_decode_match_the_reference(weights, params, honest):
+    gap, got, _ = serve(weights, params, honest)
     assert [len(g) for g in got] == [
         t - n + 1 for t, n in zip(SERVED["totals"], SERVED["lengths"])]
-    assert worst_gap(weights, rows, SERVED["lengths"], got) < TOL
+    assert gap < TOL
 
 
 def test_a_frozen_row_leaves_the_others_and_its_summaries_alone(
-        weights, params):
+        weights, params, honest):
     """Row 1 stops after 3 steps with its index at 63, where one more key
     would complete chunk 15; it is then fed padding 50 more times."""
-    rows = rows_of(3, SERVED["totals"])
-    got, cache = served_logits(eva_model(), params, rows, SERVED["lengths"],
-                               SERVED["bucket"], SERVED["max_len"],
-                               freeze=(1, 3))
+    gap, got, cache = serve(weights, params, honest, freeze=(1, 3))
     assert len(got[1]) == 4
-    assert worst_gap(weights, rows, SERVED["lengths"], got) < TOL
+    assert gap < TOL
     table = cache["decoder"]["block_0"]["attn"]["eva_summary_key"]
     assert np.abs(np.asarray(table[1, 14])).max() > 0    # keys 56-59
     assert np.abs(np.asarray(table[1, 15:])).max() == 0  # never completed
     assert np.abs(np.asarray(table[0, 80 // C:])).max() == 0
 
 
-def test_a_padded_tail_lands_in_no_summary(params):
+def test_a_padded_tail_lands_in_no_summary(params, honest):
     """Bucket 128 behind true lengths 37 and 101: chunks 9.. and 25.. hold
     padding (or are cut by the true length) and stay zero; the window kept
     is the one the true length ends in."""
     rows = rows_of(5, [37, 101])
-    _, cache = served_logits(eva_model(), params, rows, [37, 101], 128, 160)
+    _, cache = served_logits(honest, params, rows, [37, 101], 128, 160)
     attn = cache["decoder"]["block_2"]["attn"]
     table = np.asarray(attn["eva_summary_key"])
     assert np.abs(table[0, :9]).min(axis=(1, 2)).max() > 0
@@ -243,10 +207,8 @@ def _keep_the_old_window(monkeypatch):
 def test_a_broken_layer_fails_the_tolerance(weights, params, monkeypatch,
                                             break_it):
     break_it(monkeypatch)
-    rows = rows_of(3, SERVED["totals"])
-    got, _ = served_logits(eva_model(), params, rows, SERVED["lengths"],
-                           SERVED["bucket"], SERVED["max_len"])
-    assert worst_gap(weights, rows, SERVED["lengths"], got) > 100 * TOL
+    gap, _, _ = serve(weights, params, programs(eva_model()))
+    assert gap > 100 * TOL
 
 
 # ---------------------------------------------------------------------------

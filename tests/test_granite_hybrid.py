@@ -21,12 +21,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from functools import partial
+
 from benchmarks.reference import granite_hybrid as ref
+from teacher_forced import programs, served_logits, worst_gap
 from tfde_tpu.inference import server
 from tfde_tpu.inference.decode import _decode_clone, init_cache
 from tfde_tpu.inference.server import (ContinuousBatcher,
                                        SpeculativeContinuousBatcher)
-from tfde_tpu.inference.speculative import _set_index_counters
 from tfde_tpu.models.gpt import GPT, gpt_tiny_test
 from tfde_tpu.models.moe import MoEMlp
 from tfde_tpu.models.transformer import Mamba2Mixer
@@ -84,6 +86,15 @@ def highest_precision():
         yield
 
 
+@pytest.fixture(scope="module")
+def forward():
+    """The whole forward of the model as it is written, jitted: a shape
+    compiles once, where an eager apply compiles every primitive."""
+    model = hybrid_model()
+    return jax.jit(lambda params, rows, last=None: model.apply(
+        {"params": params}, rows, last=last))
+
+
 def rows_of(seed: int, lengths) -> list:
     rng = np.random.default_rng(seed)
     return [rng.integers(0, VOCAB, n).astype(np.int32) for n in lengths]
@@ -113,26 +124,27 @@ def test_chunked_scan_matches_the_position_by_position_scan(weights, length):
 
 
 @pytest.mark.parametrize("length", [3, 8, 17, 40, 100])
-def test_full_forward_matches_the_reference(weights, params, length):
+def test_full_forward_matches_the_reference(weights, params, forward,
+                                            length):
     (row,) = rows_of(length, [length])
-    got = hybrid_model().apply({"params": params}, row[None])[0]
+    got = forward(params, row[None])[0]
     assert np.abs(np.asarray(got) - reference_logits(weights, row)).max() \
         < TOL
 
 
 def test_bfloat16_for_float32_fails_the_tolerance(weights, params):
     (row,) = rows_of(1, [60])
-    got = hybrid_model(jnp.bfloat16).apply({"params": params}, row[None])[0]
+    got = jax.jit(hybrid_model(jnp.bfloat16).apply)(
+        {"params": params}, row[None])[0]
     assert np.abs(np.asarray(got) - reference_logits(weights, row)).max() \
         > 10 * TOL
 
 
-def test_the_head_at_one_position_is_that_positions_logits(params):
+def test_the_head_at_one_position_is_that_positions_logits(params, forward):
     rows = np.stack(rows_of(2, [24, 24]))
-    model = hybrid_model()
-    full = model.apply({"params": params}, rows)
+    full = forward(params, rows)
     last = jnp.asarray([5, 23])
-    one = model.apply({"params": params}, rows, last=last)
+    one = forward(params, rows, last)
     assert one.shape == (2, 1, VOCAB)
     assert np.abs(np.asarray(one[:, 0])
                   - np.asarray(full[jnp.arange(2), last])).max() < 1e-6
@@ -162,61 +174,6 @@ def test_the_layer_list_is_as_long_as_the_depth(params):
 # vector a step: rows of different true lengths in one wave
 # ---------------------------------------------------------------------------
 
-def served_logits(model, params, rows, lengths, bucket, max_len,
-                  freeze=None, snapshots=None):
-    """Teacher-forced serving of `rows` (each a full sequence): prefill
-    the first lengths[r] tokens right-padded to `bucket`, rewind the index
-    to the true lengths as admission does, then feed the rest one token a
-    step as `_decode_scan` does. `freeze` = (row, step): from that step on
-    the row is fed padding at a frozen index. `snapshots`, a list, takes
-    the cache after every step. Returns per row the logits at positions
-    lengths[r]-1 .. (one vector a fed position), and the cache."""
-    decode_model = _decode_clone(model)
-    n = len(rows)
-    lengths = np.asarray(lengths, np.int32)
-    prompts = np.zeros((n, bucket), np.int32)
-    for r, row in enumerate(rows):
-        prompts[r, :lengths[r]] = row[:lengths[r]]
-
-    @jax.jit
-    def prefill(cache, prompts, last):
-        cache = server._set_feed_pad(cache, bucket - 1 - last)
-        logits, mutated = decode_model.apply(
-            {"params": params, "cache": cache}, prompts, last=last,
-            mutable=["cache"])
-        return mutated["cache"], logits[:, 0]
-
-    @jax.jit
-    def step(cache, feed, idx, done):
-        cache = _set_index_counters(cache, idx)
-        cache = server._set_feed_pad(cache, done)
-        logits, mutated = decode_model.apply(
-            {"params": params, "cache": cache}, feed[:, None],
-            mutable=["cache"])
-        return mutated["cache"], logits[:, 0]
-
-    cache, first = prefill(init_cache(model, n, max_len),
-                           jnp.asarray(prompts), jnp.asarray(lengths - 1))
-    out = [[np.asarray(first[r])] for r in range(n)]
-    idx = lengths.copy()
-    steps = max(len(row) for row in rows) - int(lengths.min())
-    for t in range(steps):
-        done = np.asarray([idx[r] >= len(rows[r]) or (
-            freeze is not None and r == freeze[0] and t >= freeze[1])
-            for r in range(n)])
-        feed = np.asarray([0 if done[r] else rows[r][idx[r]]
-                           for r in range(n)], np.int32)
-        cache, logits = step(cache, jnp.asarray(feed), jnp.asarray(idx),
-                             jnp.asarray(done))
-        if snapshots is not None:
-            snapshots.append(jax.device_get(cache))
-        for r in range(n):
-            if not done[r]:
-                out[r].append(np.asarray(logits[r]))
-                idx[r] += 1
-    return [np.stack(o) for o in out], cache
-
-
 # prompts that end inside a chunk (13), on a chunk edge (16), one token past
 # it (17) and short of one conv tail (2), in one wave; every row decodes
 # across chunk edges
@@ -224,22 +181,27 @@ SERVED = dict(lengths=[13, 16, 17, 2], totals=[40, 30, 44, 21], bucket=32,
               max_len=48)
 
 
-def worst_gap(weights, rows, lengths, got) -> float:
-    worst = 0.0
-    for row, n, logits in zip(rows, lengths, got):
-        want = reference_logits(weights, row)[n - 1:n - 1 + len(logits)]
-        worst = max(worst, float(np.abs(logits - want).max()))
-    return worst
+@pytest.fixture(scope="module")
+def honest():
+    """The model as it is written, traced once for the tests that only
+    read what it serves."""
+    return programs(hybrid_model())
 
 
-def test_prefill_and_decode_match_the_reference(weights, params):
+def serve(weights, params, progs, **kw):
     rows = rows_of(3, SERVED["totals"])
-    got, cache = served_logits(hybrid_model(), params, rows,
-                               SERVED["lengths"], SERVED["bucket"],
-                               SERVED["max_len"])
+    got, cache = served_logits(progs, params, rows, SERVED["lengths"],
+                               SERVED["bucket"], SERVED["max_len"], **kw)
+    gap = worst_gap(partial(reference_logits, weights), rows,
+                    SERVED["lengths"], got)
+    return gap, got, cache
+
+
+def test_prefill_and_decode_match_the_reference(weights, params, honest):
+    gap, got, cache = serve(weights, params, honest)
     assert [len(g) for g in got] == [
         t - n + 1 for t, n in zip(SERVED["totals"], SERVED["lengths"])]
-    assert worst_gap(weights, rows, SERVED["lengths"], got) < TOL
+    assert gap < TOL
     mamba = cache["decoder"]["block_0"]["mamba"]
     assert mamba["ssm_state"].shape == (4, 4, 16, 16)      # no positions
     assert mamba["ssm_state"].dtype == jnp.float32
@@ -248,17 +210,15 @@ def test_prefill_and_decode_match_the_reference(weights, params):
         "cached_key", "cached_value", "cache_index"}
 
 
-def test_a_frozen_rows_state_stands_to_the_bit(weights, params):
+def test_a_frozen_rows_state_stands_to_the_bit(weights, params, honest):
     """Row 1 stops after 3 steps and is fed padding 20 more times: its
     state and its conv tail in every state-space layer stay as they were,
     and the other rows still agree with the reference."""
-    rows = rows_of(3, SERVED["totals"])
     shots = []
-    got, _ = served_logits(hybrid_model(), params, rows, SERVED["lengths"],
-                           SERVED["bucket"], SERVED["max_len"],
-                           freeze=(1, 3), snapshots=shots)
+    gap, got, _ = serve(weights, params, honest, freeze=(1, 3),
+                        snapshots=shots)
     assert len(got[1]) == 4
-    assert worst_gap(weights, rows, SERVED["lengths"], got) < TOL
+    assert gap < TOL
     for layer in ("block_0", "block_1", "block_2"):
         then, now = (s["decoder"][layer]["mamba"] for s in
                      (shots[2], shots[-1]))
@@ -278,10 +238,8 @@ def test_a_long_prefill_takes_the_other_attention_paths(weights, params,
 
     monkeypatch.setattr(transformer, "_PREFILL_SCORES_BYTES", 0)
     monkeypatch.setattr(transformer, "_PREFILL_QUERY_BLOCK", 8)
-    rows = rows_of(3, SERVED["totals"])
-    got, _ = served_logits(hybrid_model(), params, rows, SERVED["lengths"],
-                           SERVED["bucket"], SERVED["max_len"])
-    assert worst_gap(weights, rows, SERVED["lengths"], got) < TOL
+    gap, _, _ = serve(weights, params, programs(hybrid_model()))
+    assert gap < TOL
     (row,) = rows_of(8, [32])
     model = _decode_clone(hybrid_model())
     cache = init_cache(hybrid_model(), 1, 48)
@@ -291,37 +249,6 @@ def test_a_long_prefill_takes_the_other_attention_paths(weights, params,
                             row[None, 16:], mutable=["cache"])
     got = np.concatenate([np.asarray(first[0]), np.asarray(second[0])])
     assert np.abs(got - reference_logits(weights, row)).max() < TOL
-
-
-def test_a_long_prefill_of_heads_of_128_takes_the_lane_forward(monkeypatch):
-    """Two query heads of 128 over one K/V head, a cold wave of 384
-    positions (three tiles of 128) past `_PREFILL_SCORES_BYTES`: the one
-    attention layer traces the lane flash forward once and the grid
-    forward never, and the wave and the steps after it serve the
-    reference's logits."""
-    from tfde_tpu.models import transformer
-    from tfde_tpu.observability import counters
-
-    dims = dict(DIMS, hidden_size=256, num_attention_heads=2,
-                num_key_value_heads=1, mamba_n_heads=16)
-    weights = ref.make_weights(11, dims)
-    params = as_float32(ref.to_program_params(weights, dims))
-    model = hybrid_model(
-        hidden_size=256, num_heads=2, num_kv_heads=1, attn_impl="flash",
-        ssm=ssm_lib.SSMShape(heads=16, head_dim=16, state=16, groups=1,
-                             conv=4, chunk=CHUNK))
-    monkeypatch.setattr(transformer, "_PREFILL_SCORES_BYTES", 0)
-    monkeypatch.setattr(transformer, "_PREFILL_QUERY_BLOCK", 128)
-    rows, lengths = rows_of(5, [300, 386]), [298, 384]
-    before = counters.snapshot()
-    got, _ = served_logits(model, params, rows, lengths, 384, 400)
-    traced = {k: counters.value(f"flash/{k}") - before.get(f"flash/{k}", 0)
-              for k in ("fwd_lane_traces", "fwd_grid_traces")}
-    assert traced == {"fwd_lane_traces": LAYERS.count("attention"),
-                      "fwd_grid_traces": 0}
-    for row, n, logits in zip(rows, lengths, got):
-        want = reference_logits(weights, row, dims)[n - 1:]
-        assert np.abs(logits - want).max() < TOL
 
 
 # ways to get the model wrong, each of which must show
@@ -372,11 +299,8 @@ def _logits_scaling_dropped(monkeypatch):
 def test_a_broken_model_fails_the_tolerance(weights, params, monkeypatch,
                                             break_it):
     fields = break_it(monkeypatch) or {}
-    rows = rows_of(3, SERVED["totals"])
-    got, _ = served_logits(hybrid_model(**fields), params, rows,
-                           SERVED["lengths"], SERVED["bucket"],
-                           SERVED["max_len"])
-    assert worst_gap(weights, rows, SERVED["lengths"], got) > 100 * TOL
+    gap, _, _ = serve(weights, params, programs(hybrid_model(**fields)))
+    assert gap > 100 * TOL
 
 
 # ---------------------------------------------------------------------------
@@ -450,12 +374,12 @@ def test_the_expert_layer_alone_matches_the_reference(weights, params, shape,
     assert moved >= 2 * pairs
 
 
-def test_a_rows_logits_are_the_same_alone_and_in_a_wave_of_four(params):
+def test_a_rows_logits_are_the_same_alone_and_in_a_wave_of_four(params,
+                                                                forward):
     rows = np.stack(rows_of(9, [24] * 4))
-    model = hybrid_model()
-    together = np.asarray(model.apply({"params": params}, rows))
+    together = np.asarray(forward(params, rows))
     for r in range(4):
-        alone = np.asarray(model.apply({"params": params}, rows[r:r + 1]))[0]
+        alone = np.asarray(forward(params, rows[r:r + 1]))[0]
         assert np.abs(alone - together[r]).max() < 1e-5
 
 
@@ -487,7 +411,7 @@ def test_the_shares_add_up_to_the_uncut_layer_and_vocabulary():
     (row,) = rows_of(4, [30])
     logits = reference_logits(w, row, uncut)
     half = dict(whole, wte={"embedding": whole["wte"]["embedding"][:VOCAB]})
-    mine = np.asarray(hybrid_model(held=(0, EXPERTS)).apply(
+    mine = np.asarray(jax.jit(hybrid_model(held=(0, EXPERTS)).apply)(
         {"params": half}, row[None])[0])
     theirs = reference_logits(dict(w, wte=jnp.concatenate(
         [w["wte"][:VOCAB], w["wte"][VOCAB:]])), row, uncut)[:, VOCAB:]

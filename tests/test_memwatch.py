@@ -149,7 +149,10 @@ def test_live_sampler_sees_device_buffers():
     assert len(sample["top"]) <= 4
     sizes = [row["bytes"] for row in sample["top"]]
     assert sizes == sorted(sizes, reverse=True)
-    assert any(row["shape"] == [128, 128] for row in sample["top"])
+    # among ALL live buffers: whether 64 KiB is one of the four largest
+    # depends on what the files this xdist worker ran before still hold
+    everything = memwatch.sample_live(top_k=sample["buffers"] + 1)["top"]
+    assert any(row["shape"] == [128, 128] for row in everything)
     del marker
 
 

@@ -267,7 +267,7 @@ def test_memgate_injection_fails_end_to_end():
     proc = subprocess.run(
         [sys.executable, os.path.join(ROOT, "tools", "memgate.py"),
          "--check"],
-        env=env, cwd=ROOT, capture_output=True, text=True, timeout=600)
+        env=env, cwd=ROOT, capture_output=True, text=True, timeout=200)
     assert proc.returncode != 0, proc.stdout + proc.stderr
     assert "compiles > baseline" in proc.stdout
 

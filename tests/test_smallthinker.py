@@ -20,12 +20,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from functools import partial
+
 from benchmarks.reference import smallthinker as ref
+from teacher_forced import programs, served_logits, worst_gap
 from tfde_tpu.inference import server
-from tfde_tpu.inference.decode import _decode_clone, init_cache
+from tfde_tpu.inference.decode import init_cache
 from tfde_tpu.inference.server import (ContinuousBatcher,
                                        SpeculativeContinuousBatcher)
-from tfde_tpu.inference.speculative import _set_index_counters
 from tfde_tpu.models import transformer
 from tfde_tpu.models.gpt import GPT, gpt_tiny_test
 from tfde_tpu.observability.capacity import (CapacityLedger,
@@ -73,6 +75,14 @@ def highest_precision():
         yield
 
 
+@pytest.fixture(scope="module")
+def forward():
+    """The whole forward of the model as it is written, jitted: a shape
+    compiles once, where an eager apply compiles every primitive."""
+    model = window_model()
+    return jax.jit(lambda params, rows: model.apply({"params": params}, rows))
+
+
 def rows_of(seed: int, lengths) -> list:
     rng = np.random.default_rng(seed)
     return [rng.integers(0, VOCAB, n).astype(np.int32) for n in lengths]
@@ -87,16 +97,18 @@ def reference_logits(weights, row) -> np.ndarray:
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("length", [3, 8, 9, 17, 40])
-def test_full_forward_matches_the_reference(weights, params, length):
+def test_full_forward_matches_the_reference(weights, params, forward,
+                                            length):
     (row,) = rows_of(length, [length])
-    got = window_model().apply({"params": params}, row[None])[0]
+    got = forward(params, row[None])[0]
     assert np.abs(np.asarray(got) - reference_logits(weights, row)).max() \
         < TOL
 
 
 def test_bfloat16_for_float32_fails_the_tolerance(weights, params):
     (row,) = rows_of(1, [40])
-    got = window_model(jnp.bfloat16).apply({"params": params}, row[None])[0]
+    got = jax.jit(window_model(jnp.bfloat16).apply)(
+        {"params": params}, row[None])[0]
     assert np.abs(np.asarray(got) - reference_logits(weights, row)).max() \
         > 10 * TOL
 
@@ -159,60 +171,6 @@ def test_the_two_patterns_are_two_ways_of_writing_the_tuple(pattern,
 # vector a step: rows of different true lengths in one wave
 # ---------------------------------------------------------------------------
 
-def served_logits(model, params, rows, lengths, bucket, max_len,
-                  freeze=None, rolling=True):
-    """Teacher-forced serving of `rows` (each a full sequence) as the
-    batcher does it: prefill the first lengths[r] tokens right-padded to
-    `bucket` into a fresh row cache (true lengths told through
-    `feed_pad`), rewind the index to the true lengths, then feed the rest
-    one token a step under per-row indices. `freeze` = (row, step): from
-    that step on the row is fed padding at a frozen index. `rolling`
-    false: every layer keeps a slab and the band is a mask. Returns per
-    row the logits at positions lengths[r]-1 .. and the cache."""
-    decode_model = _decode_clone(model, rolling=rolling)
-    n = len(rows)
-    lengths = np.asarray(lengths, np.int32)
-    prompts = np.zeros((n, bucket), np.int32)
-    for r, row in enumerate(rows):
-        prompts[r, :lengths[r]] = row[:lengths[r]]
-
-    @jax.jit
-    def prefill(cache, prompts, last):
-        cache = server._set_feed_pad(cache, bucket - 1 - last)
-        logits, mutated = decode_model.apply(
-            {"params": params, "cache": cache}, prompts, last=last,
-            mutable=["cache", "counters"])
-        return mutated["cache"], logits[:, 0]
-
-    @jax.jit
-    def step(cache, feed, idx, done):
-        cache = _set_index_counters(cache, idx)
-        cache = server._set_feed_pad(cache, done)
-        logits, mutated = decode_model.apply(
-            {"params": params, "cache": cache}, feed[:, None],
-            mutable=["cache", "counters"])
-        return mutated["cache"], logits[:, 0]
-
-    cache, first = prefill(init_cache(model, n, max_len, rolling=rolling),
-                           jnp.asarray(prompts), jnp.asarray(lengths - 1))
-    out = [[np.asarray(first[r])] for r in range(n)]
-    idx = lengths.copy()
-    steps = max(len(row) for row in rows) - int(lengths.min())
-    for t in range(steps):
-        done = np.asarray([idx[r] >= len(rows[r]) or (
-            freeze is not None and r == freeze[0] and t >= freeze[1])
-            for r in range(n)])
-        feed = np.asarray([0 if done[r] else rows[r][idx[r]]
-                           for r in range(n)], np.int32)
-        cache, logits = step(cache, jnp.asarray(feed), jnp.asarray(idx),
-                             jnp.asarray(done))
-        for r in range(n):
-            if not done[r]:
-                out[r].append(np.asarray(logits[r]))
-                idx[r] += 1
-    return [np.stack(o) for o in out], cache
-
-
 # three rows in one wave, each padded up the ladder to a bucket of 32: a
 # prompt shorter than the window (5) whose decode turns the ring at
 # position 8 and twice more, one longer than the window (20: the ring
@@ -222,24 +180,30 @@ SERVED = dict(lengths=[5, 20, 8], totals=[30, 44, 26], bucket=32,
               max_len=48)
 
 
-def worst_gap(weights, rows, lengths, got) -> float:
-    worst = 0.0
-    for row, n, logits in zip(rows, lengths, got):
-        want = reference_logits(weights, row)[n - 1:n - 1 + len(logits)]
-        worst = max(worst, float(np.abs(logits - want).max()))
-    return worst
+def window_programs(model=None, rolling=True):
+    # the expert layers sow their counts, as under the batcher
+    return programs(model or window_model(), rolling=rolling,
+                    mutable=("cache", "counters"))
 
 
-def serve(weights, params, model=None, **kw):
+@pytest.fixture(scope="module")
+def honest():
+    """The model as it is written, its window layers on rings, traced once
+    for the tests that only read what it serves."""
+    return window_programs()
+
+
+def serve(weights, params, progs, **kw):
     rows = rows_of(3, SERVED["totals"])
-    got, cache = served_logits(model or window_model(), params, rows,
-                               SERVED["lengths"], SERVED["bucket"],
-                               SERVED["max_len"], **kw)
-    return worst_gap(weights, rows, SERVED["lengths"], got), got, cache
+    got, cache = served_logits(progs, params, rows, SERVED["lengths"],
+                               SERVED["bucket"], SERVED["max_len"], **kw)
+    gap = worst_gap(partial(reference_logits, weights), rows,
+                    SERVED["lengths"], got)
+    return gap, got, cache
 
 
-def test_prefill_and_decode_match_the_reference(weights, params):
-    gap, got, cache = serve(weights, params)
+def test_prefill_and_decode_match_the_reference(weights, params, honest):
+    gap, got, cache = serve(weights, params, honest)
     assert [len(g) for g in got] == [
         t - n + 1 for t, n in zip(SERVED["totals"], SERVED["lengths"])]
     assert gap < TOL
@@ -252,21 +216,21 @@ def test_prefill_and_decode_match_the_reference(weights, params):
         assert ("feed_pad" in attn) == bool(windowed)
 
 
-def test_a_frozen_row_leaves_the_others_alone(weights, params):
+def test_a_frozen_row_leaves_the_others_alone(weights, params, honest):
     """Row 2 stops after 3 steps and is fed padding at a frozen index for
     the rest of the run: what it emitted and what the other rows emit
     still agree with the reference."""
-    gap, got, _ = serve(weights, params, freeze=(2, 3))
+    gap, got, _ = serve(weights, params, honest, freeze=(2, 3))
     assert len(got[2]) == 4
     assert gap < TOL
 
 
-def test_the_ring_equals_the_slab_with_a_band_mask(weights, params):
+def test_the_ring_equals_the_slab_with_a_band_mask(weights, params, honest):
     """The same model and requests with every layer on a slab and the band
     as a mask over it (`rolling` off): the same logits, and the window
     layers then hold `max_len` cells a row where the ring holds 8."""
-    _, ring, _ = serve(weights, params)
-    gap, slab, cache = serve(weights, params, rolling=False)
+    _, ring, _ = serve(weights, params, honest)
+    gap, slab, cache = serve(weights, params, window_programs(rolling=False))
     assert gap < TOL
     assert max(np.abs(a - b).max() for a, b in zip(ring, slab)) < 1e-5
     assert cache["decoder"]["block_1"]["attn"]["cached_key"].shape[1] == \
@@ -286,35 +250,9 @@ def test_a_long_prefill_goes_through_the_dispatcher(weights, params,
         lambda *a, **kw: seen.append(kw.get("window")) or real(*a, **kw))
     monkeypatch.setattr(transformer, "_PREFILL_SCORES_BYTES", 0)
     monkeypatch.setattr(transformer, "_PREFILL_QUERY_BLOCK", 8)
-    gap, _, _ = serve(weights, params)
+    gap, _, _ = serve(weights, params, window_programs())
     assert gap < TOL
     assert seen.count(WINDOW) == 6 and seen.count(None) == 2
-
-
-def test_a_long_prefill_of_heads_of_128_takes_the_lane_forward(monkeypatch):
-    """Four query heads of 128 over two K/V heads, a wave of 384 positions
-    (three tiles of 128, the window of 8 across their edges) past
-    `_PREFILL_SCORES_BYTES`: each of the eight layers traces the lane flash
-    forward once and the grid forward never, and the wave and the steps
-    after it serve the reference's logits."""
-    from tfde_tpu.observability import counters
-
-    dims = dict(DIMS, head_dim=128)
-    weights = ref.make_weights(11, dims)
-    params = jax.tree.map(lambda x: x.astype(jnp.float32),
-                          ref.to_program_params(weights))
-    monkeypatch.setattr(transformer, "_PREFILL_SCORES_BYTES", 0)
-    monkeypatch.setattr(transformer, "_PREFILL_QUERY_BLOCK", 128)
-    rows, lengths = rows_of(5, [300, 386]), [298, 384]
-    before = counters.snapshot()
-    got, _ = served_logits(window_model(head_dim=128, attn_impl="flash"),
-                           params, rows, lengths, 384, 400)
-    traced = {k: counters.value(f"flash/{k}") - before.get(f"flash/{k}", 0)
-              for k in ("fwd_lane_traces", "fwd_grid_traces")}
-    assert traced == {"fwd_lane_traces": len(LAYOUT), "fwd_grid_traces": 0}
-    for row, n, logits in zip(rows, lengths, got):
-        want = np.asarray(ref.forward(weights, jnp.asarray(row), dims))
-        assert np.abs(logits - want[n - 1:]).max() < TOL
 
 
 # ways to get the model wrong, each of which must show
@@ -369,7 +307,8 @@ def _six_weights_not_renormalised(monkeypatch):
 def test_a_broken_model_fails_the_tolerance(weights, params, monkeypatch,
                                             break_it):
     fields = break_it(monkeypatch) or {}
-    gap, _, _ = serve(weights, params, window_model(**fields))
+    gap, _, _ = serve(weights, params,
+                      window_programs(window_model(**fields)))
     assert gap > 100 * TOL
 
 

@@ -2,7 +2,9 @@
 # Tier-1: the test command the driver runs, then the three gate steps.
 # Usage: tools/tier1.sh            (from anywhere; a parity sweep is the
 #        TFDE_PAGED_KV=on tools/tier1.sh   shell's own variable passing)
-# Never beside another suite: the subprocess drills depend on load.
+# No test runs longer than tests/conftest.py's TEST_LIMIT_S, and the
+# subprocess drills assert on counts they control: a second suite beside
+# this one makes it slower (about twice), not red.
 set -o pipefail
 cd "$(dirname "$0")/.." || exit 1
 rm -rf /tmp/_t1.log /tmp/_t1.xml
