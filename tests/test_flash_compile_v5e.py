@@ -68,6 +68,7 @@ def test_flash_gradient_compiles_for_v5e(one_chip, name, shape, dtype,
     ("global_layer_wave", (1, 14336, 28, 128), 4, True, None),
     ("two_rows_of_9216", (2, 9216, 28, 128), 4, True, None),
     ("hybrid_attention_wave", (1, 6144, 32, 128), 8, True, None),
+    ("latent_wave_heads_of_256", (1, 30720, 8, 256), 8, True, None),
 ])
 def test_flash_forward_compiles_for_v5e(one_chip, name, shape, kv_heads,
                                         causal, window):
@@ -76,7 +77,9 @@ def test_flash_forward_compiles_for_v5e(one_chip, name, shape, kv_heads,
     the v5e compiler takes (lane widths under and over 128, K and V of a
     long sequence whole in VMEM, the window-and-global cell's seven query
     heads of 128 and the hybrid's four against the K/V head they share, in
-    their longest waves, and the grid kernel where none of that holds)."""
+    their longest waves, a latent layer's eight heads with both widths
+    padded to 256 over its longest wave, and the grid kernel where none of
+    that holds)."""
     b, s, h, d = shape
     q = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
     k = jax.ShapeDtypeStruct((b, s, kv_heads, d), jnp.bfloat16,
@@ -96,8 +99,9 @@ def test_flash_forward_compiles_for_v5e(one_chip, name, shape, kv_heads,
 
 
 #: (experts held, experts routed over, choices a token, hidden, expert
-#: width, the gate's activation) of the two cells that run the kernel
+#: width, the gate's activation) of the three cells that run the kernel
 _EXPERT_LAYERS = {"hybrid": (36, 72, 10, 4096, 768, "silu"),
+                  "latent": (32, 128, 8, 4096, 2048, "silu"),
                   "window_and_global": (64, 64, 6, 2560, 768, "relu")}
 
 
@@ -107,9 +111,11 @@ _EXPERT_LAYERS = {"hybrid": (36, 72, 10, 4096, 768, "silu"),
 def test_moe_gmm_compiles_for_v5e(one_chip, name, tokens, layer):
     """The hybrid cell's expert layer: 36 held experts of 4096 x 768, ten
     choices a token over 72, SwiGLU; and the window-and-global cell's: all
-    64 experts of 2560 x 768, six a token, ReGLU. An expert's three
-    matrices, twice buffered, are 38 MB of VMEM beside the row tiles: the
-    kernel asks for its own limit, and the v5e compiler has to grant it."""
+    64 experts of 2560 x 768, six a token, ReGLU; and the latent cell's:
+    32 held of 128 experts of 4096 x 2048, eight a token, SwiGLU, the
+    widest it has met. An expert's three matrices, twice buffered, are 38
+    MB of VMEM beside the row tiles (100 MB at 2,048): the kernel asks for
+    its own limit, and the v5e compiler has to grant it."""
     held, experts, k, d, f, act = _EXPERT_LAYERS[layer]
     pairs = tokens * k
     tile = moe_gmm.tile_rows(pairs, experts)

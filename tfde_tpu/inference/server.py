@@ -144,8 +144,8 @@ def _rings(model, max_len: Optional[int] = None) -> bool:
 def _state_not_by_position(model,
                            max_len: Optional[int] = None) -> Optional[str]:
     """Why `model`'s cached state is not one cell per position under a
-    batcher of `max_len` positions a row, or None where it is (a K/V
-    slab): asked of the model's own fields."""
+    batcher of `max_len` positions a row, or None where it is (a K/V slab;
+    a latent layer's cell, `cached_latent`, with its own size): its fields."""
     if _rings(model, max_len):
         return ("its window layers keep a ring of `window` cells, slot = "
                 "position mod window, and a ring cannot give back an "
@@ -1702,6 +1702,12 @@ class ContinuousBatcher(_BatcherBase):
             self._prefix = _resolve_prefix(prefix_cache)
         if self._prefix is not None:
             _refuse_stateful(model, "the prefix cache", self._max_len)
+            if any(str(getattr(p[-1], "key", p[-1])) == "feed_pad" for p, _
+                   in jax.tree_util.tree_leaves_with_path(self._cache)):
+                raise NotImplementedError(
+                    "the prefix cache is not built for this model: a layer "
+                    "keeps a `feed_pad` leaf (experts routed without a "
+                    "capacity), which has no axis of positions to share")
         # device-resident loop state (tok/idx/budget/done); rebuilt from
         # host bookkeeping whenever admission desyncs it
         self._dev = None

@@ -52,6 +52,20 @@ class GPT(nn.Module):
     # True (SmallThinker): the router reads the attention sublayer's
     # normalised input, not the expert layer's (transformer.TransformerBlock)
     moe_router_pre_attention: bool = False
+    # the kind of MLP per layer where `num_experts` > 0, one entry a layer,
+    # 'dense' | 'experts' (a config's `first_k_dense_replace`: dense, then
+    # experts everywhere); None: every `moe_every`-th layer routes. A routed
+    # layer's experts are `moe_mlp_dim` wide (None: `mlp_dim`, the dense
+    # layers' width)
+    mlps: Optional[tuple] = None
+    moe_mlp_dim: Optional[int] = None
+    # how the router scores ('softmax' | 'sigmoid'), a learned bias added
+    # to the scores for the CHOICE of experts alone, and a factor on the
+    # combined weights (models/moe.py MoEMlp score / selection_bias /
+    # routed_scale)
+    moe_score: str = "softmax"
+    moe_selection_bias: bool = False
+    moe_routed_scale: Optional[float] = None
     # (first, end) of the contiguous range of experts this program holds
     # (a chip's share under expert parallelism): the router stays
     # `num_experts` wide, pairs routed elsewhere add nothing (MoEMlp)
@@ -160,12 +174,16 @@ class GPT(nn.Module):
     # residual stream and its adds in float32, the sublayers in `dtype`
     # (EvaByte's fp32_skip_add)
     fp32_residual: bool = False
-    # one mixer kind per layer, 'attention' | 'mamba' (a config's
-    # `layer_types`), as long as `depth`; None: every layer is attention.
-    # 'mamba' layers are ops/ssm.py's Mamba-2 mixer at the widths of `ssm`
-    # (an ops/ssm.SSMShape) and cache a running state, not positions
+    # one mixer kind per layer, 'attention' | 'mamba' | 'latent' (a
+    # config's `layer_types`), as long as `depth`; None: every layer is
+    # attention. 'mamba' layers are ops/ssm.py's Mamba-2 mixer at the widths
+    # of `ssm` (an ops/ssm.SSMShape) and cache a running state, not
+    # positions; 'latent' layers are latent attention at the widths of `mla`
+    # (an ops/mla.MLAShape; position='rope') and cache one [latent, rotary
+    # key] cell per position with no head axis (transformer.LatentAttention)
     mixers: Optional[tuple] = None
     ssm: Optional[Any] = None
+    mla: Optional[Any] = None
     # Granite: each sublayer's output times this before the residual add,
     # and the logits divided by `logits_scaling`
     residual_multiplier: Optional[float] = None
@@ -350,6 +368,12 @@ class GPT(nn.Module):
             moe_router_pre_attention=self.moe_router_pre_attention,
             mixers=tuple(self.mixers) if self.mixers is not None else None,
             ssm=self.ssm,
+            mla=self.mla,
+            mlps=tuple(self.mlps) if self.mlps is not None else None,
+            moe_mlp_dim=self.moe_mlp_dim,
+            moe_score=self.moe_score,
+            moe_selection_bias=self.moe_selection_bias,
+            moe_routed_scale=self.moe_routed_scale,
             residual_multiplier=self.residual_multiplier,
             name="decoder",
         )(x, mask=seg_mask, train=train)

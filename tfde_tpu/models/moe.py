@@ -211,6 +211,14 @@ class MoEMlp(nn.Module):
     num_groups: dispatch groups (default: the batch dim, one group per
     sequence) — groups route independently with per-group capacity, and the
     group dim carries the data sharding.
+
+    How the router scores a token (`score`), all in float32: 'softmax'
+    over all experts (Switch, Mixtral, Qwen2-MoE, Granite, SmallThinker)
+    or 'sigmoid', each expert on its own (the bias-balanced family).
+    `selection_bias` adds a learned `router_bias` [experts] to the scores
+    for the CHOICE of the k experts alone; the combined weights are the
+    chosen experts' scores without it, renormalised over the chosen under
+    `normalize_topk`, then times `routed_scale` where one is given.
     """
 
     num_experts: int
@@ -237,6 +245,13 @@ class MoEMlp(nn.Module):
     # (first, end): the experts whose weights this layer holds, of
     # `num_experts` routed over; None holds all. capacity_factor=None only
     held_experts: Optional[tuple] = None
+    # 'softmax' | 'sigmoid': the router's score function (class docstring)
+    score: str = "softmax"
+    # a learned float32 `router_bias` [experts] joins the scores where the
+    # k experts are chosen and never enters a weight
+    selection_bias: bool = False
+    # the combined weights times this (a config's `routed_scaling_factor`)
+    routed_scale: Optional[float] = None
     # serving: a "cache" variable `feed_pad` [rows] (how many trailing
     # tokens of this call are padding, set by the caller, read once and
     # reset) keeps padding out of the routing counts sown into "counters"
@@ -282,13 +297,25 @@ class MoEMlp(nn.Module):
             name="router",
         )((tokens if router_input is None
            else router_input.reshape(g, m, d)).astype(jnp.float32))
-        probs = jax.nn.softmax(logits, axis=-1)  # [g, m, e]
+        if self.score not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"score must be 'softmax' or 'sigmoid', got {self.score!r}")
+        probs = (jax.nn.softmax(logits, axis=-1) if self.score == "softmax"
+                 else jax.nn.sigmoid(logits))  # [g, m, e]
 
-        gate_vals, gate_idx = jax.lax.top_k(probs, k)  # [g, m, k]
+        if self.selection_bias:
+            bias = self.param("router_bias", nn.initializers.zeros, (e,),
+                              jnp.float32)
+            _, gate_idx = jax.lax.top_k(probs + bias, k)
+            gate_vals = jnp.take_along_axis(probs, gate_idx, axis=-1)
+        else:
+            gate_vals, gate_idx = jax.lax.top_k(probs, k)  # [g, m, k]
         if self.normalize_topk:
             gate_vals = gate_vals / jnp.maximum(
                 jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9
             )
+        if self.routed_scale is not None:
+            gate_vals = gate_vals * self.routed_scale
 
         # Switch load-balance aux loss: fraction routed x mean prob, top-1,
         # averaged over ALL tokens (global, not per-group)

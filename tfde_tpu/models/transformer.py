@@ -27,9 +27,10 @@ import jax.numpy as jnp
 
 from tfde_tpu.ops import attention as attn_lib
 from tfde_tpu.ops import eva_attention as eva_lib
+from tfde_tpu.ops import mla as mla_lib
 from tfde_tpu.ops import ssm as ssm_lib
 from tfde_tpu.ops.quant import QuantDenseGeneral, kv_dequantize, kv_quantize
-from tfde_tpu.ops.rotary import apply_rotary
+from tfde_tpu.ops.rotary import apply_rotary, attention_temperature
 from tfde_tpu.parallel.axes import batch_axes, constrain
 
 
@@ -1046,9 +1047,15 @@ class TransformerBlock(nn.Module):
     eva_chunk: int = 16
     norm_unit_offset: bool = False  # norm='rms' only (make_norm)
     # what mixes positions in this block: 'attention' (MultiHeadAttention)
-    # | 'mamba' (Mamba2Mixer over `ssm`, an ops/ssm.SSMShape)
+    # | 'mamba' (Mamba2Mixer over `ssm`, an ops/ssm.SSMShape) | 'latent'
+    # (LatentAttention over `mla`, an ops/mla.MLAShape)
     mixer: str = "attention"
     ssm: Optional[ssm_lib.SSMShape] = None
+    mla: Optional[mla_lib.MLAShape] = None
+    # MoEMlp.score / selection_bias / routed_scale
+    moe_score: str = "softmax"
+    moe_selection_bias: bool = False
+    moe_routed_scale: Optional[float] = None
     # both sublayers' outputs are scaled by this before the residual add
     # (Granite's residual_multiplier); None adds them as they are
     residual_multiplier: Optional[float] = None
@@ -1061,10 +1068,27 @@ class TransformerBlock(nn.Module):
         train: bool = False,
     ) -> jax.Array:
         ln = make_norm(self.norm, self.ln_eps, self.norm_unit_offset)
-        if self.mixer not in ("attention", "mamba"):
+        if self.mixer not in ("attention", "mamba", "latent"):
             raise ValueError(
-                f"mixer must be 'attention' or 'mamba', got {self.mixer!r}")
-        if self.mixer == "mamba":
+                f"mixer must be 'attention', 'mamba' or 'latent', got "
+                f"{self.mixer!r}")
+        if self.mixer == "latent":
+            if self.mla is None or not self.causal or not self.rope:
+                raise ValueError(
+                    "mixer='latent' needs its widths (`mla`), a causal "
+                    "block and rotary positions")
+            if (self.paged_blocks is not None or self.kv_quant is not None
+                    or self.window is not None or self.quant is not None):
+                raise NotImplementedError(
+                    "latent attention caches one [c, k_r] cell per position "
+                    "in a leaf of its own: the block pool, int8 cells, a "
+                    "sliding window and int8 weights are not built for it")
+            attn = LatentAttention(
+                num_heads=self.num_heads, shape=self.mla, dtype=self.dtype,
+                attn_impl=self.attn_impl, decode=self.decode,
+                rope_theta=self.rope_theta, rope_scaling=self.rope_scaling,
+                ln_eps=self.ln_eps, name="attn")
+        elif self.mixer == "mamba":
             if self.ssm is None or self.norm_style != "pre":
                 raise ValueError(
                     "mixer='mamba' needs its widths (`ssm`) and the pre-norm "
@@ -1130,6 +1154,9 @@ class TransformerBlock(nn.Module):
                 shared_expert_dim=self.moe_shared_expert_dim,
                 shared_expert_gated=self.moe_shared_expert_gated,
                 held_experts=self.moe_held_experts,
+                score=self.moe_score,
+                selection_bias=self.moe_selection_bias,
+                routed_scale=self.moe_routed_scale,
                 decode=self.decode,
                 act=self.mlp_act,
                 use_bias=self.use_bias,
@@ -1265,6 +1292,15 @@ class Encoder(nn.Module):
     moe_shared_expert_dim: Optional[int] = None
     router_z_loss_weight: float = 0.0
     moe_every: int = 2     # GShard convention: alternate dense / MoE
+    # the kind of MLP per block where `num_experts` > 0, 'dense' |
+    # 'experts', as long as the depth; None: every `moe_every`-th block
+    # routes. A routed block's experts are `moe_mlp_dim` wide (None: as
+    # wide as the dense MLP, `mlp_dim`)
+    mlps: Optional[tuple] = None
+    moe_mlp_dim: Optional[int] = None
+    moe_score: str = "softmax"             # MoEMlp.score
+    moe_selection_bias: bool = False       # MoEMlp.selection_bias
+    moe_routed_scale: Optional[float] = None  # MoEMlp.routed_scale
     moe_held_experts: Optional[tuple] = None
     moe_shared_expert_gated: bool = True
     moe_router_pre_attention: bool = False  # TransformerBlock
@@ -1272,10 +1308,12 @@ class Encoder(nn.Module):
     eva_window: int = 2048
     eva_chunk: int = 16
     norm_unit_offset: bool = False  # norm='rms' only (make_norm)
-    # one mixer kind per block, 'attention' | 'mamba' (TransformerBlock.
-    # mixer), as long as the depth; None builds every block with attention
+    # one mixer kind per block, 'attention' | 'mamba' | 'latent'
+    # (TransformerBlock.mixer), as long as the depth; None builds every
+    # block with attention
     mixers: Optional[tuple] = None
     ssm: Optional[ssm_lib.SSMShape] = None  # the 'mamba' blocks' widths
+    mla: Optional[mla_lib.MLAShape] = None  # the 'latent' blocks' widths
     residual_multiplier: Optional[float] = None  # TransformerBlock
 
     @nn.compact
@@ -1285,7 +1323,7 @@ class Encoder(nn.Module):
         mask: Optional[jax.Array] = None,
         train: bool = False,
     ) -> jax.Array:
-        for name in ("mixers", "windows", "rope_layers"):
+        for name in ("mixers", "windows", "rope_layers", "mlps"):
             per_block = getattr(self, name)
             if per_block is not None and len(per_block) != self.depth:
                 raise ValueError(
@@ -1307,13 +1345,19 @@ class Encoder(nn.Module):
                 )
             body = nn.remat(body, policy=policy)
         for i in range(self.depth):
-            is_moe = (
-                self.num_experts > 0 and i % self.moe_every == self.moe_every - 1
-            )
+            if self.mlps is not None and self.mlps[i] not in (
+                    "dense", "experts"):
+                raise ValueError(
+                    f"mlps[{i}] must be 'dense' or 'experts', got "
+                    f"{self.mlps[i]!r}")
+            is_moe = self.num_experts > 0 and (
+                i % self.moe_every == self.moe_every - 1
+                if self.mlps is None else self.mlps[i] == "experts")
             block = TransformerBlock(
                 num_heads=self.num_heads,
                 head_dim=self.head_dim,
-                mlp_dim=self.mlp_dim,
+                mlp_dim=(self.moe_mlp_dim if is_moe and self.moe_mlp_dim
+                         else self.mlp_dim),
                 dtype=self.dtype,
                 dropout_rate=self.dropout_rate,
                 attn_impl=self.attn_impl,
@@ -1358,6 +1402,10 @@ class Encoder(nn.Module):
                 mixer=(self.mixers[i] if self.mixers is not None
                        else "attention"),
                 ssm=self.ssm,
+                mla=self.mla,
+                moe_score=self.moe_score,
+                moe_selection_bias=self.moe_selection_bias,
+                moe_routed_scale=self.moe_routed_scale,
                 residual_multiplier=self.residual_multiplier,
                 name=f"block_{i}",
             )
@@ -1366,3 +1414,180 @@ class Encoder(nn.Module):
             return x  # post-LN blocks already end normalized
         return make_norm(self.norm, self.ln_eps, self.norm_unit_offset)(
             name="ln_final")(x)
+
+
+class LatentAttention(nn.Module):
+    """Latent (multi-head latent, MLA) self-attention in
+    MultiHeadAttention's place (`TransformerBlock.mixer='latent'`; the
+    arithmetic is ops/mla.py's): `query` [d, H, nope + rope] (the query is
+    not compressed), `kv_down` [d, latent + rope] to the latent `c` and the
+    ONE rotary key `k_r` all heads share, `kv_norm` (RMSNorm of the latent
+    before it is up-projected; no norm per head: that would not be linear
+    in `c`), `kv_up` [latent, H, nope + value] and `out` [H, value, d]; no
+    bias. Causal. Rotary positions turn `k_r` and the queries' `rope`
+    features; a yarn scaling's temperature is NOT folded into the tables
+    (it would scale the rotated part of a score alone) but squared onto
+    the whole score (ops/rotary.attention_temperature).
+
+    Under decode=True the "cache" collection holds the cell `[c, k_r]` of
+    every position as two leaves, `cached_latent` [B, max_len, latent] and
+    `cached_rope_key` [B, max_len, rope] (one leaf of latent + rope values
+    is no multiple of the 128 lanes: the chip then keeps it with the
+    positions in the lanes and every decode scan copies it to rows of
+    cells and back, PERF.md section 6, PR 40), with no head axis and no
+    value leaf, and `cache_index`. Which path a call takes follows from
+    what it is:
+    - S > 1 from position 0 under one shared index (a wave into a fresh
+      row cache, `generate`'s prompt): every key is the call's own, so it
+      attends PER HEAD over its own tokens (K and V up-projected,
+      `ops/mla.prefill_attention`: the flash forward on the chip once the
+      scores pass 1 GiB, `HEAD_CHUNK` heads at a time) and writes its
+      cells;
+    - a step (S = 1), and any call behind cached cells or under per-row
+      indices: ABSORBED over the cache (`ops/mla.absorbed_attention`), the
+      up-projections on the query's side, a block of queries at a time
+      where there are many. No K or V per head is ever formed there.
+    The cache is a cell per position: an index rewind hides a padded tail
+    as it does in a K/V slab. The block pool and int8 cells are not built
+    for this leaf (`TransformerBlock` refuses them)."""
+
+    num_heads: int
+    shape: mla_lib.MLAShape
+    dtype: jnp.dtype = jnp.bfloat16
+    attn_impl: str = "auto"
+    decode: bool = False
+    rope_theta: float = 10_000.0
+    rope_scaling: Optional[tuple] = None
+    ln_eps: float = 1e-6
+
+    @nn.compact
+    def __call__(self, x: jax.Array, mask: Optional[jax.Array] = None,
+                 train: bool = False) -> jax.Array:
+        if mask is not None:
+            raise NotImplementedError(
+                "latent attention is causal self-attention and takes no "
+                "explicit mask")
+        b = batch_axes()
+        shape, heads = self.shape, self.num_heads
+        bsz, sq, width = x.shape
+        init = nn.initializers.lecun_normal
+        w_q = self.param("query", init(in_axis=0, out_axis=(1, 2)),
+                         (width, heads, shape.query), jnp.float32)
+        w_down = self.param("kv_down", init(), (width, shape.cell),
+                            jnp.float32)
+        w_up = self.param("kv_up", init(in_axis=0, out_axis=(1, 2)),
+                          (shape.latent, heads, shape.nope + shape.value),
+                          jnp.float32)
+        w_out = self.param("out", init(in_axis=(0, 1), out_axis=2),
+                           (heads, shape.value, width), jnp.float32)
+        w_q, w_down, w_up, w_out = (w.astype(self.dtype)
+                                    for w in (w_q, w_down, w_up, w_out))
+        x = x.astype(self.dtype)
+        scale = shape.query ** -0.5 * attention_temperature(
+            self.rope_scaling) ** 2
+
+        filled = self.decode and self.has_variable("cache", "cached_latent")
+        if self.decode:
+            cached_c = self.variable("cache", "cached_latent", jnp.zeros,
+                                     (bsz, sq, shape.latent), self.dtype)
+            cached_r = self.variable("cache", "cached_rope_key", jnp.zeros,
+                                     (bsz, sq, shape.rope), self.dtype)
+            cache_index = self.variable("cache", "cache_index",
+                                        lambda: jnp.zeros((), jnp.int32))
+        idx = cache_index.value if filled else jnp.zeros((), jnp.int32)
+        pos = idx[..., None] + jnp.arange(sq, dtype=jnp.int32)  # [S] | [B,S]
+        rotate = functools.partial(
+            apply_rotary, positions=pos, theta=self.rope_theta,
+            scaling=self.rope_scaling, fold_temperature=False)
+
+        down = jnp.einsum("bsd,dc->bsc", x, w_down)
+        c = make_norm("rms", self.ln_eps)(name="kv_norm")(
+            down[..., :shape.latent]).astype(self.dtype)
+        k_r = rotate(down[..., None, shape.latent:])[:, :, 0]
+
+        def queries(x, w_q, rotate=rotate):
+            q = jnp.einsum("bsd,dhq->bshq", x, w_q)
+            return q[..., :shape.nope], rotate(q[..., shape.nope:])
+
+        def through_out(o, w):
+            return jnp.einsum("bshv,hvd->bsd", o, w,
+                              preferred_element_type=jnp.float32)
+
+        def per_head():
+            chunk = mla_lib.head_chunks(bsz, heads, sq)
+
+            def some(i):
+                heads_of = lambda w, axis=1: jax.lax.dynamic_slice_in_dim(
+                    w, i * chunk, chunk, axis)
+                q_nope, q_rope = queries(x, heads_of(w_q))
+                kv = jnp.einsum("bsc,chk->bshk", c, heads_of(w_up))
+                return through_out(mla_lib.prefill_attention(
+                    q_nope, q_rope, kv[..., :shape.nope], k_r,
+                    kv[..., shape.nope:], scale=scale, impl=self.attn_impl),
+                    heads_of(w_out, 0))
+
+            with jax.named_scope("mla_prefill"):
+                if chunk == heads:
+                    return some(0).astype(self.dtype)
+                # a chunk's heads go through their rows of `out` at once:
+                # no [B, S, H, value] of all heads is ever laid out
+                y, _ = jax.lax.scan(
+                    lambda y, i: (y + some(i), None),
+                    jnp.zeros((bsz, sq, width), jnp.float32),
+                    jnp.arange(heads // chunk))
+                return y.astype(self.dtype)
+
+        def absorbed(latents, rope_keys):
+            cols = jnp.arange(latents.shape[1], dtype=jnp.int32)
+            block = mla_lib.query_blocks(bsz, heads, sq, latents.shape[1])
+
+            def some(i):
+                rows = lambda t: jax.lax.dynamic_slice_in_dim(
+                    t, i * block, block, 1)
+                at = rows(jnp.broadcast_to(pos, (bsz, sq)))
+                q_nope, q_rope = queries(
+                    rows(x), w_q, functools.partial(rotate, positions=at))
+                q_abs = jnp.einsum("bshn,chn->bshc", q_nope,
+                                   w_up[..., :shape.nope])
+                o_lat = mla_lib.absorbed_attention(
+                    q_abs, q_rope, latents, rope_keys,
+                    cols <= at[..., None], scale=scale)
+                return through_out(
+                    jnp.einsum("bshc,chv->bshv", o_lat,
+                               w_up[..., shape.nope:]),
+                    w_out).astype(self.dtype)
+
+            with jax.named_scope("mla_decode"):
+                if block == sq:
+                    return some(0)
+                out = jax.lax.map(some, jnp.arange(sq // block))
+                return jnp.moveaxis(out, 0, 1).reshape(bsz, sq, width)
+
+        if not filled:
+            # the plain forward, and decode's init pass (the variables were
+            # just created from this call's [B, max_len] input)
+            y = per_head()
+        else:
+            if sq > cached_c.value.shape[1]:
+                raise ValueError(
+                    f"input length {sq} exceeds the cache budget "
+                    f"{cached_c.value.shape[1]}; re-init the cache with a "
+                    f"larger max_len")
+            with jax.named_scope("mla_cache_write"):
+                if idx.ndim == 0:
+                    put = lambda cells, new: jax.lax.dynamic_update_slice(
+                        cells, new.astype(cells.dtype), (0, idx, 0))
+                else:
+                    put = lambda cells, new: eva_lib._rows_update(
+                        cells, new, idx)
+                latents = put(cached_c.value, c)
+                rope_keys = put(cached_r.value, k_r)
+            cached_c.value = constrain(latents, b, None, None)
+            cached_r.value = constrain(rope_keys, b, None, None)
+            cache_index.value = idx + sq
+            if sq > 1 and idx.ndim == 0:
+                y = jax.lax.cond(idx == 0, per_head,
+                                 lambda: absorbed(latents, rope_keys))
+            else:
+                y = absorbed(latents, rope_keys)
+        return constrain(y, b, "seq")

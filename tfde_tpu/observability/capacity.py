@@ -92,6 +92,15 @@ def _kv_layer_cells(cache) -> list:
             if str(getattr(path[-1], "key", path[-1])) == "cached_key"]
 
 
+def _latent_layers(cache) -> int:
+    """Layers of a cache that keep one latent cell per position (models/
+    transformer.py `LatentAttention`): its `cached_latent` leaves."""
+    import jax
+
+    return sum(1 for path, _ in jax.tree_util.tree_leaves_with_path(cache)
+               if str(getattr(path[-1], "key", path[-1])) == "cached_latent")
+
+
 def kv_dtype_census(cache) -> dict:
     """Dtype split of a KV cache tree (index leaves and block tables
     excluded): payload vs scale-sidecar bytes, the payload leaf dtype,
@@ -173,7 +182,10 @@ class CapacityLedger:
         attention='eva' gets the ledger of windows and summaries, a cache
         in which some layer holds fewer cells a row than `cells_per_row`
         (a window layer's ring) the ledger that counts a layer's cells at
-        a time, a model
+        a time, a cache of latent cells (`cached_latent` leaves beside
+        their `cached_rope_key`: a cell per position like a K/V slab's,
+        `latent + rope` values wide with no head axis) the ledger that
+        counts them by layer, a model
         with state-space layers (whose rows cost the same whatever their
         length) or with experts routed without a capacity the hybrid
         one, which reads the experts' bytes off `params`."""
@@ -184,6 +196,9 @@ class CapacityLedger:
             # some layer keeps a ring shorter than the row: the cache's
             # own leaves say which, and how long
             return RingCapacityLedger.of_model(
+                cache, batch_size, cells_per_row, params, registry=registry)
+        if _latent_layers(cache):
+            return LatentCapacityLedger.of_model(
                 cache, batch_size, cells_per_row, params, registry=registry)
         if "mamba" in (getattr(model, "mixers", None) or ()) or (
                 getattr(model, "num_experts", 0)
@@ -621,6 +636,78 @@ class RingCapacityLedger(HybridCapacityLedger):
             self._counters["kv_full_cells_read"] += depth * full
             self._counters["kv_window_cells_read"] += depth * window
             self._counters["kv_window_wraps"] += wraps
+
+
+class LatentCapacityLedger(HybridCapacityLedger):
+    """Occupancy of a cache whose attention layers keep ONE LATENT CELL per
+    position (models/transformer.py `LatentAttention`: `cached_latent`
+    [rows, positions, latent] and `cached_rope_key` [rows, positions,
+    rope], the latent and the one rotary key, no head axis and no value
+    leaf): a cell per position as in a K/V
+    slab, with a size of its own (1,152 B at 512 + 64 values in bfloat16,
+    where 64 heads of K and V are 40,960).
+
+    The unit is one layer's cell of one position. A row that has committed
+    `n` tokens holds, and a decode tick reads, n cells in each such layer.
+    Expert layers routed without a capacity (and state-space state, where
+    a model had both) are the parent's account, so `scan_least_bytes`
+    counts the parameters outside the experts, the experts touched among
+    those held and the committed cells at their own size.
+
+    `counters` adds LATENT_KEYS to the parent's, all from the rows' TRUE
+    lengths, never `max_len`: cells written by prefills and decode ticks
+    (`latent_cells_committed`), per scan depth x the committed cells of
+    its active rows (`latent_cells_read`), and the (query, cell) pairs the
+    prefills attended causally, n (n + 1) / 2 a layer for a prompt of n
+    (`latent_pairs_prefilled`)."""
+
+    LATENT_KEYS = ("latent_cells_committed", "latent_cells_read",
+                   "latent_pairs_prefilled")
+
+    def __init__(self, batch_size: int, positions: int, layers: int,
+                 slab_bytes: int, state_row_bytes: int = 0,
+                 expert_bytes: int = 0, expert_slots: int = 0,
+                 registry: Optional[metrics.Registry] = None,
+                 census: Optional[dict] = None):
+        self._layers = int(layers)
+        super().__init__(batch_size, int(positions) * self._layers,
+                         slab_bytes, state_row_bytes, expert_bytes,
+                         expert_slots, registry=registry, census=census)
+        self._counters.update(dict.fromkeys(self.LATENT_KEYS, 0))
+
+    @classmethod
+    def of_model(cls, cache, batch_size: int, positions: int, params,
+                 registry: Optional[metrics.Registry] = None
+                 ) -> "LatentCapacityLedger":
+        """From a freshly-initialized batch cache and the served
+        parameters: every `cached_latent` leaf is one layer, the rest as
+        the parent reads it."""
+        return cls(batch_size, positions, _latent_layers(cache),
+                   kv_slab_bytes(cache),
+                   *cls._state_and_experts(cache, batch_size, params),
+                   registry=registry, census=kv_dtype_census(cache))
+
+    def row_cells(self, n: int) -> int:
+        return self._state_cells + self._layers * int(n)
+
+    def read_cells(self, n: int) -> int:
+        return 2 * self._state_cells + self._layers * int(n)
+
+    def note_commit(self, before: int, after: int,
+                    decoding: bool = True) -> None:
+        with self._lock:
+            self._counters["latent_cells_committed"] += (
+                self._layers * (int(after) - int(before)))
+            if not decoding:
+                self._counters["latent_pairs_prefilled"] += (
+                    self._layers * (int(after) * (int(after) + 1)
+                                    - int(before) * (int(before) + 1)) // 2)
+
+    def note_scan(self, committed, depth: int) -> None:
+        super().note_scan(committed, depth)
+        with self._lock:
+            self._counters["latent_cells_read"] += (
+                depth * self._layers * sum(int(n) for n in committed))
 
 
 class PagedCapacityLedger(CapacityLedger):

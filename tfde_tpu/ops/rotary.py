@@ -18,6 +18,8 @@ transparently under any mesh, including the 'seq' ring."""
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -93,8 +95,27 @@ def scale_frequencies(freqs: jax.Array, scaling,
     )
 
 
+def attention_temperature(scaling) -> float:
+    """yarn's attention temperature, the `attention_factor` of its tuple
+    (1.0 for every other scaling and for none). `rotary_angles` folds it
+    into cos and sin, which scales the rotated features alone; a caller
+    whose scores add a part that is not rotated (latent attention:
+    models/transformer.py `LatentAttention`) asks for the tables as they
+    are (`fold_temperature=False`) and puts this on the whole score."""
+    if scaling is not None and scaling[0] == "yarn":
+        return float(scaling[5])
+    return 1.0
+
+
+def yarn_temperature(factor: float, mscale: float = 1.0) -> float:
+    """The temperature the yarn family derives from its factor:
+    0.1 x mscale x ln(factor) + 1 (1 where nothing is stretched)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
 def rotary_angles(positions: jax.Array, dim: int,
-                  theta: float = 10_000.0, scaling=None) -> tuple:
+                  theta: float = 10_000.0, scaling=None,
+                  fold_temperature: bool = True) -> tuple:
     """(cos, sin) [..., dim/2] for integer `positions` [...]."""
     if dim % 2:
         raise ValueError(f"rotary head_dim must be even, got {dim}")
@@ -105,17 +126,18 @@ def rotary_angles(positions: jax.Array, dim: int,
         freqs = scale_frequencies(freqs, scaling, theta)
     ang = positions.astype(jnp.float32)[..., None] * freqs  # [..., dim/2]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
-    if scaling is not None and scaling[0] == "yarn":
+    if fold_temperature and attention_temperature(scaling) != 1.0:
         # yarn's attention temperature: cos/sin scale by the attention
         # factor (HF multiplies the cached cos/sin the same way)
-        att = float(scaling[5])
+        att = attention_temperature(scaling)
         cos, sin = cos * att, sin * att
     return cos, sin
 
 
 def apply_rotary(x: jax.Array, positions: jax.Array,
                  theta: float = 10_000.0,
-                 rotary_dim=None, scaling=None) -> jax.Array:
+                 rotary_dim=None, scaling=None,
+                 fold_temperature: bool = True) -> jax.Array:
     """Rotate [B, S, H, D] by per-token angles; `positions` is [S] or
     [B, S] absolute token positions. fp32 trig, result in x.dtype.
 
@@ -124,7 +146,8 @@ def apply_rotary(x: jax.Array, positions: jax.Array,
     pass through untouched. None/D = full rotation.
 
     scaling: RoPE frequency rescaling tuple (see scale_frequencies) —
-    the Llama-3.1 long-context convention."""
+    the Llama-3.1 long-context convention. `fold_temperature=False`
+    leaves yarn's temperature to the caller (`attention_temperature`)."""
     d = x.shape[-1]
     if rotary_dim is not None and rotary_dim != d:
         if not 0 < rotary_dim < d:
@@ -133,10 +156,12 @@ def apply_rotary(x: jax.Array, positions: jax.Array,
             )
         rot, rest = x[..., :rotary_dim], x[..., rotary_dim:]
         return jnp.concatenate(
-            [apply_rotary(rot, positions, theta, scaling=scaling), rest],
+            [apply_rotary(rot, positions, theta, scaling=scaling,
+                          fold_temperature=fold_temperature), rest],
             axis=-1,
         )
-    cos, sin = rotary_angles(positions, d, theta, scaling)  # [..., S, d/2]
+    cos, sin = rotary_angles(positions, d, theta, scaling,
+                             fold_temperature)  # [..., S, d/2]
     # broadcast to [B, S, 1, d/2] over heads
     if cos.ndim == 2:  # [S, d/2] -> [1, S, 1, d/2]
         cos, sin = cos[None, :, None], sin[None, :, None]
