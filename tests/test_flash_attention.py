@@ -401,7 +401,8 @@ def _forward_and_path(q, k, v, causal, bq, bk, window=None, scale=None,
         jax.block_until_ready(out)
         jax.effects_barrier()
     return (out, fa._lse_bhs(lse), dict(counts),
-            _bumped(before, "fwd_lane_traces", "fwd_grid_traces"))
+            _bumped(before, "fwd_lane_traces", "fwd_grid_traces",
+                    "fwd_two_width_traces"))
 
 
 def _reference_out_and_lse(q, k, v, causal, window, scale, cap):
@@ -469,7 +470,8 @@ def test_lane_forward_matches_reference_and_grid(
     out, lse, counts, bumped = _forward_and_path(q, k, v, causal, bq, bk,
                                                  window, scale, cap)
     assert counts["fwd_path"] == "lane"
-    assert bumped == {"fwd_lane_traces": 1, "fwd_grid_traces": 0}
+    assert bumped == {"fwd_lane_traces": 1, "fwd_grid_traces": 0,
+                      "fwd_two_width_traces": 0}
     plan = bwd_tile_plan(s, bq, bk, causal, window)
     assert counts["fwd_visits"] == plan["visits"]
     assert counts["fwd_steps_executed"] == plan["visits"]
@@ -500,64 +502,138 @@ def test_lane_forward_matches_reference_and_grid(
                                rtol=0, atol=1e-5)
 
 
-@pytest.mark.parametrize("name,s,h,kv,d,causal,window,budget,path", [
-    ("mha_causal", 128, 2, 2, 64, True, None, None, "lane"),
-    ("mha_non_causal", 128, 2, 2, 64, False, None, None, "lane"),
-    ("one_head_of_128", 128, 1, 1, 128, True, None, None, "lane"),
-    ("heads_under_a_lane_block", 128, 4, 4, 8, True, None, None, "lane"),
-    ("grouped_query", 128, 2, 1, 64, True, None, None, "grid"),
-    ("head_width_no_lane_block_tiles", 128, 3, 3, 64, True, None, None,
+@pytest.mark.parametrize("name,s,h,kv,d,dv,causal,window,budget,path", [
+    ("mha_causal", 128, 2, 2, 64, 64, True, None, None, "lane"),
+    ("mha_non_causal", 128, 2, 2, 64, 64, False, None, None, "lane"),
+    ("one_head_of_128", 128, 1, 1, 128, 128, True, None, None, "lane"),
+    ("heads_under_a_lane_block", 128, 4, 4, 8, 8, True, None, None, "lane"),
+    ("grouped_query", 128, 2, 1, 64, 64, True, None, None, "grid"),
+    ("head_width_no_lane_block_tiles", 128, 3, 3, 64, 64, True, None, None,
      "grid"),
-    ("head_width_96", 128, 4, 4, 96, True, None, None, "grid"),
-    ("past_vmem", 128, 2, 2, 64, True, None, 0, "grid"),
-    ("grouped_query_of_128", 128, 4, 2, 128, True, None, None, "lane"),
-    ("seven_over_one_of_128", 128, 7, 1, 128, True, None, None, "lane"),
-    ("grouped_query_of_128_non_causal", 128, 4, 2, 128, False, None, None,
+    ("head_width_96", 128, 4, 4, 96, 96, True, None, None, "grid"),
+    ("past_vmem", 128, 2, 2, 64, 64, True, None, 0, "grid"),
+    ("grouped_query_of_128", 128, 4, 2, 128, 128, True, None, None, "lane"),
+    ("seven_over_one_of_128", 128, 7, 1, 128, 128, True, None, None, "lane"),
+    ("grouped_query_of_128_non_causal", 128, 4, 2, 128, 128, False, None,
+     None, "lane"),
+    ("grouped_query_of_256", 128, 4, 1, 256, 256, True, None, None, "lane"),
+    ("grouped_window_across_tile_edges", 384, 6, 2, 128, 128, True, 100,
+     None, "lane"),
+    ("grouped_window_of_two_tiles", 256, 4, 2, 128, 128, True, 128, None,
      "lane"),
-    ("grouped_query_of_256", 128, 4, 1, 256, True, None, None, "lane"),
-    ("grouped_window_across_tile_edges", 384, 6, 2, 128, True, 100, None,
-     "lane"),
-    ("grouped_window_of_two_tiles", 256, 4, 2, 128, True, 128, None, "lane"),
-    ("grouped_query_of_64_pairs", 128, 4, 2, 64, True, None, None, "grid"),
-    ("grouped_query_of_128_past_vmem", 128, 4, 2, 128, True, None, 0,
+    ("grouped_query_of_64_pairs", 128, 4, 2, 64, 64, True, None, None,
      "grid"),
+    ("grouped_query_of_128_past_vmem", 128, 4, 2, 128, 128, True, None, 0,
+     "grid"),
+    # values of a width of their own: both widths whole lane blocks stay
+    # apart in the lane kernel, any other unequal pair is padded to one
+    # width inside `_flash_forward` and takes what that width takes
+    ("score_256_value_128", 128, 2, 2, 256, 128, True, None, None,
+     "two_widths"),
+    ("score_256_value_128_of_two_tiles_non_causal", 256, 3, 3, 256, 128,
+     False, None, None, "two_widths"),
+    ("score_128_value_256", 128, 2, 2, 128, 256, True, None, None,
+     "two_widths"),
+    ("score_256_value_128_window", 384, 2, 2, 256, 128, True, 100, None,
+     "two_widths"),
+    ("grouped_score_256_value_128", 128, 4, 2, 256, 128, True, None, None,
+     "two_widths"),
+    ("score_64_value_32_padded", 128, 2, 2, 64, 32, True, None, None,
+     "lane"),
+    ("score_32_value_64_padded", 128, 2, 2, 32, 64, True, None, None,
+     "lane"),
+    ("score_192_value_128_padded", 128, 3, 3, 192, 128, True, None, None,
+     "grid"),
+    ("score_256_value_128_past_vmem_padded", 128, 2, 2, 256, 128, True,
+     None, 0, "grid"),
 ])
 def test_fwd_chooses_from_its_operands(rng, monkeypatch, name, s, h, kv, d,
-                                       causal, window, budget, path):
+                                       dv, causal, window, budget, path):
     """`_flash_forward` counts the kernel it took at trace time: the lane
     kernel for multi-head attention whose heads tile the lanes and for
     grouped-query attention whose K/V heads are whole lane blocks (heads
     of 128), the grid kernel for grouped-query heads of 64, head widths no
     lane block tiles, and a block past the VMEM budget; out and lse match
     the float32 reference either way, and the lane kernel's loop ran the
-    K steps of the plan, once for the whole group."""
+    K steps of the plan, once for the whole group. Values `dv` wide where
+    the scores are `d` wide: counted as two widths only where the lane
+    kernel took them apart ("two_widths"), and out is `dv` wide whichever
+    way."""
     from tfde_tpu.ops.attention import grouped_attention
 
     if budget is not None:
         monkeypatch.setattr(
             "tfde_tpu.ops.flash_attention._FWD_KERNEL_VMEM_BUDGET", budget)
+    two_widths = path == "two_widths"
+    path = "lane" if two_widths else path
     q = jnp.asarray(rng.standard_normal((1, s, h, d)), jnp.float32)
-    k, v = (jnp.asarray(rng.standard_normal((1, s, kv, d)), jnp.float32)
-            for _ in range(2))
+    k = jnp.asarray(rng.standard_normal((1, s, kv, d)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((1, s, kv, dv)), jnp.float32)
     out, lse, counts, bumped = _forward_and_path(q, k, v, causal, 64, 64,
                                                  window)
     assert counts["fwd_path"] == path
     assert bumped == {"fwd_lane_traces": int(path == "lane"),
-                      "fwd_grid_traces": int(path == "grid")}
+                      "fwd_grid_traces": int(path == "grid"),
+                      "fwd_two_width_traces": int(two_widths)}
     assert ("fwd_steps_executed" in counts) == (path == "lane")
     if path == "lane":
         assert counts["fwd_steps_executed"] == counts["fwd_visits"]
-    assert out.shape == q.shape and lse.shape == (1, h, s)
+    assert out.shape == (1, s, h, dv) and lse.shape == (1, h, s)
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(grouped_attention(
             q, k, v, causal=causal, window=window)),
         rtol=2e-5, atol=2e-5)
-    # a query head h reads K/V head h // group: lse in that order
-    _, want_lse = _reference_out_and_lse(
+    # a query head h reads K/V head h // group: out and lse in that order
+    want_out, want_lse = _reference_out_and_lse(
         q, np.repeat(k, h // kv, axis=2), np.repeat(v, h // kv, axis=2),
         causal, window, None, None)
+    np.testing.assert_allclose(np.asarray(out, np.float64), want_out,
+                               rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(np.asarray(lse, np.float64), want_lse,
                                rtol=2e-5, atol=2e-5)
+
+
+def test_two_widths_give_what_the_padded_call_gives_to_the_bit(rng):
+    """Only products with zeros go: values at their own width against the
+    same values padded to the score width, through the same kernel."""
+    q, k = (jnp.asarray(rng.standard_normal((1, 256, 2, 256)), jnp.bfloat16)
+            for _ in range(2))
+    v = jnp.asarray(rng.standard_normal((1, 256, 2, 128)), jnp.bfloat16)
+    own, own_lse, _, bumped = _forward_and_path(q, k, v, True, 64, 128)
+    wide, wide_lse, _, _ = _forward_and_path(
+        q, k, jnp.pad(v, ((0, 0),) * 3 + ((0, 128),)), True, 64, 128)
+    assert bumped["fwd_two_width_traces"] == 1
+    assert own.shape == v.shape and wide.shape == q.shape
+    np.testing.assert_array_equal(np.asarray(own, np.float32),
+                                  np.asarray(wide[..., :128], np.float32))
+    np.testing.assert_array_equal(np.asarray(wide[..., 128:], np.float32), 0)
+    np.testing.assert_array_equal(np.asarray(own_lse), np.asarray(wide_lse))
+
+
+def test_k_and_v_must_agree_in_all_but_their_width(rng):
+    q, k, _ = _qkv(rng, s=128, h=2, d=64)
+    for shape in ((2, 128, 1, 64), (2, 64, 2, 64), (1, 128, 2, 64)):
+        with pytest.raises(ValueError, match="must match in batch, length"):
+            flash_attention(q, k, jnp.zeros(shape, q.dtype), causal=True,
+                            interpret=True)
+
+
+@pytest.mark.parametrize("name,d,dv", [("lane_kernel_two_widths", 256, 128),
+                                       ("padded_to_one_width", 64, 32)])
+def test_gradient_through_unequal_widths_raises_by_name(rng, name, d, dv):
+    """Only the forward takes two widths: no backward reads them, and a
+    silently wrong gradient is the thing to rule out."""
+    q, k = (jnp.asarray(rng.standard_normal((1, 128, 2, d)), jnp.float32)
+            for _ in range(2))
+    v = jnp.asarray(rng.standard_normal((1, 128, 2, dv)), jnp.float32)
+    out = flash_attention(q, k, v, causal=True, block_q=64, block_k=64,
+                          interpret=True)
+    assert out.shape == v.shape
+    with pytest.raises(NotImplementedError,
+                       match="only the forward takes values of a width"):
+        jax.grad(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, block_q=64, block_k=64,
+            interpret=True).sum(), argnums=(0, 1, 2))(q, k, v)
 
 
 #: sha256 of the Mosaic module the forward lowers to at the training
@@ -604,6 +680,24 @@ def test_fwd_leaves_what_does_not_fit_vmem_to_the_grid():
         <= fa._FWD_KERNEL_VMEM_BUDGET // 2
     assert fa._fwd_lane_vmem_bytes(131072, 1, 128, 2, 512, 512, 7) \
         > fa._FWD_KERNEL_VMEM_BUDGET
+    # values of a width of their own: the latent cell's longest wave keeps
+    # K at 256 and V at 128 whole, 47 MB where both at 256 are 63, and
+    # equal widths named twice count what one width counted
+    resident = lambda wv: fa._fwd_lane_vmem_bytes(
+        30720, 1, 256, 2, 512, 512, 1, wv) - fa._fwd_lane_vmem_bytes(
+            0, 1, 256, 2, 512, 512, 1, wv)
+    assert resident(256) == 2 * 2 * 30720 * 256 * 2
+    assert resident(128) == 2 * 30720 * (256 + 128) * 2
+    assert fa._fwd_lane_vmem_bytes(30720, 1, 256, 2, 512, 512, 1, 128) \
+        < fa._fwd_lane_vmem_bytes(30720, 1, 256, 2, 512, 512) \
+        == fa._fwd_lane_vmem_bytes(30720, 1, 256, 2, 512, 512, 1, 256) \
+        <= fa._FWD_KERNEL_VMEM_BUDGET
+    assert fa._fwd_lane_vmem_bytes(4096, 2, 128, 2, 512, 512, 1, 128) \
+        == fa._fwd_lane_vmem_bytes(4096, 2, 128, 2, 512, 512)
+    # a K of 256 that fits with V at 128 and not with V at 256
+    assert fa._fwd_lane_vmem_bytes(57344, 1, 256, 2, 512, 512, 1, 128) \
+        <= fa._FWD_KERNEL_VMEM_BUDGET \
+        < fa._fwd_lane_vmem_bytes(57344, 1, 256, 2, 512, 512)
 
 
 @pytest.mark.parametrize("s,bq,bk,causal,window", [

@@ -77,9 +77,8 @@ def test_flash_forward_compiles_for_v5e(one_chip, name, shape, kv_heads,
     the v5e compiler takes (lane widths under and over 128, K and V of a
     long sequence whole in VMEM, the window-and-global cell's seven query
     heads of 128 and the hybrid's four against the K/V head they share, in
-    their longest waves, a latent layer's eight heads with both widths
-    padded to 256 over its longest wave, and the grid kernel where none of
-    that holds)."""
+    their longest waves, eight heads of 256 over the latent cell's longest
+    wave, and the grid kernel where none of that holds)."""
     b, s, h, d = shape
     q = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
     k = jax.ShapeDtypeStruct((b, s, kv_heads, d), jnp.bfloat16,
@@ -96,6 +95,29 @@ def test_flash_forward_compiles_for_v5e(one_chip, name, shape, kv_heads,
     assert (f"[{b},{h},{s},{d}]" in text) == head_major
     # the grid kernel's lse is a column a head, the lane kernel's rows
     assert (f"f32[{b},{h},{s},1]" in text) == name.endswith("_grid")
+
+
+@pytest.mark.parametrize("name,s,heads,kv_heads", [
+    ("latent_wave_of_6144", 6144, 8, 8),
+    ("latent_wave_of_30720", 30720, 8, 8),
+    ("grouped_query", 6144, 8, 2),
+])
+def test_two_width_forward_compiles_for_v5e(one_chip, name, s, heads,
+                                            kv_heads):
+    """A latent layer's chunk of eight heads as its waves hand it over:
+    scores over 256 columns (192 padded), values of 128 as they are. One
+    Mosaic call with V, out and the accumulator at 128: no padded copy of
+    v and no slice of out exist around it."""
+    on = lambda heads, width: jax.ShapeDtypeStruct(
+        (1, s, heads, width), jnp.bfloat16, sharding=one_chip)
+    compiled = jax.jit(lambda q, k, v: flash_attention(
+        q, k, v, causal=True)).lower(
+            on(heads, 256), on(kv_heads, 256), on(kv_heads, 128)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "flash_fwd" in text
+    assert "while(" not in text and " pad(" not in text
+    assert compiled.out_info.shape == (1, s, heads, 128)
 
 
 #: (experts held, experts routed over, choices a token, hidden, expert

@@ -262,14 +262,31 @@ def test_the_absorbed_path_equals_the_per_head_path_on_the_same_cache():
         "bshc,chv->bshv", other, w_up[..., SHAPE.nope:]) - want)).max() > 0.01
 
 
-def test_a_long_call_pads_both_widths_to_the_dispatchers_one(monkeypatch):
+@pytest.mark.parametrize("name,heads,nope,rope,value,widths", [
+    # the toy widths: scores over 12 columns, values of 8, each padded to
+    # the lane block above it
+    ("both_under_a_lane_block", HEADS, SHAPE.nope, SHAPE.rope, SHAPE.value,
+     (128, 128)),
+    # the published widths' ratio: scores over 128 + 64, padded to 256;
+    # values of 128 go as they are
+    ("score_192_value_128", 2, 128, 64, 128, (256, 128)),
+    ("score_192_value_96", 2, 128, 64, 96, (256, 128)),
+    ("score_128_value_256", 2, 96, 32, 256, (128, 256)),
+])
+def test_a_long_call_pads_each_width_to_its_own_lane_multiple(
+        monkeypatch, name, heads, nope, rope, value, widths):
     """Past `_SCORES_BYTES` the per-head path goes through the dispatcher
-    with [q_nope, q_rope], [k_nope, k_r] and v padded to one width: the
-    same attention as the two score products."""
-    q_nope, q_rope, c, k_rope, w_up = _latent_operands(s=128, b=1)
-    kv = jnp.einsum("bsc,chk->bshk", c, w_up)
-    args = (q_nope, q_rope, kv[..., :SHAPE.nope], k_rope,
-            kv[..., SHAPE.nope:])
+    with [q_nope, q_rope] and [k_nope, k_r] padded to the lane multiple of
+    the score width and v to that of ITS width (as it is where that is a
+    lane multiple: the flash forward takes the two apart): the same
+    attention as the two score products."""
+    keys = jax.random.split(jax.random.key(1), 5)
+    q_nope, k_nope = (jax.random.normal(key, (1, 128, heads, nope))
+                      for key in keys[:2])
+    q_rope = jax.random.normal(keys[2], (1, 128, heads, rope))
+    k_rope = jax.random.normal(keys[3], (1, 128, rope))
+    v = jax.random.normal(keys[4], (1, 128, heads, value))
+    args = (q_nope, q_rope, k_nope, k_rope, v)
     want = mla_lib.prefill_attention(*args, scale=0.3)
     seen = []
     real = mla_lib.attn_lib.attention
@@ -281,8 +298,38 @@ def test_a_long_call_pads_both_widths_to_the_dispatchers_one(monkeypatch):
     monkeypatch.setattr(mla_lib, "_SCORES_BYTES", 0)
     monkeypatch.setattr(mla_lib.attn_lib, "attention", spy)
     got = mla_lib.prefill_attention(*args, scale=0.3)
-    assert seen == [((1, 128, HEADS, 128),) * 3 + (0.3, True)]
+    score, own = widths
+    assert seen == [((1, 128, heads, score),) * 2
+                    + ((1, 128, heads, own), 0.3, True)]
+    assert got.shape == want.shape == v.shape
     # values of magnitude 8: the order of float32 sums differs
+    assert np.abs(np.asarray(got - want)).max() < TOL
+
+
+def test_a_long_call_reaches_the_two_width_flash_forward():
+    """The same call through the interpreted flash forward: the lane
+    kernel with the score width (256) and the value width (128) apart,
+    counted once, against the two score products."""
+    from tfde_tpu.observability import counters
+
+    keys = jax.random.split(jax.random.key(2), 5)
+    q_nope, k_nope, v = (jax.random.normal(key, (1, 256, 2, 128))
+                         for key in keys[:3])
+    q_rope = jax.random.normal(keys[3], (1, 256, 2, 64))
+    k_rope = jax.random.normal(keys[4], (1, 256, 64))
+    args = (q_nope, q_rope, k_nope, k_rope, v)
+    want = mla_lib.prefill_attention(*args, scale=0.07)
+    before = counters.snapshot()
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(mla_lib, "_SCORES_BYTES", 0)
+        got = mla_lib.prefill_attention(*args, scale=0.07, impl="flash")
+    after = counters.snapshot()
+    assert {name: after.get(f"flash/{name}", 0) - before.get(
+        f"flash/{name}", 0) for name in (
+            "fwd_lane_traces", "fwd_grid_traces", "fwd_two_width_traces")
+            } == {"fwd_lane_traces": 1, "fwd_grid_traces": 0,
+                  "fwd_two_width_traces": 1}
+    assert got.shape == v.shape
     assert np.abs(np.asarray(got - want)).max() < TOL
 
 

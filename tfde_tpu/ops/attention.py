@@ -169,7 +169,7 @@ def grouped_attention(
         "bkgqs,bskd->bqkgd", weights.astype(v.dtype), v,
         preferred_element_type=jnp.float32,
     )
-    return out.reshape(b, sq, h, d).astype(q.dtype)
+    return out.reshape(b, sq, h, v.shape[-1]).astype(q.dtype)
 
 
 def _seq_parallel_active() -> bool:

@@ -13,7 +13,11 @@ its operands, as `_bwd` does: whole K/V heads tiling the 128 lanes
 grouped-query, each a lane block of its own (heads of 128 or 256), and a
 lane block's K and V inside VMEM take the lane kernel; grouped-query with
 heads of 64, head widths no lane block tiles and longer sequences keep the
-grid kernel.
+grid kernel. The values may have a width of their own (v [B, S, Kv, Dv]: a
+latent layer scores over 192 columns a head and carries 128): the lane
+kernel takes D and Dv apart where both are whole lane blocks, so V^T p, the
+accumulator and V's fetch cost Dv and no more; any other unequal pair is
+padded to one width inside `_flash_forward` (forward only: `_bwd` refuses).
 - the lane kernel (`_fwd_lane_kernel`): grid (batch, K/V lane block, Q
   tile) with K and V of the lane block resident in VMEM (fetched once a
   block) and an in-kernel loop over the K tiles `_tile_in_band` keeps
@@ -330,16 +334,20 @@ def _k_tile_range(qi, block_q: int, block_k: int, n_k: int, causal, window):
 
 
 def _fwd_lane_vmem_bytes(s: int, heads: int, width: int, itemsize: int,
-                         block_q: int, block_k: int, group: int = 1) -> int:
+                         block_q: int, block_k: int, group: int = 1,
+                         value_width=None) -> int:
     """What `_fwd_lane_kernel` keeps in VMEM for one K/V lane block of
-    `width` lanes (`heads` K/V heads) and the `group` query heads of each:
-    K and V whole and double-buffered, the Q and out tiles (`group` of
-    them) double-buffered, the float32 accumulator, lse and the statistics
-    padded to 8 sublanes, and the float32 score tiles: every query head's
-    at once beside the compiler's own (a dozen at two heads)."""
+    `width` lanes (`heads` K/V heads; V and out `value_width` lanes where
+    the values have a width of their own) and the `group` query heads of
+    each: K and V whole and double-buffered, the Q and out tiles (`group`
+    of them) double-buffered, the float32 accumulator, lse and the
+    statistics padded to 8 sublanes, and the float32 score tiles: every
+    query head's at once beside the compiler's own (a dozen at two
+    heads)."""
     w = max(width, 128)
-    whole = 2 * 2 * s * w * itemsize
-    tiles = block_q * w * group * (2 * 2 * itemsize + 4)
+    wv = w if value_width is None else max(value_width, 128)
+    whole = 2 * s * (w + wv) * itemsize
+    tiles = block_q * group * (2 * (w + wv) * itemsize + 4 * wv)
     rows = 4 * 8 * block_q * 4 * group
     return (whole + tiles + rows
             + (8 + 2 * heads * group) * block_q * block_k * 4)
@@ -365,9 +373,13 @@ def _fwd_lane_kernel(
     #   view, q/out [1, group, bq, d] the query heads of the one K/V head
     #   k/v [1, S, d], so each K and V tile is read from VMEM once for the
     #   whole group.
+    # v and out may have a width of their own (`dv` a head: whole lane
+    # blocks, as d then is), so values narrower than the scores cost
+    # V^T p, the accumulator and V's fetch their own width and no more;
+    # q, k, the scores, the statistics and lse know nothing of it.
     # Scores are K-major, z^T = K Q^T [bk, bq]: the running max and sum
     # are [1, bq] lane vectors reduced down the sublanes, and the
-    # accumulator is kept transposed [query heads * d, bq] so the
+    # accumulator is kept transposed [query heads * dv, bq] so the
     # correction broadcasts along its sublanes; one transpose a Q tile
     # puts the output back in the operands' layout.
     qi = pl.program_id(2)
@@ -375,7 +387,7 @@ def _fwd_lane_kernel(
     bq, w = q_ref.shape[-2], q_ref.shape[-1]
     bk = block_k
     n_k = k_ref.shape[1] // bk
-    d = w // heads
+    d, dv = w // heads, v_ref.shape[-1] // heads
 
     first_block = (pl.program_id(0) == 0) & (pl.program_id(1) == 0)
 
@@ -399,7 +411,7 @@ def _fwd_lane_kernel(
             jax.debug.callback(on_step, first_block)
         ks = pl.multiple_of(kb * bk, bk)
         k2 = k_ref[0, pl.ds(ks, bk), :]      # [bk, w]
-        v2 = v_ref[0, pl.ds(ks, bk), :]
+        v2 = v_ref[0, pl.ds(ks, bk), :]      # [bk, heads * dv]
         if causal:
             cols = ks + col0
             keep = rows >= cols
@@ -427,10 +439,10 @@ def _fwd_lane_kernel(
             corr = jnp.exp(m_prev - m_new)
             new.append(
                 (m_new, l_prev * corr + jnp.sum(p, axis=0, keepdims=True)))
-            pv = jax.lax.dot_general(        # V^T p: [w, bq]
+            pv = jax.lax.dot_general(        # V^T p: [heads * dv, bq]
                 v2, p.astype(v2.dtype), (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
-            own = slice(h * d, (h + 1) * d)
+            own = slice(h * dv, (h + 1) * dv)
             acc_ref[own, :] = acc_ref[own, :] * corr + (
                 pv if grouped else pv[own, :])
         return tuple(new)
@@ -441,7 +453,7 @@ def _fwd_lane_kernel(
          jnp.zeros((1, bq), jnp.float32))
         for _ in range(len(qs))))
     for h, (m, l) in enumerate(stats):
-        own = slice(h * d, (h + 1) * d)
+        own = slice(h * dv, (h + 1) * dv)
         l = jnp.maximum(l, 1e-20)
         if grouped:
             o_ref[0, h] = (acc_ref[own, :] / l).T.astype(o_ref.dtype)
@@ -473,7 +485,11 @@ def _flash_forward_lanes(q, k, v, *, heads: int, causal: bool, block_q: int,
     view cost a transposing copy of q and of out a layer (PERF.md
     section 6, PR 35).
 
-    Returns out [B, S, H, D] and lse as the lane vectors the kernel
+    `v` may have a width of its own ([B, S, Kv, Dv], both widths whole
+    lane blocks): its blocks, the out tile and the accumulator are then Dv
+    wide and nothing else changes.
+
+    Returns out [B, S, H, Dv] and lse as the lane vectors the kernel
     wrote, [B, lane blocks, S/block_q, query heads a block, block_q],
     query heads in order: what the fused backward reads as it is
     (multi-head), and `_lse_bhs` puts in order for the recurrences.
@@ -484,50 +500,56 @@ def _flash_forward_lanes(q, k, v, *, heads: int, causal: bool, block_q: int,
     a serving start lowers it some seventy times (`setup_s`). `on_step`
     is the interpreted recorder's callback (`_first_block_counter`)."""
     b, s, h, d = q.shape
-    kv = k.shape[2]
+    kv, dv = k.shape[2], v.shape[3]
     group = h // kv
     from jax.experimental.pallas import tpu as pltpu
 
-    n_q, w = s // block_q, heads * d
+    # a lane block of q and k, and of v and out (a group's K/V head is a
+    # lane block of its own: `heads` is 1 there)
+    n_q, w, wv = s // block_q, heads * d, heads * dv
     if group > 1:
         operands = [jnp.swapaxes(t, 1, 2) for t in (q, k, v)]
-        tile = pl.BlockSpec((1, group, block_q, d),
-                            lambda bi, hi, qi: (bi, hi, qi, 0))
-        whole = pl.BlockSpec((1, None, s, d),
-                             lambda bi, hi, qi: (bi, hi, 0, 0))
+        tile = lambda width: pl.BlockSpec(
+            (1, group, block_q, width), lambda bi, hi, qi: (bi, hi, qi, 0))
+        whole = lambda width: pl.BlockSpec(
+            (1, None, s, width), lambda bi, hi, qi: (bi, hi, 0, 0))
+        out_shape = (b, h, s, dv)
     else:
-        operands = [t.reshape(b, s, h * d) for t in (q, k, v)]
-        tile = pl.BlockSpec((1, block_q, w), lambda bi, hi, qi: (bi, qi, hi))
-        whole = pl.BlockSpec((1, s, w), lambda bi, hi, qi: (bi, 0, hi))
+        operands = [t.reshape(b, s, -1) for t in (q, k, v)]
+        tile = lambda width: pl.BlockSpec(
+            (1, block_q, width), lambda bi, hi, qi: (bi, qi, hi))
+        whole = lambda width: pl.BlockSpec(
+            (1, s, width), lambda bi, hi, qi: (bi, 0, hi))
+        out_shape = (b, s, h * dv)
     members = heads * group
     out, lse = pl.pallas_call(
         functools.partial(_fwd_lane_kernel, causal=causal, scale=scale,
                           window=window, logit_cap=logit_cap, heads=heads,
                           block_k=block_k, on_step=on_step),
         grid=(b, kv // heads, n_q),
-        in_specs=[tile, whole, whole],
+        in_specs=[tile(w), whole(w), whole(wv)],
         out_specs=[
-            tile,
+            tile(wv),
             pl.BlockSpec((1, 1, 1, members, block_q),
                          lambda bi, hi, qi: (bi, hi, qi, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(operands[0].shape, q.dtype),
+            jax.ShapeDtypeStruct(out_shape, q.dtype),
             jax.ShapeDtypeStruct((b, kv // heads, n_q, members, block_q),
                                  jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((members * d, block_q), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((members * dv, block_q), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel"),
             vmem_limit_bytes=_fwd_lane_vmem_bytes(
-                s, heads, w, q.dtype.itemsize, block_q, block_k, group),
+                s, heads, w, q.dtype.itemsize, block_q, block_k, group, wv),
         ),
         interpret=interpret,
         name="flash_fwd",
     )(*operands)
     if group > 1:
         return jnp.swapaxes(out, 1, 2), lse
-    return out.reshape(q.shape), lse
+    return out.reshape(b, s, h, dv), lse
 
 
 def _lse_bhs(lse):
@@ -546,9 +568,11 @@ def _flash_forward(
     window=None, scale=None, logit_cap=None,
 ) -> Tuple[jax.Array, jax.Array]:
     b, s, h, d = q.shape
-    if k.shape != v.shape:
-        raise ValueError(f"k {k.shape} and v {v.shape} must match")
-    kv = k.shape[2]
+    if k.shape[:3] != v.shape[:3]:
+        raise ValueError(
+            f"k {k.shape} and v {v.shape} must match in batch, length and "
+            f"heads (the last dimension, the values' width, is v's own)")
+    kv, dv = k.shape[2], v.shape[3]
     if k.shape[0] != b or k.shape[1] != s or k.shape[3] != d:
         # All tiling below derives from q.shape; a cross-attention call with
         # longer K/V would silently attend over the wrong range (ADVICE r1).
@@ -587,16 +611,30 @@ def _flash_forward(
     # from the operands as `_bwd` decides: whole K/V heads tiling the 128
     # lanes, as many of them as Q heads (a lane block holds its heads' own
     # K/V) or each a lane block of its own under a group of Q heads, and a
-    # lane block's working set (K and V whole) inside VMEM.
+    # lane block's working set (K and V whole) inside VMEM. Values of a
+    # width of their own stay at it where that kernel takes them (both
+    # widths whole lane blocks); any other unequal pair runs at one width,
+    # padded here and nowhere else (zeros add nothing to a score, and the
+    # padded output columns are dropped).
     heads = _bwd_heads_per_block(kv, d)
     lanes = (
         heads is not None and (group == 1 or d % 128 == 0)
+        and (dv == d or d % 128 == dv % 128 == 0)
         and _fwd_lane_vmem_bytes(s, heads, heads * d, q.dtype.itemsize,
-                                 block_q, block_k, group)
+                                 block_q, block_k, group, heads * dv)
         <= _FWD_KERNEL_VMEM_BUDGET
     )
+    if dv != d and not lanes:
+        fill = lambda t: jnp.pad(
+            t, ((0, 0),) * 3 + ((0, max(d, dv) - t.shape[3]),))
+        out, lse = _flash_forward(
+            fill(q), fill(k), fill(v), causal, block_q, block_k, interpret,
+            window, scale, logit_cap)
+        return out[..., :dv], lse
     counters.incr("flash/fwd_lane_traces" if lanes
                   else "flash/fwd_grid_traces")
+    if dv != d:
+        counters.incr("flash/fwd_two_width_traces")
     if _TILE_COUNTS is not None:
         n_q, n_k = s // block_q, s // block_k
         _TILE_COUNTS["fwd_path"] = "lane" if lanes else "grid"
@@ -1130,7 +1168,14 @@ def flash_attention(
     scale=None,
     logit_cap=None,
 ) -> jax.Array:
-    """softmax(cap(QK^T * scale))V over [B, S, H, D], O(S) memory.
+    """softmax(cap(QK^T * scale))V over q, k [B, S, H, D] and v
+    [B, S, H, Dv] -> [B, S, H, Dv], O(S) memory.
+
+    Dv may differ from D (a latent layer's heads score over 192 columns
+    and carry values of 128), in the forward only: where both are whole
+    lane blocks the lane kernel runs V^T p, its accumulator and V's fetch
+    at Dv, any other unequal pair is padded to one width inside
+    `_flash_forward`; the backward refuses unequal widths by name.
 
     GQA: k/v may carry fewer heads [B, S, Kv, D] with H a multiple of Kv —
     query head h reads K/V head h // (H / Kv) and the repeat-expanded K/V
@@ -1169,8 +1214,14 @@ def _bwd(causal, block_q, block_k, interpret, window, scale, logit_cap,
     # dK/dV blocks are per query head; grouped-query would need a reduction
     # across heads), whole heads tiling the 128 lanes, and a head block's
     # working set inside VMEM.
-    q, k = res[0], res[1]
+    q, k, v = res[:3]
     s, h, d = q.shape[1:]
+    if v.shape[3] != d:
+        raise NotImplementedError(
+            f"flash_attention: only the forward takes values of a width of "
+            f"their own (q and k {d} wide, v {v.shape[3]}); no backward "
+            f"reads two widths. Pad v to {d} for a gradient, or use "
+            f"impl='reference'")
     if scale is None:
         scale = 1.0 / (d ** 0.5)
     bq, bk = _resolve_block(block_q, s), _resolve_block(block_k, s)

@@ -24,9 +24,10 @@ value)` values a cell of temporaries.
 
 Both are plain `jax.numpy` here (the per-head path reaches the Mosaic flash
 forward through `ops/attention.attention` once its scores pass
-`_SCORES_BYTES`, by padding both widths to one multiple of the 128 lanes:
-the kernel takes one width for q, k and v). The flax layer that owns the
-parameters, the cache and the choice of path is
+`_SCORES_BYTES`, each of its two widths padded to ITS next multiple of the
+128 lanes: the kernel takes the score width of q and k and the value width
+of v apart, 256 and 128 at the published 192 and 128). The flax layer that
+owns the parameters, the cache and the choice of path is
 `models/transformer.py::LatentAttention`.
 """
 
@@ -86,10 +87,11 @@ def prefill_attention(q_nope, q_rope, k_nope, k_rope, v, *, scale: float,
     value] in q's dtype.
 
     Two score products while the float32 scores fit (`_SCORES_BYTES`);
-    past that through the dispatcher, which takes one width for q, k and
-    v: [q_nope, q_rope] against [k_nope, k_rope] and v, each padded with
-    zeros to the next multiple of 128 lanes (zeros add nothing to a score
-    and the padded output columns are dropped)."""
+    past that through the dispatcher: [q_nope, q_rope] against
+    [k_nope, k_rope], padded with zeros to the next multiple of 128 lanes
+    of the score width, and v at its own width, padded only where that is
+    no multiple of 128 (zeros add nothing to a score, and padded output
+    columns are dropped)."""
     b, s, h, nope = q_nope.shape
     rope, value = q_rope.shape[-1], v.shape[-1]
     if 4 * b * h * s * s <= _SCORES_BYTES or s % 128:
@@ -102,9 +104,10 @@ def prefill_attention(q_nope, q_rope, k_nope, k_rope, v, *, scale: float,
         return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v,
                           preferred_element_type=jnp.float32
                           ).astype(q_nope.dtype)
-    width = -(-max(nope + rope, value) // 128) * 128
-    fill = lambda t: jnp.pad(
-        t, ((0, 0),) * 3 + ((0, width - t.shape[-1]),))
+    def fill(t):
+        short = -t.shape[-1] % 128
+        return jnp.pad(t, ((0, 0),) * 3 + ((0, short),)) if short else t
+
     q = fill(jnp.concatenate([q_nope, q_rope], -1))
     k = fill(jnp.concatenate(
         [k_nope, jnp.broadcast_to(k_rope[:, :, None], (b, s, h, rope))], -1))
