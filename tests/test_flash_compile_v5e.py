@@ -1,6 +1,8 @@
-"""The flash kernels of the training cells and the hybrid's grouped matmul
-(`ops/moe_gmm.py`), compiled for a described v5e at the cells' widths: what
-Mosaic refuses (tiling, VMEM, a lowering it lacks) shows here, with no chip.
+"""The flash kernels of the training cells, the hybrid's grouped matmul
+(`ops/moe_gmm.py`) and the gated delta rule's two forms
+(`ops/gated_delta.py`, plain XLA), compiled for a described v5e at the
+cells' widths: what Mosaic or the compiler refuses (tiling, VMEM, a
+lowering it lacks, temporaries of a wave's length) shows here, with no chip.
 Nothing runs, so this says nothing about results or times. All in this one
 file: the worker that gets it loads libtpu."""
 
@@ -11,7 +13,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from tfde_tpu.ops import moe_gmm
+from tfde_tpu.ops import gated_delta, moe_gmm
 from tfde_tpu.ops.flash_attention import flash_attention
 
 
@@ -69,6 +71,8 @@ def test_flash_gradient_compiles_for_v5e(one_chip, name, shape, dtype,
     ("two_rows_of_9216", (2, 9216, 28, 128), 4, True, None),
     ("hybrid_attention_wave", (1, 6144, 32, 128), 8, True, None),
     ("latent_wave_heads_of_256", (1, 30720, 8, 256), 8, True, None),
+    ("gated_attention_wave_grid", (1, 30720, 16, 256), 2, True, None),
+    ("gated_attention_short_wave", (1, 2048, 16, 256), 2, True, None),
 ])
 def test_flash_forward_compiles_for_v5e(one_chip, name, shape, kv_heads,
                                         causal, window):
@@ -124,7 +128,8 @@ def test_two_width_forward_compiles_for_v5e(one_chip, name, s, heads,
 #: width, the gate's activation) of the three cells that run the kernel
 _EXPERT_LAYERS = {"hybrid": (36, 72, 10, 4096, 768, "silu"),
                   "latent": (32, 128, 8, 4096, 2048, "silu"),
-                  "window_and_global": (64, 64, 6, 2560, 768, "relu")}
+                  "window_and_global": (64, 64, 6, 2560, 768, "relu"),
+                  "delta_rule_cell": (256, 512, 10, 2048, 512, "silu")}
 
 
 @pytest.mark.parametrize("layer", sorted(_EXPERT_LAYERS))
@@ -151,3 +156,58 @@ def test_moe_gmm_compiles_for_v5e(one_chip, name, tokens, layer):
         tile=tile, act=act).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert "moe_gmm" in text
+
+
+#: the delta-rule cell's published widths: 16 key heads and 32 value heads
+#: of 128, four taps, chunks of 64
+_DELTA = gated_delta.GatedDeltaShape(key_heads=16, value_heads=32,
+                                     key_dim=128, value_dim=128)
+
+
+@pytest.mark.parametrize("name,rows,positions,temporaries_gib", [
+    ("a_short_wave", 1, 2048, 0.5),
+    ("two_rows_of_the_longest_bucket", 2, 30720, 2.0),
+])
+def test_delta_rule_prefill_compiles_for_v5e(one_chip, name, rows,
+                                             positions, temporaries_gib):
+    """The chunked form over a wave as the mixer hands it over (bfloat16
+    q, k, v after the convolution and z, float32 beta and g, the norm and
+    the gate inside the scan): the v5e compiler takes the 64 x 64 unit
+    triangular solve, and no temporary of the wave's length beyond the
+    output survives (float32 copies of q, k, v or o a wave long did,
+    before the groups were cut where the arrays lie: 3.5 GiB at 30,720)."""
+    on = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                   sharding=one_chip)
+    wave = (rows, positions)
+    compiled = jax.jit(
+        lambda qkv, z, beta, g, gain, state, lengths: gated_delta.prefill(
+            qkv, beta, g, state, lengths, _DELTA, gate=(z, gain, 1e-6))
+    ).lower(
+        on(wave + (_DELTA.conv_channels,), jnp.bfloat16),
+        on(wave + (_DELTA.value_width,), jnp.bfloat16),
+        on(wave + (32,), jnp.float32), on(wave + (32,), jnp.float32),
+        on((128,), jnp.float32), on((rows, 32, 128, 128), jnp.float32),
+        on((rows,), jnp.int32)).compile()
+    out, state = compiled.out_info
+    assert out.shape == wave + (32, 128) and out.dtype == jnp.bfloat16
+    assert state.shape == (rows, 32, 128, 128) and state.dtype == jnp.float32
+    assert compiled.memory_analysis().temp_size_in_bytes < \
+        temporaries_gib * 2 ** 30
+
+
+def test_delta_rule_step_compiles_for_v5e(one_chip):
+    """One tick of the cell's 48 rows: the state is read and written once
+    (its 100 MB in and out, aliased or not) and nothing else of its size
+    is laid out."""
+    on = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                   sharding=one_chip)
+    compiled = jax.jit(
+        lambda qkv, beta, g, state, live: gated_delta.decode_step(
+            qkv, beta, g, state, live, _DELTA), donate_argnums=(3,)
+    ).lower(
+        on((48, _DELTA.conv_channels), jnp.bfloat16),
+        on((48, 32), jnp.float32), on((48, 32), jnp.float32),
+        on((48, 32, 128, 128), jnp.float32), on((48,), jnp.bool_)).compile()
+    out, state = compiled.out_info
+    assert out.shape == (48, 32, 128) and state.shape == (48, 32, 128, 128)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 48 * 2 ** 21

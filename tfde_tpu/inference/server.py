@@ -159,6 +159,10 @@ def _state_not_by_position(model,
         return ("its 'mamba' layers cache a running state and a "
                 "convolution tail with no axis of positions "
                 "(models/transformer.py Mamba2Mixer)")
+    if "gated_delta" in (getattr(model, "mixers", None) or ()):
+        return ("its 'gated_delta' layers cache one matrix per value head "
+                "and a convolution tail with no axis of positions "
+                "(models/transformer.py GatedDeltaMixer)")
     return None
 
 

@@ -174,16 +174,26 @@ class GPT(nn.Module):
     # residual stream and its adds in float32, the sublayers in `dtype`
     # (EvaByte's fp32_skip_add)
     fp32_residual: bool = False
-    # one mixer kind per layer, 'attention' | 'mamba' | 'latent' (a
-    # config's `layer_types`), as long as `depth`; None: every layer is
+    # one mixer kind per layer, 'attention' | 'mamba' | 'latent' |
+    # 'gated_delta' (a config's `layer_types`), as long as `depth`; None:
+    # every layer is
     # attention. 'mamba' layers are ops/ssm.py's Mamba-2 mixer at the widths
     # of `ssm` (an ops/ssm.SSMShape) and cache a running state, not
     # positions; 'latent' layers are latent attention at the widths of `mla`
     # (an ops/mla.MLAShape; position='rope') and cache one [latent, rotary
-    # key] cell per position with no head axis (transformer.LatentAttention)
+    # key] cell per position with no head axis (transformer.LatentAttention);
+    # 'gated_delta' layers are ops/gated_delta.py's delta-rule mixer at the
+    # widths of `gdn` (an ops/gated_delta.GatedDeltaShape) and cache one
+    # matrix per value head (transformer.GatedDeltaMixer)
     mixers: Optional[tuple] = None
     ssm: Optional[Any] = None
     mla: Optional[Any] = None
+    gdn: Optional[Any] = None
+    # the attention layers' query is twice as wide, per head [q | gate],
+    # and sigmoid(gate) multiplies their output before `out`; with
+    # `norm_unit_offset` their q/k norms store 1 + gain as well
+    # (transformer.MultiHeadAttention.output_gate)
+    attn_output_gate: bool = False
     # Granite: each sublayer's output times this before the residual add,
     # and the logits divided by `logits_scaling`
     residual_multiplier: Optional[float] = None
@@ -369,6 +379,8 @@ class GPT(nn.Module):
             mixers=tuple(self.mixers) if self.mixers is not None else None,
             ssm=self.ssm,
             mla=self.mla,
+            gdn=self.gdn,
+            attn_output_gate=self.attn_output_gate,
             mlps=tuple(self.mlps) if self.mlps is not None else None,
             moe_mlp_dim=self.moe_mlp_dim,
             moe_score=self.moe_score,

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 
 from tfde_tpu.ops import attention as attn_lib
 from tfde_tpu.ops import eva_attention as eva_lib
+from tfde_tpu.ops import gated_delta as gdn_lib
 from tfde_tpu.ops import mla as mla_lib
 from tfde_tpu.ops import ssm as ssm_lib
 from tfde_tpu.ops.quant import QuantDenseGeneral, kv_dequantize, kv_quantize
@@ -100,6 +101,13 @@ class MultiHeadAttention(nn.Module):
     # Qwen3 arrangement (one [head_dim] scale each, shared across heads)
     qk_norm: bool = False
     ln_eps: float = 1e-6  # qk_norm epsilon (the block's rms_norm_eps)
+    # the q/k norms store their gain as 1 + scale, as the block's norms do
+    # under `norm_unit_offset` (make_norm)
+    norm_unit_offset: bool = False
+    # the Qwen3-Next arrangement: `query` is twice as wide, per head
+    # [q | gate], and the attention's output is multiplied by
+    # sigmoid(gate) before `out`
+    output_gate: bool = False
     # one [embed, 3, heads, head_dim] projection instead of three
     # [embed, heads, head_dim] GEMMs: a 3x-wider matmul keeps the MXU
     # busier at small per-chip batch (the training MFU knob). Parameter
@@ -204,11 +212,12 @@ class MultiHeadAttention(nn.Module):
             )
         in_bias = self.use_bias or self.qkv_bias
         if self.fused_qkv:
-            if self.kv_heads != self.num_heads:
+            if self.kv_heads != self.num_heads or self.output_gate:
                 raise NotImplementedError(
-                    "fused_qkv requires classic MHA (num_kv_heads=None): "
-                    "GQA's k/v projections have different shapes and "
-                    "cannot stack into one kernel"
+                    "fused_qkv requires classic MHA (num_kv_heads=None) "
+                    "without an output gate: GQA's k/v projections and a "
+                    "gated query have different shapes and cannot stack "
+                    "into one kernel"
                 )
             qkv = proj(
                 features=(3, self.num_heads, self.head_dim), name="qkv",
@@ -216,17 +225,18 @@ class MultiHeadAttention(nn.Module):
             )(x)  # [B, S, 3, H, D] from ONE GEMM
             q, k, v = (qkv[..., i, :, :] for i in range(3))
         else:
-            q = proj(features=(self.num_heads, self.head_dim),
+            q = proj(features=(self.num_heads,
+                               (1 + self.output_gate) * self.head_dim),
                      name="query", use_bias=in_bias)(x)
             k = proj(features=(self.kv_heads, self.head_dim), name="key",
                      use_bias=in_bias)(x)
             v = proj(features=(self.kv_heads, self.head_dim),
                      name="value", use_bias=in_bias)(x)
+        gate = None
+        if self.output_gate:
+            q, gate = q[..., :self.head_dim], q[..., self.head_dim:]
         if self.qk_norm:
-            qk_rms = functools.partial(
-                nn.RMSNorm, epsilon=self.ln_eps, dtype=jnp.float32,
-                param_dtype=jnp.float32,
-            )
+            qk_rms = make_norm("rms", self.ln_eps, self.norm_unit_offset)
             q = qk_rms(name="q_norm")(q).astype(self.dtype)
             k = qk_rms(name="k_norm")(k).astype(self.dtype)
         if self.rope and not self.decode:
@@ -268,6 +278,8 @@ class MultiHeadAttention(nn.Module):
                 impl=self.attn_impl, window=self.window,
                 scale=self.attn_scale, logit_cap=self.attn_logit_cap,
             )
+        if gate is not None:
+            y = y * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(y.dtype)
         y = constrain(y, b, "seq", "tensor")
         y = proj(features=x.shape[-1], axis=(-2, -1), name="out")(y)
         y = constrain(y, b, "seq")
@@ -495,16 +507,6 @@ class MultiHeadAttention(nn.Module):
                     key_scale.value, k_sc, (0, idx, 0))
                 vs_all = jax.lax.dynamic_update_slice(
                     value_scale.value, v_sc, (0, idx, 0))
-            # [1, 1, Sq, max_len]: query (position idx+i) sees kv j<=idx+i
-            pos_q = idx + jnp.arange(sq, dtype=jnp.int32)
-            cols = jnp.arange(max_len, dtype=jnp.int32)[None, :]
-            valid = cols <= pos_q[:, None]
-            if self.window is not None:
-                # sliding band over the cache: j in (pos - window, pos]
-                valid = jnp.logical_and(
-                    valid, pos_q[:, None] - cols < self.window
-                )
-            valid = valid[None, None]
         else:
             # per-row indices [B] (batched speculation, inference/
             # speculative.py: acceptance lengths diverge across rows, so
@@ -527,15 +529,21 @@ class MultiHeadAttention(nn.Module):
                 )
                 ks_all = swrite(key_scale.value, k_sc, idx)
                 vs_all = swrite(value_scale.value, v_sc, idx)
-            # [B, 1, Sq, max_len]: row b's query i sits at idx[b]+i
-            pos_w = idx[:, None] + jnp.arange(sq, dtype=jnp.int32)  # [B,sq]
-            colsb = jnp.arange(max_len, dtype=jnp.int32)[None, None, :]
-            valid = colsb <= pos_w[:, :, None]
+        cols = jnp.arange(max_len, dtype=jnp.int32)
+
+        def valid_for(queries):
+            """Which cells the call's queries number `queries` [n] see:
+            the one at position idx + i sees kv j <= idx + i, under a
+            window j in (pos - window, pos]. [1, 1, n, max_len] under the
+            shared index, [B, 1, n, max_len] under per-row indices (row
+            b's query i sits at idx[b] + i)."""
+            pos = idx[..., None] + queries                 # [n] | [B, n]
+            seen = cols <= pos[..., None]
             if self.window is not None:
-                valid = jnp.logical_and(
-                    valid, pos_w[:, :, None] - colsb < self.window
-                )
-            valid = valid[:, None]
+                seen = jnp.logical_and(seen,
+                                       pos[..., None] - cols < self.window)
+            return seen[None, None] if idx.ndim == 0 else seen[:, None]
+
         cached_key.value = constrain(k_all, batch, None, "tensor")
         cached_value.value = constrain(v_all, batch, None, "tensor")
         if quant:
@@ -556,7 +564,8 @@ class MultiHeadAttention(nn.Module):
         block = _PREFILL_QUERY_BLOCK
         if (4 * q.shape[0] * self.num_heads * sq * max_len
                 <= _PREFILL_SCORES_BYTES or sq % block):
-            return attend(q, k_all, v_all, mask=valid)
+            return attend(q, k_all, v_all, mask=valid_for(
+                jnp.arange(sq, dtype=jnp.int32)))
         # a long prefill over a long slab: the float32 scores of all its
         # queries against the whole slab ([rows, heads, Sq, max_len]: 6 GB
         # a row at 6,144 over 8,192) do not fit. Into an empty cache (a
@@ -573,11 +582,13 @@ class MultiHeadAttention(nn.Module):
 
         def behind_a_prefix():
             def some(i):
-                rows = functools.partial(
-                    jax.lax.dynamic_slice_in_dim, start_index=i * block,
-                    slice_size=block)
-                return attend(rows(q, axis=1), k_all, v_all,
-                              mask=rows(valid, axis=2))
+                # the block's mask from its positions: a [Sq, max_len]
+                # mask of the whole call sliced here would stand in memory
+                # through the loop (5 GB at 30,720 over 32,768)
+                return attend(
+                    jax.lax.dynamic_slice_in_dim(q, i * block, block, 1),
+                    k_all, v_all, mask=valid_for(
+                        i * block + jnp.arange(block, dtype=jnp.int32)))
 
             out = jax.lax.map(some, jnp.arange(sq // block))
             return jnp.moveaxis(out, 0, 1).reshape(q.shape).astype(q.dtype)
@@ -901,6 +912,104 @@ class Mamba2Mixer(nn.Module):
         return constrain(y, b, "seq")
 
 
+class GatedDeltaMixer(nn.Module):
+    """The gated delta-rule mixer (ops/gated_delta.py) in
+    MultiHeadAttention's place: `in_proj_qkvz` to [q, k, v, z] and
+    `in_proj_ba` to [b, a], a causal depthwise `conv_kernel` over [q, k, v]
+    (no bias, SiLU), the recurrence with `A_log` and `dt_bias` per value
+    head, a norm per head and then the gate (`norm_scale`, one [value_dim]
+    gain all heads share) and `out_proj`. No bias, no skip term.
+
+    Under decode=True the "cache" collection holds per row a running
+    state and no axis of positions: `delta_state` [B, Hv, K, V] float32,
+    `conv_tail` [B, conv - 1, C] (the last raw [q, k, v] inputs) and
+    `feed_pad` [B], how many trailing tokens of THIS call are padding (0
+    unless the caller sets it; read once and reset), as `Mamba2Mixer`
+    keeps them. A call continues from the cached state: S > 1 feeds
+    right-padded rows of true length S - feed_pad, past which the state
+    stands and the tail kept ends at the true length; S = 1 is one step,
+    and a padded one changes neither. A state cannot be rewound, shared or
+    re-encoded by position."""
+
+    gdn: gdn_lib.GatedDeltaShape
+    dtype: jnp.dtype = jnp.bfloat16
+    decode: bool = False
+    ln_eps: float = 1e-6
+
+    @nn.compact
+    def __call__(self, x: jax.Array, mask: Optional[jax.Array] = None,
+                 train: bool = False) -> jax.Array:
+        if mask is not None:
+            raise NotImplementedError(
+                "a delta-rule mixer takes no attention mask")
+        b = batch_axes()
+        shape = self.gdn
+        bsz, sq, width = x.shape
+        heads = shape.value_heads
+        dense = functools.partial(nn.Dense, dtype=self.dtype,
+                                  param_dtype=jnp.float32, use_bias=False)
+        conv_kernel = self.param(
+            "conv_kernel", nn.initializers.lecun_normal(),
+            (shape.conv, shape.conv_channels), jnp.float32)
+        a_log = self.param(
+            "A_log", lambda key, s: jnp.log(jax.random.uniform(
+                key, s, jnp.float32, 1e-3, 16.0)), (heads,))
+        dt_bias = self.param("dt_bias", nn.initializers.ones, (heads,),
+                             jnp.float32)
+        gain = self.param("norm_scale", nn.initializers.ones,
+                          (shape.value_dim,), jnp.float32)
+
+        qkv, z = jnp.split(dense(shape.in_features, name="in_proj_qkvz")(x),
+                           [shape.conv_channels], axis=-1)
+        beta, a = jnp.split(
+            dense(2 * heads, name="in_proj_ba")(x).astype(jnp.float32),
+            2, axis=-1)
+        beta = jax.nn.sigmoid(beta)
+        g = -jnp.exp(a_log.astype(jnp.float32)) * jax.nn.softplus(
+            a + dt_bias.astype(jnp.float32))
+
+        tail0 = jnp.zeros((bsz, shape.conv - 1, shape.conv_channels),
+                          qkv.dtype)
+        state0 = jnp.zeros((bsz, heads, shape.key_dim, shape.value_dim),
+                           jnp.float32)
+        filled = self.decode and self.has_variable("cache", "delta_state")
+        if self.decode:
+            state = self.variable("cache", "delta_state", lambda: state0)
+            tail = self.variable("cache", "conv_tail", lambda: tail0)
+            feed_pad = self.variable("cache", "feed_pad", jnp.zeros, (bsz,),
+                                     jnp.int32)
+        if filled:
+            pad = feed_pad.value
+            state0, tail0 = state.value, tail.value
+        else:
+            # the plain forward, and decode's init pass (the variables
+            # were just created; flax convention)
+            pad = jnp.zeros((bsz,), jnp.int32)
+        lengths = sq - pad
+        qkv, new_tail = ssm_lib.causal_conv(qkv, tail0, conv_kernel, None,
+                                            lengths)
+        if sq > 1 or not filled:
+            # the norm and the gate a chunk at a time, inside the scan
+            y, new_state = gdn_lib.prefill(
+                qkv, beta, g, state0, lengths, shape,
+                gate=(z, gain, self.ln_eps))
+        else:
+            o, new_state = gdn_lib.decode_step(
+                qkv[:, 0], beta[:, 0], g[:, 0], state0, pad == 0, shape)
+            y = gdn_lib.norm_then_gate(
+                o, z.reshape(bsz, heads, shape.value_dim), gain,
+                self.ln_eps)[:, None]
+        if filled:
+            state.value = constrain(new_state, b, "tensor")
+            tail.value = constrain(new_tail, b)
+            feed_pad.value = jnp.zeros_like(pad)
+        y = constrain(y.astype(self.dtype).reshape(bsz, sq,
+                                                   shape.value_width),
+                      b, "seq", "tensor")
+        y = dense(width, name="out_proj")(y)
+        return constrain(y, b, "seq")
+
+
 class Mlp(nn.Module):
     """fc1 -> act -> fc2; hidden dim carries the tensor-parallel shard.
 
@@ -1049,9 +1158,13 @@ class TransformerBlock(nn.Module):
     # what mixes positions in this block: 'attention' (MultiHeadAttention)
     # | 'mamba' (Mamba2Mixer over `ssm`, an ops/ssm.SSMShape) | 'latent'
     # (LatentAttention over `mla`, an ops/mla.MLAShape)
+    # | 'gated_delta' (GatedDeltaMixer over `gdn`, an
+    # ops/gated_delta.GatedDeltaShape)
     mixer: str = "attention"
     ssm: Optional[ssm_lib.SSMShape] = None
     mla: Optional[mla_lib.MLAShape] = None
+    gdn: Optional[gdn_lib.GatedDeltaShape] = None
+    attn_output_gate: bool = False  # MultiHeadAttention.output_gate
     # MoEMlp.score / selection_bias / routed_scale
     moe_score: str = "softmax"
     moe_selection_bias: bool = False
@@ -1068,10 +1181,10 @@ class TransformerBlock(nn.Module):
         train: bool = False,
     ) -> jax.Array:
         ln = make_norm(self.norm, self.ln_eps, self.norm_unit_offset)
-        if self.mixer not in ("attention", "mamba", "latent"):
+        if self.mixer not in ("attention", "mamba", "latent", "gated_delta"):
             raise ValueError(
-                f"mixer must be 'attention', 'mamba' or 'latent', got "
-                f"{self.mixer!r}")
+                f"mixer must be 'attention', 'mamba', 'latent' or "
+                f"'gated_delta', got {self.mixer!r}")
         if self.mixer == "latent":
             if self.mla is None or not self.causal or not self.rope:
                 raise ValueError(
@@ -1096,6 +1209,14 @@ class TransformerBlock(nn.Module):
             attn = Mamba2Mixer(ssm=self.ssm, dtype=self.dtype,
                                decode=self.decode, ln_eps=self.ln_eps,
                                name="mamba")
+        elif self.mixer == "gated_delta":
+            if self.gdn is None or self.norm_style != "pre":
+                raise ValueError(
+                    "mixer='gated_delta' needs its widths (`gdn`) and the "
+                    "pre-norm block")
+            attn = GatedDeltaMixer(gdn=self.gdn, dtype=self.dtype,
+                                   decode=self.decode, ln_eps=self.ln_eps,
+                                   name="delta")
         else:
             attn = MultiHeadAttention(
                 num_heads=self.num_heads,
@@ -1123,6 +1244,8 @@ class TransformerBlock(nn.Module):
                 qkv_bias=self.qkv_bias,
                 qk_norm=self.qk_norm,
                 ln_eps=self.ln_eps,
+                norm_unit_offset=self.norm_unit_offset,
+                output_gate=self.attn_output_gate,
                 attention=self.attention,
                 eva_window=self.eva_window,
                 eva_chunk=self.eva_chunk,
@@ -1308,12 +1431,15 @@ class Encoder(nn.Module):
     eva_window: int = 2048
     eva_chunk: int = 16
     norm_unit_offset: bool = False  # norm='rms' only (make_norm)
-    # one mixer kind per block, 'attention' | 'mamba' | 'latent'
-    # (TransformerBlock.mixer), as long as the depth; None builds every
-    # block with attention
+    # one mixer kind per block, 'attention' | 'mamba' | 'latent' |
+    # 'gated_delta' (TransformerBlock.mixer), as long as the depth; None
+    # builds every block with attention
     mixers: Optional[tuple] = None
     ssm: Optional[ssm_lib.SSMShape] = None  # the 'mamba' blocks' widths
     mla: Optional[mla_lib.MLAShape] = None  # the 'latent' blocks' widths
+    # the 'gated_delta' blocks' widths
+    gdn: Optional[gdn_lib.GatedDeltaShape] = None
+    attn_output_gate: bool = False  # MultiHeadAttention.output_gate
     residual_multiplier: Optional[float] = None  # TransformerBlock
 
     @nn.compact
@@ -1403,6 +1529,8 @@ class Encoder(nn.Module):
                        else "attention"),
                 ssm=self.ssm,
                 mla=self.mla,
+                gdn=self.gdn,
+                attn_output_gate=self.attn_output_gate,
                 moe_score=self.moe_score,
                 moe_selection_bias=self.moe_selection_bias,
                 moe_routed_scale=self.moe_routed_scale,

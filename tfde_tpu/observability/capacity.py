@@ -92,13 +92,19 @@ def _kv_layer_cells(cache) -> list:
             if str(getattr(path[-1], "key", path[-1])) == "cached_key"]
 
 
-def _latent_layers(cache) -> int:
-    """Layers of a cache that keep one latent cell per position (models/
-    transformer.py `LatentAttention`): its `cached_latent` leaves."""
+def _leaves_named(cache, name: str) -> int:
+    """How many leaves of a cache tree are called `name`: one a layer that
+    keeps such a leaf."""
     import jax
 
     return sum(1 for path, _ in jax.tree_util.tree_leaves_with_path(cache)
-               if str(getattr(path[-1], "key", path[-1])) == "cached_latent")
+               if str(getattr(path[-1], "key", path[-1])) == name)
+
+
+def _latent_layers(cache) -> int:
+    """Layers of a cache that keep one latent cell per position (models/
+    transformer.py `LatentAttention`): its `cached_latent` leaves."""
+    return _leaves_named(cache, "cached_latent")
 
 
 def kv_dtype_census(cache) -> dict:
@@ -200,6 +206,10 @@ class CapacityLedger:
         if _latent_layers(cache):
             return LatentCapacityLedger.of_model(
                 cache, batch_size, cells_per_row, params, registry=registry)
+        if "gated_delta" in (getattr(model, "mixers", None) or ()):
+            return DeltaCapacityLedger.of_model(
+                cache, batch_size, cells_per_row, params,
+                chunk=model.gdn.chunk, registry=registry)
         if "mamba" in (getattr(model, "mixers", None) or ()) or (
                 getattr(model, "num_experts", 0)
                 and getattr(model, "moe_capacity_factor", 1.0) is None):
@@ -450,7 +460,8 @@ class EvaCapacityLedger(CapacityLedger):
 class HybridCapacityLedger(CapacityLedger):
     """Occupancy of a cache in which some layers keep a running state per
     row (models/transformer.py `Mamba2Mixer`: `ssm_state`, `conv_tail`;
-    the same bytes whatever the row's length) beside layers that keep a
+    `GatedDeltaMixer`: `delta_state`, `conv_tail`; the same bytes whatever
+    the row's length) beside layers that keep a
     K/V cell per position, and the account of expert layers that route
     without a capacity (models/moe.py).
 
@@ -473,7 +484,7 @@ class HybridCapacityLedger(CapacityLedger):
     HYBRID_KEYS = ("ssm_state_bytes_touched", "kv_cells_read", "moe_pairs",
                    "moe_pairs_held", "moe_experts_touched",
                    "moe_pairs_busiest", "moe_rows_moved")
-    _STATE_LEAVES = ("ssm_state", "conv_tail")
+    _STATE_LEAVES = ("ssm_state", "delta_state", "conv_tail")
 
     def __init__(self, batch_size: int, positions: int, slab_bytes: int,
                  state_row_bytes: int, expert_bytes: int,
@@ -481,8 +492,9 @@ class HybridCapacityLedger(CapacityLedger):
                  registry: Optional[metrics.Registry] = None,
                  census: Optional[dict] = None):
         state_total = int(state_row_bytes) * int(batch_size)
-        per_position = (int(slab_bytes) - state_total) / float(
-            batch_size * positions)
+        #: all position-indexed layers' bytes of one position of one row
+        per_position = self._position_bytes = (
+            int(slab_bytes) - state_total) / float(batch_size * positions)
         # no attention layer: the one cell of a row is its state
         self._state_cells = (int(round(state_row_bytes / per_position))
                              if per_position else 1)
@@ -502,7 +514,8 @@ class HybridCapacityLedger(CapacityLedger):
                  ) -> "HybridCapacityLedger":
         """From a freshly-initialized batch cache and the served
         parameters: the state's bytes are those of the leaves named
-        `ssm_state` / `conv_tail`, the experts' those of the leaves
+        `ssm_state` / `delta_state` / `conv_tail`, the experts' those of
+        the leaves
         `experts_*` (their first axis counts the experts held)."""
         return cls(batch_size, positions, kv_slab_bytes(cache),
                    *cls._state_and_experts(cache, batch_size, params),
@@ -563,6 +576,87 @@ class HybridCapacityLedger(CapacityLedger):
         return int(depth * (int(param_bytes) - self._expert_bytes
                             + int(read_bytes))
                    + int(routed[2]) * self._slot_bytes)
+
+
+class DeltaCapacityLedger(HybridCapacityLedger):
+    """Occupancy of a cache in which delta-rule layers keep one matrix per
+    value head and a convolution tail a row (models/transformer.py
+    `GatedDeltaMixer`: `delta_state` [rows, Hv, K, V] float32,
+    `conv_tail`) beside attention layers that keep a K/V cell per
+    position; the cells, the state, the tick's least bytes and the expert
+    layers are the parent's account.
+
+    `counters` adds GDN_KEYS to the parent's. Summed over a scan's ticks,
+    what its active rows HOLD: their state (`gdn_state_bytes`: depth x
+    rows x a row's state and tails) and their committed K/V cells
+    (`kv_cell_bytes`: depth x cells x a cell's bytes), so that the one
+    over the sum of both is the state's share of the live cache over the
+    window. What the two forms of the rule worked: `gdn_steps`, a scan's
+    depth x its active rows x the delta-rule layers (row-steps of the
+    one-step form); `gdn_chunks`, per admitted request its bucket's
+    chunks x those layers (systems solved by the chunked form; a wave's
+    ladder padding repeats a row and is not counted). And the (query,
+    cell) pairs the prefills attended causally in the attention layers at
+    the rows' TRUE lengths, n (n + 1) / 2 a layer (`kv_pairs_prefilled`)."""
+
+    GDN_KEYS = ("gdn_state_bytes", "kv_cell_bytes", "gdn_steps",
+                "gdn_chunks", "kv_pairs_prefilled")
+
+    def __init__(self, batch_size: int, positions: int, slab_bytes: int,
+                 state_row_bytes: int, expert_bytes: int, expert_slots: int,
+                 delta_layers: int, kv_layers: int, chunk: int,
+                 registry: Optional[metrics.Registry] = None,
+                 census: Optional[dict] = None):
+        super().__init__(batch_size, positions, slab_bytes, state_row_bytes,
+                         expert_bytes, expert_slots, registry=registry,
+                         census=census)
+        self._delta_layers = int(delta_layers)
+        self._kv_layers = int(kv_layers)
+        self._chunk = int(chunk)
+        self._counters.update(dict.fromkeys(self.GDN_KEYS, 0))
+
+    @classmethod
+    def of_model(cls, cache, batch_size: int, positions: int, params,
+                 chunk: int = 64,
+                 registry: Optional[metrics.Registry] = None
+                 ) -> "DeltaCapacityLedger":
+        """From a freshly-initialized batch cache and the served
+        parameters: every `delta_state` leaf is one delta-rule layer,
+        every `cached_key` leaf one attention layer, `chunk` the
+        positions of one triangular system; the rest as the parent reads
+        it."""
+        return cls(batch_size, positions, kv_slab_bytes(cache),
+                   *cls._state_and_experts(cache, batch_size, params),
+                   _leaves_named(cache, "delta_state"),
+                   len(_kv_layer_cells(cache)), chunk,
+                   registry=registry, census=kv_dtype_census(cache))
+
+    def note_admission(self, kind: str, bucket: int, used_tokens: int
+                       ) -> None:
+        super().note_admission(kind, bucket, used_tokens)
+        with self._lock:
+            self._counters["gdn_chunks"] += (
+                self._delta_layers * -(-int(bucket) // self._chunk))
+
+    def note_commit(self, before: int, after: int,
+                    decoding: bool = True) -> None:
+        if decoding:
+            return
+        with self._lock:
+            self._counters["kv_pairs_prefilled"] += (
+                self._kv_layers * (int(after) * (int(after) + 1)
+                                   - int(before) * (int(before) + 1)) // 2)
+
+    def note_scan(self, committed, depth: int) -> None:
+        super().note_scan(committed, depth)
+        rows = len(committed)
+        with self._lock:
+            self._counters["gdn_state_bytes"] += (
+                depth * rows * self._state_row_bytes)
+            self._counters["kv_cell_bytes"] += int(
+                depth * self._position_bytes
+                * sum(int(n) for n in committed))
+            self._counters["gdn_steps"] += depth * rows * self._delta_layers
 
 
 class RingCapacityLedger(HybridCapacityLedger):
