@@ -172,10 +172,13 @@ def test_delta_rule_prefill_compiles_for_v5e(one_chip, name, rows,
                                              positions, temporaries_gib):
     """The chunked form over a wave as the mixer hands it over (bfloat16
     q, k, v after the convolution and z, float32 beta and g, the norm and
-    the gate inside the scan): the v5e compiler takes the 64 x 64 unit
-    triangular solve, and no temporary of the wave's length beyond the
-    output survives (float32 copies of q, k, v or o a wave long did,
-    before the groups were cut where the arrays lie: 3.5 GiB at 30,720)."""
+    the gate inside the scan): the 64 x 64 unit triangular systems are
+    inverted side by side on the vector unit, so the program holds no
+    triangular solve and not the custom call that XLA expands one into, a
+    system at a time,
+    and no temporary of the wave's length beyond the output survives
+    (float32 copies of q, k, v or o a wave long did, before the groups
+    were cut where the arrays lie: 3.5 GiB at 30,720)."""
     on = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
                                                    sharding=one_chip)
     wave = (rows, positions)
@@ -191,6 +194,9 @@ def test_delta_rule_prefill_compiles_for_v5e(one_chip, name, rows,
     out, state = compiled.out_info
     assert out.shape == wave + (32, 128) and out.dtype == jnp.bfloat16
     assert state.shape == (rows, 32, 128, 128) and state.dtype == jnp.float32
+    text = compiled.as_text()
+    assert "triangular-solve" not in text
+    assert "InvertDiagBlocksLowerTriangular" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < \
         temporaries_gib * 2 ** 30
 

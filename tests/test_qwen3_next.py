@@ -13,8 +13,9 @@ heads of 16 with 4 rotated features); every layer routes 3 of 16 experts of
 width 32 (8 held here) beside a gated shared expert; an untied head over
 96. Everything runs in float32 at the highest matmul precision, so the two
 computations differ by the order of float32 sums alone (and, in the
-chunked form, by a 64 x 64 triangular solve in the recurrence's place):
-measured under 2e-5 on logits of magnitude 4 through prefill and decode.
+chunked form, by the inverse of a 64 x 64 triangular system in the
+recurrence's place): measured under 2e-5 on logits of magnitude 4
+through prefill and decode.
 The tolerance is 1e-4; the same model with bfloat16 activations must fail
 it, and so must the reference in fp8, without the delta term or without
 the output gate.
@@ -36,6 +37,7 @@ from tfde_tpu.inference.server import (ContinuousBatcher,
 from tfde_tpu.models.gpt import GPT, gpt_tiny_test
 from tfde_tpu.models.moe import MoEMlp
 from tfde_tpu.models.transformer import MultiHeadAttention
+from tfde_tpu.observability import counters
 from tfde_tpu.observability.capacity import (CapacityLedger,
                                              DeltaCapacityLedger,
                                              HybridCapacityLedger)
@@ -151,6 +153,70 @@ def test_the_chunked_prefill_is_the_recurrence(length):
     assert np.abs(np.asarray((o - want) * real)).max() < 1e-5
     assert np.abs(np.asarray(end - want_end)).max() < 1e-5
     assert float(jnp.abs(want).max()) > 0.1
+
+
+def _chunk_systems(size, repeated, count=64, width=128):
+    """`count` chunks of `size` positions as `prefill` builds them, in
+    float64: l2-normalised keys of `width`, the unit lower triangular
+    system and its right-hand side [beta K exp(c), beta V]. `repeated`:
+    every key 16 times with 1e-3 of noise, beta in (0.9, 1), decay near
+    1, so that the strict part has entries near 1 far from the diagonal."""
+    rng = np.random.default_rng(size)
+    if repeated:
+        k = np.repeat(rng.standard_normal((count, -(-size // 16), width)),
+                      16, axis=1)[:, :size]
+        k = k + 1e-3 * rng.standard_normal(k.shape)
+        beta = rng.uniform(0.9, 1.0, (count, size))
+        g = -rng.uniform(0.0, 1e-3, (count, size))
+    else:
+        k = rng.standard_normal((count, size, width))
+        beta = rng.uniform(0.0, 1.0, (count, size))
+        g = -0.5 * np.log1p(np.exp(rng.standard_normal((count, size))))
+    k = k / np.sqrt((k * k).sum(-1, keepdims=True) + 1e-6)
+    cum = np.cumsum(g, -1)
+    seg = np.exp(np.tril(cum[:, :, None] - cum[:, None, :]))
+    system = np.eye(size) + np.tril(
+        beta[:, :, None] * (k @ k.transpose(0, 2, 1)) * seg, -1)
+    v = rng.standard_normal((count, size, width))
+    return system, beta[:, :, None] * np.concatenate(
+        [k * np.exp(cum)[..., None], v], -1)
+
+
+@pytest.mark.parametrize("repeated", [False, True],
+                         ids=["random_keys", "repeated_keys"])
+@pytest.mark.parametrize("size", [1, 3, 17, 63, 64])
+def test_the_inverse_solves_the_chunk_systems(size, repeated):
+    """`inverse(system) @ rhs` against float64 `numpy.linalg.solve`, at
+    the chunk's 64 and at what short prompts and tests give. The repeated
+    keys are the case that the nilpotent product
+    (I - N)(I + N^2)(I + N^4)... fails by 1e6 and more in float32 (N^2,
+    N^4, ... grow before they vanish and cancel): the substitution only
+    ever adds products of the system's entries with rows of the inverse,
+    which stay of the solution's size."""
+    system, rhs = _chunk_systems(size, repeated)
+    want = np.linalg.solve(system, rhs)
+    got = jax.jit(lambda a, b: jnp.einsum(
+        "nij,njw->niw", gdn.inverse(a), b,
+        precision=jax.lax.Precision.HIGHEST))(
+            jnp.asarray(system, jnp.float32), jnp.asarray(rhs, jnp.float32))
+    assert got.dtype == jnp.float32
+    assert np.abs(np.asarray(got, np.float64) - want).max() \
+        < 1e-6 * np.abs(want).max()
+
+
+def test_a_traced_prefill_says_it_holds_the_batched_inverse():
+    """`gdn/block_inverse_traces` (the issue's name for it) goes up once a
+    traced `prefill` and not at all under `decode_step`: a program in hand
+    can be asked whether it inverts its chunk systems side by side."""
+    qkv, beta, g, state = _operands(70)
+    name = "gdn/block_inverse_traces"
+    before = counters.value(name)
+    jax.jit(functools.partial(gdn.prefill, shape=SHAPE)).lower(
+        qkv, beta, g, state, jnp.asarray([70, 70]))
+    assert counters.value(name) - before == 1
+    jax.jit(functools.partial(gdn.decode_step, shape=SHAPE)).lower(
+        qkv[:, 0], beta[:, 0], g[:, 0], state, jnp.asarray([True, True]))
+    assert counters.value(name) - before == 1
 
 
 def test_a_prefill_continues_from_a_cached_state():

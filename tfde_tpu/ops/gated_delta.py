@@ -28,16 +28,26 @@ chunk's start:
     O  = (Q * exp(c)) S + lower(Q K^T * D) U'
     S <- exp(c_Q) S + (K * exp(c_Q - c))^T U'
 
-The solve does not read S, so `prefill` solves `_GROUP` chunks at once and
-then walks them with the state (two `lax.scan`s, the outer over groups so
-that only one group's W and U live; a group is cut out of the wave's
-arrays where they lie and its output written into place). Pure functions; the cache variables
+The system does not read S, so `prefill` inverts the systems of `_GROUP`
+chunks at once (`inverse`: forward substitution, row i of the inverse from
+the rows above it, every system of the group side by side in the minor
+axis, so that a step is one multiply and sum over all of them; not
+`triangular_solve`, which XLA expands into the same 64 dependent row
+updates ONE system at a time, and not the product
+(I - N)(I + N^2)(I + N^4)... of the strict part N, whose powers grow
+before they vanish and cancel in float32 where keys repeat), multiplies
+the right-hand side by the inverse, and then walks the chunks with the
+state (two `lax.scan`s, the outer over groups so that only one group's W
+and U live; a group is cut out of the wave's arrays where they lie and its
+output written into place). Pure functions; the cache variables
 and the projections live in models/transformer.py::GatedDeltaMixer. Two
 entry points beside `ssm.causal_conv`: `prefill` (chunked, over
 right-padded rows, continuing from a cached state, under
-`jax.named_scope("gdn_prefill")`) and `decode_step` (the four equations on
-one token, `gdn_decode`). Plain XLA, float32 throughout with every product
-at `Precision.HIGHEST`: no kernel here yet.
+`jax.named_scope("gdn_prefill")`; a traced one bumps the counter
+`gdn/block_inverse_traces`) and `decode_step` (the four equations on
+one token, `gdn_decode`). Plain XLA, float32 throughout: every matrix
+product at `Precision.HIGHEST`, the steps of `inverse` float32
+multiplies and sums on the vector unit. No kernel here yet.
 """
 
 from __future__ import annotations
@@ -47,7 +57,9 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-#: chunks whose triangular systems one step of the outer scan solves
+from tfde_tpu.observability import counters
+
+#: chunks whose triangular systems one step of the outer scan inverts
 _GROUP = 16
 _HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -107,6 +119,30 @@ def _split(qkv: jax.Array, shape: GatedDeltaShape) -> tuple:
                       ).astype(jnp.float32))
 
 
+def inverse(system: jax.Array) -> jax.Array:
+    """[..., n, n] unit lower triangular, float32 -> its inverse, by
+    forward substitution over the whole batch at once: row i of the
+    inverse is e_i - sum_{j < i} t_ij x_j, n - 1 dependent steps. The
+    batch goes to the minor axis first, so that a step is one float32
+    multiply and sum on the vector unit over all systems side by side,
+    where `triangular_solve` walks the rows of one system at a time. The
+    block recursion [[A, 0], [C, B]]^-1 = [[A^-1, 0], [-B^-1 C A^-1,
+    B^-1]] over blocks of 8 to 32 was tried on top of it, as vector
+    products and as batched einsums on a nearly empty MXU, and is not
+    kept: PERF.md section 6, PR 43, has the readings. Any n."""
+    n = system.shape[-1]
+    t = jnp.moveaxis(system.reshape((-1, n, n)), 0, -1)         # [n, n, N]
+    eye = jnp.eye(n, dtype=t.dtype)[:, :, None]
+
+    def row(i, x):      # rows from i on are still e's
+        below = jnp.where(jnp.arange(n)[:, None] < i, t[i], 0.0)
+        return jax.lax.dynamic_update_index_in_dim(
+            x, eye[i] - jnp.sum(below[:, None] * x, axis=0), i, 0)
+
+    x = jax.lax.fori_loop(1, n, row, jnp.broadcast_to(eye, t.shape))
+    return jnp.moveaxis(x, -1, 0).reshape(system.shape)
+
+
 def prefill(qkv: jax.Array, beta: jax.Array, g: jax.Array, state: jax.Array,
             lengths: jax.Array, shape: GatedDeltaShape, gate=None) -> tuple:
     """The recurrence over positions 0 .. S-1 of right-padded rows, a chunk
@@ -122,6 +158,7 @@ def prefill(qkv: jax.Array, beta: jax.Array, g: jax.Array, state: jax.Array,
     arrays of its length the chip has no room for). Returns (o
     [B, S, Hv, V] in qkv's dtype, normed and gated where `gate` is given;
     state)."""
+    counters.incr("gdn/block_inverse_traces")
     with jax.named_scope("gdn_prefill"):
         bsz, s, _ = qkv.shape
         hk, dk, dv = shape.key_heads, shape.key_dim, shape.value_dim
@@ -177,9 +214,10 @@ def prefill(qkv: jax.Array, beta: jax.Array, g: jax.Array, state: jax.Array,
             into = jnp.moveaxis(jnp.exp(cum), 2, -1)[..., None]
             k_h = jnp.moveaxis(kc, 2, 3)[:, :, :, None]        # [B,G,H,1,Q,K]
             v_h = jnp.moveaxis(vc, 2, 4)                       # [B,G,H,R,Q,V]
-            wu = jax.lax.linalg.triangular_solve(
-                system, beta_h * jnp.concatenate([k_h * into, v_h], -1),
-                left_side=True, lower=True, unit_diagonal=True)
+            wu = jnp.einsum(
+                "bghrij,bghrjw->bghriw", inverse(system),
+                beta_h * jnp.concatenate([k_h * into, v_h], -1),
+                precision=_HIGHEST)
             attend = jnp.where(lower, qk[:, :, :, None] * seg, 0.0)
             q_in = jnp.moveaxis(qc, 2, 3)[:, :, :, None] * into
             left = jnp.moveaxis(jnp.exp(cum[:, :, -1:] - cum), 2, -1)[..., None]
