@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from tfde_tpu.models import moe as moe_lib
 from tfde_tpu.ops import gated_delta, moe_gmm
 from tfde_tpu.ops.flash_attention import flash_attention
 
@@ -134,7 +135,8 @@ _EXPERT_LAYERS = {"hybrid": (36, 72, 10, 4096, 768, "silu"),
 
 @pytest.mark.parametrize("layer", sorted(_EXPERT_LAYERS))
 @pytest.mark.parametrize("name,tokens", [("a_prefill_block", 2048),
-                                         ("a_decode_tick", 32)])
+                                         ("a_decode_tick", 32),
+                                         ("a_long_waves_block", None)])
 def test_moe_gmm_compiles_for_v5e(one_chip, name, tokens, layer):
     """The hybrid cell's expert layer: 36 held experts of 4096 x 768, ten
     choices a token over 72, SwiGLU; and the window-and-global cell's: all
@@ -142,8 +144,17 @@ def test_moe_gmm_compiles_for_v5e(one_chip, name, tokens, layer):
     32 held of 128 experts of 4096 x 2048, eight a token, SwiGLU, the
     widest it has met. An expert's three matrices, twice buffered, are 38
     MB of VMEM beside the row tiles (100 MB at 2,048): the kernel asks for
-    its own limit, and the v5e compiler has to grant it."""
+    its own limit, and the v5e compiler has to grant it. A long wave's
+    block is what `moe.token_block` gives a call of 65,536 tokens: 8,192
+    and a tile of 64 in the delta-rule cell, 4,096 and 128 in the latent
+    and the window-and-global cell, 2,048 as before in the hybrid."""
     held, experts, k, d, f, act = _EXPERT_LAYERS[layer]
+    if tokens is None:
+        tokens = moe_lib.token_block(65536, k, experts, held, d, 2)
+        assert (tokens, moe_gmm.tile_rows(tokens * k, experts)) == {
+            "hybrid": (2048, 128), "latent": (4096, 128),
+            "window_and_global": (4096, 128),
+            "delta_rule_cell": (8192, 64)}[layer]
     pairs = tokens * k
     tile = moe_gmm.tile_rows(pairs, experts)
     tiles = moe_gmm.tiles_bound(pairs, held, tile)

@@ -355,7 +355,8 @@ def test_the_expert_layer_alone_matches_the_reference(weights, params, shape,
     one by one over all tokens."""
     from tfde_tpu.models import moe as moe_lib
 
-    monkeypatch.setattr(moe_lib, "_TOKEN_BLOCK", 64)
+    monkeypatch.setattr(moe_lib, "token_block",
+                        lambda n, *shape: min(n, 64))
     lw = weights["layers"][0]
     v = jax.random.normal(jax.random.key(5), shape + (64,))
     want = _layer_reference(lw, v.reshape(-1, 64),
@@ -364,7 +365,7 @@ def test_the_expert_layer_alone_matches_the_reference(weights, params, shape,
         {"params": params["decoder"]["block_0"]["moe"]}, v,
         mutable=["counters"])
     assert np.abs(np.asarray(got) - want).max() < TOL
-    pairs, held_pairs, touched, busiest, moved = np.asarray(
+    pairs, held_pairs, touched, busiest, moved, passes = np.asarray(
         jax.tree.leaves(sown)[0])
     tokens = shape[0] * shape[1]
     assert pairs == PER_TOKEN * tokens and 0 < held_pairs < pairs
@@ -372,6 +373,7 @@ def test_the_expert_layer_alone_matches_the_reference(weights, params, shape,
     # every pair is fetched back, and a slot for every pair and more is
     # filled on the way in
     assert moved >= 2 * pairs
+    assert passes == -(-tokens // 64)       # one a block of 64 tokens
 
 
 def test_a_rows_logits_are_the_same_alone_and_in_a_wave_of_four(params,
@@ -490,6 +492,10 @@ def test_batcher_counts_state_cells_and_routing(served):
     # at least as many slots are filled, padding tokens' among them
     assert stats["moe_rows_moved"] >= 2 * stats["moe_pairs"]
     assert stats["moe_rows_moved"] < 40 * stats["moe_pairs"]
+    # a pass over the held weights a block, layer and call: every wave and
+    # every tick here is one block in each of the four expert layers
+    assert stats["moe_weight_passes"] == 4 * (
+        stats["prefill_waves"] + stats["rounds"])
     # the counts ride the fetch each wave and each scan already makes
     assert fetches == stats["syncs"] == stats["prefill_waves"] + stats["scans"]
 
@@ -500,6 +506,7 @@ def test_a_dense_batcher_keeps_no_hybrid_counters_and_sows_nothing():
         "params"]
     srv = ContinuousBatcher(model, params, batch_size=2, max_len=32)
     assert not set(HybridCapacityLedger.HYBRID_KEYS) & set(srv.stats())
+    assert "moe_weight_passes" in HybridCapacityLedger.HYBRID_KEYS
     assert type(srv._ledger) is CapacityLedger
     srv.submit(np.arange(5, dtype=np.int32), 6)
     assert len(srv.run()) == 1
@@ -573,16 +580,16 @@ def test_least_bytes_count_the_touched_experts_only():
     got = ledger.scan_least_bytes(params, 9_000, 2, [60, 30, 12, 9, 400])
     assert got == 2 * (100_000 + 9_000) + 12 * expert
     ledger.note_scan([10, 20], 2)
-    ledger.note_routed([60, 30, 12, 9, 400])
+    ledger.note_routed([60, 30, 12, 9, 400, 7])
     ledger.note_routed(None)
     assert ledger.counters == {
         "ssm_state_bytes_touched": 2 * 2 * 2 * 4096,
         "kv_cells_read": 2 * 30, "moe_pairs": 60, "moe_pairs_held": 30,
         "moe_experts_touched": 12, "moe_pairs_busiest": 9,
-        "moe_rows_moved": 400}
+        "moe_rows_moved": 400, "moe_weight_passes": 7}
     dense = CapacityLedger(2, 64, 2 * 64 * 256)
     assert dense.scan_least_bytes(1000, 50, 3, None) == 3 * 1050
-    dense.note_routed([1, 1, 1, 1, 1])
+    dense.note_routed([1, 1, 1, 1, 1, 1])
     assert dense.counters == {}
 
 

@@ -346,7 +346,8 @@ def _loop_reference(params, x, lo, hi, real, router_input=None,
 
 def _apply_uncapped(layer, params, x, block, pad, monkeypatch,
                     router_input=None):
-    monkeypatch.setattr(moe_lib, "_TOKEN_BLOCK", block)
+    monkeypatch.setattr(moe_lib, "token_block",
+                        lambda n, *shape: min(n, block))
     variables = {"params": params}
     if pad is not None:
         variables["cache"] = {"feed_pad": jnp.asarray(pad, jnp.int32)}
@@ -392,6 +393,89 @@ def test_rows_moved_reads_what_the_form_moves(name, monkeypatch):
     slots = moe_gmm.tiles_bound(pairs, hi - lo, tile) * tile
     assert counted[4] == -(-n // block) * (slots + pairs)
     assert slots >= pairs and tile % 16 == 0
+    # ... in as many passes over the held weights as the call has blocks
+    assert counted[5] == -(-n // block)
+
+
+def test_blocks_of_a_call_agree_to_float32_rounding(monkeypatch):
+    """A token's result does not depend on which tokens share its block:
+    150 tokens in one block, two and three (the last one filled) differ
+    by the order of float32 sums alone, and the four routing counts are
+    the same; the rows moved and the passes follow the blocks."""
+    layer, params, x, _, _, _ = _uncapped_case(
+        "n_not_a_multiple_of_the_block")
+    got = {block: _apply_uncapped(layer, params, x, block, None, monkeypatch)
+           for block in (150, 75, 64)}
+    whole, counted = got[150]
+    assert np.abs(whole).max() > 0.1 and counted[5] == 1
+    for block, passes in ((75, 2), (64, 3)):
+        y, c = got[block]
+        assert np.abs(y - whole).max() < UNCAPPED_TOL
+        assert c[:4].tolist() == counted[:4].tolist() and c[5] == passes
+
+
+#: (experts routed over, held, k, d) of the four served cells' expert
+#: layers, all bfloat16
+_CELL_7, _CELL_8 = (72, 36, 10, 4096), (64, 64, 6, 2560)
+_CELL_9, _CELL_10 = (128, 32, 8, 4096), (512, 256, 10, 2048)
+
+
+@pytest.mark.parametrize("n,shape,block,tile", [
+    # a wave of 8,192 tokens in each cell: the least multiple of 2,048 at
+    # which an expert's even share reaches 256 rows (284, 384, 256; and
+    # 8,192 for 160 of them where 512 experts share the pairs: the next
+    # step's copy would pass the byte budget)
+    (8192, _CELL_7, 2048, 128), (8192, _CELL_8, 4096, 128),
+    (8192, _CELL_9, 4096, 128), (8192, _CELL_10, 8192, 64),
+    (16384, _CELL_10, 8192, 64),
+    # the widest waves, in equal blocks
+    (30720, _CELL_10, 7680, 64), (61440, _CELL_10, 7680, 64),
+    (30720, _CELL_9, 3840, 64),
+    (28672, _CELL_8, 4096, 128), (14336, _CELL_8, 3584, 128),
+    # a decode tick and a call under 2,048 tokens are one block
+    (48, _CELL_10, 48, 16), (1024, _CELL_8, 1024, 32),
+    # 12,288 tokens are two blocks of 6,144, not 8,192 and a half-empty
+    # one
+    (12288, _CELL_10, 6144, 32),
+    # where 2,048 is the target the call is cut as it was: 3,072 tokens
+    # are two blocks of 2,048, the second half filled, not two of 1,536
+    (3072, _CELL_7, 2048, 128),
+    # 10,240 tokens under a target of 4,096: three blocks of 3,584 would
+    # hold 512 fill tokens more than the call has today, four of 2,560
+    (10240, _CELL_9, 2560, 64),
+    # the cap wins: at d = 16,384 the sorted copy of 4,096 tokens' pairs
+    # would be 0.81 GB, so the block stays at 2,048 and its share at 40
+    (8192, (512, 256, 10, 16384), 2048, 16),
+])
+def test_the_block_follows_the_shapes(n, shape, block, tile):
+    experts, held, k, d = shape
+    got = moe_lib.token_block(n, k, experts, held, d, 2)
+    assert got == block
+    assert moe_gmm.tile_rows(got * k, experts) == tile
+    pairs = got * k
+    copy = moe_gmm.tiles_bound(pairs, held, tile) * tile * d * 2
+    assert got <= 2048 or copy <= moe_lib._SORTED_COPY_BYTES
+
+
+@pytest.mark.parametrize("shape", [_CELL_7, _CELL_8, _CELL_9, _CELL_10])
+def test_no_call_carries_more_fill_than_rounding_up_to_2048(shape):
+    """Whatever the call's tokens: the blocks hold them all, are whole
+    steps of 256 tokens past 2,048, never fewer than 2,048 each where the
+    call has that many, and together no larger than the call rounded up
+    to 2,048, which is what it was cut into before."""
+    experts, held, k, d = shape
+    rng = np.random.default_rng(experts)
+    calls = set(rng.integers(1, 70000, 400).tolist()) | {
+        2048 * m for m in range(1, 33)} | {1, 2047, 2049, 65536}
+    for n in sorted(calls):
+        block = moe_lib.token_block(n, k, experts, held, d, 2)
+        blocks = -(-n // block)
+        assert blocks * block >= n
+        assert blocks * block <= -(-n // 2048) * 2048 or n < 2048
+        if n <= 2048:
+            assert block == n
+        else:
+            assert block >= 2048 and block % 256 == 0
 
 
 def _one_tile_left_out(monkeypatch):

@@ -566,6 +566,9 @@ def test_batcher_counts_what_a_known_schedule_makes(params):
     fed = (20 + 4) + (70 + 4)
     assert stats["moe_pairs"] == LAYERS * PER_TOKEN * fed
     assert 0 < stats["moe_pairs_held"] < stats["moe_pairs"]
+    # a pass over the held experts' weights a block, expert layer and
+    # call: both waves and all four ticks are one block each
+    assert stats["moe_weight_passes"] == 1 * LAYERS * (2 + 4)
     assert stats["decode_least_bytes"] > 0
 
 

@@ -30,9 +30,11 @@ objective automatically when the model mutates that collection.
 **Without a capacity** (`capacity_factor=None`, the serving form): no token
 is dropped and no [.., capacity] tensor exists; the work follows the pairs
 routed and a token's result does not depend on which other tokens share
-its call. A block of `_TOKEN_BLOCK` tokens at a time (`_uncapped` cuts the
-blocks, `_held_pairs`, jitted on its own so that all layers and programs
-of one block shape share a trace, walks them):
+its call. A block of tokens at a time, each block one pass over the held
+experts' weights (`token_block` sizes the blocks from the call's shapes,
+so that an expert's share of a block pays for fetching its matrices;
+`_uncapped` cuts them; `_held_pairs`, jitted on its own so that all
+layers and programs of one block shape share a trace, walks them):
 
 - nothing is sorted. Each of the block's (token, choice) pairs whose expert
   is held gets a row of a layout in which an expert's pairs stand together
@@ -53,14 +55,18 @@ of one block shape share a trace, walks them):
   kernel's grid over `pairs // tile + held` tiles, of which it runs the
   `live` first (held pairs / tile, plus up to one an expert); `_rows_back`'s
   loop over the k choices, `_ROW_CHUNK // block` of them a step (one step
-  for a decode tick). The tile follows the block (`moe_gmm.tile_rows`):
-  128 rows for 2,048 tokens of ten choices over 72 experts, 16 for a tick.
+  for a decode tick). The tile follows the block (`moe_gmm.tile_rows`).
+  What a prefill wave gets: 2,048 tokens and a tile of 128 where ten of
+  72 experts are chosen (36 held), 4,096 and 128 for six of 64 and for
+  eight of 128 (32 held), 8,192 and 64 for ten of 512 (256 held), each
+  made equal over the wave (30,720 tokens: four blocks of 7,680); a tick
+  is one block and a tile of 16.
 
 Such a layer may hold a contiguous range of the experts (`held_experts`, a
 chip's share under expert parallelism): the router keeps all `num_experts`
 outputs and its top k, and pairs whose expert lives elsewhere get no row
 and add nothing; there is no stand-in for the absent chips or their
-exchange. Five counts are sown into "counters" (`_uncapped`).
+exchange. Six counts are sown into "counters" (`_uncapped`).
 """
 
 from __future__ import annotations
@@ -105,11 +111,58 @@ def dispatch_shape(batch: int, seq: int, num_experts: int,
 #: under the name `ops/moe_gmm.py` has for it
 _GATE_ACTS = {"swiglu": "silu", "reglu": "relu"}
 
-#: tokens whose pairs are laid out and multiplied at a time without a
-#: capacity: the sorted copy is about this x experts_per_token rows
+#: the least tokens whose pairs are laid out and multiplied at a time
+#: without a capacity, where a call has that many (`token_block`)
 _TOKEN_BLOCK = 2048
+#: a call's blocks are whole multiples of this many tokens
+_BLOCK_STEP = 256
+#: what a block's sorted copy may take (its slots x d on the way in; the
+#: kernel's result is as much again): 8,192 tokens of ten choices over
+#: 512 experts, 256 held, at d = 2,048 are 98,304 slots, 384 MiB. Past
+#: some 450 MB XLA's fusions around the kernel cost more a token than the
+#: rarer fetch saves (the layer alone on a v5e: 1.11 us a token in blocks
+#: of 7,680, 1.38 in blocks of 10,240, 2.18 in blocks of 2,048)
+_SORTED_COPY_BYTES = 400 << 20
 #: rows of d that one step of `_rows_back` gathers
 _ROW_CHUNK = 2048
+
+
+def token_block(n: int, k: int, num_experts: int, held: int, d: int,
+                itemsize: int) -> int:
+    """Tokens that share one pass over the held experts' weights in a call
+    of `n` tokens, k choices each over `num_experts`, `held` of them here,
+    rows of `d` values of `itemsize` bytes. From shapes alone:
+
+    - a call of no more than `_TOKEN_BLOCK` tokens (a decode tick) is one
+      block;
+    - the target is the least multiple of `_TOKEN_BLOCK` at which an
+      expert's even share of the block's pairs, block x k / num_experts,
+      reaches `moe_gmm.ROWS_A_FETCH`, the rows that pay for fetching its
+      matrices, as long as the block's sorted copy stays within
+      `_SORTED_COPY_BYTES`;
+    - the call's ceil(n / target) blocks are then made equal, in steps of
+      `_BLOCK_STEP` tokens (12,288 tokens are two blocks of 6,144, not
+      8,192 and a half-empty one), with one block more wherever they
+      would otherwise hold more fill tokens than rounding n up to
+      `_TOKEN_BLOCK` does; with as many blocks as that rounding has, they
+      are `_TOKEN_BLOCK` tokens each, as the call was cut before."""
+    if n <= _TOKEN_BLOCK:
+        return n
+
+    def copy_bytes(block: int) -> int:
+        tile = moe_gmm.tile_rows(block * k, num_experts)
+        return moe_gmm.tiles_bound(block * k, held, tile) * tile * d * itemsize
+
+    block = _TOKEN_BLOCK
+    while (block * k < moe_gmm.ROWS_A_FETCH * num_experts
+           and copy_bytes(block + _TOKEN_BLOCK) <= _SORTED_COPY_BYTES):
+        block += _TOKEN_BLOCK
+    grown = -(-n // _TOKEN_BLOCK) * _TOKEN_BLOCK
+    for blocks in range(-(-n // block), grown // _TOKEN_BLOCK):
+        equal = -(-n // (blocks * _BLOCK_STEP)) * _BLOCK_STEP
+        if blocks * equal <= grown:
+            return equal
+    return _TOKEN_BLOCK
 
 
 def _layout(key, held: int, tile: int, real):
@@ -422,8 +475,9 @@ class MoEMlp(nn.Module):
         row, by expert and each expert from a tile's first row; x's rows
         are gathered into that order, every slot of it; `ops/moe_gmm.py`
         multiplies the tiles in use; `_rows_back` fetches each token's k
-        results, weights and sums them. Here: the blocks are cut, the
-        tile chosen from the block's shape, and the five counts sown."""
+        results, weights and sums them. Here: the blocks are cut
+        (`token_block`), the tile chosen from the block's shape, and the
+        six counts sown."""
         bsz, seq, d = x.shape
         k, held = self.experts_per_token, hi - lo
         if self.act not in _GATE_ACTS or self.use_bias:
@@ -441,7 +495,8 @@ class MoEMlp(nn.Module):
                 valid = (jnp.arange(seq)[None, :]
                          < seq - feed_pad.value[:, None])
                 feed_pad.value = jnp.zeros_like(feed_pad.value)
-        block = min(n, _TOKEN_BLOCK)
+        block = token_block(n, k, self.num_experts, held, d,
+                            jnp.dtype(self.dtype).itemsize)
         grown = -(-n // block) * block
         pairs = block * k
         tile = moe_gmm.tile_rows(pairs, self.num_experts)
@@ -461,15 +516,17 @@ class MoEMlp(nn.Module):
         counted = counted.sum(0)
         # of this call's real tokens: pairs routed, pairs whose expert is
         # held, held experts with a pair, the busiest held expert's pairs;
-        # and the rows of d the call copied into sorted order (every slot
-        # of every block's layout) and fetched back from it (every pair)
+        # the rows of d the call copied into sorted order (every slot of
+        # every block's layout) and fetched back from it (every pair); and
+        # its passes over the held experts' weights (its blocks)
         self.sow("counters", "moe_routing",
                  jnp.stack([routed.sum().astype(jnp.int32), counted.sum(),
                             (counted > 0).sum().astype(jnp.int32),
                             counted.max(),
-                            jnp.int32(grown // block * (slots + pairs))]),
+                            jnp.int32(grown // block * (slots + pairs)),
+                            jnp.int32(grown // block)]),
                  reduce_fn=lambda a, b: a + b,
-                 init_fn=lambda: jnp.zeros((5,), jnp.int32))
+                 init_fn=lambda: jnp.zeros((6,), jnp.int32))
         return y.reshape(grown, d)[:n].reshape(bsz, seq, d)
 
     def _finish(self, x, y, train: bool):

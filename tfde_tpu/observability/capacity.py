@@ -479,11 +479,14 @@ class HybridCapacityLedger(CapacityLedger):
     layers and ticks: pairs routed, pairs whose expert is held, held
     experts with at least one pair, the busiest held expert's pairs; and,
     of every token they were handed, the rows of the hidden width they
-    copied into sorted order and fetched back from it."""
+    copied into sorted order and fetched back from it, and their passes
+    over the held experts' weights (a call's blocks: one a layer for a
+    tick, more for a wave longer than `moe.token_block` gives a block)."""
 
     HYBRID_KEYS = ("ssm_state_bytes_touched", "kv_cells_read", "moe_pairs",
                    "moe_pairs_held", "moe_experts_touched",
-                   "moe_pairs_busiest", "moe_rows_moved")
+                   "moe_pairs_busiest", "moe_rows_moved",
+                   "moe_weight_passes")
     _STATE_LEAVES = ("ssm_state", "delta_state", "conv_tail")
 
     def __init__(self, batch_size: int, positions: int, slab_bytes: int,

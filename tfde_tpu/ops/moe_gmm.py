@@ -18,9 +18,14 @@ the last one in use maps onto that one's blocks (nothing is fetched) and
 is skipped. The pad rows that complete an expert's last tile are
 multiplied like the others and read by nobody.
 
-The tile follows the block (`tile_rows`): a prefill block of 2,048 tokens
-gives an expert a few hundred rows, a decode tick a handful, where the
-kernel is bound by fetching the weights whatever the tile.
+The tile follows the block (`tile_rows`), and the caller chooses the block
+so that an expert's even share of its pairs reaches `ROWS_A_FETCH` where
+the sorted copy's bytes allow (`models/moe.py::token_block`): 2,048 tokens
+of ten choices over 72 experts give an expert 284 rows (tile 128), 4,096
+of six over 64 give 384 and of eight over 128 give 256 (tile 128), and
+8,192 of ten over 512 give 160 (tile 64, the byte budget's block). A
+decode tick gives an expert a handful, and the kernel is bound there by
+fetching the weights whatever the tile.
 """
 
 from __future__ import annotations
@@ -32,9 +37,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-#: the largest tile: [256, d] rows against an expert's matrices keeps the
-#: MXU streaming; pads cost half a tile an expert
-_TILE_MAX = 256
+#: rows an expert needs for one fetch of its three matrices before the
+#: arithmetic and not the fetch sets the time: the matrices are 6 d f bytes
+#: in bfloat16 and a row costs 6 d f FLOP, so the rows a fetch are the
+#: chip's FLOP a byte whatever d and f; a v5e does 197 TFLOP/s over
+#: 819 GB/s = 240, and 256 is the power of two above it
+ROWS_A_FETCH = 256
+#: the largest tile: an expert with that many rows has paid for its
+#: fetch, and a larger tile only adds pads (half a tile an expert)
+_TILE_MAX = ROWS_A_FETCH
 #: the smallest: one packed bfloat16 sublane group
 _TILE_MIN = 16
 
