@@ -25,8 +25,8 @@ from tfde_tpu.inference.server import (ContinuousBatcher,
                                        SpeculativeContinuousBatcher)
 from tfde_tpu.models.gpt import GPT, gpt_tiny_test
 from tfde_tpu.models.transformer import UnitOffsetRMSNorm
-from tfde_tpu.observability.capacity import (CapacityLedger,
-                                             EvaCapacityLedger)
+from tfde_tpu.models.cache_state import CacheState, layout_of
+from tfde_tpu.observability.capacity import CapacityLedger, kv_slab_bytes
 from tfde_tpu.ops import eva_attention as eva_lib
 
 W, C, HEADS, LAYERS, VOCAB = 32, 4, 4, 3, 320
@@ -253,7 +253,7 @@ def test_batcher_counts_summaries_turns_and_cells(served):
         e // W - p // W for e, (p, _) in zip(ends, REQUESTS))
     assert stats["eva_window_cells_read"] > 0
     assert stats["eva_summary_cells_read"] > 0
-    assert set(EvaCapacityLedger.EVA_KEYS) <= set(stats)
+    assert set(CapacityLedger.EVA_KEYS) <= set(stats)
 
 
 def test_a_dense_batcher_keeps_no_eva_counters():
@@ -261,7 +261,7 @@ def test_a_dense_batcher_keeps_no_eva_counters():
     params = model.init(jax.random.key(0), np.zeros((1, 8), np.int32))[
         "params"]
     srv = ContinuousBatcher(model, params, batch_size=2, max_len=32)
-    assert not set(EvaCapacityLedger.EVA_KEYS) & set(srv.stats())
+    assert not set(CapacityLedger.EVA_KEYS) & set(srv.stats())
     cache = init_cache(model, 2, 32)
     same = server._set_feed_pad(cache, jnp.zeros(2, jnp.int32))
     assert all(a is b for a, b in zip(jax.tree.leaves(cache),
@@ -331,6 +331,15 @@ def test_window_must_be_whole_chunks():
 # capacity and least bytes, against hand arithmetic
 # ---------------------------------------------------------------------------
 
+#: one layer of a cache of 192 positions: 32 window cells and 48 summaries
+#: of K and V of 4 heads of 16 float32
+EVA_LAYER = CacheState("eva", W + 48, 512, window=W, chunk=C)
+
+
+def eva_ledger():
+    return CapacityLedger(4, 192, 4 * 80 * 1536, [EVA_LAYER] * LAYERS)
+
+
 @pytest.mark.parametrize("n,live,visible,held", [
     (0, 0, 0, 0),
     (31, 31, 0, 7),       # first window: nothing remote yet
@@ -339,10 +348,11 @@ def test_window_must_be_whole_chunks():
     (101, 5, 24, 25),     # 3 windows closed; chunk 24 written, not yet read
 ])
 def test_ledger_counts_live_window_and_summaries(n, live, visible, held):
-    ledger = EvaCapacityLedger(4, W, 48, 4 * 80 * 1536, W, C)
-    assert ledger.attended(n) == (live, visible)
-    assert ledger.read_cells(n) == live + visible
-    assert ledger.row_cells(n) == live + held
+    ledger = eva_ledger()
+    assert EVA_LAYER.attended(n) == (live, visible)
+    # a cell is one layer's: the three layers hold and read alike
+    assert ledger.read_cells(n) == LAYERS * (live + visible)
+    assert ledger.row_cells(n) == LAYERS * (live + held)
 
 
 @pytest.mark.parametrize("before,after,decoding,written,turns", [
@@ -353,7 +363,7 @@ def test_ledger_counts_live_window_and_summaries(n, live, visible, held):
 ])
 def test_ledger_counts_summaries_and_turns(before, after, decoding, written,
                                            turns):
-    ledger = EvaCapacityLedger(4, W, 48, 4 * 80 * 1536, W, C)
+    ledger = eva_ledger()
     ledger.note_commit(before, after, decoding=decoding)
     ledger.note_scan([37, 101], 4)
     assert ledger.counters == {
@@ -362,17 +372,20 @@ def test_ledger_counts_summaries_and_turns(before, after, decoding, written,
         "eva_summary_cells_read": 4 * (8 + 24)}
 
 
-def test_the_model_chooses_the_ledger():
-    """`from_cache` reads the layout off the served model; a slab of one
+def test_the_layers_say_what_the_ledger_counts():
+    """The ledger is built from the layers' own descriptions; a slab of one
     cell per position has no counters of its own and its notes do nothing."""
-    eva = CapacityLedger.from_cache(init_cache(eva_model(), 2, 64), 2, 64,
-                                    model=eva_model())
-    assert type(eva) is EvaCapacityLedger
-    assert eva.cells_per_row == W + 64 // C and eva.attended(37) == (5, 8)
+    layers = layout_of(eva_model(), 64).layers
+    eva = CapacityLedger(2, 64, kv_slab_bytes(init_cache(eva_model(), 2, 64)),
+                         layers)
+    assert eva.kinds == {"eva"} and set(eva.counters) == set(eva.EVA_KEYS)
+    assert eva.cells_per_row == LAYERS * (W + 64 // C)
+    assert layers[0].attended(37) == (5, 8)
     dense_model = gpt_tiny_test()
-    dense = CapacityLedger.from_cache(init_cache(dense_model, 2, 32), 2, 32,
-                                      model=dense_model)
-    assert type(dense) is CapacityLedger
+    dense = CapacityLedger(
+        2, 32, kv_slab_bytes(init_cache(dense_model, 2, 32)),
+        layout_of(dense_model, 32).layers)
+    assert dense.kinds == {"kv"}
     dense.note_commit(0, 40)
     dense.note_scan([40], 4)
     assert dense.counters == {}
@@ -393,10 +406,11 @@ def test_batcher_capacity_and_least_bytes(params):
         param_bytes + ((5 + 8) + (5 + 24)) * cell)
     assert stats["eva_window_cells_read"] == 4 * (5 + 5)
     assert stats["eva_summary_cells_read"] == 4 * (8 + 24)
-    # now at 41 and 105 committed: 9 + 10 and 9 + 26 cells hold state
+    # now at 41 and 105 committed: 9 + 10 and 9 + 26 cells hold state in
+    # each layer
     kv = srv.kv_stats()
-    assert kv["used_cells"] == (9 + 10) + (9 + 26)
-    assert kv["used_bytes"] == kv["used_cells"] * cell
+    assert kv["used_cells"] == LAYERS * ((9 + 10) + (9 + 26))
+    assert kv["used_bytes"] == kv["used_cells"] * cell // LAYERS
 
 
 # ---------------------------------------------------------------------------

@@ -4,6 +4,7 @@ writes events + exports there. These tests drive the same surface against
 fsspec's in-memory filesystem (`memory://`) — hermetic stand-in for gs://."""
 
 import json
+import os
 import struct
 
 import jax.numpy as jnp
@@ -132,3 +133,33 @@ def test_estimator_remote_model_dir():
         sig = json.load(f)
     assert sig["framework"] == "tfde_tpu"
     est.close()
+
+
+def test_a_remote_model_dir_is_never_a_directory_called_memory(
+        tmp_path, monkeypatch):
+    """`Estimator(model_dir="memory://...")` arms the flight recorder on
+    a URL. Whoever dumps it later writes through utils/fs, where the
+    events land, and not into `memory:/<run>/debug` under the working
+    directory, as it did while the recorder took the URL for a path."""
+    from tfde_tpu.observability import flightrec
+
+    monkeypatch.chdir(tmp_path)
+    model_dir = "memory://flight-run"
+    est = Estimator(
+        PlainCNN(), optax.sgd(0.1),
+        config=RunConfig(model_dir=model_dir, save_checkpoints_steps=None))
+    rng = np.random.default_rng(0)
+    batch = (rng.random((8, 784), np.float32),
+             rng.integers(0, 10, (8, 1)).astype(np.int32))
+    est.train(lambda: iter([batch] * 2), max_steps=1)
+    est.close()
+    path = flightrec.dump("drill")
+    try:
+        assert path.startswith("memory://flight-run/debug/flight_")
+        assert os.listdir(tmp_path) == []
+        kinds = [ev["kind"] for ev in flightrec.load(path)]
+        assert "armed" in kinds and kinds[-1] == "dump"
+    finally:
+        # the process's recorder is left where no later dump writes
+        flightrec.default_recorder()._dump_dir = None
+

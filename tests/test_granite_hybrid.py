@@ -29,11 +29,12 @@ from tfde_tpu.inference import server
 from tfde_tpu.inference.decode import _decode_clone, init_cache
 from tfde_tpu.inference.server import (ContinuousBatcher,
                                        SpeculativeContinuousBatcher)
+from tfde_tpu.models import moe
+from tfde_tpu.models.cache_state import CacheState, layout_of
 from tfde_tpu.models.gpt import GPT, gpt_tiny_test
 from tfde_tpu.models.moe import MoEMlp
 from tfde_tpu.models.transformer import Mamba2Mixer
-from tfde_tpu.observability.capacity import (CapacityLedger,
-                                             HybridCapacityLedger)
+from tfde_tpu.observability.capacity import CapacityLedger, kv_slab_bytes
 from tfde_tpu.ops import ssm as ssm_lib
 
 LAYERS = ("mamba", "mamba", "mamba", "attention")
@@ -476,7 +477,7 @@ def test_batcher_serves_the_references_first_choice(weights, served, i):
 def test_batcher_counts_state_cells_and_routing(served):
     srv, _, _, fetches = served
     stats = srv.stats()
-    assert set(HybridCapacityLedger.HYBRID_KEYS) <= set(stats)
+    assert set(CapacityLedger.HYBRID_KEYS) <= set(stats)
     # every real token fed, in a wave or a tick, is routed in 4 layers to 3
     # experts; a request of prompt P and budget T feeds P + T - 1 tokens,
     # and a wave of three repeats its first row to fill the ladder
@@ -505,9 +506,9 @@ def test_a_dense_batcher_keeps_no_hybrid_counters_and_sows_nothing():
     params = model.init(jax.random.key(0), np.zeros((1, 8), np.int32))[
         "params"]
     srv = ContinuousBatcher(model, params, batch_size=2, max_len=32)
-    assert not set(HybridCapacityLedger.HYBRID_KEYS) & set(srv.stats())
-    assert "moe_weight_passes" in HybridCapacityLedger.HYBRID_KEYS
-    assert type(srv._ledger) is CapacityLedger
+    assert not set(CapacityLedger.HYBRID_KEYS) & set(srv.stats())
+    assert "moe_weight_passes" in CapacityLedger.HYBRID_KEYS
+    assert srv._ledger.kinds == {"kv"} and srv._ledger.counters == {}
     srv.submit(np.arange(5, dtype=np.int32), 6)
     assert len(srv.run()) == 1
     assert server._sown_counters({"cache": {}}) is None
@@ -539,43 +540,50 @@ def test_speculation_and_the_primed_hand_off_are_refused(params):
 
 
 def test_the_refusal_is_asked_of_the_model_not_of_a_family():
-    assert server._state_not_by_position(gpt_tiny_test()) is None
-    assert "mamba" in server._state_not_by_position(hybrid_model())
-    assert "eva" in server._state_not_by_position(
-        gpt_tiny_test(position="rope", attention="eva"))
+    assert layout_of(gpt_tiny_test()).not_by_position is None
+    assert "mamba" in layout_of(hybrid_model()).not_by_position
+    assert "eva" in layout_of(
+        gpt_tiny_test(position="rope", attention="eva")).not_by_position
 
 
 # ---------------------------------------------------------------------------
 # capacity and least bytes, against hand arithmetic
 # ---------------------------------------------------------------------------
 
-def test_the_model_chooses_the_hybrid_ledger(params):
+def test_the_layers_give_the_ledger_states_beside_cells(params):
     model = hybrid_model()
     cache = init_cache(model, 2, 64)
-    ledger = CapacityLedger.from_cache(cache, 2, 64, model=model,
-                                       params=params)
-    assert type(ledger) is HybridCapacityLedger
+    ledger = CapacityLedger(2, 64, kv_slab_bytes(cache),
+                            layout_of(model, 64).layers,
+                            moe.held_experts(params))
+    assert ledger.kinds == {"kv", "state"}
+    assert set(ledger.counters) == set(ledger.HYBRID_KEYS)
     # a position: one attention layer, K and V of 2 heads of 16, float32
     per_position = 2 * 2 * 16 * 4
     # a row's state: three layers of [4, 16, 16] float32 and a tail of
     # 3 x 96 float32
     state = 3 * (4 * 16 * 16 * 4 + 3 * 96 * 4)
     assert ledger.slab_bytes == 2 * (64 * per_position + state)
-    cells = round(state / per_position)
-    assert ledger.cells_per_row == cells + 64
-    assert ledger.row_cells(0) == cells
-    assert ledger.row_cells(37) == cells + 37     # grows with attention only
-    assert ledger.read_cells(37) == 2 * cells + 37
+    # a state is bytes and no cell; a tick reads it and writes it back
+    assert ledger.cells_per_row == 64
+    assert ledger.row_bytes == 64 * per_position + state
+    assert ledger.row_cells(0) == 0
+    assert ledger.row_cells(37) == 37             # grows with attention only
+    assert ledger.read_cells(37) == 37
+    assert ledger.read_bytes([37]) == 37 * per_position + 2 * state
+    assert ledger.observe([0, 37], [1, 2])["used_bytes"] == (
+        37 * per_position + 2 * state)
 
 
 def test_least_bytes_count_the_touched_experts_only():
     expert = 3 * 64 * 32 * 4                      # one expert of one layer
-    ledger = HybridCapacityLedger(
+    ledger = CapacityLedger(
         batch_size=2, positions=64, slab_bytes=2 * (64 * 256 + 4096),
-        state_row_bytes=4096, expert_bytes=4 * 4 * expert,
-        expert_slots=4 * 4)
+        states=[CacheState("kv", 64, 256),
+                CacheState("state", fixed_bytes=4096)],
+        experts=moe.HeldExperts(4 * 4 * expert, 4 * 4))
     params = 100_000 + 16 * expert
-    assert ledger.read_cells(10) == 2 * 16 + 10
+    assert ledger.read_bytes([10]) == 10 * 256 + 2 * 4096
     # two ticks in which 5 and 7 (layer, expert) slots received a pair
     got = ledger.scan_least_bytes(params, 9_000, 2, [60, 30, 12, 9, 400])
     assert got == 2 * (100_000 + 9_000) + 12 * expert
@@ -597,8 +605,8 @@ def test_batcher_least_bytes_follow_the_ledger(params, served):
     srv, _, _, _ = served
     stats = srv.stats()
     ledger = srv._ledger
-    outside = srv._param_bytes - ledger._expert_bytes
-    assert ledger._expert_bytes == 4 * 4 * 3 * 64 * 32 * 4
+    outside = srv._param_bytes - ledger._experts.bytes
+    assert ledger._experts == (4 * 4 * 3 * 64 * 32 * 4, 4 * 4)
     low = stats["rounds"] * outside
     assert low < stats["decode_least_bytes"] < (
         stats["rounds"] * (srv._param_bytes + 4 * ledger.row_bytes))

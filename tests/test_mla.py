@@ -32,11 +32,12 @@ from tfde_tpu.inference import server
 from tfde_tpu.inference.decode import _decode_clone, init_cache
 from tfde_tpu.inference.server import ContinuousBatcher
 from tfde_tpu.inference.speculative import _set_index_counters
-from tfde_tpu.models import transformer
+from tfde_tpu.models import moe, transformer
+from tfde_tpu.models.cache_state import layout_of
 from tfde_tpu.models.gpt import GPT
 from tfde_tpu.models.moe import MoEMlp
-from tfde_tpu.observability.capacity import (CapacityLedger,
-                                             LatentCapacityLedger)
+from tfde_tpu.models.transformer import LatentAttention
+from tfde_tpu.observability.capacity import CapacityLedger, kv_slab_bytes
 from tfde_tpu.ops import mla as mla_lib
 from tfde_tpu.ops import rotary
 
@@ -570,7 +571,7 @@ def test_the_batchers_cache_is_latent_cells_and_nothing_per_head(served):
                 if str(getattr(p[-1], "key", p[-1])) == name] == [
             (4, 96, width)] * LAYERS
     ledger = srv._ledger
-    assert isinstance(ledger, LatentCapacityLedger)
+    assert ledger.kinds == {"latent"}
     # the program's own account: rows x cells x layers x a cell's bytes
     assert ledger.slab_bytes == 4 * 96 * LAYERS * 4 * SHAPE.cell
     assert ledger.cell_bytes == 4 * SHAPE.cell
@@ -598,12 +599,14 @@ def test_batcher_counts_the_cells_at_true_lengths_and_the_routing(served):
     assert stats["decode_least_bytes"] > 0
 
 
-def test_the_ledger_reads_the_layers_off_the_cache(params):
+def test_the_ledger_counts_the_layers_the_model_describes(params):
     model = latent_model()
     cache = init_cache(model, 4, 96)
-    ledger = CapacityLedger.from_cache(cache, 4, 96, model=model,
-                                       params=params)
-    assert isinstance(ledger, LatentCapacityLedger)
+    ledger = CapacityLedger(4, 96, kv_slab_bytes(cache),
+                            layout_of(model, 96).layers,
+                            moe.held_experts(params))
+    assert set(ledger.counters) == set(
+        ledger.HYBRID_KEYS + ledger.LATENT_KEYS)
     assert ledger.cells_per_row == LAYERS * 96
     ledger.note_commit(0, 10, decoding=False)
     ledger.note_commit(10, 12)
@@ -643,5 +646,5 @@ def test_the_prefix_cache_is_refused_over_a_feed_pad_leaf(params):
 
 
 def test_a_latent_cache_is_a_cell_per_position():
-    assert server._state_not_by_position(latent_model(), 96) is None
-    assert "cached_latent" in server._state_not_by_position.__doc__
+    assert layout_of(latent_model(), 96).not_by_position is None
+    assert "cached_latent" in LatentAttention.cache_state.__doc__

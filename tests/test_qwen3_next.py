@@ -34,13 +34,13 @@ from tfde_tpu.inference import server
 from tfde_tpu.inference.decode import init_cache
 from tfde_tpu.inference.server import (ContinuousBatcher,
                                        SpeculativeContinuousBatcher)
+from tfde_tpu.models import moe
+from tfde_tpu.models.cache_state import layout_of
 from tfde_tpu.models.gpt import GPT, gpt_tiny_test
 from tfde_tpu.models.moe import MoEMlp
 from tfde_tpu.models.transformer import MultiHeadAttention
 from tfde_tpu.observability import counters
-from tfde_tpu.observability.capacity import (CapacityLedger,
-                                             DeltaCapacityLedger,
-                                             HybridCapacityLedger)
+from tfde_tpu.observability.capacity import CapacityLedger, kv_slab_bytes
 from tfde_tpu.ops import gated_delta as gdn
 
 VOCAB, LAYERS, EXPERTS, HELD, PER_TOKEN = 96, 8, 16, (0, 8), 3
@@ -529,14 +529,16 @@ def test_the_batchers_cache_is_states_beside_cells(served):
     assert names.count("delta_state") == names.count("conv_tail") == 6
     assert names.count("cached_key") == names.count("cached_value") == 2
     ledger = srv._ledger
-    assert isinstance(ledger, DeltaCapacityLedger)
+    assert ledger.kinds == {"kv", "state"}
     # a row's state: six layers of [4, 8, 8] float32 and a tail of 3 x 64;
     # a position: two attention layers' K and V of 2 heads of 16
     state = 6 * (4 * 8 * 8 * 4 + 3 * 64 * 4)
     position = 2 * 2 * 2 * 16 * 4
     assert ledger.slab_bytes == 4 * (160 * position + state)
-    assert ledger.row_cells(0) == round(state / position)
-    assert ledger.row_cells(37) - ledger.row_cells(0) == 37
+    # a state is bytes and no cell; a cell is one attention layer's
+    assert ledger.row_cells(0) == 0 and ledger.row_cells(37) == 2 * 37
+    assert ledger.row_bytes == 160 * position + state
+    assert ledger.read_bytes([37]) == 37 * position + 2 * state
 
 
 def test_batcher_counts_what_a_known_schedule_makes(params):
@@ -550,8 +552,8 @@ def test_batcher_counts_what_a_known_schedule_makes(params):
         srv.submit(rng.integers(0, VOCAB, n).astype(np.int32), 5)
     assert len(srv.run()) == 2
     stats = srv.stats()
-    assert set(DeltaCapacityLedger.GDN_KEYS) <= set(stats)
-    assert set(HybridCapacityLedger.HYBRID_KEYS) <= set(stats)
+    assert set(CapacityLedger.GDN_KEYS) <= set(stats)
+    assert set(CapacityLedger.HYBRID_KEYS) <= set(stats)
     assert (stats["prefill_waves"], stats["scans"], stats["rounds"]) == (
         2, 1, 4)
     # six delta-rule layers: a chunk for the bucket of 64, two for 128
@@ -562,7 +564,7 @@ def test_batcher_counts_what_a_known_schedule_makes(params):
     assert stats["gdn_state_bytes"] == 4 * 2 * state
     assert stats["ssm_state_bytes_touched"] == 2 * stats["gdn_state_bytes"]
     assert stats["kv_cell_bytes"] == 4 * (20 + 70) * 2 * 2 * 2 * 16 * 4
-    assert stats["kv_cells_read"] == 4 * (20 + 70)
+    assert stats["kv_cells_read"] == 4 * (20 + 70) * 2   # attention layers
     fed = (20 + 4) + (70 + 4)
     assert stats["moe_pairs"] == LAYERS * PER_TOKEN * fed
     assert 0 < stats["moe_pairs_held"] < stats["moe_pairs"]
@@ -572,12 +574,13 @@ def test_batcher_counts_what_a_known_schedule_makes(params):
     assert stats["decode_least_bytes"] > 0
 
 
-def test_the_ledger_reads_the_layers_off_the_cache(params):
+def test_the_ledger_counts_the_layers_the_model_describes(params):
     model = delta_model()
     cache = init_cache(model, 4, 160)
-    ledger = CapacityLedger.from_cache(cache, 4, 160, model=model,
-                                       params=params)
-    assert type(ledger) is DeltaCapacityLedger
+    ledger = CapacityLedger(4, 160, kv_slab_bytes(cache),
+                            layout_of(model, 160).layers,
+                            moe.held_experts(params))
+    assert set(ledger.counters) == set(ledger.HYBRID_KEYS + ledger.GDN_KEYS)
     ledger.note_admission("cold", 128, 100)
     ledger.note_commit(0, 100, decoding=False)
     ledger.note_commit(100, 104)
@@ -587,7 +590,7 @@ def test_the_ledger_reads_the_layers_off_the_cache(params):
     assert counted["kv_pairs_prefilled"] == 2 * 5050
     assert counted["gdn_steps"] == 6 * 4 * 2
     assert counted["kv_cell_bytes"] == 4 * 134 * 512
-    assert counted["kv_cells_read"] == 4 * 134
+    assert counted["kv_cells_read"] == 4 * 134 * 2
 
 
 @pytest.mark.parametrize("kw,word", [
@@ -617,7 +620,6 @@ def test_speculation_and_the_primed_hand_off_are_refused(params):
 
 
 def test_the_refusal_is_asked_of_the_model_not_of_a_family():
-    assert server._state_not_by_position(gpt_tiny_test()) is None
-    assert "gated_delta" in server._state_not_by_position(delta_model())
-    assert "GatedDeltaMixer" in server._state_not_by_position(
-        delta_model(), 64)
+    assert layout_of(gpt_tiny_test()).not_by_position is None
+    assert "gated_delta" in layout_of(delta_model()).not_by_position
+    assert "GatedDeltaMixer" in layout_of(delta_model(), 64).not_by_position

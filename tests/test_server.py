@@ -959,12 +959,15 @@ def test_step_ledger(ledger_run, case):
     case(ledger_run)
 
 
-def test_decode_least_bytes_is_the_hand_count(lm):
+@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
+def test_decode_least_bytes_is_the_hand_count(lm, paged):
     """Two rows, one scan: depth x (the parameters + the committed cells
-    of both rows), every size from the toy model's shapes."""
+    of both rows), every size from the toy model's shapes. The pool holds
+    more cells than rows x max_len (a block past the end a row, and the
+    null block) and a cell costs what it costs in the slab."""
     model, params = lm
     srv = ContinuousBatcher(model, params, kv_quant="fp", batch_size=2,
-                            max_len=32, scan_depth=4)
+                            max_len=32, scan_depth=4, paged=paged)
     srv.submit(np.arange(1, 4), 3)
     srv.submit(np.arange(1, 6), 3)
     srv.run()

@@ -28,11 +28,10 @@ from tfde_tpu.inference import server
 from tfde_tpu.inference.decode import init_cache
 from tfde_tpu.inference.server import (ContinuousBatcher,
                                        SpeculativeContinuousBatcher)
-from tfde_tpu.models import transformer
+from tfde_tpu.models import moe, transformer
+from tfde_tpu.models.cache_state import layout_of
 from tfde_tpu.models.gpt import GPT, gpt_tiny_test
-from tfde_tpu.observability.capacity import (CapacityLedger,
-                                             HybridCapacityLedger,
-                                             RingCapacityLedger)
+from tfde_tpu.observability.capacity import CapacityLedger, kv_slab_bytes
 
 LAYOUT = (0, 1, 1, 1, 0, 1, 1, 1)
 WINDOW, EXPERTS, PER_TOKEN, VOCAB = 8, 8, 2, 96
@@ -362,9 +361,9 @@ def test_the_batchers_window_layers_hold_a_ring(served):
 def test_batcher_counts_both_kinds_of_cell_and_the_routing(served):
     srv, _, _ = served
     stats = srv.stats()
-    assert type(srv._ledger) is RingCapacityLedger
-    assert set(RingCapacityLedger.RING_KEYS) <= set(stats)
-    assert set(HybridCapacityLedger.HYBRID_KEYS) <= set(stats)
+    assert srv._ledger.kinds == {"kv", "ring"}
+    assert set(CapacityLedger.RING_KEYS) <= set(stats)
+    assert set(CapacityLedger.HYBRID_KEYS) <= set(stats)
     assert stats["kv_cells_read"] == (stats["kv_full_cells_read"]
                                       + stats["kv_window_cells_read"])
     # two global layers read every committed cell, six window layers at
@@ -381,15 +380,21 @@ def test_batcher_counts_both_kinds_of_cell_and_the_routing(served):
     assert stats["syncs"] == stats["prefill_waves"] + stats["scans"]
 
 
-def test_the_ledger_reads_each_layers_cells_off_the_cache(params):
+def ledger_of(model, params, cache, positions):
+    layout = layout_of(model, positions)
+    return CapacityLedger(
+        2, positions, kv_slab_bytes(cache), layout.layers,
+        moe.held_experts(params) if layout.uncapped_experts else None)
+
+
+def test_the_ledger_counts_each_layers_cells_as_the_layer_says(params):
     model = window_model()
     cache = init_cache(model, 2, 48, rolling=True)
-    ledger = CapacityLedger.from_cache(cache, 2, 48, model=model,
-                                       params=params)
-    assert type(ledger) is RingCapacityLedger
+    ledger = ledger_of(model, params, cache, 48)
+    assert ledger.kinds == {"kv", "ring"}
     cell = 2 * 2 * 16 * 4              # K and V of 2 heads of 16, float32
     assert ledger.cells_per_row == 2 * 48 + 6 * WINDOW
-    assert abs(ledger.cell_bytes - cell) < 1      # the pad counts beside
+    assert ledger.cell_bytes == cell
     assert ledger.row_cells(5) == 8 * 5
     assert ledger.read_cells(20) == 2 * 20 + 6 * WINDOW
     ledger.note_scan([5, 8, 20], 4)
@@ -402,11 +407,11 @@ def test_the_ledger_reads_each_layers_cells_off_the_cache(params):
     got = ledger.scan_least_bytes(outside + 8 * EXPERTS * expert, 7_000, 2,
                                   [40, 40, 9, 3, 100])
     assert got == 2 * (outside + 7_000) + 9 * expert
-    # without a ring the slab's ledgers are chosen as before
-    slab = init_cache(model, 2, 48)
-    assert type(CapacityLedger.from_cache(slab, 2, 48, model=model,
-                                          params=params)) \
-        is HybridCapacityLedger
+    # no window is left behind in 8 positions: slabs alone, and the
+    # experts' family of counters with no ring's beside it
+    slabs = ledger_of(model, params, init_cache(model, 2, 8), 8)
+    assert slabs.kinds == {"kv"}
+    assert set(slabs.counters) == set(CapacityLedger.HYBRID_KEYS)
 
 
 @pytest.mark.parametrize("kw,word", [
@@ -433,13 +438,13 @@ def test_speculation_and_the_primed_hand_off_are_refused(params):
     primed = server.PrimedRequest(np.arange(8, dtype=np.int32), 1, 4, {})
     with pytest.raises(NotImplementedError, match="submit_primed.*ring"):
         srv.submit_primed(primed)
-    assert "ring" in server._state_not_by_position(
-        gpt_tiny_test(sliding_window=8))
-    assert "ring" in server._state_not_by_position(
-        gpt_tiny_test(sliding_window=8), 9)
-    assert server._state_not_by_position(
-        gpt_tiny_test(sliding_window=8), 8) is None
-    assert server._state_not_by_position(gpt_tiny_test()) is None
+    assert "ring" in layout_of(
+        gpt_tiny_test(sliding_window=8)).not_by_position
+    assert "ring" in layout_of(
+        gpt_tiny_test(sliding_window=8), 9).not_by_position
+    assert layout_of(
+        gpt_tiny_test(sliding_window=8), 8).not_by_position is None
+    assert layout_of(gpt_tiny_test()).not_by_position is None
 
 
 @pytest.mark.parametrize("kw", [dict(), dict(paged=True)],
@@ -453,7 +458,8 @@ def test_a_window_no_row_outgrows_keeps_the_slab_and_its_features(
     srv = ContinuousBatcher(wide, params, batch_size=2, max_len=48,
                             scan_depth=4, prompt_buckets=(16, 32, 48), **kw)
     assert not srv._ring and not srv._decode_model.rolling_cache
-    assert type(srv._ledger) is not RingCapacityLedger
+    assert "ring" not in srv._ledger.kinds
+    assert not set(CapacityLedger.RING_KEYS) & set(srv.stats())
     prompts, budgets = rows_of(3, [5, 20]), (12, 6)
     rids = [srv.submit(p, b) for p, b in zip(prompts, budgets)]
     out = dict(srv.run())

@@ -84,6 +84,8 @@ from tfde_tpu.inference.prefix_cache import (
     resolve as _resolve_prefix,
 )
 from tfde_tpu.inference.speculative import _set_index_counters
+from tfde_tpu.models import moe as _moe
+from tfde_tpu.models.cache_state import layout_of as _layout_of
 from tfde_tpu.analysis import hlolint as _hlolint
 from tfde_tpu.observability import boot as _boot
 from tfde_tpu.observability import capacity as _capacity
@@ -128,52 +130,15 @@ _PHASE_KEYS = (
 )
 
 
-def _rings(model, max_len: Optional[int] = None) -> bool:
-    """Does any layer of `model` attend through a sliding window shorter
-    than `max_len` (None: than some length a batcher could be given)?
-    Under the batcher such a layer keeps a ring of `window` cells
-    (`_decode_clone(rolling=True)`). A window of `max_len` or more is
-    never left behind: every layer then keeps the slab, under its band
-    mask, and everything built on a cell per position."""
-    windows = getattr(model, "layer_windows", None)
-    windows = windows() if windows is not None else None
-    return windows is not None and any(
-        w is not None and (max_len is None or w < max_len) for w in windows)
-
-
-def _state_not_by_position(model,
-                           max_len: Optional[int] = None) -> Optional[str]:
-    """Why `model`'s cached state is not one cell per position under a
-    batcher of `max_len` positions a row, or None where it is (a K/V slab;
-    a latent layer's cell, `cached_latent`, with its own size): its fields."""
-    if _rings(model, max_len):
-        return ("its window layers keep a ring of `window` cells, slot = "
-                "position mod window, and a ring cannot give back an "
-                "overwritten cell (models/transformer.py "
-                "MultiHeadAttention._rolling_attention)")
-    if getattr(model, "attention", "full") == "eva":
-        return ("attention='eva' caches one window in progress and one "
-                "summary per chunk (models/transformer.py "
-                "MultiHeadAttention._eva_attention)")
-    if "mamba" in (getattr(model, "mixers", None) or ()):
-        return ("its 'mamba' layers cache a running state and a "
-                "convolution tail with no axis of positions "
-                "(models/transformer.py Mamba2Mixer)")
-    if "gated_delta" in (getattr(model, "mixers", None) or ()):
-        return ("its 'gated_delta' layers cache one matrix per value head "
-                "and a convolution tail with no axis of positions "
-                "(models/transformer.py GatedDeltaMixer)")
-    return None
-
-
 def _refuse_stateful(model, what: str,
                      max_len: Optional[int] = None) -> None:
     """Features that rewind, share or re-encode cached state BY POSITION
     have nothing to hold on to in a layout that is not a cell per
     position (a summary folds 16 positions into one cell, a window slot
     is reused every 2,048, a state-space layer keeps one state, a ring's
-    cell is overwritten one window on)."""
-    why = _state_not_by_position(model, max_len)
+    cell is overwritten one window on). The layers say which they are
+    (models/cache_state.py): the first that is not gives the reason."""
+    why = _layout_of(model, max_len).not_by_position
     if why is not None:
         raise NotImplementedError(
             f"{what} is not built for this model: {why}, not a cell per "
@@ -704,7 +669,7 @@ class _BatcherBase:
         # shares or re-encodes cells by position is refused for such a
         # model, `_refuse_stateful`); a window no row can outgrow leaves
         # the slab, its band mask and all of those as they were
-        self._ring = _rings(model, self._max_len)
+        self._ring = _layout_of(model, self._max_len).rings
         self._eos = eos_id
         self._pad = pad_id
         self._rng = rng if rng is not None else jax.random.key(0)
@@ -840,16 +805,22 @@ class _BatcherBase:
         its own)."""
         self._usage.arm(model_dir)
 
-    def _init_capacity(self, cache, cells_per_row: Optional[int] = None
-                       ) -> None:
+    def _init_capacity(self, cache, decode_model,
+                       cells_per_row: Optional[int] = None) -> None:
         """Build the KV occupancy ledger + headroom model from the
-        freshly-initialized dense slab (subclass constructors call this
-        once the cache exists). `cells_per_row` defaults to max_len;
-        the speculative batcher's slab carries draft slack beyond it."""
+        freshly-initialized dense slab and what `decode_model`, the clone
+        that made it, says its layers keep there (subclass constructors
+        call this once the cache exists). `cells_per_row` defaults to
+        max_len; the speculative batcher's slab carries draft slack
+        beyond it."""
         cells = int(cells_per_row if cells_per_row is not None
                     else self._max_len)
-        self._ledger = _capacity.CapacityLedger.from_cache(
-            cache, self._b, cells, model=self._model, params=self._params)
+        layout = _layout_of(decode_model, cells)
+        self._ledger = _capacity.CapacityLedger(
+            self._b, cells, _capacity.kv_slab_bytes(cache), layout.layers,
+            (_moe.held_experts(self._params) if layout.uncapped_experts
+             else None),
+            census=_capacity.kv_dtype_census(cache))
         self._cap_model = _capacity.CapacityModel(self._ledger)
 
     def kv_stats(self) -> dict:
@@ -1143,13 +1114,11 @@ class _BatcherBase:
             return program()
 
     def _kv_read_bytes(self, active: list) -> int:
-        """Bytes of the cells a decode tick of the `active` rows cannot
-        avoid reading (every committed cell of a K/V slab; the live
-        window and the visible summaries of attention='eva'), from
-        shapes."""
-        cells = sum(self._ledger.read_cells(n)
-                    for n in self._committed[active])
-        return int(round(cells * self._ledger.cell_bytes))
+        """Bytes of the cached state a decode tick of the `active` rows
+        cannot avoid reading (every committed cell of a K/V slab; the live
+        window and the visible summaries of attention='eva'; a state and
+        back), from shapes."""
+        return int(round(self._ledger.read_bytes(self._committed[active])))
 
     # -- hooks --------------------------------------------------------------
     def _validate_submit(self, prompt: np.ndarray,
@@ -1706,16 +1675,14 @@ class ContinuousBatcher(_BatcherBase):
             self._prefix = _resolve_prefix(prefix_cache)
         if self._prefix is not None:
             _refuse_stateful(model, "the prefix cache", self._max_len)
-            if any(str(getattr(p[-1], "key", p[-1])) == "feed_pad" for p, _
-                   in jax.tree_util.tree_leaves_with_path(self._cache)):
+            why = _layout_of(model, self._max_len).uncapped_experts
+            if why is not None:
                 raise NotImplementedError(
-                    "the prefix cache is not built for this model: a layer "
-                    "keeps a `feed_pad` leaf (experts routed without a "
-                    "capacity), which has no axis of positions to share")
+                    f"the prefix cache is not built for this model: {why}")
         # device-resident loop state (tok/idx/budget/done); rebuilt from
         # host bookkeeping whenever admission desyncs it
         self._dev = None
-        self._init_capacity(self._cache)
+        self._init_capacity(self._cache, self._decode_model)
 
     # -- public -------------------------------------------------------------
     def stats(self) -> dict:
@@ -1743,22 +1710,15 @@ class ContinuousBatcher(_BatcherBase):
         bucket; granted blocks under paging); `decode_least_bytes`, bytes:
         per scan, depth x (the parameters handed to the scan + the
         committed KV cells of its active rows), what the ticks cannot
-        avoid reading, computed from shapes and not measured. Over a
-        model with attention='eva' those cells are the live window
-        positions and the visible summaries, and the ledger of that
-        layout adds its own counters (`EvaCapacityLedger.EVA_KEYS`):
-        `eva_summaries_written` (one per completed chunk and row,
-        prefill and decode), `eva_window_turns` (windows handed over in
-        decode), and per scan, depth x the cells its active rows attend
-        to at its start, `eva_window_cells_read` and
-        `eva_summary_cells_read`. Over a model with window layers, whose
-        cache is a ring of `window` cells beside the other layers' slabs,
-        the ledger counts a layer's cell at a time
-        (`RingCapacityLedger.RING_KEYS`): per scan, depth x the committed
-        cells of its active rows summed over the layers without a window,
-        `kv_full_cells_read`, and min(committed, ring) summed over the
-        window layers, `kv_window_cells_read`; and `kv_window_wraps`, the
-        rows of a scan whose ring has turned."""
+        avoid reading, computed from shapes and not measured: what the
+        rows read is each layer's own formula (models/cache_state.py
+        `CacheState.read_bytes`: a slab's committed cells, a ring's
+        min(committed, ring), the live window and the visible summaries of
+        attention='eva', a state once and back). Each kind of layer that
+        is present adds its family of counters, all documented in ONE
+        place, `observability/capacity.py` `CapacityLedger` (`EVA_KEYS`,
+        `HYBRID_KEYS`, `RING_KEYS`, `LATENT_KEYS`, `GDN_KEYS`); a model of
+        slabs alone adds none."""
         g = max(self._generated, 1)
         return {
             "rounds": self._rounds,
@@ -2065,9 +2025,11 @@ class ContinuousBatcher(_BatcherBase):
         stats; nothing else should allocate from it."""
         return self._pool
 
-    def _init_capacity(self, cache, cells_per_row=None) -> None:
+    def _init_capacity(self, cache, decode_model, cells_per_row=None
+                       ) -> None:
         if not self._paged:
-            return super()._init_capacity(cache, cells_per_row)
+            return super()._init_capacity(cache, decode_model,
+                                          cells_per_row)
         cells = int(cells_per_row if cells_per_row is not None
                     else self._max_len)
         self._ledger = _capacity.PagedCapacityLedger(
@@ -2601,7 +2563,7 @@ class SpeculativeContinuousBatcher(_BatcherBase):
                                      self._cache_len)
         # the ledger tracks the TARGET slab (the draft cache is a cost
         # of speculation, not serving capacity)
-        self._init_capacity(self._tgt_cache,
+        self._init_capacity(self._tgt_cache, self._tgt,
                             cells_per_row=self._cache_len)
         self._round_tokens = 0   # tokens produced by speculative rounds
         self._draft_proposed = 0  # num_draft per active row per round

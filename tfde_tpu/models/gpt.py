@@ -20,6 +20,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from tfde_tpu.models.cache_state import CacheLayout
+from tfde_tpu.models.moe import FEED_PAD_UNSHARED
 from tfde_tpu.models.transformer import Encoder
 from tfde_tpu.parallel.axes import batch_axes, constrain
 
@@ -222,6 +224,91 @@ class GPT(nn.Module):
             if self.sliding_window_pattern == "all" or i % 2 == 0 else None
             for i in range(self.depth))
 
+    @nn.nowrap
+    def stack(self) -> Encoder:
+        """The stack of blocks at the fields this model hands it:
+        `__call__` runs it, `cache_layout` asks it what it keeps."""
+        return Encoder(
+            depth=self.depth,
+            num_heads=self.num_heads,
+            head_dim=self.head_dim or self.hidden_size // self.num_heads,
+            mlp_dim=self.mlp_dim,
+            dtype=self.dtype,
+            dropout_rate=self.dropout_rate,
+            attn_impl=self.attn_impl,
+            causal=True,
+            decode=self.decode,
+            rope=self.position == "rope",
+            rope_theta=self.rope_theta,
+            rope_scaling=(tuple(self.rope_scaling)
+                          if self.rope_scaling is not None else None),
+            rope_dim=self.rope_dim,
+            num_kv_heads=self.num_kv_heads,
+            fused_qkv=self.fused_qkv,
+            quant=self.quant,
+            windows=self.layer_windows(),
+            rope_layers=(tuple(bool(r) for r in self.rope_layers)
+                         if self.rope_layers is not None else None),
+            rolling_cache=self.rolling_cache,
+            paged_blocks=self.paged_blocks,
+            kv_block=self.kv_block,
+            kv_quant=self.kv_quant,
+            attn_scale=self.attn_scale,
+            attn_logit_cap=self.attn_logit_cap,
+            norm=self.norm,
+            norm_style=self.norm_style,
+            mlp_act=self.mlp_act,
+            use_bias=self.use_bias,
+            qkv_bias=self.qkv_bias,
+            qk_norm=self.qk_norm,
+            ln_eps=self.ln_eps,
+            remat=self.remat,
+            num_experts=self.num_experts,
+            moe_every=self.moe_every,
+            experts_per_token=self.experts_per_token,
+            moe_capacity_factor=self.moe_capacity_factor,
+            moe_normalize_topk=self.moe_normalize_topk,
+            moe_shared_expert_dim=self.moe_shared_expert_dim,
+            router_z_loss_weight=self.router_z_loss_weight,
+            attention=self.attention,
+            eva_window=self.eva_window,
+            eva_chunk=self.eva_chunk,
+            norm_unit_offset=self.norm_unit_offset,
+            moe_held_experts=(tuple(self.moe_held_experts)
+                              if self.moe_held_experts is not None else None),
+            moe_shared_expert_gated=self.moe_shared_expert_gated,
+            moe_router_pre_attention=self.moe_router_pre_attention,
+            mixers=tuple(self.mixers) if self.mixers is not None else None,
+            ssm=self.ssm,
+            mla=self.mla,
+            gdn=self.gdn,
+            attn_output_gate=self.attn_output_gate,
+            mlps=tuple(self.mlps) if self.mlps is not None else None,
+            moe_mlp_dim=self.moe_mlp_dim,
+            moe_score=self.moe_score,
+            moe_selection_bias=self.moe_selection_bias,
+            moe_routed_scale=self.moe_routed_scale,
+            residual_multiplier=self.residual_multiplier,
+            name="decoder",
+        )
+
+    @nn.nowrap
+    def cache_layout(self, max_len: Optional[int] = None) -> CacheLayout:
+        """What this model keeps in the decode cache under a batcher of
+        `max_len` positions a row (None: of some length): each block's
+        description of itself, from the module that keeps the state
+        (which is where a window shorter than `max_len` is found to keep a
+        ring). A decode clone says itself whether its windows roll; a
+        model not yet cloned is described as the batcher clones it
+        (`_decode_clone(rolling=True)`)."""
+        stack = self.stack().clone(
+            rolling_cache=self.rolling_cache or not self.decode)
+        return CacheLayout(
+            stack.cache_states(max_len),
+            uncapped_experts=(FEED_PAD_UNSHARED if self.num_experts > 0
+                              and self.moe_capacity_factor is None
+                              else None))
+
     @nn.compact
     def __call__(self, input_ids: jax.Array, train: bool = False,
                  segment_ids: Optional[jax.Array] = None,
@@ -326,69 +413,7 @@ class GPT(nn.Module):
         x = constrain(x, b, "seq")
         if self.dropout_rate > 0.0:
             x = nn.Dropout(self.dropout_rate, deterministic=not train)(x)
-        x = Encoder(
-            depth=self.depth,
-            num_heads=self.num_heads,
-            head_dim=self.head_dim or self.hidden_size // self.num_heads,
-            mlp_dim=self.mlp_dim,
-            dtype=self.dtype,
-            dropout_rate=self.dropout_rate,
-            attn_impl=self.attn_impl,
-            causal=True,
-            decode=self.decode,
-            rope=self.position == "rope",
-            rope_theta=self.rope_theta,
-            rope_scaling=(tuple(self.rope_scaling)
-                          if self.rope_scaling is not None else None),
-            rope_dim=self.rope_dim,
-            num_kv_heads=self.num_kv_heads,
-            fused_qkv=self.fused_qkv,
-            quant=self.quant,
-            windows=self.layer_windows(),
-            rope_layers=(tuple(bool(r) for r in self.rope_layers)
-                         if self.rope_layers is not None else None),
-            rolling_cache=self.rolling_cache,
-            paged_blocks=self.paged_blocks,
-            kv_block=self.kv_block,
-            kv_quant=self.kv_quant,
-            attn_scale=self.attn_scale,
-            attn_logit_cap=self.attn_logit_cap,
-            norm=self.norm,
-            norm_style=self.norm_style,
-            mlp_act=self.mlp_act,
-            use_bias=self.use_bias,
-            qkv_bias=self.qkv_bias,
-            qk_norm=self.qk_norm,
-            ln_eps=self.ln_eps,
-            remat=self.remat,
-            num_experts=self.num_experts,
-            moe_every=self.moe_every,
-            experts_per_token=self.experts_per_token,
-            moe_capacity_factor=self.moe_capacity_factor,
-            moe_normalize_topk=self.moe_normalize_topk,
-            moe_shared_expert_dim=self.moe_shared_expert_dim,
-            router_z_loss_weight=self.router_z_loss_weight,
-            attention=self.attention,
-            eva_window=self.eva_window,
-            eva_chunk=self.eva_chunk,
-            norm_unit_offset=self.norm_unit_offset,
-            moe_held_experts=(tuple(self.moe_held_experts)
-                              if self.moe_held_experts is not None else None),
-            moe_shared_expert_gated=self.moe_shared_expert_gated,
-            moe_router_pre_attention=self.moe_router_pre_attention,
-            mixers=tuple(self.mixers) if self.mixers is not None else None,
-            ssm=self.ssm,
-            mla=self.mla,
-            gdn=self.gdn,
-            attn_output_gate=self.attn_output_gate,
-            mlps=tuple(self.mlps) if self.mlps is not None else None,
-            moe_mlp_dim=self.moe_mlp_dim,
-            moe_score=self.moe_score,
-            moe_selection_bias=self.moe_selection_bias,
-            moe_routed_scale=self.moe_routed_scale,
-            residual_multiplier=self.residual_multiplier,
-            name="decoder",
-        )(x, mask=seg_mask, train=train)
+        x = self.stack()(x, mask=seg_mask, train=train)
         if last is not None:
             x = x[jnp.arange(x.shape[0]), last][:, None]
         if self.tie_embeddings:

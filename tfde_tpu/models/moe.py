@@ -72,7 +72,7 @@ exchange. Six counts are sown into "counters" (`_uncapped`).
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import flax.linen as nn
 import jax
@@ -80,6 +80,35 @@ import jax.numpy as jnp
 
 from tfde_tpu.ops import moe_gmm
 from tfde_tpu.parallel.axes import batch_axes, constrain
+
+
+#: what a model whose expert layers route without a capacity says of its
+#: cache (models/cache_state.py `CacheLayout.uncapped_experts`): `_uncapped` keeps
+#: `feed_pad` under decode=True, and the prefix cache's refusal prints this
+FEED_PAD_UNSHARED = (
+    "a layer keeps a `feed_pad` leaf (experts routed without a capacity), "
+    "which has no axis of positions to share")
+
+
+class HeldExperts(NamedTuple):
+    """The experts a program holds: their bytes, and how many (layer,
+    expert) slots those are."""
+
+    bytes: int
+    slots: int
+
+
+def held_experts(params) -> HeldExperts:
+    """Off the leaves `_expert_params` names: every `experts_*` leaf's
+    bytes (its first axis counts the experts held), one layer for each
+    `experts_fc1`."""
+    leaves = [(str(getattr(path[-1], "key", path[-1])), leaf) for path, leaf
+              in jax.tree_util.tree_leaves_with_path(params or {})]
+    experts = [leaf for name, leaf in leaves if name.startswith("experts_")]
+    layers = sum(name == "experts_fc1" for name, _ in leaves)
+    return HeldExperts(
+        sum(int(leaf.size) * leaf.dtype.itemsize for leaf in experts),
+        layers * (experts[0].shape[0] if experts else 0))
 
 
 def group_capacity(tokens_per_group: int, num_experts: int,

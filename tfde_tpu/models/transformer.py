@@ -25,6 +25,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from tfde_tpu.models.cache_state import CacheState
 from tfde_tpu.ops import attention as attn_lib
 from tfde_tpu.ops import eva_attention as eva_lib
 from tfde_tpu.ops import gated_delta as gdn_lib
@@ -179,6 +180,38 @@ class MultiHeadAttention(nn.Module):
     @property
     def kv_heads(self) -> int:
         return self.num_kv_heads or self.num_heads
+
+    def cache_state(self, max_len: Optional[int]) -> CacheState:
+        """What this layer keeps of one row under decode=True in a cache
+        of `max_len` positions (None: of a length not yet known), as the
+        `self.variable("cache", ...)` calls of `_eva_attention` and
+        `_decode_attention` make it: `cache_index` and `feed_pad` are
+        bookkeeping and no bytes of the state."""
+        cell = 2 * self.kv_heads * self.head_dim * jnp.dtype(
+            self.dtype).itemsize
+        if self.attention == "eva":
+            cells = (min(self.eva_window, max_len)
+                     + max(1, max_len // self.eva_chunk)) if max_len else 0
+            return CacheState(
+                "eva", cells, cell, window=self.eva_window,
+                chunk=self.eva_chunk, not_by_position=(
+                    "attention='eva' caches one window in progress and one "
+                    "summary per chunk (models/transformer.py "
+                    "MultiHeadAttention._eva_attention)"))
+        if (self.rolling_cache and self.window is not None
+                and (max_len is None or self.window < max_len)):
+            # a window of `max_len` or more is never left behind: such a
+            # ring has the slab's cells and its arithmetic
+            return CacheState(
+                "ring", self.window, cell, not_by_position=(
+                    "its window layers keep a ring of `window` cells, slot "
+                    "= position mod window, and a ring cannot give back an "
+                    "overwritten cell (models/transformer.py "
+                    "MultiHeadAttention._rolling_attention)"))
+        if self.kv_quant == "int8":
+            # an int8 payload and one float32 scale per K/V head beside it
+            cell = 2 * self.kv_heads * (self.head_dim + 4)
+        return CacheState("kv", max_len or 0, cell)
 
     @nn.compact
     def __call__(
@@ -840,6 +873,20 @@ class Mamba2Mixer(nn.Module):
     decode: bool = False
     ln_eps: float = 1e-6
 
+    def cache_state(self, max_len: Optional[int] = None) -> CacheState:
+        """`ssm_state` and `conv_tail` of one row, as `__call__` makes
+        them (`feed_pad` is bookkeeping), whatever the cache's length."""
+        shape = self.ssm
+        return CacheState(
+            "state", fixed_bytes=(
+                shape.heads * shape.head_dim * shape.state * 4
+                + (shape.conv - 1) * shape.conv_channels
+                * jnp.dtype(self.dtype).itemsize),
+            not_by_position=(
+                "its 'mamba' layers cache a running state and a "
+                "convolution tail with no axis of positions "
+                "(models/transformer.py Mamba2Mixer)"))
+
     @nn.compact
     def __call__(self, x: jax.Array, mask: Optional[jax.Array] = None,
                  train: bool = False) -> jax.Array:
@@ -935,6 +982,22 @@ class GatedDeltaMixer(nn.Module):
     dtype: jnp.dtype = jnp.bfloat16
     decode: bool = False
     ln_eps: float = 1e-6
+
+    def cache_state(self, max_len: Optional[int] = None) -> CacheState:
+        """`delta_state` and `conv_tail` of one row, as `__call__` makes
+        them (`feed_pad` is bookkeeping), whatever the cache's length;
+        `chunk`, the positions of one triangular system of the chunked
+        prefill."""
+        shape = self.gdn
+        return CacheState(
+            "state", chunk=shape.chunk, fixed_bytes=(
+                shape.value_heads * shape.key_dim * shape.value_dim * 4
+                + (shape.conv - 1) * shape.conv_channels
+                * jnp.dtype(self.dtype).itemsize),
+            not_by_position=(
+                "its 'gated_delta' layers cache one matrix per value head "
+                "and a convolution tail with no axis of positions "
+                "(models/transformer.py GatedDeltaMixer)"))
 
     @nn.compact
     def __call__(self, x: jax.Array, mask: Optional[jax.Array] = None,
@@ -1173,14 +1236,13 @@ class TransformerBlock(nn.Module):
     # (Granite's residual_multiplier); None adds them as they are
     residual_multiplier: Optional[float] = None
 
-    @nn.compact
-    def __call__(
-        self,
-        x: jax.Array,
-        mask: Optional[jax.Array] = None,
-        train: bool = False,
-    ) -> jax.Array:
-        ln = make_norm(self.norm, self.ln_eps, self.norm_unit_offset)
+    @nn.nowrap
+    def mixer_module(self) -> nn.Module:
+        """The module that mixes positions in this block, at this block's
+        fields: `__call__` runs it, `cache_state` asks it what it keeps.
+        Not wrapped, like `Encoder.block` and `GPT.stack`: the module made
+        is then the child of whoever is being applied, and of no one
+        where a model that is not bound is asked for its descriptions."""
         if self.mixer not in ("attention", "mamba", "latent", "gated_delta"):
             raise ValueError(
                 f"mixer must be 'attention', 'mamba', 'latent' or "
@@ -1196,61 +1258,76 @@ class TransformerBlock(nn.Module):
                     "latent attention caches one [c, k_r] cell per position "
                     "in a leaf of its own: the block pool, int8 cells, a "
                     "sliding window and int8 weights are not built for it")
-            attn = LatentAttention(
+            return LatentAttention(
                 num_heads=self.num_heads, shape=self.mla, dtype=self.dtype,
                 attn_impl=self.attn_impl, decode=self.decode,
                 rope_theta=self.rope_theta, rope_scaling=self.rope_scaling,
                 ln_eps=self.ln_eps, name="attn")
-        elif self.mixer == "mamba":
+        if self.mixer == "mamba":
             if self.ssm is None or self.norm_style != "pre":
                 raise ValueError(
                     "mixer='mamba' needs its widths (`ssm`) and the pre-norm "
                     "block")
-            attn = Mamba2Mixer(ssm=self.ssm, dtype=self.dtype,
+            return Mamba2Mixer(ssm=self.ssm, dtype=self.dtype,
                                decode=self.decode, ln_eps=self.ln_eps,
                                name="mamba")
-        elif self.mixer == "gated_delta":
+        if self.mixer == "gated_delta":
             if self.gdn is None or self.norm_style != "pre":
                 raise ValueError(
                     "mixer='gated_delta' needs its widths (`gdn`) and the "
                     "pre-norm block")
-            attn = GatedDeltaMixer(gdn=self.gdn, dtype=self.dtype,
+            return GatedDeltaMixer(gdn=self.gdn, dtype=self.dtype,
                                    decode=self.decode, ln_eps=self.ln_eps,
                                    name="delta")
-        else:
-            attn = MultiHeadAttention(
-                num_heads=self.num_heads,
-                head_dim=self.head_dim,
-                dtype=self.dtype,
-                dropout_rate=self.dropout_rate,
-                attn_impl=self.attn_impl,
-                causal=self.causal,
-                decode=self.decode,
-                rope=self.rope,
-                rope_theta=self.rope_theta,
-                rope_scaling=self.rope_scaling,
-                rope_dim=self.rope_dim,
-                num_kv_heads=self.num_kv_heads,
-                fused_qkv=self.fused_qkv,
-                quant=self.quant,
-                window=self.window,
-                rolling_cache=self.rolling_cache,
-                paged_blocks=self.paged_blocks,
-                kv_block=self.kv_block,
-                kv_quant=self.kv_quant,
-                attn_scale=self.attn_scale,
-                attn_logit_cap=self.attn_logit_cap,
-                use_bias=self.use_bias,
-                qkv_bias=self.qkv_bias,
-                qk_norm=self.qk_norm,
-                ln_eps=self.ln_eps,
-                norm_unit_offset=self.norm_unit_offset,
-                output_gate=self.attn_output_gate,
-                attention=self.attention,
-                eva_window=self.eva_window,
-                eva_chunk=self.eva_chunk,
-                name="attn",
-            )
+        return MultiHeadAttention(
+            num_heads=self.num_heads,
+            head_dim=self.head_dim,
+            dtype=self.dtype,
+            dropout_rate=self.dropout_rate,
+            attn_impl=self.attn_impl,
+            causal=self.causal,
+            decode=self.decode,
+            rope=self.rope,
+            rope_theta=self.rope_theta,
+            rope_scaling=self.rope_scaling,
+            rope_dim=self.rope_dim,
+            num_kv_heads=self.num_kv_heads,
+            fused_qkv=self.fused_qkv,
+            quant=self.quant,
+            window=self.window,
+            rolling_cache=self.rolling_cache,
+            paged_blocks=self.paged_blocks,
+            kv_block=self.kv_block,
+            kv_quant=self.kv_quant,
+            attn_scale=self.attn_scale,
+            attn_logit_cap=self.attn_logit_cap,
+            use_bias=self.use_bias,
+            qkv_bias=self.qkv_bias,
+            qk_norm=self.qk_norm,
+            ln_eps=self.ln_eps,
+            norm_unit_offset=self.norm_unit_offset,
+            output_gate=self.attn_output_gate,
+            attention=self.attention,
+            eva_window=self.eva_window,
+            eva_chunk=self.eva_chunk,
+            name="attn",
+        )
+
+    @nn.nowrap
+    def cache_state(self, max_len: Optional[int]) -> CacheState:
+        """What this block keeps of one row in a decode cache of `max_len`
+        positions: its mixer's own description."""
+        return self.mixer_module().cache_state(max_len)
+
+    @nn.compact
+    def __call__(
+        self,
+        x: jax.Array,
+        mask: Optional[jax.Array] = None,
+        train: bool = False,
+    ) -> jax.Array:
+        ln = make_norm(self.norm, self.ln_eps, self.norm_unit_offset)
+        attn = self.mixer_module()
         if self.num_experts > 0:
             if (self.mlp_act, self.use_bias) not in (
                 ("gelu", True), ("swiglu", False), ("reglu", False),
@@ -1442,6 +1519,90 @@ class Encoder(nn.Module):
     attn_output_gate: bool = False  # MultiHeadAttention.output_gate
     residual_multiplier: Optional[float] = None  # TransformerBlock
 
+    @nn.nowrap
+    def block(self, i: int) -> TransformerBlock:
+        """Block `i` of the stack at the fields this stack hands it:
+        `__call__` runs it, `cache_states` asks it what it keeps."""
+        for name in ("mixers", "windows", "rope_layers", "mlps"):
+            per_block = getattr(self, name)
+            if per_block is not None and len(per_block) != self.depth:
+                raise ValueError(
+                    f"{name} names {len(per_block)} blocks, depth is "
+                    f"{self.depth}")
+        if self.mlps is not None and self.mlps[i] not in (
+                "dense", "experts"):
+            raise ValueError(
+                f"mlps[{i}] must be 'dense' or 'experts', got "
+                f"{self.mlps[i]!r}")
+        is_moe = self.num_experts > 0 and (
+            i % self.moe_every == self.moe_every - 1
+            if self.mlps is None else self.mlps[i] == "experts")
+        return TransformerBlock(
+            num_heads=self.num_heads,
+            head_dim=self.head_dim,
+            mlp_dim=(self.moe_mlp_dim if is_moe and self.moe_mlp_dim
+                     else self.mlp_dim),
+            dtype=self.dtype,
+            dropout_rate=self.dropout_rate,
+            attn_impl=self.attn_impl,
+            causal=self.causal,
+            decode=self.decode,
+            rope=self.rope and (self.rope_layers is None
+                                or bool(self.rope_layers[i])),
+            rope_theta=self.rope_theta,
+            rope_scaling=self.rope_scaling,
+            rope_dim=self.rope_dim,
+            num_kv_heads=self.num_kv_heads,
+            fused_qkv=self.fused_qkv,
+            quant=self.quant,
+            window=self.windows[i] if self.windows is not None else None,
+            rolling_cache=self.rolling_cache,
+            paged_blocks=self.paged_blocks,
+            kv_block=self.kv_block,
+            kv_quant=self.kv_quant,
+            attn_scale=self.attn_scale,
+            attn_logit_cap=self.attn_logit_cap,
+            norm_style=self.norm_style,
+            norm=self.norm,
+            mlp_act=self.mlp_act,
+            use_bias=self.use_bias,
+            qkv_bias=self.qkv_bias,
+            qk_norm=self.qk_norm,
+            ln_eps=self.ln_eps,
+            num_experts=self.num_experts if is_moe else 0,
+            experts_per_token=self.experts_per_token,
+            moe_capacity_factor=self.moe_capacity_factor,
+            moe_normalize_topk=self.moe_normalize_topk,
+            moe_shared_expert_dim=self.moe_shared_expert_dim,
+            router_z_loss_weight=self.router_z_loss_weight,
+            moe_held_experts=self.moe_held_experts,
+            moe_shared_expert_gated=self.moe_shared_expert_gated,
+            moe_router_pre_attention=(self.moe_router_pre_attention
+                                      and is_moe),
+            attention=self.attention,
+            eva_window=self.eva_window,
+            eva_chunk=self.eva_chunk,
+            norm_unit_offset=self.norm_unit_offset,
+            mixer=(self.mixers[i] if self.mixers is not None
+                   else "attention"),
+            ssm=self.ssm,
+            mla=self.mla,
+            gdn=self.gdn,
+            attn_output_gate=self.attn_output_gate,
+            moe_score=self.moe_score,
+            moe_selection_bias=self.moe_selection_bias,
+            moe_routed_scale=self.moe_routed_scale,
+            residual_multiplier=self.residual_multiplier,
+            name=f"block_{i}",
+        )
+
+    @nn.nowrap
+    def cache_states(self, max_len: Optional[int]) -> tuple:
+        """What a row keeps in a decode cache of `max_len` positions: one
+        description a block, in the tree's order."""
+        return tuple(self.block(i).cache_state(max_len)
+                     for i in range(self.depth))
+
     @nn.compact
     def __call__(
         self,
@@ -1449,13 +1610,6 @@ class Encoder(nn.Module):
         mask: Optional[jax.Array] = None,
         train: bool = False,
     ) -> jax.Array:
-        for name in ("mixers", "windows", "rope_layers", "mlps"):
-            per_block = getattr(self, name)
-            if per_block is not None and len(per_block) != self.depth:
-                raise ValueError(
-                    f"{name} names {len(per_block)} blocks, depth is "
-                    f"{self.depth}")
-
         def body(mdl: TransformerBlock, h: jax.Array) -> jax.Array:
             # mask/train close over: constants to jax.checkpoint (no grads
             # flow to them — mask is boolean, train is a Python bool).
@@ -1471,73 +1625,7 @@ class Encoder(nn.Module):
                 )
             body = nn.remat(body, policy=policy)
         for i in range(self.depth):
-            if self.mlps is not None and self.mlps[i] not in (
-                    "dense", "experts"):
-                raise ValueError(
-                    f"mlps[{i}] must be 'dense' or 'experts', got "
-                    f"{self.mlps[i]!r}")
-            is_moe = self.num_experts > 0 and (
-                i % self.moe_every == self.moe_every - 1
-                if self.mlps is None else self.mlps[i] == "experts")
-            block = TransformerBlock(
-                num_heads=self.num_heads,
-                head_dim=self.head_dim,
-                mlp_dim=(self.moe_mlp_dim if is_moe and self.moe_mlp_dim
-                         else self.mlp_dim),
-                dtype=self.dtype,
-                dropout_rate=self.dropout_rate,
-                attn_impl=self.attn_impl,
-                causal=self.causal,
-                decode=self.decode,
-                rope=self.rope and (self.rope_layers is None
-                                    or bool(self.rope_layers[i])),
-                rope_theta=self.rope_theta,
-                rope_scaling=self.rope_scaling,
-                rope_dim=self.rope_dim,
-                num_kv_heads=self.num_kv_heads,
-                fused_qkv=self.fused_qkv,
-                quant=self.quant,
-                window=self.windows[i] if self.windows is not None else None,
-                rolling_cache=self.rolling_cache,
-                paged_blocks=self.paged_blocks,
-                kv_block=self.kv_block,
-                kv_quant=self.kv_quant,
-                attn_scale=self.attn_scale,
-                attn_logit_cap=self.attn_logit_cap,
-                norm_style=self.norm_style,
-                norm=self.norm,
-                mlp_act=self.mlp_act,
-                use_bias=self.use_bias,
-                qkv_bias=self.qkv_bias,
-                qk_norm=self.qk_norm,
-                ln_eps=self.ln_eps,
-                num_experts=self.num_experts if is_moe else 0,
-                experts_per_token=self.experts_per_token,
-                moe_capacity_factor=self.moe_capacity_factor,
-                moe_normalize_topk=self.moe_normalize_topk,
-                moe_shared_expert_dim=self.moe_shared_expert_dim,
-                router_z_loss_weight=self.router_z_loss_weight,
-                moe_held_experts=self.moe_held_experts,
-                moe_shared_expert_gated=self.moe_shared_expert_gated,
-                moe_router_pre_attention=(self.moe_router_pre_attention
-                                          and is_moe),
-                attention=self.attention,
-                eva_window=self.eva_window,
-                eva_chunk=self.eva_chunk,
-                norm_unit_offset=self.norm_unit_offset,
-                mixer=(self.mixers[i] if self.mixers is not None
-                       else "attention"),
-                ssm=self.ssm,
-                mla=self.mla,
-                gdn=self.gdn,
-                attn_output_gate=self.attn_output_gate,
-                moe_score=self.moe_score,
-                moe_selection_bias=self.moe_selection_bias,
-                moe_routed_scale=self.moe_routed_scale,
-                residual_multiplier=self.residual_multiplier,
-                name=f"block_{i}",
-            )
-            x = body(block, x)
+            x = body(self.block(i), x)
         if self.norm_style == "post":
             return x  # post-LN blocks already end normalized
         return make_norm(self.norm, self.ln_eps, self.norm_unit_offset)(
@@ -1587,6 +1675,13 @@ class LatentAttention(nn.Module):
     rope_theta: float = 10_000.0
     rope_scaling: Optional[tuple] = None
     ln_eps: float = 1e-6
+
+    def cache_state(self, max_len: Optional[int]) -> CacheState:
+        """`cached_latent` and `cached_rope_key` of one row: a cell per
+        position like a K/V slab's, `latent + rope` values wide, so
+        nothing that works by position is refused on its account."""
+        return CacheState("latent", max_len or 0, self.shape.cell
+                          * jnp.dtype(self.dtype).itemsize)
 
     @nn.compact
     def __call__(self, x: jax.Array, mask: Optional[jax.Array] = None,

@@ -5,8 +5,11 @@ eliminating pad-ladder waste — but nothing measured that waste, so the
 win could be neither sized in advance nor proven after. This module is
 the capacity half of the observability stack, three legs:
 
-- **CapacityLedger** — the occupancy picture, one subclass per cache
-  layout. The dense base reports the per-row slab: the batcher feeds
+- **CapacityLedger** — the occupancy picture. The dense ledger reports
+  the per-row slab, summed from the layers' own descriptions of what
+  they cache (models/cache_state.py, handed in; this module imports
+  nothing of models/ or inference/ and asks no leaf its name beyond what
+  `kv_slab_bytes` / `kv_dtype_census` leave out): the batcher feeds
   committed cells (the true per-row index) per decode round and
   pad-ladder allocation per admission wave; the ledger publishes the
   ``kv/{allocated_bytes,used_bytes,waste_frac,rows_active,rows_free}``
@@ -41,6 +44,7 @@ it (the PR 14 guarded-attrs rule).
 
 from __future__ import annotations
 
+import collections
 import json
 import os
 import threading
@@ -82,31 +86,6 @@ def kv_slab_bytes(cache) -> int:
     return total
 
 
-def _kv_layer_cells(cache) -> list:
-    """Cells a row holds in each layer that caches keys by position, in
-    the tree's order: the second axis of every `cached_key` leaf."""
-    import jax
-
-    return [int(leaf.shape[1]) for path, leaf in
-            jax.tree_util.tree_leaves_with_path(cache)
-            if str(getattr(path[-1], "key", path[-1])) == "cached_key"]
-
-
-def _leaves_named(cache, name: str) -> int:
-    """How many leaves of a cache tree are called `name`: one a layer that
-    keeps such a leaf."""
-    import jax
-
-    return sum(1 for path, _ in jax.tree_util.tree_leaves_with_path(cache)
-               if str(getattr(path[-1], "key", path[-1])) == name)
-
-
-def _latent_layers(cache) -> int:
-    """Layers of a cache that keep one latent cell per position (models/
-    transformer.py `LatentAttention`): its `cached_latent` leaves."""
-    return _leaves_named(cache, "cached_latent")
-
-
 def kv_dtype_census(cache) -> dict:
     """Dtype split of a KV cache tree (index leaves and block tables
     excluded): payload vs scale-sidecar bytes, the payload leaf dtype,
@@ -142,35 +121,165 @@ def kv_dtype_census(cache) -> dict:
     }
 
 
-class CapacityLedger:
-    """Dense-slab KV occupancy and pad-ladder waste accounting.
+class _Slabs:
+    """The description a ledger works from where every layer keeps a slab
+    of one cell per position, and where it was given none (the pool's
+    ledger; a model that describes nothing): a position of all the layers
+    is ONE cell, `cell_bytes` wide, the unit of `/load`'s headroom_tokens
+    and of the block pool. It has the arithmetic of the 'kv' descriptions
+    it stands for (models/cache_state.py `CacheState`)."""
 
-    One ledger per batcher cache. `observe` is fed the host-side
-    committed counts every decode round / stats publish;
-    `note_admission` is fed every admitted request's (bucket, true
-    prompt length) at wave time. Listed in tools/tfdelint.py
-    LOCKED_CLASSES: all shared state under `_lock`.
+    kind = "kv"
+
+    def __init__(self, cells: int, cell_bytes: float):
+        self.cells, self.cell_bytes = cells, cell_bytes
+
+    def held_cells(self, n: int) -> int:
+        return n
+
+    def held_bytes(self, n: int) -> float:
+        return n * self.cell_bytes
+
+    read_cells, read_bytes = held_cells, held_bytes
+
+    @property
+    def row_bytes(self) -> float:
+        return self.cells * self.cell_bytes
+
+
+class CapacityLedger:
+    """Dense-cache occupancy, pad-ladder waste and the counters of what
+    the layers keep, from the layers' own descriptions.
+
+    One ledger per batcher cache. `states` is one description a layer
+    (models/cache_state.py `CacheState`, duck-typed: `kind`, `cells`,
+    `window`, `chunk`, `row_bytes`, and of a row at `n` committed tokens
+    `held_cells`, `read_cells`, `held_bytes`, `read_bytes`, which the
+    ledger sums and never works out again), `experts` the held experts'
+    (`bytes`, `slots`) of a
+    model that routes without a capacity, else None. What is summed across
+    kinds is bytes; what counts cells counts (layer, position) cells, but
+    for a model of slabs alone (`_Slabs`). `observe` is fed the host-side
+    committed counts every decode round / stats publish; `note_admission`
+    every admitted request's (bucket, true prompt length) at wave time;
+    `note_commit` / `note_scan` / `note_routed` what the rows committed,
+    what a scan starts over and what the expert layers counted. Listed in
+    tools/tfdelint.py LOCKED_CLASSES: all shared state under `_lock`.
+
+    `counters`, which the batcher hands on in `stats()`: a family of keys
+    for each kind of layer that is present, every number from the rows'
+    TRUE lengths, never `max_len`. A model of slabs alone has none.
+
+    EVA_KEYS ('eva'; a count of the row's positions, not of its layers'):
+    chunk summaries written (prefill and decode, one per chunk and row),
+    windows handed over in decode (a prefill keeps the window its true
+    length ends in and hands none over), and per scan, depth x what its
+    active rows attend to at the scan's start: live window positions and
+    visible summaries.
+
+    HYBRID_KEYS (a ring, a latent layer, a state or `experts`; one family,
+    zeros included: the readers tell a program by the keys it has): per
+    scan, depth x (twice the active rows' state bytes, read and written;
+    the (layer, position) cells they hold), and what the expert layers
+    counted of real tokens in prefill waves and scans alike, summed over
+    layers and ticks: pairs routed, pairs whose expert is held, held
+    experts with at least one pair, the busiest held expert's pairs; and,
+    of every token they were handed, the rows of the hidden width they
+    copied into sorted order and fetched back from it, and their passes
+    over the held experts' weights (a call's blocks: one a layer for a
+    tick, more for a wave longer than `moe.token_block` gives a block).
+
+    RING_KEYS ('ring'): per scan, depth x the cells its active rows hold
+    at its start, summed over the slab layers (`kv_full_cells_read`) and
+    over the rings (`kv_window_cells_read`: min(n, ring) a layer), and the
+    rows of a scan whose next write lands on a cell one window back: whose
+    shortest ring has turned (`kv_window_wraps`).
+
+    LATENT_KEYS ('latent'): cells written by prefills and decode ticks
+    (`latent_cells_committed`), per scan depth x the committed cells of
+    its active rows (`latent_cells_read`), and the (query, cell) pairs the
+    prefills attended causally, n (n + 1) / 2 a layer for a prompt of n
+    (`latent_pairs_prefilled`).
+
+    GDN_KEYS (a 'state' with a `chunk`, the delta rule's). Summed over a
+    scan's ticks, what its active rows HOLD: their state
+    (`gdn_state_bytes`: depth x rows x a row's states and tails) and their
+    committed cells (`kv_cell_bytes`), so that the one over the sum of
+    both is the state's share of the live cache over the window. What the
+    two forms of the rule worked: `gdn_steps`, a scan's depth x its active
+    rows x the delta-rule layers (row-steps of the one-step form);
+    `gdn_chunks`, per admitted request its bucket's chunks x those layers
+    (systems solved by the chunked form; a wave's ladder padding repeats a
+    row and is not counted). And the pairs the prefills attended causally
+    in the slab layers, as the latent family counts them
+    (`kv_pairs_prefilled`).
     """
 
-    def __init__(self, batch_size: int, cells_per_row: int,
-                 slab_bytes: int,
+    EVA_KEYS = ("eva_summaries_written", "eva_window_turns",
+                "eva_window_cells_read", "eva_summary_cells_read")
+    HYBRID_KEYS = ("ssm_state_bytes_touched", "kv_cells_read", "moe_pairs",
+                   "moe_pairs_held", "moe_experts_touched",
+                   "moe_pairs_busiest", "moe_rows_moved",
+                   "moe_weight_passes")
+    RING_KEYS = ("kv_full_cells_read", "kv_window_cells_read",
+                 "kv_window_wraps")
+    LATENT_KEYS = ("latent_cells_committed", "latent_cells_read",
+                   "latent_pairs_prefilled")
+    GDN_KEYS = ("gdn_state_bytes", "kv_cell_bytes", "gdn_steps",
+                "gdn_chunks", "kv_pairs_prefilled")
+
+    def __init__(self, batch_size: int, positions: int, slab_bytes: int,
+                 states=None, experts=None,
                  registry: Optional[metrics.Registry] = None,
                  census: Optional[dict] = None):
-        if batch_size < 1 or cells_per_row < 1:
+        if batch_size < 1 or positions < 1:
             raise ValueError(
-                f"need batch_size/cells_per_row >= 1, got "
-                f"{batch_size}/{cells_per_row}"
+                f"need batch_size/positions >= 1, got "
+                f"{batch_size}/{positions}"
             )
         self._lock = threading.Lock()
         self._b = int(batch_size)
-        self._cells = int(cells_per_row)
+        self._positions = int(positions)
         self._slab_bytes = int(slab_bytes)
         #: dtype split of the slab (kv_dtype_census) — prices the
         #: quantized-vs-fp delta; empty when the builder predates it
         self._census = dict(census or {})
-        #: measured per-cell cost: the slab's own bytes over its cells,
-        #: so used_bytes sums exactly to the slab when every row is full
-        self._cell_bytes = self._slab_bytes / float(self._b * self._cells)
+        states = tuple(states or ())
+        if all(s.kind == "kv" for s in states):
+            # measured per-cell cost: the slab's own bytes over its cells,
+            # so used_bytes sums exactly to the slab when every row is full
+            states = (_Slabs(self._positions, self._slab_bytes
+                             / float(self._b * self._positions)),)
+        #: (description, how many layers gave it): a row's account is
+        #: summed a distinct description at a time, not a layer at a time
+        self._states = tuple(collections.Counter(states).items())
+        #: layers of each kind; the 'eva' layers' one window and chunk;
+        #: the shortest ring
+        self._layers = collections.Counter(s.kind for s in states)
+        self._eva = next((s for s in states if s.kind == "eva"), None)
+        self._ring = min((s.cells for s in states if s.kind == "ring"),
+                         default=None)
+        #: a state-only model's one cell of a row is its state
+        self._cells = sum(k * s.cells for s, k in self._states) or 1
+        self._row_bytes = sum(k * s.row_bytes for s, k in self._states)
+        self._cell_bytes = self._row_bytes / self._cells
+        self._experts = experts
+        #: bytes of one expert of one layer
+        self._slot_bytes = (experts.bytes / experts.slots
+                            if experts is not None and experts.slots
+                            else 0.0)
+        self._delta_layers = sum(k for s, k in self._states
+                                 if s.kind == "state" and s.chunk)
+        kinds = self._layers
+        keys = self.EVA_KEYS if kinds["eva"] else ()
+        if (kinds["ring"] or kinds["latent"] or kinds["state"]
+                or experts is not None):
+            keys += self.HYBRID_KEYS
+        keys += self.RING_KEYS if kinds["ring"] else ()
+        keys += self.LATENT_KEYS if kinds["latent"] else ()
+        keys += self.GDN_KEYS if self._delta_layers else ()
+        self._keys = keys
+        self._counters = dict.fromkeys(keys, 0)
         self._reg = registry or metrics.default_registry()
         self._used_cells = 0
         self._rows_active = 0
@@ -179,54 +288,28 @@ class CapacityLedger:
         self._bucket_alloc: Dict[int, int] = {}
         self._bucket_waste: Dict[int, int] = {}
 
-    @classmethod
-    def from_cache(cls, cache, batch_size: int, cells_per_row: int,
-                   registry: Optional[metrics.Registry] = None,
-                   model=None, params=None) -> "CapacityLedger":
-        """Build a ledger from a freshly-initialized dense slab. The
-        served `model`, where given, says which layout the slab has:
-        attention='eva' gets the ledger of windows and summaries, a cache
-        in which some layer holds fewer cells a row than `cells_per_row`
-        (a window layer's ring) the ledger that counts a layer's cells at
-        a time, a cache of latent cells (`cached_latent` leaves beside
-        their `cached_rope_key`: a cell per position like a K/V slab's,
-        `latent + rope` values wide with no head axis) the ledger that
-        counts them by layer, a model
-        with state-space layers (whose rows cost the same whatever their
-        length) or with experts routed without a capacity the hybrid
-        one, which reads the experts' bytes off `params`."""
-        if getattr(model, "attention", "full") == "eva":
-            return EvaCapacityLedger.of_model(cache, batch_size, model,
-                                              registry=registry)
-        if any(n < cells_per_row for n in _kv_layer_cells(cache)):
-            # some layer keeps a ring shorter than the row: the cache's
-            # own leaves say which, and how long
-            return RingCapacityLedger.of_model(
-                cache, batch_size, cells_per_row, params, registry=registry)
-        if _latent_layers(cache):
-            return LatentCapacityLedger.of_model(
-                cache, batch_size, cells_per_row, params, registry=registry)
-        if "gated_delta" in (getattr(model, "mixers", None) or ()):
-            return DeltaCapacityLedger.of_model(
-                cache, batch_size, cells_per_row, params,
-                chunk=model.gdn.chunk, registry=registry)
-        if "mamba" in (getattr(model, "mixers", None) or ()) or (
-                getattr(model, "num_experts", 0)
-                and getattr(model, "moe_capacity_factor", 1.0) is None):
-            return HybridCapacityLedger.of_model(
-                cache, batch_size, cells_per_row, params, registry=registry)
-        return cls(batch_size, cells_per_row, kv_slab_bytes(cache),
-                   registry=registry, census=kv_dtype_census(cache))
-
     # -- read surface --------------------------------------------------------
     @property
     def cell_bytes(self) -> float:
+        """A row's bytes, a state's among them, over its cells: one
+        cell's where all are alike."""
         return self._cell_bytes
 
     @property
     def row_bytes(self) -> float:
         """Per-row slab cost — the headroom model's admission unit."""
-        return self._cell_bytes * self._cells
+        return self._row_bytes
+
+    @property
+    def positions(self) -> int:
+        """Tokens a row was allocated for: what the headroom model counts
+        in, whatever the layers keep of a token."""
+        return self._positions
+
+    @property
+    def token_bytes(self) -> float:
+        """The slab's bytes over the positions it was allocated for."""
+        return self._slab_bytes / float(self._b * self._positions)
 
     @property
     def slab_bytes(self) -> int:
@@ -237,48 +320,121 @@ class CapacityLedger:
         return self._cells
 
     @property
+    def kinds(self) -> frozenset:
+        """The kinds of layer the ledger was built from."""
+        return frozenset(self._layers)
+
+    @property
     def census(self) -> dict:
         """The slab/pool dtype split (kv_dtype_census); {} when unknown."""
         return dict(self._census)
 
-    # -- what a row of `n` committed tokens holds and reads ------------------
+    # -- what rows of `n` committed tokens hold and read ----------------------
+    def _sum(self, what: str, committed, *kinds: str) -> float:
+        """A description's formula `what` of a row (`held_cells`,
+        `read_cells`, `held_bytes`, `read_bytes`), summed over rows at
+        these `committed` counts and over the layers: of these `kinds`,
+        where any is named."""
+        counts = [int(n) for n in committed]
+        return sum(k * sum(getattr(s, what)(n) for n in counts)
+                   for s, k in self._states if not kinds or s.kind in kinds)
+
     def row_cells(self, n: int) -> int:
-        """Cells of a row's slab that hold live state once it has
-        committed `n` tokens: one per token here."""
-        return int(n)
+        """Cells of a row that hold live state once it has committed `n`
+        tokens."""
+        return self._sum("held_cells", [n])
 
     def read_cells(self, n: int) -> int:
-        """Cells one decode tick of such a row cannot avoid reading:
-        every committed one here."""
-        return int(n)
+        """Cells one decode tick of such a row cannot avoid reading."""
+        return self._sum("read_cells", [n])
 
-    # -- what the layout adds to the batcher's account ------------------------
+    def read_bytes(self, committed) -> float:
+        """Bytes one decode tick of rows at these `committed` counts cannot
+        avoid reading: the cells each kind's formula gives, and every
+        state once and back."""
+        return self._sum("read_bytes", committed)
+
+    # -- what the layers add to the batcher's account --------------------------
     @property
     def counters(self) -> dict:
-        """Counters of this layout's own events, which the batcher hands
-        on in `stats()`: a slab of one cell per position has none."""
-        return {}
+        with self._lock:
+            return dict(self._counters)
+
+    def _count(self, **amounts) -> None:
+        """Add to the counters of the families this ledger keeps."""
+        with self._lock:
+            for key, n in amounts.items():
+                if key in self._counters:
+                    self._counters[key] += int(n)
 
     def note_commit(self, before: int, after: int,
                     decoding: bool = True) -> None:
         """A row went from `before` to `after` committed tokens, in a
         decode scan or (`decoding` false) by its prefill."""
+        if not self._keys:
+            return
+        before, after = int(before), int(after)
+        layers, eva = self._layers, self._eva
+        pairs = (0 if decoding
+                 else (after * (after + 1) - before * (before + 1)) // 2)
+        self._count(
+            eva_summaries_written=(
+                after // eva.chunk - before // eva.chunk if eva else 0),
+            eva_window_turns=(
+                after // eva.window - before // eva.window
+                if eva and decoding else 0),
+            latent_cells_committed=layers["latent"] * (after - before),
+            latent_pairs_prefilled=layers["latent"] * pairs,
+            kv_pairs_prefilled=layers["kv"] * pairs)
 
     def note_scan(self, committed, depth: int) -> None:
         """A decode scan of `depth` ticks starts over active rows at
         these `committed` counts."""
+        if not self._keys:
+            return
+        committed = [int(n) for n in committed]
+        rows = len(committed)
+        # what the rows hold at the scan's start, a kind at a time
+        cells, held = (collections.Counter({
+            kind: self._sum(what, committed, kind) for kind in self._layers})
+            for what in ("held_cells", "held_bytes"))
+        live, visible = (map(sum, zip(*(self._eva.attended(n)
+                                        for n in committed)))
+                         if self._eva and rows else (0, 0))
+        self._count(
+            eva_window_cells_read=depth * live,
+            eva_summary_cells_read=depth * visible,
+            ssm_state_bytes_touched=depth * self._sum(
+                "read_bytes", committed, "state"),
+            kv_cells_read=depth * sum(cells.values()),
+            kv_full_cells_read=depth * cells["kv"],
+            kv_window_cells_read=depth * cells["ring"],
+            kv_window_wraps=sum(n >= self._ring for n in committed
+                                ) if self._ring else 0,
+            latent_cells_read=depth * cells["latent"],
+            gdn_state_bytes=depth * held["state"],
+            kv_cell_bytes=depth * (sum(held.values()) - held["state"]),
+            gdn_steps=depth * rows * self._delta_layers)
 
     def note_routed(self, routed) -> None:
         """What the expert layers of one program counted on the device
-        ([pairs, pairs held, experts touched, busiest expert's pairs],
-        summed over layers and ticks): nothing to a layout without
-        experts."""
+        ([pairs, pairs held, experts touched, busiest expert's pairs, rows
+        moved, weight passes], summed over layers and ticks): nothing to
+        a ledger without the hybrid family."""
+        if routed is not None:
+            self._count(**dict(zip(self.HYBRID_KEYS[2:], routed)))
 
     def scan_least_bytes(self, param_bytes: int, read_bytes: int,
                          depth: int, routed=None) -> int:
-        """Bytes `depth` decode ticks cannot avoid reading: every
-        parameter and the rows' cells (`read_bytes`, a tick's), a tick."""
-        return depth * (int(param_bytes) + int(read_bytes))
+        """Bytes `depth` decode ticks cannot avoid reading: the rows'
+        cells and states (`read_bytes`, a tick's) and every parameter, a
+        tick; where the expert layers counted (`routed`), of the held
+        experts only the ones that received a pair that tick."""
+        if routed is None or self._experts is None:
+            return depth * (int(param_bytes) + int(read_bytes))
+        return int(depth * (int(param_bytes) - self._experts.bytes
+                            + int(read_bytes))
+                   + int(routed[2]) * self._slot_bytes)
 
     def _publish_census(self) -> dict:
         """Gauge + stats-dict surface of the dtype split: obs_dump's
@@ -300,17 +456,14 @@ class CapacityLedger:
         """Fold one host-bookkeeping snapshot (`committed` [B] counts,
         `req` [B] request-id-or-None) into the occupancy gauges; returns
         the stats dict (`/load`'s kv block)."""
-        used = 0
-        active = 0
-        for r in range(self._b):
-            if req[r] is not None:
-                active += 1
-                used += self.row_cells(int(committed[r]))
+        live = [committed[r] for r in range(self._b) if req[r] is not None]
+        active = len(live)
+        used = self._sum("held_cells", live)
         with self._lock:
             self._used_cells = used
             self._rows_active = active
-        used_bytes = used * self._cell_bytes
-        waste = 1.0 - used / float(self._b * self._cells)
+        used_bytes = self._sum("held_bytes", live)
+        waste = 1.0 - used_bytes / self._slab_bytes
         g = self._reg.gauge
         g("kv/allocated_bytes").set(self._slab_bytes)
         g("kv/used_bytes").set(used_bytes)
@@ -346,6 +499,9 @@ class CapacityLedger:
                 self._bucket_alloc.get(bucket, 0) + bucket)
             self._bucket_waste[bucket] = (
                 self._bucket_waste.get(bucket, 0) + waste)
+        self._count(gdn_chunks=sum(
+            k * -(-bucket // s.chunk) for s, k in self._states
+            if s.kind == "state" and s.chunk))
         c = self._reg.counter
         c("kv/pad_alloc_tokens").incr(bucket)
         if waste:
@@ -368,443 +524,6 @@ class CapacityLedger:
                     for b in sorted(self._bucket_alloc)
                 },
             }
-
-
-class EvaCapacityLedger(CapacityLedger):
-    """Occupancy of the attention='eva' layout (models/transformer.py
-    `_eva_attention`): a row's slab is one window buffer of W positions
-    and a table of one summary per chunk of C positions, and a cell of
-    either kind is the same bytes (a key and a value of every head, in
-    every layer), so both count in one unit. A row that has committed
-    `n` tokens holds n mod W live window cells (the window is handed
-    over at every multiple of W) and n // C summaries; a decode tick
-    attends to the live window cells and to the summaries of the windows
-    already closed, (n // W) W / C of them: the summaries of the window
-    in progress are written and not yet read.
-
-    `counters` (EVA_KEYS): chunk summaries written (prefill and decode,
-    one per chunk and row, not per layer), windows handed over in
-    decode, and per scan, depth x what its active rows attend to at the
-    scan's start: live window positions and visible summaries."""
-
-    EVA_KEYS = ("eva_summaries_written", "eva_window_turns",
-                "eva_window_cells_read", "eva_summary_cells_read")
-
-    def __init__(self, batch_size: int, window_cells: int,
-                 summary_cells: int, slab_bytes: int, window: int,
-                 chunk: int, registry: Optional[metrics.Registry] = None,
-                 census: Optional[dict] = None):
-        super().__init__(batch_size, window_cells + summary_cells,
-                         slab_bytes, registry=registry, census=census)
-        self._window = int(window)
-        self._chunk = int(chunk)
-        self._counters = dict.fromkeys(self.EVA_KEYS, 0)
-
-    @classmethod
-    def of_model(cls, cache, batch_size: int, model,
-                 registry: Optional[metrics.Registry] = None
-                 ) -> "EvaCapacityLedger":
-        """From a freshly-initialized batch cache of `model`: the two
-        tables' lengths are read off one layer's leaves, window and
-        chunk off the model's fields."""
-        import jax
-
-        lengths = {str(getattr(path[-1], "key", path[-1])): leaf.shape[1]
-                   for path, leaf in jax.tree_util.tree_leaves_with_path(
-                       cache) if leaf.ndim > 1}
-        return cls(batch_size, lengths["eva_window_key"],
-                   lengths["eva_summary_key"], kv_slab_bytes(cache),
-                   model.eva_window, model.eva_chunk, registry=registry,
-                   census=kv_dtype_census(cache))
-
-    def attended(self, n: int) -> tuple:
-        """(live window cells, visible summaries) of a row at `n`."""
-        n = int(n)
-        return (n % self._window,
-                n // self._window * (self._window // self._chunk))
-
-    def row_cells(self, n: int) -> int:
-        return int(n) % self._window + int(n) // self._chunk
-
-    def read_cells(self, n: int) -> int:
-        return sum(self.attended(n))
-
-    @property
-    def counters(self) -> dict:
-        with self._lock:
-            return dict(self._counters)
-
-    def note_commit(self, before: int, after: int,
-                    decoding: bool = True) -> None:
-        """The chunk summaries that completed, and when `decoding` the
-        windows that were handed over (a prefill keeps the window its
-        true length ends in and hands none over)."""
-        with self._lock:
-            self._counters["eva_summaries_written"] += (
-                after // self._chunk - before // self._chunk)
-            if decoding:
-                self._counters["eva_window_turns"] += (
-                    after // self._window - before // self._window)
-
-    def note_scan(self, committed, depth: int) -> None:
-        local = remote = 0
-        for n in committed:
-            live, visible = self.attended(n)
-            local += live
-            remote += visible
-        with self._lock:
-            self._counters["eva_window_cells_read"] += depth * local
-            self._counters["eva_summary_cells_read"] += depth * remote
-
-
-class HybridCapacityLedger(CapacityLedger):
-    """Occupancy of a cache in which some layers keep a running state per
-    row (models/transformer.py `Mamba2Mixer`: `ssm_state`, `conv_tail`;
-    `GatedDeltaMixer`: `delta_state`, `conv_tail`; the same bytes whatever
-    the row's length) beside layers that keep a
-    K/V cell per position, and the account of expert layers that route
-    without a capacity (models/moe.py).
-
-    The unit is one position's K/V over the attention layers; a row's
-    state counts as the `state_cells` such cells its bytes come to, live
-    from admission on. A decode tick reads every committed K/V cell and
-    reads and writes the state: `read_cells(n)` = n + 2 x state cells.
-    Of the parameters a tick cannot avoid those outside the experts and,
-    of the held experts, the ones that received a pair that tick
-    (`scan_least_bytes`, from the device's own count).
-
-    `counters` (HYBRID_KEYS): per scan, depth x (twice the active rows'
-    state bytes; their committed K/V cells), and what the expert layers
-    counted of real tokens in prefill waves and scans alike, summed over
-    layers and ticks: pairs routed, pairs whose expert is held, held
-    experts with at least one pair, the busiest held expert's pairs; and,
-    of every token they were handed, the rows of the hidden width they
-    copied into sorted order and fetched back from it, and their passes
-    over the held experts' weights (a call's blocks: one a layer for a
-    tick, more for a wave longer than `moe.token_block` gives a block)."""
-
-    HYBRID_KEYS = ("ssm_state_bytes_touched", "kv_cells_read", "moe_pairs",
-                   "moe_pairs_held", "moe_experts_touched",
-                   "moe_pairs_busiest", "moe_rows_moved",
-                   "moe_weight_passes")
-    _STATE_LEAVES = ("ssm_state", "delta_state", "conv_tail")
-
-    def __init__(self, batch_size: int, positions: int, slab_bytes: int,
-                 state_row_bytes: int, expert_bytes: int,
-                 expert_slots: int,
-                 registry: Optional[metrics.Registry] = None,
-                 census: Optional[dict] = None):
-        state_total = int(state_row_bytes) * int(batch_size)
-        #: all position-indexed layers' bytes of one position of one row
-        per_position = self._position_bytes = (
-            int(slab_bytes) - state_total) / float(batch_size * positions)
-        # no attention layer: the one cell of a row is its state
-        self._state_cells = (int(round(state_row_bytes / per_position))
-                             if per_position else 1)
-        self._positions = int(positions) if per_position else 0
-        super().__init__(batch_size, self._state_cells + self._positions,
-                         slab_bytes, registry=registry, census=census)
-        self._state_row_bytes = int(state_row_bytes)
-        self._expert_bytes = int(expert_bytes)
-        #: bytes of one expert of one layer
-        self._slot_bytes = (int(expert_bytes) / expert_slots
-                            if expert_slots else 0.0)
-        self._counters = dict.fromkeys(self.HYBRID_KEYS, 0)
-
-    @classmethod
-    def of_model(cls, cache, batch_size: int, positions: int, params,
-                 registry: Optional[metrics.Registry] = None
-                 ) -> "HybridCapacityLedger":
-        """From a freshly-initialized batch cache and the served
-        parameters: the state's bytes are those of the leaves named
-        `ssm_state` / `delta_state` / `conv_tail`, the experts' those of
-        the leaves
-        `experts_*` (their first axis counts the experts held)."""
-        return cls(batch_size, positions, kv_slab_bytes(cache),
-                   *cls._state_and_experts(cache, batch_size, params),
-                   registry=registry, census=kv_dtype_census(cache))
-
-    @classmethod
-    def _state_and_experts(cls, cache, batch_size: int, params) -> tuple:
-        """(a row's state bytes, the experts' bytes, how many (layer,
-        expert) slots they are) off the leaves' names, as `of_model`
-        says."""
-        import jax
-
-        name = lambda path: str(getattr(path[-1], "key", path[-1]))
-        state = sum(int(leaf.nbytes) for path, leaf in
-                    jax.tree_util.tree_leaves_with_path(cache)
-                    if name(path) in cls._STATE_LEAVES)
-        experts = [(leaf.shape[0], int(leaf.size) * leaf.dtype.itemsize)
-                   for path, leaf in
-                   jax.tree_util.tree_leaves_with_path(params or {})
-                   if name(path).startswith("experts_")]
-        layers = sum(1 for path, _ in
-                     jax.tree_util.tree_leaves_with_path(params or {})
-                     if name(path) == "experts_fc1")
-        held = experts[0][0] if experts else 0
-        return (state // batch_size, sum(b for _, b in experts),
-                layers * held)
-
-    def row_cells(self, n: int) -> int:
-        return self._state_cells + (int(n) if self._positions else 0)
-
-    def read_cells(self, n: int) -> int:
-        return 2 * self._state_cells + (int(n) if self._positions else 0)
-
-    @property
-    def counters(self) -> dict:
-        with self._lock:
-            return dict(self._counters)
-
-    def note_scan(self, committed, depth: int) -> None:
-        rows = len(committed)
-        cells = sum(self.row_cells(n) - self._state_cells for n in committed)
-        with self._lock:
-            self._counters["ssm_state_bytes_touched"] += (
-                depth * 2 * rows * self._state_row_bytes)
-            self._counters["kv_cells_read"] += depth * cells
-
-    def note_routed(self, routed) -> None:
-        if routed is None:
-            return
-        with self._lock:
-            for key, n in zip(self.HYBRID_KEYS[2:], routed):
-                self._counters[key] += int(n)
-
-    def scan_least_bytes(self, param_bytes: int, read_bytes: int,
-                         depth: int, routed=None) -> int:
-        if routed is None:
-            return super().scan_least_bytes(param_bytes, read_bytes, depth)
-        return int(depth * (int(param_bytes) - self._expert_bytes
-                            + int(read_bytes))
-                   + int(routed[2]) * self._slot_bytes)
-
-
-class DeltaCapacityLedger(HybridCapacityLedger):
-    """Occupancy of a cache in which delta-rule layers keep one matrix per
-    value head and a convolution tail a row (models/transformer.py
-    `GatedDeltaMixer`: `delta_state` [rows, Hv, K, V] float32,
-    `conv_tail`) beside attention layers that keep a K/V cell per
-    position; the cells, the state, the tick's least bytes and the expert
-    layers are the parent's account.
-
-    `counters` adds GDN_KEYS to the parent's. Summed over a scan's ticks,
-    what its active rows HOLD: their state (`gdn_state_bytes`: depth x
-    rows x a row's state and tails) and their committed K/V cells
-    (`kv_cell_bytes`: depth x cells x a cell's bytes), so that the one
-    over the sum of both is the state's share of the live cache over the
-    window. What the two forms of the rule worked: `gdn_steps`, a scan's
-    depth x its active rows x the delta-rule layers (row-steps of the
-    one-step form); `gdn_chunks`, per admitted request its bucket's
-    chunks x those layers (systems solved by the chunked form; a wave's
-    ladder padding repeats a row and is not counted). And the (query,
-    cell) pairs the prefills attended causally in the attention layers at
-    the rows' TRUE lengths, n (n + 1) / 2 a layer (`kv_pairs_prefilled`)."""
-
-    GDN_KEYS = ("gdn_state_bytes", "kv_cell_bytes", "gdn_steps",
-                "gdn_chunks", "kv_pairs_prefilled")
-
-    def __init__(self, batch_size: int, positions: int, slab_bytes: int,
-                 state_row_bytes: int, expert_bytes: int, expert_slots: int,
-                 delta_layers: int, kv_layers: int, chunk: int,
-                 registry: Optional[metrics.Registry] = None,
-                 census: Optional[dict] = None):
-        super().__init__(batch_size, positions, slab_bytes, state_row_bytes,
-                         expert_bytes, expert_slots, registry=registry,
-                         census=census)
-        self._delta_layers = int(delta_layers)
-        self._kv_layers = int(kv_layers)
-        self._chunk = int(chunk)
-        self._counters.update(dict.fromkeys(self.GDN_KEYS, 0))
-
-    @classmethod
-    def of_model(cls, cache, batch_size: int, positions: int, params,
-                 chunk: int = 64,
-                 registry: Optional[metrics.Registry] = None
-                 ) -> "DeltaCapacityLedger":
-        """From a freshly-initialized batch cache and the served
-        parameters: every `delta_state` leaf is one delta-rule layer,
-        every `cached_key` leaf one attention layer, `chunk` the
-        positions of one triangular system; the rest as the parent reads
-        it."""
-        return cls(batch_size, positions, kv_slab_bytes(cache),
-                   *cls._state_and_experts(cache, batch_size, params),
-                   _leaves_named(cache, "delta_state"),
-                   len(_kv_layer_cells(cache)), chunk,
-                   registry=registry, census=kv_dtype_census(cache))
-
-    def note_admission(self, kind: str, bucket: int, used_tokens: int
-                       ) -> None:
-        super().note_admission(kind, bucket, used_tokens)
-        with self._lock:
-            self._counters["gdn_chunks"] += (
-                self._delta_layers * -(-int(bucket) // self._chunk))
-
-    def note_commit(self, before: int, after: int,
-                    decoding: bool = True) -> None:
-        if decoding:
-            return
-        with self._lock:
-            self._counters["kv_pairs_prefilled"] += (
-                self._kv_layers * (int(after) * (int(after) + 1)
-                                   - int(before) * (int(before) + 1)) // 2)
-
-    def note_scan(self, committed, depth: int) -> None:
-        super().note_scan(committed, depth)
-        rows = len(committed)
-        with self._lock:
-            self._counters["gdn_state_bytes"] += (
-                depth * rows * self._state_row_bytes)
-            self._counters["kv_cell_bytes"] += int(
-                depth * self._position_bytes
-                * sum(int(n) for n in committed))
-            self._counters["gdn_steps"] += depth * rows * self._delta_layers
-
-
-class RingCapacityLedger(HybridCapacityLedger):
-    """Occupancy of a cache whose window layers keep a ring of `window`
-    cells a row (models/transformer.py `_rolling_attention`: slot =
-    position mod window) beside the slabs of the layers without a window.
-
-    The unit is ONE layer's K and V of one position. A row that has
-    committed `n` tokens holds, and a decode tick reads, n cells in each
-    layer without a window and min(n, ring) in each window layer: which
-    layers are which, and each ring's length, are read off the cache's own
-    leaves (`of_model`). State-space state beside them and expert layers
-    routed without a capacity are the parent's account.
-
-    `counters` adds RING_KEYS to the parent's: per scan, depth x the cells
-    its active rows hold at its start, summed over the layers without a
-    window (`kv_full_cells_read`) and over the window layers
-    (`kv_window_cells_read`; the two make up `kv_cells_read`), and the
-    rows of a scan whose next write lands on a cell one window back: whose
-    shortest ring has turned (`kv_window_wraps`)."""
-
-    RING_KEYS = ("kv_full_cells_read", "kv_window_cells_read",
-                 "kv_window_wraps")
-
-    def __init__(self, batch_size: int, positions: int, layer_cells,
-                 slab_bytes: int, state_row_bytes: int = 0,
-                 expert_bytes: int = 0, expert_slots: int = 0,
-                 registry: Optional[metrics.Registry] = None,
-                 census: Optional[dict] = None):
-        layer_cells = [int(n) for n in layer_cells]
-        self._full_layers = sum(n >= positions for n in layer_cells)
-        self._rings = tuple(n for n in layer_cells if n < positions)
-        super().__init__(batch_size, sum(layer_cells), slab_bytes,
-                         state_row_bytes, expert_bytes, expert_slots,
-                         registry=registry, census=census)
-        self._counters.update(dict.fromkeys(self.RING_KEYS, 0))
-
-    @classmethod
-    def of_model(cls, cache, batch_size: int, positions: int, params,
-                 registry: Optional[metrics.Registry] = None
-                 ) -> "RingCapacityLedger":
-        """From a freshly-initialized batch cache and the served
-        parameters: every `cached_key` leaf gives one layer's cells a
-        row (`positions` of them in a layer without a window), the rest
-        as the parent reads it."""
-        return cls(batch_size, positions, _kv_layer_cells(cache),
-                   kv_slab_bytes(cache),
-                   *cls._state_and_experts(cache, batch_size, params),
-                   registry=registry, census=kv_dtype_census(cache))
-
-    def _kv_cells(self, n: int) -> tuple:
-        """(cells of the layers without a window, cells of the window
-        layers) a row holds once it has committed `n` tokens."""
-        return (self._full_layers * int(n),
-                sum(min(int(n), ring) for ring in self._rings))
-
-    def row_cells(self, n: int) -> int:
-        return self._state_cells + sum(self._kv_cells(n))
-
-    def read_cells(self, n: int) -> int:
-        return 2 * self._state_cells + sum(self._kv_cells(n))
-
-    def note_scan(self, committed, depth: int) -> None:
-        super().note_scan(committed, depth)
-        full = window = wraps = 0
-        for n in committed:
-            a, b = self._kv_cells(n)
-            full, window = full + a, window + b
-            wraps += int(n) >= min(self._rings, default=n + 1)
-        with self._lock:
-            self._counters["kv_full_cells_read"] += depth * full
-            self._counters["kv_window_cells_read"] += depth * window
-            self._counters["kv_window_wraps"] += wraps
-
-
-class LatentCapacityLedger(HybridCapacityLedger):
-    """Occupancy of a cache whose attention layers keep ONE LATENT CELL per
-    position (models/transformer.py `LatentAttention`: `cached_latent`
-    [rows, positions, latent] and `cached_rope_key` [rows, positions,
-    rope], the latent and the one rotary key, no head axis and no value
-    leaf): a cell per position as in a K/V
-    slab, with a size of its own (1,152 B at 512 + 64 values in bfloat16,
-    where 64 heads of K and V are 40,960).
-
-    The unit is one layer's cell of one position. A row that has committed
-    `n` tokens holds, and a decode tick reads, n cells in each such layer.
-    Expert layers routed without a capacity (and state-space state, where
-    a model had both) are the parent's account, so `scan_least_bytes`
-    counts the parameters outside the experts, the experts touched among
-    those held and the committed cells at their own size.
-
-    `counters` adds LATENT_KEYS to the parent's, all from the rows' TRUE
-    lengths, never `max_len`: cells written by prefills and decode ticks
-    (`latent_cells_committed`), per scan depth x the committed cells of
-    its active rows (`latent_cells_read`), and the (query, cell) pairs the
-    prefills attended causally, n (n + 1) / 2 a layer for a prompt of n
-    (`latent_pairs_prefilled`)."""
-
-    LATENT_KEYS = ("latent_cells_committed", "latent_cells_read",
-                   "latent_pairs_prefilled")
-
-    def __init__(self, batch_size: int, positions: int, layers: int,
-                 slab_bytes: int, state_row_bytes: int = 0,
-                 expert_bytes: int = 0, expert_slots: int = 0,
-                 registry: Optional[metrics.Registry] = None,
-                 census: Optional[dict] = None):
-        self._layers = int(layers)
-        super().__init__(batch_size, int(positions) * self._layers,
-                         slab_bytes, state_row_bytes, expert_bytes,
-                         expert_slots, registry=registry, census=census)
-        self._counters.update(dict.fromkeys(self.LATENT_KEYS, 0))
-
-    @classmethod
-    def of_model(cls, cache, batch_size: int, positions: int, params,
-                 registry: Optional[metrics.Registry] = None
-                 ) -> "LatentCapacityLedger":
-        """From a freshly-initialized batch cache and the served
-        parameters: every `cached_latent` leaf is one layer, the rest as
-        the parent reads it."""
-        return cls(batch_size, positions, _latent_layers(cache),
-                   kv_slab_bytes(cache),
-                   *cls._state_and_experts(cache, batch_size, params),
-                   registry=registry, census=kv_dtype_census(cache))
-
-    def row_cells(self, n: int) -> int:
-        return self._state_cells + self._layers * int(n)
-
-    def read_cells(self, n: int) -> int:
-        return 2 * self._state_cells + self._layers * int(n)
-
-    def note_commit(self, before: int, after: int,
-                    decoding: bool = True) -> None:
-        with self._lock:
-            self._counters["latent_cells_committed"] += (
-                self._layers * (int(after) - int(before)))
-            if not decoding:
-                self._counters["latent_pairs_prefilled"] += (
-                    self._layers * (int(after) * (int(after) + 1)
-                                    - int(before) * (int(before) + 1)) // 2)
-
-    def note_scan(self, committed, depth: int) -> None:
-        super().note_scan(committed, depth)
-        with self._lock:
-            self._counters["latent_cells_read"] += (
-                depth * self._layers * sum(int(n) for n in committed))
 
 
 class PagedCapacityLedger(CapacityLedger):
@@ -850,6 +569,7 @@ class PagedCapacityLedger(CapacityLedger):
         # per-cell cost re-based on the POOL's geometry (the null block
         # included in the denominator: it is real allocated HBM)
         self._cell_bytes = pool_bytes / float(num_blocks * block)
+        self._states = ((_Slabs(self._cells, self._cell_bytes), 1),)
         self._snapshot = snapshot
 
     @property
@@ -940,13 +660,13 @@ class CapacityModel:
         rows_free = int(occ["rows_free"])
         if self.budget_bytes <= 0:
             rows = rows_free
-            tokens = rows_free * self._ledger.cells_per_row
+            tokens = rows_free * self._ledger.positions
         else:
             spare = self.budget_bytes - float(occ["used_bytes"])
             rows = min(rows_free,
                        max(0, int(spare // self._ledger.row_bytes)))
-            tokens = min(rows_free * self._ledger.cells_per_row,
-                         max(0, int(spare // self._ledger.cell_bytes)))
+            tokens = min(rows_free * self._ledger.positions,
+                         max(0, int(spare // self._ledger.token_bytes)))
         g = self._reg.gauge
         g("kv/headroom_rows").set(rows)
         g("kv/headroom_tokens").set(tokens)

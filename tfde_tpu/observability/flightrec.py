@@ -23,7 +23,10 @@ Design points:
   silently skipped elsewhere, mirroring the preemption guard.
 - Dumps are atomic (tmp file + `os.replace`) and idempotent: the latest
   dump wins, so a SIGTERM dump followed by the excepthook firing does not
-  interleave partial files.
+  interleave partial files. A `model_dir` that is a URL (gs://,
+  memory://) is reached through utils/fs like every other side file,
+  where a write replaces the object whole; it is never taken for a local
+  path.
 - `load(path)` is the inverse — the replay surface tests and obs_dump use.
 """
 
@@ -38,6 +41,8 @@ import sys
 import threading
 import time
 from typing import Dict, List, Optional
+
+from tfde_tpu.utils import fs
 
 log = logging.getLogger(__name__)
 
@@ -98,7 +103,7 @@ class FlightRecorder:
         """Fix the dump directory to `<model_dir>/debug` and (once) install
         the SIGTERM + excepthook death hooks. Re-arming with a new
         model_dir just moves the dump target."""
-        self._dump_dir = os.path.join(model_dir, "debug")
+        self._dump_dir = fs.join(model_dir, "debug")
         self.record("armed", model_dir=model_dir, host=_host_id(),
                     pid=os.getpid())
         if install_handlers and not self._hooks_installed:
@@ -145,7 +150,7 @@ class FlightRecorder:
     def dump_path(self) -> Optional[str]:
         if self._dump_dir is None:
             return None
-        return os.path.join(
+        return fs.join(
             self._dump_dir, f"flight_{_host_id()}_{os.getpid()}.jsonl"
         )
 
@@ -159,17 +164,20 @@ class FlightRecorder:
             log.debug("flight recorder dump(%s): not armed; skipping", reason)
             return None
         self.record("dump", reason=reason)
-        events = self.events()
+        body = "".join(json.dumps(ev, sort_keys=True, default=repr) + "\n"
+                       for ev in self.events())
         try:
-            os.makedirs(os.path.dirname(path), exist_ok=True)
-            tmp = f"{path}.tmp.{os.getpid()}"
-            with open(tmp, "w") as f:
-                for ev in events:
-                    f.write(json.dumps(ev, sort_keys=True, default=repr) + "\n")
-                f.flush()
-                os.fsync(f.fileno())
-            os.replace(tmp, path)
-        except OSError:
+            fs.makedirs(self._dump_dir)
+            if fs.is_remote(path):
+                fs.write_bytes(path, body.encode())
+            else:
+                tmp = f"{path}.tmp.{os.getpid()}"
+                with open(tmp, "w") as f:
+                    f.write(body)
+                    f.flush()
+                    os.fsync(f.fileno())
+                os.replace(tmp, path)
+        except Exception:  # noqa: BLE001 — a remote filesystem's own errors
             log.exception("flight recorder dump to %s failed", path)
             return None
         self.last_dump_path = path
@@ -181,7 +189,7 @@ def load(path: str) -> List[dict]:
     inverse of `dump`). Tolerates a truncated final line — the one case a
     dying process can leave behind."""
     events: List[dict] = []
-    with open(path) as f:
+    with fs.fs_open(path, "r") as f:
         for line in f:
             line = line.strip()
             if not line:

@@ -126,6 +126,10 @@ LOCKED_CLASSES: Dict[Tuple[str, str], LockSpec] = {
     # (/load's kv block) and test threads, so each carries its own lock
     ("tfde_tpu/observability/capacity.py", "CapacityLedger"): LockSpec(
         lock="_lock",
+        # the counters of every kind of layer live in this one class
+        # (`_count` adds, `counters` copies): no access outside the lock,
+        # reads included
+        guarded_attrs=("_counters",),
     ),
     ("tfde_tpu/observability/capacity.py", "UsageMeter"): LockSpec(
         lock="_lock",
